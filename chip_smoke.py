@@ -1,0 +1,464 @@
+"""Does the system still start on the chip?  ``python chip_smoke.py``
+
+One process drives the two hot paths through the entry points a user calls,
+at the full width of ``models.transformer_lm()`` (vocab 32000, units 768,
+hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
+
+- *device*    what jax sees, the versions, where the compile cache is;
+- *kernels*   the Pallas kernels compiled by Mosaic (not interpreted) and
+              compared on the chip with the repo's XLA references at
+              production shapes;
+- *train*     ``parallel.ShardedTrainer`` on a one-device mesh, 4 x 2048
+              tokens, bf16 compute, default attention dispatch, 5 steps;
+- *serve*     ``DecodeEngine`` -> ``DecodeScheduler`` -> ``ServeServer``,
+              concurrent ``ServeClient.generate()`` streams checked token
+              for token against the dense reference run on the chip;
+- *multichip* with >= 4 chips: the same trainer on dp2 x tp2, then on
+              dp2 x sp2 at seq 4096 (ring attention, the Pallas kernel
+              inside shard_map). Otherwise reported ``not_run``.
+
+It takes no arguments and reads no variables of its own. It exits non-zero
+unless jax's backend is ``tpu``; no phase's failure is caught, so the first
+one ends the run with a traceback. A passing run ends with two lines:
+``summary {...}`` (per-phase status and set-up seconds, the attention path
+each phase ran, the compile cache, ``"claim": null``), then, last, exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`` as
+jax reports the device. It measures set-up time per phase and no rate: what
+the program costs on the chip is the benchmark's to say.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+
+VOCAB = 32000          # transformer_lm()'s default width; depth is its 12 too
+TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 4, 2048, 5
+SLOTS, PAGE = 8, 16
+BUCKETS = [128, 512]
+PROMPT_LENS = [5, 100, 200, 400]   # one bucket-128 pair, one bucket-512 pair
+NEW_TOKENS = 32
+_MOSAIC = "tpu_custom_call"        # what a compiled Pallas kernel lowers to
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = [0]   # programs built (compiled or read from the cache) so far
+
+
+def _count_compile(event, _secs, **_kw):
+    if event == _COMPILE_EVENT:
+        _compiles[0] += 1
+
+
+class _Phase:
+    """Prints ``<name> PASS <seconds>s`` when its block ends without an
+    exception, and records both for the summary. Never swallows one."""
+
+    results: dict = {}
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.t0 = time.monotonic()
+        print(f"== {self.name}", flush=True)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        secs = round(time.monotonic() - self.t0, 1)
+        if exc_type is None:
+            _Phase.results[self.name] = {"status": "PASS", "seconds": secs}
+            print(f"== {self.name} PASS {secs}s", flush=True)
+        else:
+            print(f"== {self.name} FAIL {secs}s", flush=True)
+        return False
+
+
+def _require(ok, message):
+    """A check that holds under ``python -O`` too, unlike ``assert``."""
+    if not ok:
+        raise AssertionError(message)
+
+
+def _rel_err(got, ref):
+    """max|got - ref| / max|ref| in f32 — one number per comparison."""
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)), 1e-30))
+
+
+def _check_close(what, got, ref, tol):
+    _require(np.all(np.isfinite(np.asarray(got, np.float32))),
+             f"{what}: non-finite values")
+    err = _rel_err(got, ref)
+    print(f"   {what}: rel err {err:.2e} (tol {tol:.0e})", flush=True)
+    _require(err <= tol, f"{what}: rel err {err:.3e} > {tol:.0e}")
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+def phase_device():
+    import jax
+    import jaxlib
+    import libtpu
+
+    import mxnet_tpu  # noqa: F401  (places the compile cache)
+
+    with _Phase("device"):
+        backend = jax.default_backend()
+        _require(backend == "tpu",
+                 f"jax backend is {backend!r}, not 'tpu': this smoke only "
+                 "means something on the chip")
+        devs = jax.devices()
+        info = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                "count": len(devs)}
+        cache_dir = jax.config.jax_compilation_cache_dir
+        entries = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+        cache = {"dir": cache_dir, "from_env":
+                 bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")),
+                 "entries_at_start": entries, "warm": entries > 0}
+        print(f"   {info['platform']} / {info['kind']} x {info['count']}; "
+              f"jax {jax.__version__}, jaxlib {jaxlib.__version__}, "
+              f"libtpu {libtpu.__version__}", flush=True)
+        print(f"   compile cache: {cache_dir} "
+              f"({'JAX_COMPILATION_CACHE_DIR' if cache['from_env'] else 'checkout default'}), "
+              f"{entries} entries at start", flush=True)
+    return info, cache
+
+
+# ---------------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------------
+
+def _require_mosaic(jitted, *args):
+    _require(_MOSAIC in jitted.lower(*args).as_text(),
+             "kernel lowered without the Mosaic custom call")
+
+
+def phase_kernels():
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import plain_attention
+    from mxnet_tpu.ops.flash_attention import (_decode_attention_xla,
+                                               flash_attention,
+                                               flash_decode_attention)
+
+    with _Phase("kernels"):
+        # flash forward + gradients, the train phase's exact attention shape
+        b, h, s, d = TRAIN_BATCH, 12, TRAIN_SEQ, 64
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
+                   for kk in keys[:3])
+        w = jax.random.normal(keys[3], (b, h, s, d), jnp.float32)
+
+        def loss(attn, q, k, v):
+            out = attn(q, k, v, causal=True)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+
+        flash = jax.jit(jax.value_and_grad(
+            lambda *a: loss(flash_attention, *a), argnums=(0, 1, 2),
+            has_aux=True))
+        # the reference sees the same bf16 values, widened: its own
+        # rounding would otherwise be as large as the error under test
+        ref = jax.jit(jax.value_and_grad(
+            lambda *a: loss(plain_attention, *a), argnums=(0, 1, 2),
+            has_aux=True))
+        _require_mosaic(flash, q, k, v)
+        (_, out), grads = flash(q, k, v)
+        (_, out_ref), grads_ref = ref(*(x.astype(jnp.float32)
+                                        for x in (q, k, v)))
+        _check_close("flash fwd  (4,12,2048,64) bf16 causal", out, out_ref,
+                     2e-2)
+        for name, g, gr in zip(("dq", "dk", "dv"), grads, grads_ref):
+            _check_close(f"flash bwd {name}", g, gr, 4e-2)
+
+        # paged decode: the serve phase's pool geometry, ragged lengths
+        n_seq, max_pages = SLOTS, 2048 // PAGE
+        n_pages = n_seq * max_pages + 1
+        lengths = np.array([0, 1, 15, 16, 17, 1000, 2047, 2048], np.int32)
+        rng = np.random.RandomState(0)
+        table = (rng.permutation(n_pages - 1) + 1).astype(np.int32) \
+            .reshape(n_seq, max_pages)
+        live = lengths > 0   # a length-0 row is an idle slot: garbage out
+        decode = jax.jit(flash_decode_attention)
+        decode_ref = jax.jit(
+            lambda *a: _decode_attention_xla(*a, 1.0 / np.sqrt(d)))
+        for dtype, tol in ((jnp.float32, 1e-4), (jnp.bfloat16, 2e-2)):
+            kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
+            qd = jax.random.normal(kq, (n_seq, h, d), dtype)
+            kp = jax.random.normal(kk, (n_pages, PAGE, h, d), dtype)
+            vp = jax.random.normal(kv, (n_pages, PAGE, h, d), dtype)
+            _require_mosaic(decode, qd, kp, vp, table, lengths)
+            got = np.asarray(decode(qd, kp, vp, table, lengths), np.float32)
+            want = np.asarray(decode_ref(qd, kp, vp, table, lengths),
+                              np.float32)
+            _require(np.all(np.isfinite(got)), "decode kernel: non-finite")
+            _check_close(f"paged decode (8,12,64) x {max_pages} pages "
+                         f"{jnp.dtype(dtype).name}", got[live], want[live],
+                         tol)
+
+
+# ---------------------------------------------------------------------------
+# train (one chip, and the multichip meshes)
+# ---------------------------------------------------------------------------
+
+def _on_tpu(tree):
+    import jax
+
+    return all(dev.platform == "tpu"
+               for leaf in jax.tree_util.tree_leaves(tree)
+               for dev in leaf.devices())
+
+
+def run_trainer(mesh_axes, devices, seq, steps):
+    """``transformer_lm()`` at full width under ShardedTrainer on the given
+    mesh: ``steps`` steps on one fixed batch. Returns (trainer, losses,
+    attention path) after the invariants every mesh shares."""
+    import jax
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import bert_sharding_rules, transformer_lm
+
+    mx.random.seed(0)
+    net = transformer_lm(max_length=seq)
+    net.initialize()
+    trainer = par.ShardedTrainer(
+        net, mx.gluon.loss.SoftmaxCrossEntropyLoss(),
+        par.make_mesh(mesh_axes, devices=devices),
+        rules=bert_sharding_rules(), optimizer="adam",
+        optimizer_params={"learning_rate": 1e-4}, compute_dtype="bfloat16")
+    tokens = np.random.RandomState(0).randint(
+        0, VOCAB, (TRAIN_BATCH, seq + 1)).astype(np.int32)
+    x, y = nd.array(tokens[:, :-1]), nd.array(tokens[:, 1:])
+
+    losses = []
+    compiles_after_first = None
+    for _ in range(steps):
+        losses.append(float(trainer.step(x, y).asnumpy()))
+        if compiles_after_first is None:
+            compiles_after_first = _compiles[0]
+    trainer.block_until_ready()
+    print(f"   mesh {mesh_axes} seq {seq}: loss "
+          + " ".join(f"{l:.4f}" for l in losses), flush=True)
+    _require(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _require(trainer._step_fn._cache_size() == 1,
+             f"{trainer._step_fn._cache_size()} step programs, expected 1")
+    _require(_compiles[0] == compiles_after_first,
+             f"{_compiles[0] - compiles_after_first} program(s) built "
+             "after step 1")
+    _require(_on_tpu(trainer.param_vals) and _on_tpu(trainer.opt_state),
+             "a parameter or optimizer slot is not on a TPU device")
+
+    # which attention ran: read it off the step program itself
+    with par.mesh_scope(trainer.mesh):
+        text = trainer._step_fn.lower(
+            trainer.param_vals, trainer.opt_state, jnp.float32(1e-4),
+            jnp.float32(1), x._data, y._data).as_text()
+    kernel = "flash" if _MOSAIC in text else "plain"
+    path = f"ring+{kernel}" if mesh_axes.get("sp", 1) > 1 else kernel
+    print(f"   attention: {path}", flush=True)
+    return trainer, losses, path
+
+
+def phase_train():
+    import jax
+
+    with _Phase("train"):
+        _trainer, losses, path = run_trainer(
+            {"dp": 1}, jax.devices()[:1], TRAIN_SEQ, TRAIN_STEPS)
+        _require(losses[-1] < losses[0],
+                 f"loss did not fall over {TRAIN_STEPS} steps: {losses}")
+        _require(path == "flash",
+                 f"auto dispatch ran {path} attention at seq {TRAIN_SEQ}")
+    return losses, path
+
+
+def phase_multichip(one_chip_loss):
+    """dp2 x tp2, then dp2 x sp2 at seq 4096, on the first four chips."""
+    import jax
+
+    devs = jax.devices()
+    if len(devs) < 4:
+        _Phase.results["multichip"] = {"status": "not_run",
+                                       "devices": len(devs)}
+        print(f"== multichip not_run ({len(devs)} device(s), needs 4)",
+              flush=True)
+        return {}
+    paths = {}
+    with _Phase("multichip"):
+        trainer, losses, paths["dp2xtp2"] = run_trainer(
+            {"dp": 2, "tp": 2}, devs[:4], TRAIN_SEQ, 2)
+        holders = {d for v in trainer.param_vals.values()
+                   for d in v.devices()}
+        _require(len(holders) == 4,
+                 f"shards on {len(holders)} devices, not 4")
+        name = next(n for n in trainer.param_vals if n.endswith("qkv_weight"))
+        whole = trainer.param_vals[name]
+        shard = whole.addressable_shards[0].data
+        _require(shard.nbytes * 2 == whole.nbytes,
+                 f"{name}: {shard.nbytes} bytes per device of "
+                 f"{whole.nbytes} — not halved over tp")
+        print(f"   {name}: {whole.nbytes} bytes, {shard.nbytes} per device "
+              f"on {len(holders)} devices", flush=True)
+        _require(abs(losses[0] - one_chip_loss) <= 1e-2 * abs(one_chip_loss),
+                 f"step-1 loss {losses[0]} vs one chip {one_chip_loss}")
+
+        _t, _l, paths["dp2xsp2"] = run_trainer(
+            {"dp": 2, "sp": 2}, devs[:4], 4096, 2)
+        _require(paths["dp2xsp2"] == "ring+flash", paths["dp2xsp2"])
+    return paths
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+
+def dense_reference(cfg, params, prompts, new_tokens):
+    """Greedy tokens from ``lm_prefill`` + ``lm_decode_step`` over a dense
+    KV cache — no pages, no buckets, no scheduler — for all prompts as one
+    batch, on the same device and in the same f32 as the engine."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models.transformer import lm_decode_step, lm_prefill
+
+    n, longest = len(prompts), max(len(p) for p in prompts)
+    lens = np.array([len(p) for p in prompts], np.int32)
+    padded = np.zeros((n, longest), np.int32)
+    for i, p in enumerate(prompts):
+        padded[i, :len(p)] = p
+    params = jax.tree_util.tree_map(jnp.asarray, params)
+
+    @jax.jit
+    def prefill(params, tokens):
+        logits, k, v = lm_prefill(cfg, params, tokens)
+        pad = ((0, 0), (0, 0), (0, new_tokens), (0, 0), (0, 0))
+        first = jnp.argmax(logits[jnp.arange(n), lens - 1], axis=-1)
+        return first.astype(jnp.int32), (jnp.pad(k, pad), jnp.pad(v, pad))
+
+    @jax.jit
+    def step(params, tokens, kv, positions):
+        logits, kv = lm_decode_step(cfg, params, tokens, kv, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv
+
+    tok, kv = prefill(params, padded)
+    out = [np.asarray(tok)]
+    for t in range(new_tokens - 1):
+        tok, kv = step(params, tok, kv, jnp.asarray(lens + t))
+        out.append(np.asarray(tok))
+    return np.stack(out, axis=1).tolist()
+
+
+def phase_serve():
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    from mxnet_tpu.analysis import TraceLinter
+    from mxnet_tpu.models import transformer_lm
+    from mxnet_tpu.models.transformer import decode_config, decode_params
+    from mxnet_tpu.ops.flash_attention import decode_attention_impl
+    from mxnet_tpu.serve import (DecodeEngine, DecodeScheduler, ServeClient,
+                                 ServeServer)
+
+    with _Phase("serve"):
+        mx.random.seed(1)
+        lm = transformer_lm()
+        lm.initialize()
+        lm(nd.zeros((1, 8)))   # deferred shapes
+        max_len = decode_config(lm)["max_length"]
+        engine = DecodeEngine(
+            lm, slots=SLOTS, page_size=PAGE,
+            num_pages=SLOTS * max_len // PAGE + 1, prompt_buckets=BUCKETS)
+        engine.warmup()
+        attn = decode_attention_impl()
+        print(f"   decode_attn: {attn}", flush=True)
+
+        rng = np.random.RandomState(2)
+        prompts = [rng.randint(0, VOCAB, n).tolist() for n in PROMPT_LENS]
+        sched = DecodeScheduler(engine, max_new_tokens=NEW_TOKENS)
+        server = ServeServer(engine=None, decode=sched, port=0)
+        server.start()
+        got = [None] * len(prompts)
+
+        def stream(i):
+            with ServeClient("127.0.0.1", server.port) as client:
+                got[i] = list(client.generate(prompts[i],
+                                              max_new_tokens=NEW_TOKENS))
+
+        try:
+            threads = [threading.Thread(target=stream, args=(i,))
+                       for i in range(len(prompts))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(300)
+            _require(not any(t.is_alive() for t in threads),
+                     "a stream hung")
+        finally:
+            server.stop()
+
+        want = dense_reference(decode_config(lm), decode_params(lm), prompts,
+                               NEW_TOKENS)
+        for i, (g, w) in enumerate(zip(got, want)):
+            _require(g == w, f"stream {i} (prompt {PROMPT_LENS[i]}): engine "
+                             f"{g} != dense reference {w}")
+        print(f"   {len(prompts)} streams x {NEW_TOKENS} tokens equal the "
+              "dense reference", flush=True)
+
+        stats = engine.stats()
+        _require(stats["num_programs"] == len(BUCKETS) + 1,
+                 stats["programs"])
+        _require(TraceLinter().check_decode_engine(engine) == [],
+                 "check_decode_engine found retrace churn")
+        engine.pool.assert_baseline()   # pages leaked == 0
+        print(f"   {stats['num_programs']} programs for {len(BUCKETS)} "
+              f"buckets + 1 step; {stats['pool']['used']} pages held",
+              flush=True)
+        # the step program carries the path it was traced with
+        _require((attn == "pallas") == (_MOSAIC in _step_text(engine)),
+                 f"decode_attn says {attn} but the step program disagrees")
+    return attn
+
+
+def _step_text(engine):
+    z = np.zeros((engine.slots,), np.int32)
+    tables = np.zeros((engine.slots, engine.max_pages), np.int32)
+    return engine._step_jit.lower(
+        engine._params, engine.kv, z, z, tables, z, np.uint32(0),
+        np.zeros((engine.slots,), np.float32)).as_text()
+
+
+# ---------------------------------------------------------------------------
+
+def main():
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_count_compile)
+    t0 = time.monotonic()
+    device, cache = phase_device()
+    phase_kernels()
+    losses, train_attn = phase_train()
+    decode_attn = phase_serve()
+    multichip_attn = phase_multichip(losses[0])
+    print("summary " + json.dumps({
+        "phases": _Phase.results,
+        "attention": {"train": train_attn, "decode": decode_attn,
+                      **multichip_attn},
+        "compile_cache": cache,
+        "wall_seconds": round(time.monotonic() - t0, 1),
+        "claim": None,
+    }), flush=True)
+    # the last line is the driver's contract: these two keys and no others
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -25,7 +25,17 @@ __all__ = [
     "dtype_name",
     "capped_backoff",
     "configure_socket_keepalive",
+    "checkout_path",
 ]
+
+
+def checkout_path(name: str) -> str:
+    """``<checkout root>/<name>``: the fixed home of this checkout's caches
+    (``.jax_cache``, ``.mxnet_progcache`` — both git-ignored). Cache keys
+    embed their directory, so the path must not depend on the user, the
+    pid, or a temp name."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), name)
 
 
 def capped_backoff(attempt: int, base_interval: float,
@@ -48,7 +58,7 @@ def configure_socket_keepalive(sock, idle: int = 30, interval: int = 5,
 
     The ONE keepalive policy shared by the PS client, the serve client, and
     the elastic heartbeater: a peer that vanished without a FIN (SIGKILL'd
-    VM, dropped tunnel) is detected by the kernel after
+    VM, dropped link) is detected by the kernel after
     ``idle + interval*count`` seconds instead of whenever the OS default
     (often hours) gives up. The per-platform TCP_KEEP* constants are probed
     — missing ones just fall back to the system defaults; any OSError is

@@ -14,7 +14,7 @@ lookup when chaos is off:
   *i* as its ``MXNET_CHAOS_KILL``, so one env var SIGKILLs exactly one
   member of a fleet at a named point.
 - :mod:`mxnet_tpu.chaos.platform` — hang the guarded platform entry points
-  (``MXNET_CHAOS_TUNNEL_HANG``) the way a dead accelerator tunnel does, so
+  (``MXNET_CHAOS_PLATFORM_HANG``) the way a hung accelerator backend does, so
   every driver's bounded-exit + platform-error-artifact path is testable.
 - :mod:`mxnet_tpu.chaos.nan` — poison a named tensor with NaN at a counted
   occurrence of it entering an Executor forward (``MXNET_CHAOS_NAN``), so

@@ -9,6 +9,11 @@ Re-design of the reference ``python/mxnet/context.py`` + ``include/mxnet/base.h`
 - ``mx.gpu(i)`` **aliases to the accelerator** when no real GPU exists, so
   reference training scripts written against ``mx.gpu()`` run unmodified on a
   TPU pod (BASELINE.json north star).
+- An accelerator context never resolves to a CPU device behind the caller's
+  back. Only a process *configured* for CPU (``JAX_PLATFORMS=cpu``, or
+  ``jax.config.update("jax_platforms", "cpu")`` as tests/conftest.py does)
+  aliases ``mx.tpu(i)``/``mx.gpu(i)`` to its CPU devices, so CI runs device
+  scripts unchanged; anywhere else a missing accelerator raises.
 - There is no storage manager / stream pool here: PJRT owns device memory and
   XLA owns streams (reference L0 `src/storage/` is subsumed — SURVEY.md §2.1).
 """
@@ -70,7 +75,13 @@ class Context:
             devs = _cpu_devices()
         else:
             devs = _accel_devices()
-            if not devs:  # CPU-only process (CI): accelerator ctx falls back
+            if not devs:
+                if not _configured_for_cpu():
+                    raise RuntimeError(
+                        f"{self}: jax found no accelerator (backend "
+                        f"{jax.default_backend()!r}) and this process was "
+                        "not configured for CPU — set JAX_PLATFORMS=cpu to "
+                        "run accelerator contexts on the host on purpose")
                 devs = _cpu_devices()
         return devs[self.device_id % len(devs)]
 
@@ -95,7 +106,18 @@ class Context:
             # Resolved on first use, NOT at import: touching jax.devices()
             # at import time would initialize the XLA backend and break the
             # create-kvstore-before-arrays contract jax.distributed needs.
-            _DEFAULT = Context("tpu", 0) if _accel_devices() else Context("cpu", 0)
+            if _accel_devices():
+                _DEFAULT = Context("tpu", 0)
+            else:
+                if not _configured_for_cpu():
+                    import warnings
+
+                    warnings.warn(
+                        "mxnet_tpu: jax found no accelerator and fell back "
+                        f"to {jax.default_backend()!r}; the default context "
+                        "is cpu(0). Set JAX_PLATFORMS=cpu to say so on "
+                        "purpose.", RuntimeWarning, stacklevel=3)
+                _DEFAULT = Context("cpu", 0)
         return _DEFAULT
 
 
@@ -105,6 +127,12 @@ def _cpu_devices():
     if jax.default_backend() != "cpu":
         return jax.local_devices(backend="cpu")
     return jax.local_devices()
+
+
+def _configured_for_cpu() -> bool:
+    """Was this process told to run on the CPU (as opposed to jax failing
+    to take a chip and falling back to it)?"""
+    return jax.config.jax_platforms == "cpu"
 
 
 _ACCEL_CACHE: Optional[list] = None

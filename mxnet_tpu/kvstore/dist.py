@@ -166,16 +166,7 @@ class DistKVStore(KVStore):
                 "DMLC_PS_ROOT_PORT) — launch through tools/launch.py")
         # NB: can't guard with jax.process_count() — that call would itself
         # initialize the backend before distributed init.
-        try:
-            initialized = jax.distributed.is_initialized()
-        except AttributeError:  # older jax: fall back to the private state
-            try:
-                from jax._src import distributed as _jax_dist
-
-                initialized = _jax_dist.global_state.client is not None
-            except (ImportError, AttributeError):
-                initialized = False
-        if not initialized:
+        if not jax.distributed.is_initialized():
             jax.distributed.initialize(coordinator_address=coord,
                                        num_processes=self._num_workers,
                                        process_id=self._rank)

@@ -60,22 +60,30 @@ class MultiHeadAttention(HybridBlock):
 
         from .. import parallel as par
         from ..ndarray.ndarray import invoke_fn
+        from ..ops.attention import attention_impl, fused_attention
 
         mesh = par.current_mesh()
         sp = 1
         if mesh is not None:
             sp = dict(zip(mesh.axis_names, mesh.devices.shape)).get("sp", 1)
-
-        from ..ops.attention import fused_attention
-
-        if mesh is not None and sp > 1:
+        shape = (b, h, s, d)
+        # On a mesh the Pallas kernel has to sit inside shard_map (ring
+        # attention over sp; per-shard batch rows and heads otherwise):
+        # XLA partitions plain attention by itself but not a Mosaic call.
+        kernel_on_mesh = (
+            mesh is not None and mesh.size > 1
+            and attention_impl(shape, shape, mask is not None) == "flash")
+        if sp > 1 or kernel_on_mesh:
+            if mask is not None:
+                raise NotImplementedError(
+                    "sequence-parallel attention takes no explicit mask")
             out = invoke_fn(
                 lambda qq, kk, vv: par.sequence_sharded_attention(
                     qq, kk, vv, mesh, causal=self._causal),
                 [q, k, v])
         else:
-            # single-chip path: flash (Pallas) for long sequences, fused
-            # XLA softmax-attention otherwise — see ops/attention.py policy
+            # flash (Pallas) for long sequences, fused XLA
+            # softmax-attention otherwise — see ops/attention.py policy
             def attn(qq, kk, vv, mm=None):
                 return fused_attention(qq, kk, vv, mask=mm,
                                        causal=self._causal)

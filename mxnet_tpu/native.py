@@ -15,6 +15,8 @@ import os
 import subprocess
 import threading
 
+from .base import checkout_path
+
 __all__ = ["io_lib", "ps_server_binary", "native_dir", "build"]
 
 _lock = threading.Lock()
@@ -22,17 +24,17 @@ _cache: dict = {}
 
 
 def native_dir() -> str:
-    return os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                        "native")
+    return checkout_path("native")
 
 
 def _build_target(target: str) -> str | None:
     nd = native_dir()
     out = os.path.join(nd, "build", target)
-    if os.path.exists(out):
-        return out
     if os.environ.get("MXNET_NO_NATIVE_BUILD"):
-        return None
+        return out if os.path.exists(out) else None
+    # make decides, every time: an existing binary may predate the source
+    # beside it (a copied checkout carries build/ along), and an
+    # up-to-date one costs make a stat
     try:
         subprocess.run(["make", "-C", nd, os.path.join("build", target)],
                        check=True, capture_output=True, timeout=120)

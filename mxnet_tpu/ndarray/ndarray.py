@@ -633,7 +633,7 @@ def stack(*data, axis=0):
 
 def waitall():
     """Block until all launched work is done (reference MXNDArrayWaitAll)."""
-    (jax.effects_barrier if hasattr(jax, "effects_barrier") else lambda: None)()
+    jax.effects_barrier()
 
 
 # ---------------------------------------------------------------------------

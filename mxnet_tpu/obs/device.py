@@ -57,7 +57,8 @@ from . import trace as _trace
 
 __all__ = ["active", "capture", "analyze_compiled", "record", "cost_of",
            "costs", "attribute", "annotate_span", "roofline_class",
-           "set_peak", "get_peak", "live_bytes", "sample", "LeakDetector",
+           "set_peak", "get_peak", "nominal_peak", "DEVICE_PEAKS",
+           "live_bytes", "sample", "LeakDetector",
            "monitor", "reset"]
 
 # ---------------------------------------------------------------------------
@@ -80,15 +81,17 @@ def active() -> bool:
 # peaks (the MFU denominator and the roofline ceiling)
 # ---------------------------------------------------------------------------
 
-# Nominal single-chip numbers; PLACEHOLDERS for backends we can't name —
-# bench.py overwrites the flops peak with the slope-measured matmul rate
-# (the honest denominator), env vars override both. The tpu row mirrors
-# bench.py's NOMINAL_V5E_BF16_TFLOPS/NOMINAL_V5E_HBM_GBPS — keep in sync
-# (bench.py defers ALL framework imports for outage-proofing, so it
-# cannot import these).
-_DEFAULT_PEAKS = {"tpu": (197.0, 819.0),   # v5e bf16 TFLOPs, HBM GB/s
-                  "gpu": (312.0, 1555.0),  # A100-class placeholder
-                  "cpu": (0.2, 20.0)}      # placeholder; override to taste
+# Published single-chip peaks keyed by jax's ``device_kind``: (dense bf16
+# TFLOP/s, HBM GB/s). THE one table — bench.py imports it. A TPU kind that
+# is not listed raises where a peak is asked for: a default would put some
+# other chip's ceiling under this one's numbers.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197.0, 819.0),  # Google Cloud documentation, "TPU v5e"
+}
+# The CPU backend has no published peak; a fixed placeholder keeps the
+# attribution internally consistent there (never absolute, never a device
+# metric).
+_CPU_PLACEHOLDER_PEAK = (0.2, 20.0)
 _peak_override: list = [None, None]        # [tflops, gbps]
 
 
@@ -101,11 +104,24 @@ def set_peak(tflops: Optional[float] = None, gbps: Optional[float] = None):
         _peak_override[1] = float(gbps)
 
 
+def nominal_peak() -> Tuple[float, float]:
+    """The published (TFLOP/s, GB/s) of the device this process runs on."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return _CPU_PLACEHOLDER_PEAK
+    if dev.device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peak for device kind {dev.device_kind!r}: add "
+            "it, with its source, to obs.device.DEVICE_PEAKS")
+    return DEVICE_PEAKS[dev.device_kind]
+
+
 def get_peak() -> Tuple[float, float]:
     """(peak_tflops, peak_gbps): explicit ``set_peak`` wins, then the
-    ``MXNET_DEVICE_PEAK_TFLOPS``/``_GBPS`` env, then a per-backend nominal
-    default (a *placeholder* on CPU — the attribution is still internally
-    consistent, just not absolute)."""
+    ``MXNET_DEVICE_PEAK_TFLOPS``/``_GBPS`` env, then the device's published
+    peak (:func:`nominal_peak`)."""
     tflops, gbps = _peak_override
     if tflops is None:
         env = os.environ.get("MXNET_DEVICE_PEAK_TFLOPS")
@@ -114,13 +130,7 @@ def get_peak() -> Tuple[float, float]:
         env = os.environ.get("MXNET_DEVICE_PEAK_GBPS")
         gbps = float(env) if env else None
     if tflops is None or gbps is None:
-        try:
-            import jax
-
-            backend = jax.default_backend()
-        except Exception:  # lint-ok: peaks must never take down a caller
-            backend = "cpu"
-        dt, db = _DEFAULT_PEAKS.get(backend, _DEFAULT_PEAKS["cpu"])
+        dt, db = nominal_peak()
         tflops = dt if tflops is None else tflops
         gbps = db if gbps is None else gbps
     return tflops, gbps
@@ -150,9 +160,6 @@ def analyze_compiled(compiled) -> dict:
     cost: dict = {k: 0 for k in COST_FIELDS}
     try:
         ca = compiled.cost_analysis()
-        # jax returns a dict on some versions, a 1-elem list on others
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         if ca:
             cost["flops"] = int(ca.get("flops", 0) or 0)
             cost["bytes_accessed"] = int(ca.get("bytes accessed", 0) or 0)

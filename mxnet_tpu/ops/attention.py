@@ -10,7 +10,9 @@ bench_lm_long, TransformerLM bf16 train step, end-to-end): flash wins
   f32 accumulation — precision pinned DEFAULT, see flash_attention.py).
 - explicit masks: plain (the kernel handles causal only).
 
-``MXNET_ATTENTION_IMPL`` ∈ {auto, plain, flash} overrides.
+``MXNET_ATTENTION_IMPL`` ∈ {auto, plain, flash} overrides; ``flash`` where
+the kernel cannot run (an explicit mask, S_q != S_k, S not a multiple of
+8) raises instead of quietly running plain attention.
 """
 from __future__ import annotations
 
@@ -21,7 +23,7 @@ import jax.numpy as jnp
 
 from .flash_attention import flash_attention, flash_attention_with_lse
 
-__all__ = ["fused_attention", "plain_attention"]
+__all__ = ["fused_attention", "plain_attention", "attention_impl"]
 
 _FLASH_MIN_SEQ = 1024
 
@@ -41,23 +43,33 @@ def plain_attention(q, k, v, mask=None, causal=False, scale=None):
     return jnp.einsum("bhqk,bhkd->bhqd", w, v)
 
 
-def _flash_ok(q, k):
+def attention_impl(q_shape, k_shape, has_mask=False, impl=None) -> str:
+    """``"flash"`` or ``"plain"`` — the dispatch rule, from shapes alone so
+    callers that must know before they trace (the mesh wrapper in
+    parallel/ring_attention.py) ask the same question ``fused_attention``
+    does. ``impl``/``MXNET_ATTENTION_IMPL`` = ``flash`` where the kernel
+    cannot run raises."""
+    impl = impl or os.environ.get("MXNET_ATTENTION_IMPL", "auto")
     # block specs cover the full head dim, so only S needs tiling-friendly
     # factors (block sizes are shrunk to divide S; 8 is the sublane minimum)
-    s_q, s_k = q.shape[-2], k.shape[-2]
-    return s_q == s_k and s_q % 8 == 0 and q.ndim == 4
+    s_q, s_k = q_shape[-2], k_shape[-2]
+    can_flash = (not has_mask and len(q_shape) == 4 and s_q == s_k
+                 and s_q % 8 == 0)
+    if impl == "flash":
+        if not can_flash:
+            raise ValueError(
+                "impl='flash' cannot run here: the kernel takes no explicit "
+                "mask and needs 4-D q/k with equal sequence lengths that "
+                f"are a multiple of 8 (q {tuple(q_shape)}, k "
+                f"{tuple(k_shape)}, mask={'given' if has_mask else 'none'})")
+        return "flash"
+    if impl == "plain":
+        return "plain"
+    return "flash" if can_flash and s_q >= _FLASH_MIN_SEQ else "plain"
 
 
 def fused_attention(q, k, v, mask=None, causal=False, scale=None, impl=None):
     """The attention entry point for the model zoo (MultiHeadAttention)."""
-    impl = impl or os.environ.get("MXNET_ATTENTION_IMPL", "auto")
-    if impl == "flash":
-        use_flash = mask is None and _flash_ok(q, k)
-    elif impl == "plain":
-        use_flash = False
-    else:  # auto
-        use_flash = (mask is None and _flash_ok(q, k)
-                     and q.shape[-2] >= _FLASH_MIN_SEQ)
-    if use_flash:
+    if attention_impl(q.shape, k.shape, mask is not None, impl) == "flash":
         return flash_attention(q, k, v, causal=causal, scale=scale)
     return plain_attention(q, k, v, mask=mask, causal=causal, scale=scale)

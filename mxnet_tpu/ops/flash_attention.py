@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "decode_attention", "flash_decode_attention"]
+           "decode_attention", "decode_attention_impl",
+           "flash_decode_attention"]
 
 _NEG_INF = -1e30  # avoids -inf NaN propagation inside the kernel
 _LOG2E = math.log2(math.e)
@@ -165,27 +166,15 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the input's vma so the kernel composes with
     shard_map's check_vma (ring attention calls this inside shard_map)."""
-    try:
-        vma = jax.typeof(like).vma
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _match_vma(x, like):
     """Broadcast x's varying-manual-axes to like's so pallas_call composes
     with shard_map's check_vma."""
-    try:
-        vma = jax.typeof(like).vma
-        if vma and hasattr(lax, "pvary"):
-            missing = tuple(sorted(set(vma) - set(jax.typeof(x).vma)))
-            if missing:
-                return lax.pvary(x, missing)
-    except (AttributeError, TypeError):
-        pass
-    return x
+    missing = tuple(sorted(set(jax.typeof(like).vma)
+                           - set(jax.typeof(x).vma)))
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def _fwd_pallas(q, k, v, offset, scale, causal, block_q, block_k, interpret):
@@ -572,7 +561,6 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
     max_pages = page_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(d)
     s2_scale = scale * _LOG2E
-    prec = _dot_prec(q.dtype)
 
     def kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref):
         seq = pl.program_id(0)
@@ -589,34 +577,34 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
 
         @pl.when(j < n_live)
         def _block():
-            qv = q_ref[0]                                  # (H, D)
-            # (1, 0, 2) keeps the minor dim — Mosaic-friendly transpose
-            kt = jnp.transpose(k_ref[0], (1, 0, 2))        # (H, page, D)
-            vt = jnp.transpose(v_ref[0], (1, 0, 2))        # (H, page, D)
-            sc = lax.dot_general(                           # (H, page), log2
-                qv, kt, (((1,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-                precision=prec) * s2_scale
-            cols = j * page + lax.broadcasted_iota(jnp.int32, (h, page), 1)
-            sc = jnp.where(cols < length, sc, _NEG_INF)
-            m_prev = m_ref[:, 0]                                    # (H,)
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
+            # One query row per head is a mat-vec: no MXU shape fits it
+            # (Mosaic has no batched dot without an M dimension), and the
+            # step is bound by streaming the page, not by arithmetic. So
+            # the products run on the VPU in f32, in the pool's own
+            # (page, H, D) layout — heads on sublanes, D on lanes, per-head
+            # statistics as (H, 1) columns — with no transpose or relayout.
+            qv = q_ref[0].astype(jnp.float32)              # (H, D)
+            kb = k_ref[0].astype(jnp.float32)              # (page, H, D)
+            vb = v_ref[0].astype(jnp.float32)
+            sc = jnp.sum(kb * qv[None], axis=-1,           # (page, H, 1),
+                         keepdims=True) * s2_scale         # log2 domain
+            pos = j * page + lax.broadcasted_iota(jnp.int32, (page, h, 1), 0)
+            sc = jnp.where(pos < length, sc, _NEG_INF)
+            m_prev = m_ref[:, 0:1]                         # (H, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
             alpha = jnp.exp2(m_prev - m_new)
-            p = jnp.exp2(sc - m_new[:, None])
-            p = jnp.where(sc <= _NEG_INF / 2, 0.0, p)               # (H, page)
-            l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-            pv = lax.dot_general(                           # (H, D)
-                p.astype(vt.dtype), vt, (((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32, precision=prec)
-            o_ref[0] = o_ref[0] * alpha[:, None] + pv
-            m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+            p = jnp.exp2(sc - m_new[None])
+            p = jnp.where(sc <= _NEG_INF / 2, 0.0, p)      # (page, H, 1)
+            l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=0)
+            o_ref[0] = o_ref[0] * alpha + jnp.sum(p * vb, axis=0)
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
         @pl.when(j == max_pages - 1)
         def _norm():
             # length-0 rows (inactive slots) never accumulate: clamp keeps
             # their garbage finite instead of 0/0
-            o_ref[0] = o_ref[0] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+            o_ref[0] = o_ref[0] / jnp.maximum(l_ref[:, 0:1], 1e-30)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -644,6 +632,20 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
     return out.astype(q.dtype)
 
 
+def decode_attention_impl() -> str:
+    """The path :func:`decode_attention` takes in this process: ``pallas``
+    or ``xla``. ``MXNET_DECODE_ATTN`` names one outright; ``auto`` (the
+    default) is the Pallas kernel on a TPU backend and the XLA gather
+    everywhere else."""
+    impl = os.environ.get("MXNET_DECODE_ATTN", "auto")
+    if impl == "auto":
+        return "xla" if _use_interpret() else "pallas"
+    if impl not in ("pallas", "xla"):
+        raise ValueError(
+            f"MXNET_DECODE_ATTN={impl!r}: expected auto, pallas or xla")
+    return impl
+
+
 def decode_attention(q, k_pages, v_pages, page_table, lengths, scale=None):
     """Single-position attention against a paged KV cache.
 
@@ -652,15 +654,10 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, scale=None):
     (B, max_pages) int32 — page ids in position order (pad unused slots
     with any valid page, e.g. scratch page 0); lengths (B,) int32 —
     positions visible per sequence (0 = inactive row, output garbage).
-    Returns (B, H, D).
-
-    ``MXNET_DECODE_ATTN`` picks the path: ``auto`` (default — Pallas on
-    TPU, XLA elsewhere), ``xla``, or ``pallas``.
+    Returns (B, H, D). :func:`decode_attention_impl` picks the path.
     """
-    impl = os.environ.get("MXNET_DECODE_ATTN", "auto")
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    use_pallas = impl == "pallas" or (impl == "auto" and not _use_interpret())
-    if use_pallas:
+    if decode_attention_impl() == "pallas":
         return flash_decode_attention(q, k_pages, v_pages, page_table,
                                       lengths, scale=scale,
                                       interpret=_use_interpret())

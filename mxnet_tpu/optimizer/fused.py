@@ -609,8 +609,7 @@ class FusedUpdateEngine:
                                              key=pk)
         elif pc is not None:  # cache armed, cost capture vetoed: plain AOT
             compiled = _progcache.aot_compile(jitted, example)
-            cost = (_device.analyze_compiled(compiled)
-                    if compiled is not None else None)
+            cost = _device.analyze_compiled(compiled)
         if compiled is not None:
             if pc is not None:
                 pc.put(pk, compiled, meta=dict(cost or {}))

@@ -20,14 +20,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
-from ..ops.attention import plain_attention  # re-export (compat)
+from ..ops.attention import fused_attention, plain_attention  # noqa: F401
 from ..ops.flash_attention import flash_attention_with_lse
 
 __all__ = ["ring_attention", "sequence_sharded_attention", "plain_attention"]
@@ -131,30 +127,31 @@ def sequence_sharded_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     """Global-view attention sharded (B over dp, H over tp, S over sp).
 
     q,k,v: (B, H, S, D) global arrays (or tracers under an enclosing pjit).
-    Returns same-shaped output. Uses shard_map + ring rotation; degenerate
-    1-shard meshes reduce to plain attention.
+    Returns same-shaped output. Under ``shard_map`` each device attends its
+    own batch rows and heads: with an ``sp`` axis the K/V blocks rotate
+    around the ring; without one every shard holds whole sequences and runs
+    ``fused_attention`` on them. The wrap is what lets the Pallas kernel
+    run on a mesh at all — XLA cannot partition a Mosaic call by itself.
+    A batch or head count the axis does not divide stays replicated.
     """
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    if sizes.get(axis_name, 1) == 1:
-        return plain_attention(q, k, v, causal=causal, scale=scale)
-    b_ax = batch_axis if sizes.get(batch_axis, 1) > 1 else None
-    h_ax = head_axis if sizes.get(head_axis, 1) > 1 else None
-    spec = P(b_ax, h_ax, axis_name, None)
-    kwargs = {}
-    try:  # replication tracking can't see through pallas_call yet (jax
-        # suggests disabling it); the flag is check_rep up to jax 0.4.x
-        # and check_vma after the shard_map graduation — probe for either
-        import inspect
 
-        params = inspect.signature(shard_map).parameters
-        for flag in ("check_vma", "check_rep"):
-            if flag in params:
-                kwargs[flag] = False
-                break
-    except (ValueError, TypeError):
-        pass
-    fn = shard_map(partial(_ring_body, axis_name=axis_name, causal=causal,
-                           scale=scale, use_flash=use_flash),
-                   mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                   **kwargs)
+    def axis_for(name, dim):
+        n = sizes.get(name, 1)
+        return name if n > 1 and dim % n == 0 else None
+
+    b_ax = axis_for(batch_axis, q.shape[0])
+    h_ax = axis_for(head_axis, q.shape[1])
+    if sizes.get(axis_name, 1) == 1:
+        body = partial(fused_attention, causal=causal, scale=scale)
+        if b_ax is None and h_ax is None:
+            return body(q, k, v)
+        spec = P(b_ax, h_ax, None, None)
+    else:
+        body = partial(_ring_body, axis_name=axis_name, causal=causal,
+                       scale=scale, use_flash=use_flash)
+        spec = P(b_ax, h_ax, axis_name, None)
+    # check_vma=False: replication tracking cannot see through pallas_call
+    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec, check_vma=False)
     return fn(q, k, v)

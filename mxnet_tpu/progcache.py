@@ -23,10 +23,10 @@ once (``jit.lower().compile()``) and keys it in the device-plane
   compiled ``jax.stages.Compiled`` is exported via
   ``jax.experimental.serialize_executable`` (XLA's own executable
   serialization — the deserialized program is the *same machine code*, so
-  a cache hit is bitwise-identical to the compile it replaced). Backends
-  that refuse executable export degrade to jax's persistent
-  *compilation* cache (:func:`enable_jax_fallback_cache`) — slower than
-  a deserialize but still skips XLA optimization on re-compiles.
+  a cache hit is bitwise-identical to the compile it replaced). A
+  backend that refuses executable export still has jax's persistent
+  *compilation* cache under it (``mxnet_tpu/__init__.py`` places it) —
+  slower than a deserialize but it skips XLA optimization on re-compiles.
 - **Never a wrong program**: every entry embeds an environment
   fingerprint (backend platform + device kind + topology + jax/jaxlib
   versions + an ``mxnet_tpu`` source-tree content hash) checked before
@@ -38,7 +38,7 @@ once (``jit.lower().compile()``) and keys it in the device-plane
   rename, per-entry CRC32, keep-last-N GC (``MXNET_PROGCACHE_KEEP``).
 
 Activation: ``MXNET_PROGCACHE_DIR=<dir>`` (or ``MXNET_PROGCACHE=1`` with
-the default ``~/.cache/mxnet_tpu/progcache``) arms the process-global
+the default ``<checkout>/.mxnet_progcache``) arms the process-global
 cache; ``MXNET_PROGCACHE=0`` vetoes it even with a dir set. Serving
 artifacts can also ship their executables: ``serve.ship_programs`` writes
 an engine's compiled buckets into a ``programs/`` payload next to the
@@ -57,17 +57,18 @@ import threading
 import time
 from typing import Any, Dict, NamedTuple, Optional
 
+from .base import checkout_path
 from .checkpoint.atomic import atomic_write_bytes, crc32_bytes
 
 __all__ = ["ProgramKey", "ProgramCache", "CacheEntry", "program_key",
            "env_fingerprint", "code_fingerprint", "active", "cache",
-           "configure", "aot_compile", "serialize_compiled",
-           "enable_jax_fallback_cache", "default_dir", "reset"]
+           "configure", "aot_compile", "serialize_compiled", "default_dir",
+           "reset"]
 
 # entry format version — bump on any layout/semantic change so old caches
 # read as structured rejects, not parse errors
 _MAGIC = b"MXPROG1\n"
-_SCHEMA = 1
+_SCHEMA = 2  # 2: the payload records the program's device ids
 
 _TRUE = ("1", "true", "yes", "on")
 _FALSE = ("0", "false", "no", "off")
@@ -212,26 +213,32 @@ def env_fingerprint() -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 def aot_compile(jitted, args: tuple, kwargs: Optional[dict] = None):
-    """``jitted.lower(*args).compile()`` or None — the capture-free AOT
-    path for when the persistent cache is on but device-cost capture is
-    vetoed (the two switches stay independent)."""
-    try:
-        return jitted.lower(*args, **(kwargs or {})).compile()
-    except Exception:  # lint-ok: AOT refusal degrades to the jit path
-        return None
+    """``jitted.lower(*args).compile()`` — the capture-free AOT path for
+    when the persistent cache is on but device-cost capture is vetoed (the
+    two switches stay independent). A compile error is the caller's to
+    see: the jit path it would fall back to runs the same compiler."""
+    return jitted.lower(*args, **(kwargs or {})).compile()
+
+
+def _program_devices(compiled) -> list:
+    """The devices a ``jax.stages.Compiled`` was compiled for, in the
+    executable's own order."""
+    return list(compiled._executable.xla_executable.local_devices())
 
 
 def serialize_compiled(compiled) -> Optional[bytes]:
     """Export a ``jax.stages.Compiled`` to bytes (pickle of XLA's
-    serialized executable + the call signature pytrees), or None when the
-    backend refuses export — the caller then falls back to jax's
-    persistent compilation cache."""
+    serialized executable, the call signature pytrees, and the ids of the
+    devices it was compiled for), or None when the backend refuses
+    export — the program then recompiles through jax's persistent
+    compilation cache."""
     try:
         from jax.experimental import serialize_executable as _se
 
         payload, in_tree, out_tree = _se.serialize(compiled)
+        device_ids = [d.id for d in _program_devices(compiled)]
         buf = io.BytesIO()
-        pickle.dump((payload, in_tree, out_tree), buf,
+        pickle.dump((payload, in_tree, out_tree, device_ids), buf,
                     protocol=pickle.HIGHEST_PROTOCOL)
         return buf.getvalue()
     except Exception:  # lint-ok: export support is backend-dependent
@@ -239,50 +246,18 @@ def serialize_compiled(compiled) -> Optional[bytes]:
 
 
 def _deserialize_compiled(blob: bytes):
+    """Load a serialized program back onto the devices it was compiled
+    for. ``deserialize_and_load`` defaults to EVERY device of the backend,
+    which makes a one-device program unrunnable on a multi-device host
+    ("expected 8 shards, got 1") — hence the recorded ids."""
+    import jax
     from jax.experimental import serialize_executable as _se
 
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
-
-
-_fallback_enabled = [False]
-_fallback_lock = threading.Lock()
-
-
-def enable_jax_fallback_cache(directory: str) -> bool:
-    """Point jax's persistent *compilation* cache at ``<dir>/xla`` — the
-    degraded mode for backends whose executables refuse serialization
-    (``serialize_compiled`` → None): re-compiles skip XLA optimization by
-    hitting the compiler-level cache instead. Idempotent; returns whether
-    the config took. Serialized under a lock — concurrent warmup workers
-    can hit export refusal together, and ``jax.config.update`` is a
-    process-global mutation that must happen exactly once."""
-    if _fallback_enabled[0]:
-        return True
-    with _fallback_lock:
-        return _enable_jax_fallback_cache_locked(directory)
-
-
-def _enable_jax_fallback_cache_locked(directory: str) -> bool:
-    if _fallback_enabled[0]:
-        return True
-    try:
-        import jax
-
-        path = os.path.join(directory, "xla")
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache even fast compiles — cold start is dominated by many small
-        # programs, each under the default 1s floor
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:  # lint-ok: knob name varies across jax versions
-            pass
-        _fallback_enabled[0] = True
-        return True
-    except Exception:  # lint-ok: fallback is best-effort by contract
-        return False
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 # ---------------------------------------------------------------------------
@@ -410,10 +385,9 @@ class ProgramCache:
     # -- write ---------------------------------------------------------
     def put(self, key: ProgramKey, compiled,
             meta: Optional[dict] = None) -> bool:
-        """Serialize + commit one executable. Returns False (after
-        arming the jax fallback cache) when the backend refuses export.
-        Concurrent writers of the same key are safe: rename is atomic and
-        both wrote identical content.
+        """Serialize + commit one executable. Returns False when the
+        backend refuses export. Concurrent writers of the same key are
+        safe: rename is atomic and both wrote identical content.
 
         Every blob is round-trip verified (``deserialize_and_load``)
         before it is published: XLA:CPU's JIT dedupes identical kernels
@@ -421,18 +395,20 @@ class ProgramCache:
         can REFERENCE kernels it does not embed — its serialization loads
         nowhere, not even in the writer process. Deserialization builds a
         fresh function library from the blob alone, so the verify catches
-        exactly the entries a cold reader would have to reject; a
-        non-self-contained export counts as ``export_refused`` and arms
-        the compiler-level fallback cache instead of poisoning the dir."""
+        exactly the entries a cold reader would have to reject — and the
+        loaded program must sit on the devices the original was compiled
+        for, or its first call would fail. A blob that fails either check
+        counts as ``export_refused`` instead of poisoning the dir."""
         blob = serialize_compiled(compiled)
         if blob is not None:
             try:
-                _deserialize_compiled(blob)
+                loaded = _deserialize_compiled(blob)
+                if _program_devices(loaded) != _program_devices(compiled):
+                    blob = None
             except Exception:  # lint-ok: unloadable export = refused export
                 blob = None
         if blob is None:
             self._count("export_refused", site=key.site, label=key.label)
-            enable_jax_fallback_cache(self.root)
             return False
         header = json.dumps(
             {"key": key._asdict(), "env": env_fingerprint(),
@@ -498,10 +474,7 @@ _global_lock = threading.Lock()
 
 
 def default_dir() -> str:
-    return os.path.join(
-        os.environ.get("XDG_CACHE_HOME",
-                       os.path.join(os.path.expanduser("~"), ".cache")),
-        "mxnet_tpu", "progcache")
+    return checkout_path(".mxnet_progcache")
 
 
 def active() -> bool:

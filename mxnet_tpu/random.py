@@ -99,22 +99,13 @@ def get_state_data():
     (a resumed process will lazily seed exactly like a fresh one)."""
     if _STATE.key is None:
         return None
-    key = _STATE.key
-    try:
-        data = jax.random.key_data(key)
-    except (TypeError, AttributeError):  # already a raw uint32 key array
-        data = key
-    return np.asarray(data)
+    return np.asarray(jax.random.key_data(_STATE.key))
 
 
 def set_state_data(data) -> None:
     """Restore the stream captured by :func:`get_state_data` (checkpoint
     resume) — draws after this replay bit-identically."""
-    arr = np.asarray(data, np.uint32)
-    try:
-        _STATE.key = jax.random.wrap_key_data(arr)
-    except (TypeError, AttributeError):  # older jax: raw arrays are keys
-        _STATE.key = arr
+    _STATE.key = jax.random.wrap_key_data(np.asarray(data, np.uint32))
 
 
 # ---------------------------------------------------------------------------

@@ -290,8 +290,7 @@ class DecodeEngine:
                     from .. import progcache as _progcache
 
                     compiled = _progcache.aot_compile(jitted, call_args)
-                    cost = (obs.device.analyze_compiled(compiled)
-                            if compiled is not None else None)
+                    cost = obs.device.analyze_compiled(compiled)
                 if compiled is not None:
                     self._aot[sig] = compiled
                     if pc is not None:

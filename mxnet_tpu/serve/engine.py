@@ -623,8 +623,7 @@ class InferenceEngine:
                             self._jitted,
                             (self._rng_data, arg_vals,
                              list(snapshot.aux_vals)))
-                        cost = (obs.device.analyze_compiled(compiled)
-                                if compiled is not None else None)
+                        cost = obs.device.analyze_compiled(compiled)
                 if compiled is not None:
                     self._aot[sig] = compiled
                     if pc is not None:
@@ -769,8 +768,6 @@ class InferenceEngine:
                 with self._mesh_ctx():
                     compiled = _progcache.aot_compile(
                         self._jitted, self._args_for_sig(sig, snapshot))
-                if compiled is None:
-                    continue
                 self._aot[sig] = compiled
             pk = self._program_key(sig, bucket)
             meta = dict(self._sig_cost.get(sig) or {}, bucket=bucket)

@@ -73,6 +73,7 @@ import signal
 import socket
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -226,6 +227,21 @@ class LocalReplica:
             self.server = None
 
 
+def _jax_platform() -> Optional[str]:
+    """The jax platform this process is bound to — what it was configured
+    for, else the backend it already runs on — or None while it has not
+    touched jax at all (a supervisor that stays off the chip so that its
+    children can take it has no platform to hand down)."""
+    import jax
+    from jax._src import xla_bridge
+
+    if jax.config.jax_platforms:
+        return jax.config.jax_platforms
+    if xla_bridge.backends_are_initialized():
+        return jax.default_backend()
+    return None
+
+
 class ProcReplica:
     """Subprocess replica: ``python -m mxnet_tpu.serve.server <model>`` on
     a pre-picked port. ``kill()`` is a real SIGKILL. Per-replica chaos:
@@ -238,7 +254,15 @@ class ProcReplica:
     an ``obs_dir`` (param or ``MXNET_OBS_DIR``) the child also streams
     flush-per-event JSONL to ``<obs_dir>/replica-<pid>.jsonl`` — so a
     SIGKILL'd replica still leaves its half of the timeline on disk, and
-    ``tools/trace_report.py`` merges it back in by pid lane."""
+    ``tools/trace_report.py`` merges it back in by pid lane.
+
+    The child comes up on the parent's jax platform or not at all: a chip
+    belongs to one process, so a child of a parent that holds it cannot
+    take it — and jax left to itself would then log a warning, fall back
+    to the CPU and answer from there. ``JAX_PLATFORMS`` in the child's
+    environment turns that into an exit. Its output goes to ``log_path``
+    (default: a per-replica file under ``obs_dir`` or the temp dir), where
+    the reason for such an exit can be read."""
 
     def __init__(self, model: str, *, args: Sequence[str] = (),
                  env: Optional[dict] = None, log_path: Optional[str] = None,
@@ -247,7 +271,7 @@ class ProcReplica:
         self.model = model
         self._args = list(args)
         self._env = dict(env or {})
-        self._log_path = log_path
+        self.log_path = log_path  # None: start() picks the default file
         self._obs_dir = obs_dir or os.environ.get("MXNET_OBS_DIR")
         # persistent AOT program cache (mxnet_tpu/progcache.py): an
         # explicit dir pins the child's cache; otherwise the parent's
@@ -261,6 +285,9 @@ class ProcReplica:
     def start(self) -> Tuple[str, int]:
         port = _free_port()
         env = dict(os.environ)
+        platform = _jax_platform()
+        if platform:
+            env["JAX_PLATFORMS"] = platform
         env.update(self._env)
         # the child must import mxnet_tpu regardless of the caller's cwd
         pkg_root = os.path.dirname(os.path.dirname(
@@ -301,16 +328,16 @@ class ProcReplica:
             # anchors); only an explicit per-replica env wins over it.
             env["MXNET_OBS_JSONL"] = os.path.join(
                 self._obs_dir, "replica-%p.jsonl")
-        out = open(self._log_path, "ab") if self._log_path \
-            else subprocess.DEVNULL
-        try:
+        if self.log_path is None:
+            self.log_path = os.path.join(
+                self._obs_dir or tempfile.gettempdir(),
+                f"mxnet-replica-{os.getpid()}-{self.idx}.log")
+        os.makedirs(os.path.dirname(self.log_path) or ".", exist_ok=True)
+        with open(self.log_path, "ab") as out:
             self.proc = subprocess.Popen(
                 [sys.executable, "-m", "mxnet_tpu.serve.server", self.model,
                  "--port", str(port)] + self._args,
                 env=env, stdout=out, stderr=subprocess.STDOUT)
-        finally:
-            if out is not subprocess.DEVNULL:
-                out.close()
         return ("127.0.0.1", port)
 
     def alive(self) -> bool:

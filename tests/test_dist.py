@@ -27,26 +27,11 @@ def _run_launcher(extra_args, mode, timeout=240, env_extra=None):
     return proc.stdout
 
 
-# This image's jaxlib CPU backend rejects cross-process collectives
-# ("Multiprocess computations aren't implemented on the CPU backend"), so
-# the jax.distributed dist_sync transport cannot run here at all — an
-# environment limitation, not a framework regression (docs/ROBUSTNESS.md
-# "Elastic training", carried-failure triage). The SAME known-value worker
-# passes over the elastic PS-reduce transport below, which keeps every
-# dist_sync semantic covered on this box.
-_CPU_COLLECTIVES = pytest.mark.xfail(
-    reason="jaxlib CPU backend lacks multiprocess collectives; dist_sync "
-    "semantics are covered by the elastic-transport twins below",
-    strict=False)
-
-
-@_CPU_COLLECTIVES
 def test_dist_sync_three_workers():
     out = _run_launcher(["-n", "3"], "dist_sync")
     assert out.count("OK") == 3, out[-2000:]
 
 
-@_CPU_COLLECTIVES
 def test_dist_sync_four_workers():
     """n=4 known-value run (VERDICT r3 item 6: dist testing stopped at 3
     processes; the reference nightly runs more — dist_sync_kvstore.py TBV).

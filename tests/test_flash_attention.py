@@ -107,10 +107,14 @@ def test_dispatcher_policy(monkeypatch):
         monkeypatch.setenv("MXNET_ATTENTION_IMPL", impl)
         np.testing.assert_allclose(np.asarray(fused_attention(q, k, v)),
                                    np.asarray(ref), atol=2e-5)
-    # masks always take the plain path — must not error under impl=flash
+    # the kernel takes no explicit mask: auto falls to plain, but ASKING
+    # for flash must raise rather than quietly run plain attention
     mask = jnp.ones((1, 1, 64, 64), bool)
-    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "flash")
+    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "auto")
     fused_attention(q, k, v, mask=mask)
+    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "flash")
+    with pytest.raises(ValueError, match="impl='flash' cannot run"):
+        fused_attention(q, k, v, mask=mask)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -128,6 +132,30 @@ def test_ring_attention_flash_blocks(causal):
     out2 = par.sequence_sharded_attention(q, k, v, mesh, causal=causal,
                                           use_flash=False)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref), atol=2e-4)
+
+
+def test_mesh_without_sp_runs_the_kernel_per_shard(monkeypatch):
+    """On a dp/tp mesh the kernel sits inside shard_map (batch over dp,
+    heads over tp): XLA cannot partition a Mosaic call by itself, which on
+    the chip was a compile error for every multi-device mesh without sp."""
+    from mxnet_tpu import parallel as par
+
+    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "flash")
+    mesh = par.make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    q, k, v = (_rand((2, 2, 64, 16), i) for i in range(3))
+
+    def attn(q, k, v):
+        return par.sequence_sharded_attention(q, k, v, mesh, causal=True)
+
+    assert "shard_map" in str(jax.make_jaxpr(attn)(q, k, v))
+    np.testing.assert_allclose(
+        np.asarray(attn(q, k, v)),
+        np.asarray(plain_attention(q, k, v, causal=True)), atol=2e-5)
+    # a head count tp does not divide stays replicated instead of failing
+    q3, k3, v3 = (_rand((2, 3, 64, 16), i) for i in range(3))
+    np.testing.assert_allclose(
+        np.asarray(par.sequence_sharded_attention(q3, k3, v3, mesh)),
+        np.asarray(plain_attention(q3, k3, v3)), atol=2e-5)
 
 
 def test_ring_attention_flash_grad():

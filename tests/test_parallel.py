@@ -56,7 +56,10 @@ def test_ring_attention_matches_plain(causal):
     v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
     ref = plain_attention(q, k, v, causal=causal)
     mesh = par.make_mesh({"sp": 8})
-    out = par.sequence_sharded_attention(q, k, v, mesh, causal=causal)
+    # jitted, as the trainer runs it: an eager shard_map dispatches the
+    # unrolled ring op by op across 8 devices and takes 7x as long
+    out = jax.jit(lambda q, k, v: par.sequence_sharded_attention(
+        q, k, v, mesh, causal=causal))(q, k, v)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-5)
 
 

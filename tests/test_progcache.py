@@ -128,6 +128,26 @@ def test_roundtrip_hit_and_miss(tmp_path):
     np.testing.assert_array_equal(np.asarray(out), np.full((3, 2), 2.0))
 
 
+def test_hit_runs_on_the_devices_it_was_compiled_for(tmp_path):
+    """A one-device program read back on this 8-device host is loaded AND
+    called: ``deserialize_and_load`` defaults to every device of the
+    backend, which loads fine and then fails at the first call ("expected
+    8 shards, got 1") — so the entry records the program's devices. Device
+    3, not 0, so a loader that merely picked the default device fails."""
+    import jax
+    import jax.numpy as jnp
+
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.ones((3, 2)), dev)
+    compiled = jax.jit(lambda x: x * 2.0).lower(x).compile()
+    cache = progcache.ProgramCache(str(tmp_path))
+    key = progcache.program_key("test", "dev3", ("dev3",))
+    assert cache.put(key, compiled)
+    out = cache.get(key).executable(x)
+    np.testing.assert_array_equal(np.asarray(out), np.full((3, 2), 2.0))
+    assert out.devices() == {dev}
+
+
 def test_truncated_entry_rejects(tmp_path):
     cache = progcache.ProgramCache(str(tmp_path))
     key, _ = _put_one(cache)

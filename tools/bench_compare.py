@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """bench_compare.py — the perf-regression dossier over BENCH_r*.json.
 
-Loads the committed bench trajectory (every round's captured ``bench.py``
-output), computes per-gain deltas with noise bands from the artifacts' own
-``*_spread`` honesty fields, treats ``platform_unavailable`` rounds (the
-void BENCH_r05) as GAPS — never as 100% regressions — and flags
+Loads a bench trajectory (each round's captured ``bench.py`` output),
+computes per-gain deltas with noise bands from the artifacts' own
+``*_spread`` honesty fields, treats ``platform_unavailable`` rounds as
+GAPS — never as 100% regressions — and flags
 cross-metric anomalies like the bf16-piped-slower-than-fp32-piped
 inversion. Logic lives in ``mxnet_tpu/obs/regress.py`` (loaded directly by
 file path — no framework/jax import, so this runs anywhere the JSON does).

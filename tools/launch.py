@@ -23,6 +23,14 @@ Env contract exported to each worker (reference DMLC vars):
 On TPU pods the equivalent of ssh/mpi launch is the platform's own
 multi-host runner (each host runs the same program; jax.distributed picks up
 the topology), so --launcher ssh/mpi intentionally raises here.
+
+One TPU host is NOT a target for ``-n > 1``: every worker gets the same
+environment, so they would all ask for the same chips, and a chip belongs to
+one process — the first worker takes it and the rest fail or hang at start-up.
+On such a host one process drives all of its chips as a mesh
+(``parallel.make_mesh`` + ``ShardedTrainer``); the local launcher is for
+CPU workers (``JAX_PLATFORMS=cpu``, what the tests use) and for the
+host-side parameter server.
 """
 from __future__ import annotations
 
