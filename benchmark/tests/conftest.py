@@ -1,0 +1,9 @@
+"""These tests are run by hand (``python3 -m pytest benchmark/tests -q``),
+not by tier-1: they drive the harness on the CPU at rehearsal size."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
