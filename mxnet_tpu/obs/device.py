@@ -333,8 +333,8 @@ def annotate_span(span, phase: str, seconds: float,
     """``attribute`` + fold the attrs into a live span (before its
     ``__exit__`` records it). No-op on the shared no-op span."""
     attrs = attribute(phase, seconds, cost)
-    if attrs and isinstance(span, _trace._Span):
-        span.attrs = dict(span.attrs or {}, **attrs)
+    if attrs:
+        span.set(**attrs)
     return attrs
 
 

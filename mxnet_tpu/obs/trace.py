@@ -20,8 +20,16 @@ Overhead contract (tested in tests/test_obs.py):
   is gated on this ONE flag (``_ENABLED``), flipped by ``obs.enable()`` /
   ``MXNET_OBS=1``.
 - **Enabled**: ``__enter__``/``__exit__`` cost two ``time.monotonic()``
-  calls and one deque append into a bounded ring buffer (old events drop,
-  newest win — a long run cannot OOM the tracer).
+  calls, one deque append into a bounded ring buffer (old events drop,
+  newest win — a long run cannot OOM the tracer), and one
+  ``jax.profiler.TraceAnnotation`` (under half a microsecond while no
+  profiler session is running).
+
+The bridge to the profiler's clock: every live span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so a ``jax.profiler``
+trace taken while telemetry is enabled shows the framework's spans on its
+``/host:CPU`` plane, thread by thread, beside the device's ``XLA Ops``.
+``complete()`` spans are recorded after the fact and cannot be bridged.
 
 Spans nest per thread (a thread-local stack records depth); the context
 manager is reentrant across threads because each thread owns its stack.
@@ -66,6 +74,10 @@ _ENABLED = False
 _TAIL_SINK = None
 _BLACKBOX_SINK = None
 
+# jax.profiler, imported by the first live span (importing this module must
+# not import jax); the attribute is looked up per span
+_profiler = None
+
 
 def _trace_epoch() -> float:
     return time.monotonic()
@@ -82,6 +94,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs):
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -93,7 +108,8 @@ class _Span:
     body AS the current context, and stamps trace/span/parent ids into its
     attrs — the cross-process parent chain."""
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "_ctx", "_parent")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "_ctx", "_parent",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[dict],
                  parent=None):
@@ -103,16 +119,26 @@ class _Span:
         self._parent = parent
         self._ctx = None
 
+    def set(self, **attrs):
+        """Attributes known only once the body has run."""
+        self.attrs = dict(self.attrs, **attrs) if self.attrs else attrs
+
     def __enter__(self):
+        global _profiler
+        if _profiler is None:
+            import jax.profiler as _profiler
         if self._parent is not None:
             self._ctx = self._parent.child()
             _context._set(self._ctx)
         self._tracer._stack().append(self)
+        self._annotation = _profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         t1 = time.monotonic()
+        self._annotation.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()

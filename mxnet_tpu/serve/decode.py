@@ -260,8 +260,6 @@ class DecodeEngine:
 
         sig = (kind,) + tuple(
             (tuple(np.shape(a)), str(np.asarray(a).dtype)) for a in args)
-        rec = obs.enabled()
-        t0 = time.monotonic()
         is_compile = sig not in self._programs
         cache_hit = False
         call_args = (self._params, self.kv) + tuple(args)
@@ -305,22 +303,24 @@ class DecodeEngine:
         fn = self._aot.get(sig, jitted)
         with obs.trace.span("decode.execute", kind=kind, label=label,
                             compile=is_compile, cache_hit=cache_hit):
-            new_kv, toks = fn(*call_args)
-            self.kv = new_kv
+            # argument upload and launch: returns before the device is done
+            with obs.trace.span("decode.dispatch"):
+                kv, toks = fn(*call_args)
+            self.kv = kv
             # the step's sampled tokens ARE the wire payload — this d2h is
             # the one accounted sync of the decode hot path
             copytrack.TRACKER.host_sync("serve.decode.device_get")
-            host = np.asarray(jax.device_get(toks))  # lint: disable=host-sync-on-hot-path
-        if rec:
-            dt = time.monotonic() - t0
-            if is_compile and not cache_hit:
-                obs.inc("decode.compile")
-                obs.observe("decode.compile_seconds", dt)
-            elif cache_hit:
-                obs.inc("decode.cache_hit")
-                obs.observe("decode.deserialize_seconds", dt)
-            else:
-                obs.observe("decode.execute_seconds", dt)
+            # the wait for the device's last operation, then the copy back:
+            # device-idle time under this span is the host not yet awake
+            with obs.trace.span("decode.device_get"):
+                host = np.asarray(jax.device_get(toks))  # lint: disable=host-sync-on-hot-path
+        # an operator's "which call recompiled, which deserialized" alarm,
+        # one increment per first call of a signature (the decode.execute
+        # span carries compile, cache_hit and the duration)
+        if is_compile and not cache_hit:
+            obs.inc("decode.compile")
+        elif cache_hit:
+            obs.inc("decode.cache_hit")
         with self._stat_lock:
             self._programs[sig] = self._programs.get(sig, 0) + 1
             self.exec_count += 1
@@ -606,7 +606,8 @@ class DecodeScheduler:
                 with self._cv:
                     while (self._running and self._qsize() == 0
                            and self._active() == 0):
-                        self._cv.wait(1.0)
+                        with obs.trace.span("decode.idle_wait"):
+                            self._cv.wait(1.0)
                     if not self._running:
                         return
                 self.step()
@@ -619,62 +620,73 @@ class DecodeScheduler:
         """One continuous-batch step: admit → decode → distribute →
         retire. Returns the number of tokens produced. This is the
         decode data plane's hot root (analysis/dataplane.py)."""
-        now = time.monotonic()
-        joined = self._admit(now)
+        with obs.trace.span("decode.turn") as turn:
+            joined, active, left = self._turn()
+            turn.set(joined=joined, active=active, left=left)
+        return active
+
+    def _turn(self):
+        """The body of :meth:`step`; returns (joined, active, left)."""
+        joined = self._admit(time.monotonic())
         active = [(i, g) for i, g in enumerate(self._slots)
                   if g is not None]
         if not active:
-            return 0
+            return joined, 0, 0
         eng = self.engine
-        tokens = np.zeros((eng.slots,), np.int32)
-        positions = np.zeros((eng.slots,), np.int32)
-        lengths = np.zeros((eng.slots,), np.int32)
-        temps = np.zeros((eng.slots,), np.float32)
-        tables = np.full((eng.slots, eng.max_pages), SCRATCH_PAGE,
-                         np.int32)
-        stepping = []
-        for i, g in active:
-            pos = g.prompt_len + g.produced - 1
-            try:
-                table = self._ensure_pages(g, pos)
-            except PagesExhausted as e:
-                # shedding a RUNNING stream, not a queued one: freeing its
-                # pages is what lets the rest of the batch keep stepping
-                self.shed += 1
-                self.shed_by_reason["pages"] += 1
-                obs.inc("decode.shed_pages")
-                self._retire(i, g, "pages", error=e)
-                continue
-            tokens[i] = g.last_token
-            positions[i] = pos
-            lengths[i] = pos + 1
-            temps[i] = g.temperature
-            tables[i, :len(table)] = table
-            stepping.append((i, g))
+        with obs.trace.span("decode.build", active=len(active)):
+            tokens = np.zeros((eng.slots,), np.int32)
+            positions = np.zeros((eng.slots,), np.int32)
+            lengths = np.zeros((eng.slots,), np.int32)
+            temps = np.zeros((eng.slots,), np.float32)
+            tables = np.full((eng.slots, eng.max_pages), SCRATCH_PAGE,
+                             np.int32)
+            stepping = []
+            for i, g in active:
+                pos = g.prompt_len + g.produced - 1
+                try:
+                    table = self._ensure_pages(g, pos)
+                except PagesExhausted as e:
+                    # shedding a RUNNING stream, not a queued one: freeing
+                    # its pages is what lets the rest of the batch keep
+                    # stepping
+                    self.shed += 1
+                    self.shed_by_reason["pages"] += 1
+                    obs.inc("decode.shed_pages")
+                    self._retire(i, g, "pages", error=e)
+                    continue
+                tokens[i] = g.last_token
+                positions[i] = pos
+                lengths[i] = pos + 1
+                temps[i] = g.temperature
+                tables[i, :len(table)] = table
+                stepping.append((i, g))
         if not stepping:
-            return 0
+            return joined, 0, 0
         t0 = time.monotonic()
         out = eng.step(tokens, positions, tables, lengths, temps,
                        seed=self._step_seed())
         dt = time.monotonic() - t0
         left = 0
         now = time.monotonic()
-        for i, g in stepping:
-            tok = int(out[i])
-            g.last_token = tok
-            g.produced += 1
-            self.tokens_out += 1
-            obs.observe("decode.token_seconds", dt)
-            if g.ctx is not None and g.ctx.sampled:
-                obs.trace.complete("decode.token", t0, dt, ctx=g.ctx,
-                                   index=g.produced, slot=i)
-            if not g.handle._emit(("token", tok, g.produced)):
-                self._retire(i, g, "backpressure", error=RequestRejected(
-                    "stream consumer too slow (token buffer full)"))
-                left += 1
-                continue
-            if self._done(g, tok, now):
-                left += 1
+        with obs.trace.span("decode.distribute") as distribute:
+            for i, g in stepping:
+                tok = int(out[i])
+                g.last_token = tok
+                g.produced += 1
+                self.tokens_out += 1
+                if g.ctx is not None and g.ctx.sampled:
+                    obs.trace.complete("decode.token", t0, dt, ctx=g.ctx,
+                                       index=g.produced, slot=i)
+                if not g.handle._emit(("token", tok, g.produced)):
+                    self._retire(i, g, "backpressure",
+                                 error=RequestRejected(
+                                     "stream consumer too slow (token "
+                                     "buffer full)"))
+                    left += 1
+                    continue
+                if self._done(g, tok, now):
+                    left += 1
+            distribute.set(left=left)
         self.steps += 1
         occ = len(stepping) / eng.slots
         self._occupancy = (occ if self.steps == 1
@@ -682,7 +694,7 @@ class DecodeScheduler:
         obs.set_gauge("decode.occupancy", self._occupancy)
         obs.trace.complete("decode.step", t0, dt, active=len(stepping),
                            joined=joined, left=left)
-        return len(stepping)
+        return joined, len(stepping), left
 
     def _step_seed(self) -> int:
         # deterministic per step-count: replays reproduce token-for-token
@@ -692,7 +704,7 @@ class DecodeScheduler:
         """Move queued generations into free slots (prefill at the step
         boundary). Page exhaustion leaves the request queued."""
         admitted = []
-        with self._cv:
+        with obs.trace.span("decode.admit") as admit, self._cv:
             free = [i for i, g in enumerate(self._slots) if g is None]
             for lane in self._lanes:
                 while lane and free:
@@ -721,15 +733,18 @@ class DecodeScheduler:
                     lane.pop(0)
                     slot = free.pop(0)
                     self._slots[slot] = g
-                    admitted.append(g)
-        for g in admitted:
+                    admitted.append((g, bucket))
+            admit.set(admitted=len(admitted))
+        for g, bucket in admitted:
             g.t_admit = time.monotonic()
             obs.trace.complete("decode.queue_wait", g.t_submit,
                               g.t_admit - g.t_submit, ctx=g.ctx,
                               priority=g.priority)
-            tok = self.engine.prefill(
-                g.tokens, self.engine.pool.table(g.seq),
-                temperature=g.temperature, seed=g.seed)
+            with obs.trace.span("decode.prefill", bucket=bucket,
+                                prompt_len=g.prompt_len):
+                tok = self.engine.prefill(
+                    g.tokens, self.engine.pool.table(g.seq),
+                    temperature=g.temperature, seed=g.seed)
             g.last_token = tok
             g.produced = 1
             self.tokens_out += 1

@@ -509,14 +509,18 @@ def test_serve_telemetry_resolves_retained_ids_before_drain():
         with context.use(ctx):
             with obs.trace.span("serve.execute"):
                 pass
-        assert tail.buffer().pending_count() == 1
+        # every assertion is on THIS trace id: the server's handler thread
+        # closes its own spans of the infer above after the reply is out,
+        # so one of them may land in the fresh buffer beside ours
+        assert tail.buffer().pending_count() >= 1
         tel = cli.telemetry(drain=True, retained=[ctx.trace_id])
         (part,) = tel["parts"]
         promoted = [s for s in part["spans"]
-                    if s.get("name") == "serve.execute"]
+                    if s.get("name") == "serve.execute"
+                    and s["args"].get("trace_id") == ctx.trace_id]
         assert promoted, "verdict-promoted span missing from the part"
-        assert promoted[0]["args"]["trace_id"] == ctx.trace_id
-        assert tail.buffer().pending_count() == 0
+        # it left with that collection: a second verdict finds nothing held
+        assert tail.buffer().resolve([ctx.trace_id]) == 0
     finally:
         cli.close()
         srv.stop()
