@@ -178,25 +178,28 @@ def phase_kernels():
         for name, g, gr in zip(("dq", "dk", "dv"), grads, grads_ref):
             _check_close(f"flash bwd {name}", g, gr, 4e-2)
 
-        # paged decode: the serve phase's pool geometry, ragged lengths
-        n_seq, max_pages = SLOTS, 2048 // PAGE
+        # paged decode: the serve phase's pool geometry (every layer in the
+        # one pool, K and V side by side), ragged lengths, one inner layer
+        n_seq, max_pages, layers, layer = SLOTS, 2048 // PAGE, 3, 1
         n_pages = n_seq * max_pages + 1
         lengths = np.array([0, 1, 15, 16, 17, 1000, 2047, 2048], np.int32)
         rng = np.random.RandomState(0)
         table = (rng.permutation(n_pages - 1) + 1).astype(np.int32) \
             .reshape(n_seq, max_pages)
         live = lengths > 0   # a length-0 row is an idle slot: garbage out
-        decode = jax.jit(flash_decode_attention)
+        decode = jax.jit(flash_decode_attention, static_argnums=2)
         decode_ref = jax.jit(
-            lambda *a: _decode_attention_xla(*a, 1.0 / np.sqrt(d)))
+            lambda q, pool, *a: _decode_attention_xla(
+                q, pool, layer, *a, 1.0 / np.sqrt(d)))
         for dtype, tol in ((jnp.float32, 1e-4), (jnp.bfloat16, 2e-2)):
-            kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
+            kq, kp = jax.random.split(jax.random.PRNGKey(1), 2)
             qd = jax.random.normal(kq, (n_seq, h, d), dtype)
-            kp = jax.random.normal(kk, (n_pages, PAGE, h, d), dtype)
-            vp = jax.random.normal(kv, (n_pages, PAGE, h, d), dtype)
-            _require_mosaic(decode, qd, kp, vp, table, lengths)
-            got = np.asarray(decode(qd, kp, vp, table, lengths), np.float32)
-            want = np.asarray(decode_ref(qd, kp, vp, table, lengths),
+            pool = jax.random.normal(kp, (n_pages, layers, PAGE, h, 2 * d),
+                                     dtype)
+            _require_mosaic(decode, qd, pool, layer, table, lengths)
+            got = np.asarray(decode(qd, pool, layer, table, lengths),
+                             np.float32)
+            want = np.asarray(decode_ref(qd, pool, table, lengths),
                               np.float32)
             _require(np.all(np.isfinite(got)), "decode kernel: non-finite")
             _check_close(f"paged decode (8,12,64) x {max_pages} pages "
@@ -425,6 +428,17 @@ def phase_serve():
         # the step program carries the path it was traced with
         _require((attn == "pallas") == (_MOSAIC in _step_text(engine)),
                  f"decode_attn says {attn} but the step program disagrees")
+        # ... and reads the pool where it lies: by the compiler's own
+        # account it allocates less than one layer's K of the pool
+        k_slice = engine.kv.nbytes // (2 * engine.cfg["layers"])
+        program = stats["step_program"]
+        print(f"   step program: temp_bytes {program['temp_bytes']}, "
+              f"bytes_accessed {program['bytes_accessed']}; one layer's K "
+              f"of the pool is {k_slice} bytes", flush=True)
+        _require(program["temp_bytes"] < k_slice,
+                 f"the step program allocates {program['temp_bytes']} bytes "
+                 f"of temporaries, one layer's K of the pool ({k_slice}) or "
+                 "more: it slices, copies or lays the pool out again")
     return attn
 
 

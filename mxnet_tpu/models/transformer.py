@@ -395,25 +395,46 @@ def decode_layer(cfg, lp, x, attend):
     return _ln(x + out, lp["ln2_g"], lp["ln2_b"]), k, v
 
 
+def stack_layers(layers):
+    """A list of per-layer param dicts (``decode_params``'s layout) as one
+    dict of arrays with a leading layer axis — what ``lm_prefill`` scans
+    over and ``DecodeEngine`` holds on the device."""
+    import jax
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *layers)
+
+
 def lm_prefill(cfg, params, tokens):
     """Causal forward over a prompt batch. tokens (B, S) int32.
+    ``params["layers"]`` is the list ``decode_params`` gives or the same
+    weights already stacked (``stack_layers``).
 
     Returns (logits (B, S, V), k (L, B, S, H, D), v (L, B, S, H, D)) —
     the dense KV state ``lm_decode_step`` consumes. Padded positions are
     harmless: causal masking means row i only sees columns <= i, and the
-    caller reads logits at its true last position."""
+    caller reads logits at its true last position.
+
+    The layers run under ``lax.scan``, one compiled body for all of them:
+    unrolled, a 24-layer prefill at 768 positions is 190 MB of TPU code
+    (8 MB a layer, none of it shared), against 9 MB scanned — which is
+    what a replica loads at start-up and what the compile cache holds."""
+    import jax
     import jax.numpy as jnp
     b, s = tokens.shape
     x = params["embed"][tokens] + params["pos"][:s]
     causal = jnp.tril(jnp.ones((s, s), dtype=bool))
-    ks, vs = [], []
-    for lp in params["layers"]:
+    layers = params["layers"]
+    if not isinstance(layers, dict):
+        layers = stack_layers(layers)
+
+    def layer(x, lp):
         x, k, v = prefill_layer(cfg, lp, x, causal)
-        ks.append(k)
-        vs.append(v)
+        return x, (k, v)
+
+    x, (k, v) = jax.lax.scan(layer, x, layers)
     x = _ln(x, params["final_g"], params["final_b"])
     logits = _dense(x, params["dec_w"], params["dec_b"])
-    return logits, jnp.stack(ks), jnp.stack(vs)
+    return logits, k, v
 
 
 def lm_decode_step(cfg, params, tokens, kv, positions):

@@ -512,25 +512,30 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 # ---------------------------------------------------------------------------
 # Decode-shape attention over a paged KV cache (serve/decode.py)
 #
-# One query position per sequence against its page table. The Pallas
-# kernel never gathers: scalar-prefetched page tables drive the K/V
-# BlockSpec index_map, so grid step (b, j) streams page ``table[b, j]``
-# straight from the pool — attention IS the gather. Off-TPU (and for the
-# reference/parity tests) the XLA path materializes the gather instead.
+# One query position per sequence against its page table. The pool is ONE
+# array ``(pages, layers, page_size, heads, 2 * head_dim)`` — a position's
+# K row and V row for one head side by side on the minor axis — and both
+# paths below take it whole, with the layer as a static index: nothing
+# between a layer's KV write and its attention slices, copies or lays out
+# again any part of the pool. The Pallas kernel never gathers:
+# scalar-prefetched page tables drive the BlockSpec index_map, so grid
+# step (b, j) streams block ``(table[b, j], layer)`` — that page's K and V
+# in one contiguous DMA — straight from the pool: attention IS the gather.
+# Off-TPU (and for the reference/parity tests) the XLA path gathers the
+# page table's pages of that layer, and only those, instead.
 # ---------------------------------------------------------------------------
 
 
-def _decode_attention_xla(q, k_pages, v_pages, page_table, lengths, scale):
-    """Gather-then-attend reference. q (B, H, D); k/v_pages
-    (P, page, H, D); page_table (B, max_pages) int32; lengths (B,) int32.
-    Returns (B, H, D)."""
+def _decode_attention_xla(q, pool, layer, page_table, lengths, scale):
+    """Gather-then-attend reference. q (B, H, D); pool
+    (P, L, page, H, 2D); ``layer`` a Python int; page_table (B, max_pages)
+    int32; lengths (B,) int32. Returns (B, H, D)."""
     b, h, d = q.shape
-    page = k_pages.shape[1]
-    k = k_pages[page_table]  # (B, max_pages, page, H, D)
-    v = v_pages[page_table]
-    s = k.shape[1] * page
-    k = k.reshape(b, s, h, d)
-    v = v.reshape(b, s, h, d)
+    page = pool.shape[2]
+    kv = pool[page_table, layer]  # one gather: (B, max_pages, page, H, 2D)
+    s = kv.shape[1] * page
+    kv = kv.reshape(b, s, h, 2 * d)
+    k, v = kv[..., :d], kv[..., d:]
     prec = _dot_prec(q.dtype)
     scores = jnp.einsum("bhd,bshd->bhs", q, k,
                         preferred_element_type=jnp.float32,
@@ -545,24 +550,27 @@ def _decode_attention_xla(q, k_pages, v_pages, page_table, lengths, scale):
                       precision=prec).astype(q.dtype)
 
 
-def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                           scale=None, interpret=False):
+def flash_decode_attention(q, pool, layer, page_table, lengths, scale=None,
+                           interpret=False):
     """Pallas paged decode attention. Shapes as ``decode_attention``.
 
     Grid (B, max_pages): the page axis is innermost-sequential, so the
     per-sequence online-softmax statistics (log2 domain, f32) live in VMEM
     scratch across page steps; ``pl.when`` skips pages past the
-    sequence's length, and the last step normalizes."""
+    sequence's length, and the last step normalizes. The pool is the
+    kernel's operand as it lies: the block of grid step (b, j) is
+    ``pool[table[b, j], layer]``, the layer axis squeezed."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    n_pages, page = k_pages.shape[:2]
+    page = pool.shape[2]
+    layer = int(layer)
     max_pages = page_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(d)
     s2_scale = scale * _LOG2E
 
-    def kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref):
+    def kernel(pt_ref, len_ref, q_ref, kv_ref, o_ref, m_ref, l_ref):
         seq = pl.program_id(0)
         j = pl.program_id(1)
 
@@ -581,11 +589,12 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
             # (Mosaic has no batched dot without an M dimension), and the
             # step is bound by streaming the page, not by arithmetic. So
             # the products run on the VPU in f32, in the pool's own
-            # (page, H, D) layout — heads on sublanes, D on lanes, per-head
-            # statistics as (H, 1) columns — with no transpose or relayout.
+            # (page, H, 2D) layout — heads on sublanes, K then V on lanes,
+            # per-head statistics as (H, 1) columns — with no transpose or
+            # relayout of the page.
             qv = q_ref[0].astype(jnp.float32)              # (H, D)
-            kb = k_ref[0].astype(jnp.float32)              # (page, H, D)
-            vb = v_ref[0].astype(jnp.float32)
+            blk = kv_ref[0].astype(jnp.float32)            # (page, H, 2D)
+            kb = blk[..., :d]                              # (page, H, D)
             sc = jnp.sum(kb * qv[None], axis=-1,           # (page, H, 1),
                          keepdims=True) * s2_scale         # log2 domain
             pos = j * page + lax.broadcasted_iota(jnp.int32, (page, h, 1), 0)
@@ -596,7 +605,10 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
             p = jnp.exp2(sc - m_new[None])
             p = jnp.where(sc <= _NEG_INF / 2, 0.0, p)      # (page, H, 1)
             l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=0)
-            o_ref[0] = o_ref[0] * alpha + jnp.sum(p * vb, axis=0)
+            # p weighs the whole row; the V half of the (H, 2D) sum is the
+            # update, so the one lane shift is of the sum, not of the page
+            pv = jnp.sum(p * blk, axis=0)[:, d:]           # (H, D)
+            o_ref[0] = o_ref[0] * alpha + pv
             m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
             l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -611,10 +623,8 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
         grid=(b, max_pages),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda sq, j, pt, ln: (sq, 0, 0)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda sq, j, pt, ln: (pt[sq, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda sq, j, pt, ln: (pt[sq, j], 0, 0, 0)),
+            pl.BlockSpec((1, None, page, h, 2 * d),
+                         lambda sq, j, pt, ln: (pt[sq, j], layer, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda sq, j, pt, ln: (sq, 0, 0)),
         scratch_shapes=[
@@ -627,8 +637,7 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages)
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
     return out.astype(q.dtype)
 
 
@@ -646,20 +655,22 @@ def decode_attention_impl() -> str:
     return impl
 
 
-def decode_attention(q, k_pages, v_pages, page_table, lengths, scale=None):
-    """Single-position attention against a paged KV cache.
+def decode_attention(q, pool, layer, page_table, lengths, scale=None):
+    """Single-position attention against one layer of a paged KV cache.
 
-    q (B, H, D) — one query position per live sequence; k_pages/v_pages
-    (P, page_size, H, D) — the device page pool; page_table
-    (B, max_pages) int32 — page ids in position order (pad unused slots
-    with any valid page, e.g. scratch page 0); lengths (B,) int32 —
-    positions visible per sequence (0 = inactive row, output garbage).
-    Returns (B, H, D). :func:`decode_attention_impl` picks the path.
+    q (B, H, D) — one query position per live sequence; pool
+    (P, L, page_size, H, 2D) — the whole device page pool, every layer's,
+    K on ``[..., :D]`` and V on ``[..., D:]``; ``layer`` — a Python int,
+    the layer whose pages are read (no caller slices the pool: the paths
+    address ``(page, layer)`` themselves); page_table (B, max_pages) int32
+    — page ids in position order (pad unused slots with any valid page,
+    e.g. scratch page 0); lengths (B,) int32 — positions visible per
+    sequence (0 = inactive row, output garbage). Returns (B, H, D).
+    :func:`decode_attention_impl` picks the path.
     """
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if decode_attention_impl() == "pallas":
-        return flash_decode_attention(q, k_pages, v_pages, page_table,
-                                      lengths, scale=scale,
+        return flash_decode_attention(q, pool, layer, page_table, lengths,
+                                      scale=scale,
                                       interpret=_use_interpret())
-    return _decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
-                                 scale)
+    return _decode_attention_xla(q, pool, layer, page_table, lengths, scale)

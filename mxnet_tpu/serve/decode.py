@@ -7,21 +7,31 @@ shapes serve every generation:
 
 - a **bucketed prefill program** (one trace per prompt bucket; buckets
   are powers of two in *positions*, always multiples of the page size):
-  full causal forward over one padded prompt, per-layer K/V scattered
-  into the page pool at the sequence's page ids, first token sampled
-  on-device;
+  full causal forward over one padded prompt (the layers under one
+  ``lax.scan`` over their stacked weights: one compiled layer body, a
+  program twenty times smaller to compile, cache and load than 24
+  unrolled), every layer's K/V written in place into the page pool at the
+  sequence's page ids, first token sampled on-device;
 - a **single decode-step program** (one trace, period): one new position
   for every slot of the fixed continuous batch — embed, per-layer
   paged-KV write + paged attention (ops/flash_attention.decode_attention),
   LM head, on-device greedy/temperature sampling.
 
 Growing a sequence never changes a program shape: the KV pool is one
-fixed array ``(pages, layers, 2, page_size, heads, head_dim)`` and growth
-is a host-side page-table edit (serve/kvcache.py) — the engine.py
-pad-and-slice idiom applied to the time axis. Program accounting mirrors
-InferenceEngine exactly: ``compile_log`` entries, progcache get/put so a
-scaled-out replica deserializes instead of compiling
-(``decode.cache_hit`` vs ``decode.compile``), and
+fixed array ``(pages, layers, page_size, heads, 2 * head_dim)`` — K and V
+of a position side by side — and growth is a host-side page-table edit
+(serve/kvcache.py) — the engine.py pad-and-slice idiom applied to the
+time axis. The pool is read and written where it lies: the programs
+donate it, write single rows (step) or whole pages (prefill) in place,
+and hand the attention paths the whole array plus a static layer index;
+between a layer's write and its attention no operation slices, copies or
+lays out again any part of it, and on a TPU it rests in the layout the
+Mosaic kernel reads (``_row_major``). ``stats()["step_program"]`` is the
+compiler's account of that (``temp_bytes``, ``bytes_accessed``).
+
+Program accounting mirrors InferenceEngine exactly: ``compile_log``
+entries, progcache get/put so a scaled-out replica deserializes instead
+of compiling (``decode.cache_hit`` vs ``decode.compile``), and
 analysis/trace.py::check_decode_engine proves the
 ``len(prompt_buckets) + 1`` program bound.
 
@@ -108,7 +118,8 @@ class DecodeEngine:
                  num_pages: Optional[int] = None,
                  prompt_buckets: Optional[List[int]] = None,
                  progcache_dir: Optional[str] = None):
-        from ..models.transformer import decode_config, decode_params
+        from ..models.transformer import (decode_config, decode_params,
+                                          stack_layers)
 
         if params is None:
             self.cfg = decode_config(lm)
@@ -143,21 +154,30 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        # the layers' weights stacked along a leading axis: prefill scans
+        # over them (one compiled layer body, not one per layer), the step
+        # takes layer i's as static slices, which cost nothing
+        params = dict(params, layers=stack_layers(params["layers"]))
         self._params = jax.tree_util.tree_map(
             lambda a: jnp.asarray(a, jnp.float32), params)
         self._param_avals = tuple(
             (tuple(a.shape), str(a.dtype))
             for a in jax.tree_util.tree_leaves(self._params))
         cfg = self.cfg
-        self.kv = jnp.zeros(
-            (self.num_pages, cfg["layers"], 2, self.page_size,
-             cfg["heads"], cfg["head_dim"]), jnp.float32)
-
-        # donating the pool buffer makes the per-step KV write in-place on
-        # TPU; CPU/GPU test backends would only warn about it
-        donate = (1,) if jax.default_backend() == "tpu" else ()
-        self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=donate)
-        self._step_jit = jax.jit(self._step_fn, donate_argnums=donate)
+        pool_shape = (self.num_pages, cfg["layers"], self.page_size,
+                      cfg["heads"], 2 * cfg["head_dim"])
+        if jax.default_backend() == "tpu":
+            pool = self._row_major(
+                pool_shape,
+                jax.tree_util.tree_leaves(self._params)[0].sharding)
+            # donating the pool buffer makes the KV writes in-place on TPU;
+            # CPU/GPU test backends would only warn about it
+            donate = (1,)
+        else:
+            pool, donate = None, ()
+        self.kv = jax.jit(lambda: jnp.zeros(pool_shape, jnp.float32),
+                          out_shardings=pool)()
+        self._prefill_jit, self._step_jit = self._jit_programs(pool, donate)
 
         # program accounting — mirrors InferenceEngine so the TraceLinter
         # and the coldstart idiom read both the same way
@@ -180,6 +200,29 @@ class DecodeEngine:
 
     # -- pure device programs ------------------------------------------
 
+    @staticmethod
+    def _row_major(shape, sharding):
+        """The layout the pool rests in on a TPU: row-major, which is what
+        the Mosaic call reads. Left to itself the TPU client lays an array
+        out by whichever axis order pads least (the page axis minor-most
+        for some widths), and every program would then convert the whole
+        pool on its way in and again on its way out."""
+        from jax.experimental.layout import Format, Layout
+
+        return Format(Layout(tuple(range(len(shape)))), sharding)
+
+    def _jit_programs(self, pool, donate):
+        """(prefill, step) jitted with the pool argument and result held
+        to ``pool`` (a ``Format``, or None for the backend's default)."""
+        import jax
+
+        def jit(fn, n_args):
+            return jax.jit(fn, donate_argnums=donate,
+                           in_shardings=(None, pool) + (None,) * (n_args - 2),
+                           out_shardings=(pool, None))
+
+        return jit(self._prefill_fn, 7), jit(self._step_fn, 8)
+
     def _prefill_fn(self, params, kv, tokens, length, page_ids, seed, temp):
         """One padded prompt (1, S) → KV pages written, first token.
         S is the bucket (multiple of page_size); ``page_ids``
@@ -196,14 +239,22 @@ class DecodeEngine:
         n = s // self.page_size
         cfg = self.cfg
 
-        def blocks(x):  # (L, 1, S, H, D) → (n, L, page, H, D)
-            x = jnp.squeeze(x, 1).reshape(
-                cfg["layers"], n, self.page_size, cfg["heads"],
-                cfg["head_dim"])
-            return jnp.transpose(x, (1, 0, 2, 3, 4))
+        # (L, 1, S, H, D) twice → (L, n, page, H, 2D), then page by page —
+        # every layer's rows of the page in one update — in place at the
+        # sequence's page ids. (One scatter would say the same; but for
+        # head counts off the 8-row tile XLA's TPU scatter wants the pool
+        # in a layout of its own, and converts the whole pool there and
+        # back.)
+        rows = jnp.concatenate([k, v], axis=-1).reshape(
+            cfg["layers"], n, self.page_size, cfg["heads"],
+            2 * cfg["head_dim"])
 
-        kv = kv.at[page_ids, :, 0].set(blocks(k))
-        kv = kv.at[page_ids, :, 1].set(blocks(v))
+        def write_page(j, kv):
+            page = jax.lax.dynamic_slice_in_dim(rows, j, 1, axis=1)
+            return jax.lax.dynamic_update_slice(
+                kv, jnp.swapaxes(page, 0, 1), (page_ids[j], 0, 0, 0, 0))
+
+        kv = jax.lax.fori_loop(0, n, write_page, kv)
         last = logits[0, length - 1]
         tok = sample_token(last[None], jax.random.PRNGKey(seed), temp)
         return kv, tok[0]
@@ -226,13 +277,14 @@ class DecodeEngine:
         pids = page_tables[rows, positions // self.page_size]
         offs = positions % self.page_size
         x = params["embed"][tokens] + params["pos"][positions]
-        for i, lp in enumerate(params["layers"]):
+        for i in range(cfg["layers"]):
+            lp = {k: w[i] for k, w in params["layers"].items()}
+
             def attend(q, k_new, v_new, _i=i):
                 nonlocal kv
-                kv = kv.at[pids, _i, 0, offs].set(k_new)
-                kv = kv.at[pids, _i, 1, offs].set(v_new)
-                return decode_attention(q, kv[:, _i, 0], kv[:, _i, 1],
-                                        page_tables, lengths)
+                kv = kv.at[pids, _i, offs].set(
+                    jnp.concatenate([k_new, v_new], axis=-1))
+                return decode_attention(q, kv, _i, page_tables, lengths)
 
             x, _, _ = decode_layer(cfg, lp, x, attend)
         x = _ln(x, params["final_g"], params["final_b"])
@@ -279,7 +331,9 @@ class DecodeEngine:
                     if cost:
                         entry.update(cost)
             entry["cache_hit"] = cache_hit
-            if not cache_hit and (obs.device.active() or pc is not None):
+            if not cache_hit:
+                # always through the AOT path: the one compile is measured
+                # (stats()["step_program"]) and run, observed or not
                 if obs.device.active():
                     compiled, cost = obs.device.capture(
                         jitted, call_args, site="decode", label=label,
@@ -395,6 +449,13 @@ class DecodeEngine:
                 "cache_hits": self.cache_hits,
                 "programs": {repr(k): v for k, v in self._programs.items()},
             }
+            step = next((e for e in self.compile_log
+                         if e["kind"] == "step"), None)
+        # the compiler's own account of the one step program (None until
+        # it is built): temporaries far under one layer's share of the pool
+        # say that no program slices, copies or lays the pool out again
+        out["step_program"] = step and {
+            k: step.get(k, 0) for k in ("temp_bytes", "bytes_accessed")}
         out["pool"] = self.pool.stats()
         if self._progcache is not None:
             out["progcache"] = dict(self._progcache.stats,

@@ -2,9 +2,11 @@
 
 Reference: vLLM's PagedAttention block tables (TBV — PAPERS.md), rebuilt
 on the engine.py pad-and-slice discipline: the device-resident KV pool is
-ONE fixed-shape array (``(pages, layers, 2, page_size, heads, head_dim)``,
-allocated once by ``serve/decode.py``), so no program ever sees a ragged
-cache shape — growth is a *page-table edit on the host*, never a retrace.
+ONE fixed-shape array (``(pages, layers, page_size, heads, 2 * head_dim)``,
+K and V of a position side by side on the minor axis; allocated once by
+``serve/decode.py`` and read and written where it lies — no program
+slices or copies it), so no program ever sees a ragged cache shape —
+growth is a *page-table edit on the host*, never a retrace.
 
 This module owns the host half: a :class:`PagePool` free list with
 per-sequence page tables, alloc/free at step granularity, and leak-checked

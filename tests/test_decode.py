@@ -468,37 +468,145 @@ def test_sample_token_greedy_and_temperature():
     assert a.tolist() == b.tolist()
 
 
-def test_decode_attention_parity():
-    from mxnet_tpu.ops.flash_attention import (_decode_attention_xla,
-                                               decode_attention,
-                                               flash_decode_attention)
+def _paged_case():
+    """A shared pool of three layers and a page table with everything a
+    decode batch can hold: a mid-page length, a full table, an inactive
+    row on scratch pages only, and a row that repeats a page, shares one
+    with another row and pads with the scratch page."""
     rng = np.random.RandomState(3)
-    n_pages, page, heads, dim, max_pages = 7, 4, 2, 8, 4
-    q = rng.randn(3, heads, dim).astype(np.float32)
-    k_pages = rng.randn(n_pages, page, heads, dim).astype(np.float32)
-    v_pages = rng.randn(n_pages, page, heads, dim).astype(np.float32)
-    table = np.zeros((3, max_pages), np.int32)
+    n_pages, layers, page, heads, dim = 9, 3, 4, 2, 8
+    q = rng.randn(4, heads, dim).astype(np.float32)
+    pool = rng.randn(n_pages, layers, page, heads,
+                     2 * dim).astype(np.float32)
+    table = np.full((4, 4), SCRATCH_PAGE, np.int32)
     table[0, :2] = [1, 2]
-    table[1, :4] = [3, 4, 5, 6]
-    lengths = np.array([5, 13, 0], np.int32)  # row 2 inactive
+    table[1] = [3, 4, 5, 6]
+    table[3, :3] = [7, 7, 3]
+    lengths = np.array([5, 16, 0, 10], np.int32)  # row 2 inactive
+    return q, pool, table, lengths
 
-    def ref_row(i):
-        ln = int(lengths[i])
-        ks = np.concatenate([k_pages[p] for p in table[i]], 0)[:ln]
-        vs = np.concatenate([v_pages[p] for p in table[i]], 0)[:ln]
+
+def _paged_reference(q, pool, layer, table, lengths):
+    """Row by row in numpy: K is ``pool[p, layer, :, :, :D]``, V the other
+    half. Inactive rows (length 0) are left NaN: garbage by contract."""
+    dim = q.shape[-1]
+    out = np.full(q.shape, np.nan, np.float32)
+    for i, ln in enumerate(lengths):
+        if ln == 0:
+            continue
+        rows = np.concatenate([pool[p, layer] for p in table[i]], 0)[:ln]
+        ks, vs = rows[..., :dim], rows[..., dim:]
         s = np.einsum("hd,lhd->hl", q[i], ks) / math.sqrt(dim)
         p = np.exp(s - s.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
-        return np.einsum("hl,lhd->hd", p, vs)
+        out[i] = np.einsum("hl,lhd->hd", p, vs)
+    return out
 
-    for fn in (lambda *a: _decode_attention_xla(*a, 1.0 / math.sqrt(dim)),
-               decode_attention,
-               lambda *a: flash_decode_attention(*a, interpret=True)):
-        out = np.asarray(fn(q, k_pages, v_pages, table, lengths))
-        assert out.shape == q.shape
-        for i in (0, 1):  # inactive row 2 is garbage by contract
-            np.testing.assert_allclose(out[i], ref_row(i), rtol=2e-5,
-                                       atol=2e-5)
+
+def _paged_impl(name):
+    from mxnet_tpu.ops.flash_attention import (_decode_attention_xla,
+                                               decode_attention,
+                                               flash_decode_attention)
+    return {
+        "xla": lambda q, *a: _decode_attention_xla(
+            q, *a, 1.0 / math.sqrt(q.shape[-1])),
+        "dispatch": decode_attention,
+        "pallas": lambda *a: flash_decode_attention(*a, interpret=True),
+    }[name]
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("impl", ["xla", "dispatch", "pallas"])
+def test_decode_attention_parity(impl, layer):
+    """Every path reads layer ``layer`` of the one shared pool, K and V
+    from their halves of the minor axis, and agrees with numpy."""
+    q, pool, table, lengths = _paged_case()
+    out = np.asarray(_paged_impl(impl)(q, pool, layer, table, lengths))
+    want = _paged_reference(q, pool, layer, table, lengths)
+    assert out.shape == q.shape and np.all(np.isfinite(out))
+    live = lengths > 0
+    np.testing.assert_allclose(out[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["xla", "dispatch", "pallas"])
+def test_decode_attention_reads_only_its_layer(impl):
+    """Rewriting every OTHER layer's pages (and the dead tail of the pages
+    a row holds) changes no bit of layer 1's attention: the paths address
+    ``(page, layer)``, not a copy or a neighbour of it."""
+    q, pool, table, lengths = _paged_case()
+    fn = _paged_impl(impl)
+    before = np.asarray(fn(q, pool, 1, table, lengths))
+    other = pool.copy()
+    other[:, [0, 2]] = -other[:, [0, 2]] + 3.0
+    other[2, 1, 1:] = 7.0     # row 0 holds 5 positions: page 2 from slot 1
+    after = np.asarray(fn(q, other, 1, table, lengths))
+    live = lengths > 0
+    np.testing.assert_array_equal(before[live], after[live])
+
+
+def _engine_with_random_pool(lm):
+    import jax.numpy as jnp
+    eng = DecodeEngine(lm, slots=4, page_size=8, num_pages=16,
+                       prompt_buckets=[8, 16])
+    rng = np.random.RandomState(5)
+    before = rng.randn(*eng.kv.shape).astype(np.float32)
+    eng.kv = jnp.asarray(before)
+    return eng, before
+
+
+def test_step_writes_one_row_per_slot_and_layer(lm):
+    """A decode step's KV write lands on row ``(page, layer, offset)`` of
+    each slot, for every layer, K and V halves both — and every other
+    element of the pool, all layers, stays bit-identical."""
+    eng, before = _engine_with_random_pool(lm)
+    dim = eng.cfg["head_dim"]
+    positions = np.array([3, 8, 21, 0], np.int32)
+    tables = np.full((4, eng.max_pages), SCRATCH_PAGE, np.int32)
+    tables[0, :1] = [5]
+    tables[1, :2] = [2, 9]
+    tables[2, :3] = [4, 7, 11]
+    lengths = np.array([4, 9, 22, 0], np.int32)   # slot 3 inactive
+    eng.step(np.array([1, 2, 3, 0], np.int32), positions, tables, lengths,
+             np.zeros((4,), np.float32))
+    after = np.asarray(eng.kv)
+    assert after.shape == before.shape == (16, 2, 8, 4, 2 * dim)
+    written = np.zeros(before.shape[:3], bool)       # (page, layer, offset)
+    for slot, pos in enumerate(positions):
+        written[tables[slot, pos // 8], :, pos % 8] = True
+    np.testing.assert_array_equal(after[~written], before[~written])
+    live = [(5, 3), (9, 0), (11, 5)]                 # the active slots' rows
+    for page, off in live:
+        for layer in range(2):
+            row, old = after[page, layer, off], before[page, layer, off]
+            assert not np.any(row[:, :dim] == old[:, :dim])   # K written
+            assert not np.any(row[:, dim:] == old[:, dim:])   # V written
+    # layer 0 and layer 1 hold different rows: no layer was written twice
+    assert not np.array_equal(after[5, 0, 3], after[5, 1, 3])
+
+
+def test_prefill_writes_whole_pages_of_every_layer(lm):
+    """Prefill scatters the prompt's K and V into its own pages, every
+    layer's, and nowhere else; what it wrote is what ``lm_prefill``
+    computed, position by position."""
+    eng, before = _engine_with_random_pool(lm)
+    dim = eng.cfg["head_dim"]
+    prompt = np.arange(1, 12, dtype=np.int32)        # 11 tokens, bucket 16
+    eng.prefill(prompt, [6, 3])
+    after = np.asarray(eng.kv)
+    untouched = np.ones(16, bool)
+    untouched[[6, 3]] = False
+    np.testing.assert_array_equal(after[untouched], before[untouched])
+    padded = np.zeros((1, 16), np.int32)
+    padded[0, :11] = prompt
+    _logits, k, v = lm_prefill(eng.cfg, decode_params(lm), padded)
+    for layer in range(2):
+        got = np.concatenate([after[6, layer], after[3, layer]], 0)
+        np.testing.assert_allclose(got[:11, :, :dim],
+                                   np.asarray(k)[layer, 0, :11],
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(got[:11, :, dim:],
+                                   np.asarray(v)[layer, 0, :11],
+                                   rtol=1e-6, atol=1e-6)
 
 
 def test_engine_greedy_matches_dense_reference(stack, lm):
@@ -624,7 +732,10 @@ def test_fleet_stream_relay_failover_and_merged_timeline():
         srv.start()
         return srv
 
-    pool = ReplicaPool.local(factory, 2, probe_interval=0.2)
+    # the probe must not beat the four requests below to the killed
+    # replica, or nothing is left to fail over from: under a loaded
+    # machine 0.2 s did (937 passed, this one failed, PR 28's first whole run)
+    pool = ReplicaPool.local(factory, 2, probe_interval=1.0)
     pool.start()
     router = Router(pool, breaker_cooldown=0.3)
     front = FleetServer(router, port=0)

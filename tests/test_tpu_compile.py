@@ -1,0 +1,193 @@
+"""What the TPU's compiler makes of the decode programs, without the chip.
+
+libtpu is installed on a CPU-only host and compiles for a chip that is
+described and not attached (docs: .claude/skills/verify/SKILL.md "Checking
+a kernel or a step against Mosaic WITHOUT the chip"). Nothing runs, so no
+result or time is checked here — only what the optimised program *is*: that
+the paged kernel goes through Mosaic, and that no decode program slices,
+copies or lays out again any part of the KV pool.
+
+A CPU program cannot show the second point. XLA:CPU fuses a per-layer slice
+of the pool into the gather that reads it, so its ``temp_bytes`` were tiny
+before the pool was addressed in place and are tiny after (the last test
+says how tiny); and the page-axis-minor layout the TPU client picks for a
+pool-shaped array, which cost two whole-pool conversions a program, does
+not exist off the TPU.
+
+The topology is described inside a fixture and in this file alone: only one
+process may hold libtpu, and a worker must not load it while it imports or
+collects (on-chip-measurement guide, section 2).
+"""
+import numpy as np
+import pytest
+
+from mxnet_tpu import obs
+from mxnet_tpu.ops import flash_attention
+from mxnet_tpu.serve import DecodeEngine
+
+pytestmark = pytest.mark.decode
+
+# 12 heads: not a multiple of the 8-row tile, so the TPU's own choice of
+# layout for the pool is NOT row-major — the engine has to ask for it
+CFG = {"vocab": 512, "units": 768, "heads": 12, "head_dim": 64, "layers": 2,
+       "max_length": 128}
+HIDDEN = 256
+SLOTS, PAGE, NUM_PAGES, BUCKET = 2, 16, 1025, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else the compiler logs to /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu here, or another process has it
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip can be written to the persistent
+        # cache but never read back without the chip: keep it out
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def engine():
+    u, v = CFG["units"], CFG["vocab"]
+    layer = {"qkv_w": (3 * u, u), "qkv_b": (3 * u,), "proj_w": (u, u),
+             "proj_b": (u,), "ln1_g": (u,), "ln1_b": (u,),
+             "ffn1_w": (HIDDEN, u), "ffn1_b": (HIDDEN,),
+             "ffn2_w": (u, HIDDEN), "ffn2_b": (u,), "ln2_g": (u,),
+             "ln2_b": (u,)}
+    top = {"embed": (v, u), "pos": (CFG["max_length"], u), "final_g": (u,),
+           "final_b": (u,), "dec_w": (v, u), "dec_b": (v,)}
+    params = {k: np.zeros(s, np.float32) for k, s in top.items()}
+    params["layers"] = [{k: np.zeros(s, np.float32)
+                         for k, s in layer.items()}
+                        for _ in range(CFG["layers"])]
+    return DecodeEngine(CFG, params=params, slots=SLOTS, page_size=PAGE,
+                        num_pages=NUM_PAGES, prompt_buckets=[BUCKET])
+
+
+def _tpu_program(engine, one_chip, kind, monkeypatch):
+    """The engine's own program, jitted as its constructor does on a TPU
+    (pool row-major and donated) and compiled for the described chip."""
+    import jax
+    import jax.numpy as jnp
+
+    # the backend here is cpu, so the library would pick interpret mode
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+
+    def spec(shape, dtype=jnp.float32, sharding=one_chip):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool = engine._row_major(engine.kv.shape, one_chip)
+    prefill, step = engine._jit_programs(pool, donate=(1,))
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape), engine._params)
+    kv = spec(engine.kv.shape, sharding=pool)
+    i32 = jnp.int32
+    if kind == "step":
+        lowered = step.lower(
+            params, kv, spec((SLOTS,), i32), spec((SLOTS,), i32),
+            spec((SLOTS, engine.max_pages), i32), spec((SLOTS,), i32),
+            spec((), jnp.uint32), spec((SLOTS,)))
+    else:
+        lowered = prefill.lower(
+            params, kv, spec((1, BUCKET), i32), spec((), i32),
+            spec((BUCKET // PAGE,), i32), spec((), jnp.uint32), spec(()))
+    return lowered, lowered.compile()
+
+
+def _k_slice_bytes(engine):
+    """One layer's K of the pool: what ``kv[:, i, 0]`` used to make."""
+    return engine.kv.nbytes // (2 * CFG["layers"])
+
+
+def _pool_lines(compiled, engine):
+    """Instructions of the optimised entry computation that yield or take
+    a pool-shaped array."""
+    shape = "f32[" + ",".join(str(n) for n in engine.kv.shape) + "]"
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY ") + 1:]
+    return [line.strip() for line in entry.splitlines()[1:]
+            if shape in line]
+
+
+def test_tpu_step_program_reads_the_pool_where_it_lies(
+        engine, one_chip, monkeypatch):
+    """Structural, at a size where the pool (201.5 MB, 1025 pages)
+    dominates the weights (19 MB) and the pages far outnumber
+    slots x max_pages (16).
+
+    The step compiled for a v5e allocates less than one layer's K of the
+    pool (50.4 MB): this tree reads ``temp_bytes`` 0 and ``bytes_accessed``
+    65 MB; the tree before it — pool ``(pages, layers, 2, page, H, D)``,
+    sliced per layer for the kernel — read 808,135,680 and 2.72 GB at the
+    same size: the whole pool converted from the page-minor layout it
+    rested in and back, and a padded K and V slice per layer (compile-only
+    run of commit 35acd89, PR 28). Here the pool parameter rests
+    row-major, and the only instructions that touch a pool-shaped array
+    are the in-place scatters (one per layer), the Mosaic calls (one per
+    layer), the parameter and the result."""
+    lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
+    assert lowered.as_text().count("tpu_custom_call") == CFG["layers"]
+    cost = obs.device.analyze_compiled(compiled)
+    assert cost["bytes_accessed"] > 0
+    assert cost["temp_bytes"] < _k_slice_bytes(engine), cost
+    lines = _pool_lines(compiled, engine)
+    assert "{4,3,2,1,0:T(8,128)}" in lines[0] and "parameter(" in lines[0]
+    kinds = sorted(
+        "scatter" if " fusion(" in line and "scatter" in line
+        else "mosaic" if "tpu_custom_call" in line
+        else "root" if line.startswith("ROOT ") and " tuple(" in line
+        else line for line in lines[1:])
+    assert kinds == (["mosaic"] * CFG["layers"] + ["root"]
+                     + ["scatter"] * CFG["layers"]), kinds
+    # donated and written in place: the result IS the parameter's buffer
+    assert cost["alias_bytes"] >= engine.kv.nbytes
+
+
+def test_tpu_prefill_program_writes_the_pool_in_place(
+        engine, one_chip, monkeypatch):
+    """A prefill writes its pages into the donated pool, page by page in a
+    loop, and does nothing else to it: ``temp_bytes`` 1,354,752 here
+    against 405,304,320 in the tree before (the same two conversions of the
+    whole pool). As ONE scatter of all the pages the write had XLA convert
+    the whole pool to ``{4,2,3,1,0}`` and back (12 heads pad an 8-row tile;
+    the scatter would rather tile the page axis): two pool-shaped ``copy``
+    instructions, which is what the lines are searched for."""
+    _lowered, compiled = _tpu_program(engine, one_chip, "prefill",
+                                      monkeypatch)
+    cost = obs.device.analyze_compiled(compiled)
+    assert cost["temp_bytes"] < _k_slice_bytes(engine), cost
+    lines = _pool_lines(compiled, engine)
+    assert "{4,3,2,1,0:T(8,128)}" in lines[0] and "parameter(" in lines[0]
+    assert not [line for line in lines
+                if " copy(" in line or " fusion(" in line], lines
+    assert any(" while(" in line for line in lines), lines
+    assert cost["alias_bytes"] >= engine.kv.nbytes
+
+
+def test_step_program_stats_on_this_backend(engine):
+    """``stats()["step_program"]`` is the compiler's account of the step
+    the engine really runs — here the CPU's, XLA gather path. It reads
+    ``temp_bytes`` 22,065,792 (XLA:CPU copies each layer's weights out of
+    their stack, 19 MB, and gathers the 16 live pages twice) against a K
+    slice of 50.4 MB; the tree before read 1,612,096 on the CPU too (see
+    the module docstring), so this holds the line and shows the counter,
+    not the cure. None until the step is built."""
+    assert engine.stats()["step_program"] is None
+    engine.warmup()
+    program = engine.stats()["step_program"]
+    assert set(program) == {"temp_bytes", "bytes_accessed"}
+    assert 0 < program["temp_bytes"] < _k_slice_bytes(engine)
+    assert program["bytes_accessed"] > 0
+    engine.pool.assert_baseline()
