@@ -13,6 +13,11 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
 - *serve*     ``DecodeEngine`` -> ``DecodeScheduler`` -> ``ServeServer``,
               concurrent ``ServeClient.generate()`` streams checked token
               for token against the dense reference run on the chip;
+- *latent*    a small latent-attention + routed-experts model
+              (``models.mla_moe``, the cache row at its published 576
+              bfloat16 values in 640 columns) through ``DecodeEngine``: the paged latent
+              kernel against the XLA gather on the chip, the step program's
+              temporaries under one layer's latents, nothing dropped;
 - *multichip* with >= 4 chips: the same trainer on dp2 x tp2, then on
               dp2 x sp2 at seq 4096 (ring attention, the Pallas kernel
               inside shard_map). Otherwise reported ``not_run``.
@@ -442,6 +447,78 @@ def phase_serve():
     return attn
 
 
+LATENT = {"vocab_size": 4096, "hidden_size": 1024, "num_layers": 3,
+          "first_dense": 1, "num_heads": 16, "qk_nope": 128, "qk_rope": 64,
+          "v_head": 128, "kv_rank": 512, "dense_width": 2048,
+          "expert_width": 512, "router_experts": 32, "experts_first": 8,
+          "experts_held": 8, "experts_per_token": 8, "routed_scale": 2.5,
+          "rms_eps": 1e-6, "max_length": 2048,
+          "rope": {"theta": 10000, "factor": 40,
+                   "original_max_position_embeddings": 4096, "beta_fast": 32,
+                   "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}}
+
+
+def phase_latent():
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import mla_moe
+    from mxnet_tpu.ops.flash_attention import (_latent_decode_attention_xla,
+                                               flash_latent_decode_attention)
+    from mxnet_tpu.serve import DecodeEngine
+    from mxnet_tpu.serve.kvcache import SCRATCH_PAGE
+
+    with _Phase("latent"):
+        slots, page = 4, 256
+        model = mla_moe.MLAMoEDecodeModel(LATENT, seed=3)
+        engine = DecodeEngine(model, slots=slots, page_size=page,
+                              num_pages=slots * 8 + 1, prompt_buckets=[512])
+        engine.warmup()
+        rng = np.random.RandomState(4)
+        prompt = rng.randint(0, LATENT["vocab_size"], 300)
+        engine.pool.alloc(0, 2)
+        table = engine.pool.table(0)
+        tok = engine.prefill(prompt, table)
+        _require(engine.last_counters["moe.dropped"] == 0
+                 and engine.last_counters["moe.assignments"] == 2 * 300 * 8,
+                 f"prefill counted {engine.last_counters}")
+        tables = np.full((slots, engine.max_pages), SCRATCH_PAGE, np.int32)
+        tables[0, :len(table)] = table
+        z = np.zeros((slots,), np.int32)
+        for i in range(4):
+            pos, lengths, toks = z.copy(), z.copy(), z.copy()
+            pos[0], lengths[0], toks[0] = 300 + i, 301 + i, tok
+            tok = int(engine.step(toks, pos, tables, lengths,
+                                  np.zeros((slots,), np.float32))[0])
+            _require(engine.last_counters["moe.dropped"] == 0
+                     and engine.last_counters["moe.assignments"] == 2 * 8,
+                     f"step counted {engine.last_counters}")
+        # the kernel against the gather, over the rows the engine just wrote
+        q = jax.random.normal(jax.random.PRNGKey(5), (slots, 16, 640),
+                              jnp.bfloat16)
+        args = (q, engine.kv, 1, jnp.asarray(tables),
+                jnp.asarray([304, 0, 0, 0], jnp.int32), 512, 0.135)
+        kernel = jax.jit(flash_latent_decode_attention, static_argnums=(2, 5, 6))
+        _require_mosaic(kernel, *args)
+        got = np.asarray(kernel(*args), np.float32)[0]
+        want = np.asarray(jax.jit(_latent_decode_attention_xla,
+                                  static_argnums=(2, 5, 6))(*args),
+                          np.float32)[0]
+        _require(np.all(np.isfinite(got)), "latent kernel: non-finite")
+        _check_close("paged latent decode (16 x 640) over 304 positions",
+                     got, want, 3e-2)
+        engine.pool.free(0)
+        engine.pool.assert_baseline()
+        program = engine.stats()["step_program"]
+        layer = engine.kv.nbytes // LATENT["num_layers"]
+        print(f"   latent pool {engine.kv.shape} {engine.kv.dtype}: step "
+              f"temp_bytes {program['temp_bytes']}, one layer's latents "
+              f"{layer} bytes", flush=True)
+        _require(program["temp_bytes"] < layer,
+                 f"the latent step allocates {program['temp_bytes']} bytes: "
+                 "it slices, copies or lays the pool out again")
+
+
 def _step_text(engine):
     z = np.zeros((engine.slots,), np.int32)
     tables = np.zeros((engine.slots, engine.max_pages), np.int32)
@@ -461,6 +538,7 @@ def main():
     phase_kernels()
     losses, train_attn = phase_train()
     decode_attn = phase_serve()
+    phase_latent()
     multichip_attn = phase_multichip(losses[0])
     print("summary " + json.dumps({
         "phases": _Phase.results,

@@ -437,6 +437,67 @@ def lm_prefill(cfg, params, tokens):
     return logits, k, v
 
 
+class TransformerDecodeModel:
+    """``TransformerLM``'s decode programs behind the interface
+    ``serve.DecodeEngine`` takes a model by: ``cfg`` (``max_length``),
+    ``params`` (a device tree), the cache row of one position in one layer
+    (``cache_row``, ``cache_dtype``; here K and V of every head side by
+    side), ``prefill``, ``step`` and ``attention``. ``lm`` is an initialized
+    block, or its config dict with ``params`` as ``decode_params`` gives
+    them."""
+
+    counters = ()           # this model reports none with its tokens
+
+    def __init__(self, lm, params=None):
+        import jax
+        import jax.numpy as jnp
+
+        if params is None:
+            self.cfg, params = decode_config(lm), decode_params(lm)
+        else:
+            self.cfg = dict(lm)
+        self.layers = self.cfg["layers"]
+        self.cache_row = (self.cfg["heads"], 2 * self.cfg["head_dim"])
+        self.cache_dtype = jnp.float32
+        # the layers' weights stacked along a leading axis: prefill scans
+        # over them (one compiled layer body, not one per layer), the step
+        # takes layer i's as static slices, which cost nothing
+        params = dict(params, layers=stack_layers(params["layers"]))
+        self.params = jax.tree_util.tree_map(
+            lambda a: jnp.asarray(a, jnp.float32), params)
+
+    def prefill(self, params, tokens, length):
+        """tokens (1, S) -> (logits at ``length - 1`` (V,), every layer's
+        cache rows (L, S, H, 2D), None)."""
+        import jax.numpy as jnp
+
+        logits, k, v = lm_prefill(self.cfg, params, tokens)
+        rows = jnp.concatenate([k, v], axis=-1)
+        return logits[0, length - 1], rows.reshape(
+            (self.layers, tokens.shape[1]) + self.cache_row), None
+
+    def step(self, params, tokens, positions, live, attend):
+        """One position for every slot; ``attend(layer, q, row)`` writes
+        the row into the cache and attends. Returns (logits (B, V), None)."""
+        import jax.numpy as jnp
+
+        x = params["embed"][tokens] + params["pos"][positions]
+        for i in range(self.layers):
+            lp = {k: w[i] for k, w in params["layers"].items()}
+
+            def attend_kv(q, k_new, v_new, _i=i):
+                return attend(_i, q, jnp.concatenate([k_new, v_new], axis=-1))
+
+            x, _, _ = decode_layer(self.cfg, lp, x, attend_kv)
+        x = _ln(x, params["final_g"], params["final_b"])
+        return _dense(x, params["dec_w"], params["dec_b"]), None
+
+    def attention(self, query, pool, layer, page_table, lengths):
+        from ..ops.flash_attention import decode_attention
+
+        return decode_attention(query, pool, layer, page_table, lengths)
+
+
 def lm_decode_step(cfg, params, tokens, kv, positions):
     """One decode step over dense KV (the paged engine's reference).
 

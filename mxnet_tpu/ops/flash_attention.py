@@ -47,7 +47,8 @@ from jax import lax
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "decode_attention", "decode_attention_impl",
-           "flash_decode_attention"]
+           "flash_decode_attention", "latent_decode_attention",
+           "flash_latent_decode_attention"]
 
 _NEG_INF = -1e30  # avoids -inf NaN propagation inside the kernel
 _LOG2E = math.log2(math.e)
@@ -78,8 +79,10 @@ def _dotA(a, b, prec):
                            preferred_element_type=jnp.float32, precision=prec)
 
 
-def _fwd_core(q, load_kv, offset, q_start, s_total, block_k, scale, causal):
+def _fwd_core(q, load_kv, offset, q_start, s_total, block_k, scale, causal,
+              d_v):
     """Shared fwd tile loop: one resident q block vs streamed K/V blocks.
+    ``d_v`` is the width of a value row (it need not be q's and k's).
 
     Phase split: blocks [0, nk_full) are fully visible (no mask math);
     blocks [nk_full, nk_run) get the causal iota mask. Softmax statistics
@@ -88,7 +91,7 @@ def _fwd_core(q, load_kv, offset, q_start, s_total, block_k, scale, causal):
     the ref slicing.
     Returns (normalized out f32, lse).
     """
-    bq, d = q.shape
+    bq = q.shape[0]
     nk = s_total // block_k
     prec = _dot_prec(q.dtype)
     c = scale * _LOG2E  # exp(s*scale - m) == exp2((s - m_raw) * c)
@@ -123,7 +126,7 @@ def _fwd_core(q, load_kv, offset, q_start, s_total, block_k, scale, causal):
         l = l * corr + jnp.sum(p, axis=-1)
         return acc, new_m, l
 
-    acc0 = jnp.zeros((bq, d), jnp.float32)
+    acc0 = jnp.zeros((bq, d_v), jnp.float32)
     m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     carry = lax.fori_loop(0, nk_full,
@@ -157,7 +160,8 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
                 v_ref[0, pl.ds(j * block_k, block_k), :])
 
     out, lse = _fwd_core(q, load_kv, off_ref[0], q_blk_idx * block_q,
-                         k_ref.shape[1], block_k, scale, causal)
+                         k_ref.shape[1], block_k, scale, causal,
+                         v_ref.shape[2])
     o_ref[0] = out.astype(o_ref.dtype)
     # lse lives in an (bq, 8)-lane block purely to satisfy TPU tiling
     lse_ref[0] = jnp.broadcast_to(lse[:, None], (lse.shape[0], 8))
@@ -182,10 +186,11 @@ def _fwd_pallas(q, k, v, offset, scale, causal, block_q, block_k, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
+    dv = v.shape[-1]          # a value row may be narrower than q's and k's
     bh = b * h
     q3 = q.reshape(bh, s, d)
     k3 = k.reshape(bh, s, d)
-    v3 = v.reshape(bh, s, d)
+    v3 = v.reshape(bh, s, dv)
     off = _match_vma(jnp.asarray(offset, jnp.int32).reshape(1), q)
     grid = (bh, s // block_q)
     kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
@@ -197,19 +202,19 @@ def _fwd_pallas(q, k, v, offset, scale, causal, block_q, block_k, interpret):
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, s, dv), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, 8), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            _sds((bh, s, d), q.dtype, q),
+            _sds((bh, s, dv), q.dtype, q),
             _sds((bh, s, 8), jnp.float32, q),
         ],
         interpret=interpret,
     )(off, q3, k3, v3)
-    return out.reshape(b, h, s, d), lse[..., 0].reshape(b, h, s)
+    return out.reshape(b, h, s, dv), lse[..., 0].reshape(b, h, s)
 
 
 # ---------------------------------------------------------------------------
@@ -472,6 +477,9 @@ def _flash_fwd(q, k, v, offset, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+    if res[2].shape[-1] != res[0].shape[-1]:
+        raise NotImplementedError(
+            "flash attention with d_v != d_qk is forward-only (prefill)")
     impl = os.environ.get("MXNET_FLASH_BWD", "auto")
     use_pallas = impl == "pallas" or (impl == "auto" and not interpret)
     if use_pallas:
@@ -503,7 +511,9 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None, offset=0,
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None):
-    """Flash attention. q,k,v: (B, H, S, D) → (B, H, S, D)."""
+    """Flash attention. q, k (B, H, S, D), v (B, H, S, Dv) → (B, H, S, Dv);
+    ``Dv != D`` (latent attention's 192-wide keys, 128-wide values) in the
+    forward pass only."""
     out, _ = flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
                                       block_q=block_q, block_k=block_k)
     return out
@@ -674,3 +684,131 @@ def decode_attention(q, pool, layer, page_table, lengths, scale=None):
                                       scale=scale,
                                       interpret=_use_interpret())
     return _decode_attention_xla(q, pool, layer, page_table, lengths, scale)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA, absorbed form) decode attention over a paged latent pool
+#
+# The pool is ``(pages, layers, page_size, R)``: ONE row per position per
+# layer and no head axis — the compressed latent ``c`` (the first ``d_v``
+# columns) and the shared rotary key beside it. Every head's query (already
+# taken through ``W_uk``: ``[q_nope.W_uk || q_rope]``, R wide) scores against
+# that one row, and the values are the row's first ``d_v`` columns; ``W_uv``
+# is applied by the caller. Addressed as the kernel above: the pool whole,
+# a static layer, page table and lengths; block (b, j) is
+# ``pool[table[b, j], layer]``.
+# ---------------------------------------------------------------------------
+
+
+def _latent_decode_attention_xla(q, pool, layer, page_table, lengths, d_v,
+                                 scale):
+    """Gather-then-attend reference. q (B, H, R); pool (P, L, page, R);
+    returns (B, H, d_v)."""
+    b = q.shape[0]
+    rows = pool[page_table, layer]            # (B, max_pages, page, R)
+    rows = rows.reshape(b, -1, rows.shape[-1])
+    prec = _dot_prec(q.dtype)
+    scores = jnp.einsum("bhr,bsr->bhs", q, rows,
+                        preferred_element_type=jnp.float32,
+                        precision=prec) * scale
+    live = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]
+    scores = jnp.where(live[:, None], scores, _NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1).astype(pool.dtype)
+    return jnp.einsum("bhs,bsv->bhv", p, rows[..., :d_v],
+                      preferred_element_type=jnp.float32,
+                      precision=prec).astype(q.dtype)
+
+
+def flash_latent_decode_attention(q, pool, layer, page_table, lengths, d_v,
+                                  scale, interpret=False):
+    """Pallas paged latent decode attention; shapes as
+    ``latent_decode_attention``. Grid (B, max_pages), the page axis
+    innermost: per slot, the H x R queries meet one ``(page, R)`` block a
+    step on the MXU (H rows: a real matmul, unlike the per-head kernel
+    above), online softmax in float32 in the log2 domain, the running
+    ``(H, d_v)`` sum in the output block; ``pl.when`` skips the pages past
+    the sequence's length (their block index repeats the scratch page, so
+    nothing is fetched for them)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, r = q.shape
+    page = pool.shape[2]
+    layer = int(layer)
+    max_pages = page_table.shape[1]
+    s2_scale = float(scale) * _LOG2E
+    prec = _dot_prec(pool.dtype)
+
+    def kernel(pt_ref, len_ref, q_ref, kv_ref, o_ref, m_ref, l_ref):
+        seq = pl.program_id(0)
+        j = pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        length = len_ref[seq]
+
+        @pl.when(j * page < length)
+        def _block():
+            blk = kv_ref[0]                                 # (page, R)
+            sc = _dotT(q_ref[0], blk, prec) * s2_scale      # (H, page)
+            pos = j * page + lax.broadcasted_iota(jnp.int32, (h, page), 1)
+            sc = jnp.where(pos < length, sc, _NEG_INF)
+            m_prev = m_ref[:, 0:1]                          # (H, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp2(m_prev - m_new)
+            # this block holds a live column, so m_new is finite and the
+            # masked columns' exp2 is 0
+            p = jnp.exp2(sc - m_new)
+            l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            o_ref[0] = o_ref[0] * alpha + jnp.dot(
+                p.astype(blk.dtype), blk[:, :d_v],
+                preferred_element_type=jnp.float32, precision=prec)
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+        @pl.when(j == max_pages - 1)
+        def _norm():
+            o_ref[0] = o_ref[0] / jnp.maximum(l_ref[:, 0:1], 1e-30)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, max_pages),
+        in_specs=[
+            pl.BlockSpec((1, h, r), lambda sq, j, pt, ln: (sq, 0, 0)),
+            pl.BlockSpec((1, None, page, r),
+                         lambda sq, j, pt, ln: (pt[sq, j], layer, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, h, d_v), lambda sq, j, pt, ln: (sq, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 128), jnp.float32),   # running max (log2)
+            pltpu.VMEM((h, 128), jnp.float32),   # running denominator
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d_v), jnp.float32),
+        interpret=interpret,
+        name="mla_decode",
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
+    return out.astype(q.dtype)
+
+
+def latent_decode_attention(q, pool, layer, page_table, lengths, d_v, scale):
+    """Single-position latent attention against one layer of a paged latent
+    pool. q (B, H, R) — every head's absorbed query; pool (P, L, page_size,
+    R) — the whole pool, a position's latent and rotary key on one row;
+    ``layer`` a Python int; page_table (B, max_pages) int32; lengths (B,)
+    int32 (0 = inactive row, output garbage). Returns (B, H, d_v): each
+    head's softmax-weighted sum of the rows' first ``d_v`` columns.
+    :func:`decode_attention_impl` picks the path."""
+    if decode_attention_impl() == "pallas":
+        return flash_latent_decode_attention(
+            q, pool, layer, page_table, lengths, d_v, scale,
+            interpret=_use_interpret())
+    return _latent_decode_attention_xla(q, pool, layer, page_table, lengths,
+                                        d_v, scale)

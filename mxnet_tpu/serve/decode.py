@@ -17,9 +17,16 @@ shapes serve every generation:
   paged-KV write + paged attention (ops/flash_attention.decode_attention),
   LM head, on-device greedy/temperature sampling.
 
+The engine takes the **model by interface** (``DecodeEngine``'s docstring):
+what a cache row is, the prefill and step bodies and the paged read are the
+model's; pages, programs, accounting and the scheduler are shared by every
+model (``models.transformer.TransformerDecodeModel`` — K and V of every head
+side by side, float32; ``models.mla_moe.MLAMoEDecodeModel`` — one latent
+row and no head axis, bfloat16).
+
 Growing a sequence never changes a program shape: the KV pool is one
-fixed array ``(pages, layers, page_size, heads, 2 * head_dim)`` — K and V
-of a position side by side — and growth is a host-side page-table edit
+fixed array ``(pages, layers, page_size) + model.cache_row`` of
+``model.cache_dtype`` and growth is a host-side page-table edit
 (serve/kvcache.py) — the engine.py pad-and-slice idiom applied to the
 time axis. The pool is read and written where it lies: the programs
 donate it, write single rows (step) or whole pages (prefill) in place,
@@ -87,14 +94,28 @@ def default_decode_buckets(max_prompt: int, page_size: int) -> List[int]:
 
 
 class DecodeEngine:
-    """Paged-KV generation engine around a :class:`TransformerLM`.
+    """Paged-KV generation engine around a decode model.
 
     Parameters
     ----------
-    lm : TransformerLM or dict
-        An initialized LM block (config/params extracted via
-        models/transformer.decode_config/decode_params), or the config
-        dict itself when ``params`` is given.
+    lm : decode model, TransformerLM or dict
+        A **decode model**: an object with ``cfg`` (a dict with
+        ``max_length``), ``params`` (a tree of device arrays, passed to the
+        programs as their first argument), ``layers``, ``cache_row`` and
+        ``cache_dtype`` (the pool is ``(pages, layers, page_size) +
+        cache_row`` of that dtype), ``counters`` (full names, as
+        ``"moe.held"``, of the int32 values its bodies return beside the
+        logits; may be empty), and three pure
+        functions: ``prefill(params, tokens (1, S), length) -> (last logits
+        (V,), rows (layers, S) + cache_row, counters or None)``,
+        ``step(params, tokens (B,), positions (B,), live (B,), attend) ->
+        (logits (B, V), counters or None)`` where ``attend(layer, query,
+        row)`` writes ``row`` (B,) + cache_row at the step's positions and
+        returns ``attention(query, pool, layer, page_tables, lengths)``, and
+        that ``attention`` itself, the paged read of one layer.
+        Anything else is taken for a ``TransformerLM`` (an initialized
+        block, or its config dict when ``params`` is given) and wrapped in
+        ``models.transformer.TransformerDecodeModel``.
     params : dict, optional
         Pre-extracted param dict (host numpy) when ``lm`` is a config.
     slots : int
@@ -118,14 +139,13 @@ class DecodeEngine:
                  num_pages: Optional[int] = None,
                  prompt_buckets: Optional[List[int]] = None,
                  progcache_dir: Optional[str] = None):
-        from ..models.transformer import (decode_config, decode_params,
-                                          stack_layers)
-
-        if params is None:
-            self.cfg = decode_config(lm)
-            params = decode_params(lm)
+        if hasattr(lm, "prefill") and hasattr(lm, "step"):
+            self.model = lm
         else:
-            self.cfg = dict(lm)
+            from ..models.transformer import TransformerDecodeModel
+
+            self.model = TransformerDecodeModel(lm, params)
+        self.cfg = self.model.cfg
         self.slots = int(slots if slots is not None
                          else env_int("MXNET_DECODE_SLOTS", 8))
         self.page_size = int(page_size if page_size is not None
@@ -154,18 +174,13 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        # the layers' weights stacked along a leading axis: prefill scans
-        # over them (one compiled layer body, not one per layer), the step
-        # takes layer i's as static slices, which cost nothing
-        params = dict(params, layers=stack_layers(params["layers"]))
-        self._params = jax.tree_util.tree_map(
-            lambda a: jnp.asarray(a, jnp.float32), params)
+        self._params = self.model.params
         self._param_avals = tuple(
             (tuple(a.shape), str(a.dtype))
             for a in jax.tree_util.tree_leaves(self._params))
-        cfg = self.cfg
-        pool_shape = (self.num_pages, cfg["layers"], self.page_size,
-                      cfg["heads"], 2 * cfg["head_dim"])
+        row, dtype = tuple(self.model.cache_row), self.model.cache_dtype
+        pool_shape = (self.num_pages, self.model.layers, self.page_size) + row
+        self.cache_row_bytes = int(np.prod(row)) * jnp.dtype(dtype).itemsize
         if jax.default_backend() == "tpu":
             pool = self._row_major(
                 pool_shape,
@@ -175,9 +190,12 @@ class DecodeEngine:
             donate = (1,)
         else:
             pool, donate = None, ()
-        self.kv = jax.jit(lambda: jnp.zeros(pool_shape, jnp.float32),
+        self.kv = jax.jit(lambda: jnp.zeros(pool_shape, dtype),
                           out_shardings=pool)()
         self._prefill_jit, self._step_jit = self._jit_programs(pool, donate)
+        # what the model's bodies counted in the last program call, by
+        # name; fetched with the tokens, in the same transfer
+        self.last_counters: Dict[str, int] = {}
 
         # program accounting — mirrors InferenceEngine so the TraceLinter
         # and the coldstart idiom read both the same way
@@ -194,9 +212,9 @@ class DecodeEngine:
         self._progcache = (_progcache.ProgramCache(progcache_dir)
                            if progcache_dir else _progcache.cache())
         self._key_statics = (
-            tuple(sorted(self.cfg.items())), self.slots, self.page_size,
-            self.num_pages, self.max_pages, tuple(self.buckets),
-            self._param_avals)
+            type(self.model).__name__, tuple(sorted(self.cfg.items())),
+            self.slots, self.page_size, self.num_pages, self.max_pages,
+            tuple(self.buckets), self._param_avals)
 
     # -- pure device programs ------------------------------------------
 
@@ -227,37 +245,34 @@ class DecodeEngine:
         """One padded prompt (1, S) → KV pages written, first token.
         S is the bucket (multiple of page_size); ``page_ids``
         (S // page_size,) are the sequence's pages in position order.
-        Pad positions scatter garbage K/V — masked by ``length`` until
+        Pad positions scatter garbage rows — masked by ``length`` until
         each slot is overwritten by a decode step."""
         import jax
         import jax.numpy as jnp
 
-        from ..models.transformer import lm_prefill, sample_token
+        from ..models.transformer import sample_token
 
-        logits, k, v = lm_prefill(self.cfg, params, tokens)
-        s = tokens.shape[1]
-        n = s // self.page_size
-        cfg = self.cfg
+        last, rows, counters = self.model.prefill(params, tokens, length)
+        n = tokens.shape[1] // self.page_size
 
-        # (L, 1, S, H, D) twice → (L, n, page, H, 2D), then page by page —
+        # (L, S) + row → (L, n, page) + row, then page by page —
         # every layer's rows of the page in one update — in place at the
         # sequence's page ids. (One scatter would say the same; but for
         # head counts off the 8-row tile XLA's TPU scatter wants the pool
         # in a layout of its own, and converts the whole pool there and
         # back.)
-        rows = jnp.concatenate([k, v], axis=-1).reshape(
-            cfg["layers"], n, self.page_size, cfg["heads"],
-            2 * cfg["head_dim"])
+        rows = rows.reshape((self.model.layers, n, self.page_size)
+                            + rows.shape[2:])
 
         def write_page(j, kv):
             page = jax.lax.dynamic_slice_in_dim(rows, j, 1, axis=1)
             return jax.lax.dynamic_update_slice(
-                kv, jnp.swapaxes(page, 0, 1), (page_ids[j], 0, 0, 0, 0))
+                kv, jnp.swapaxes(page, 0, 1),
+                (page_ids[j],) + (0,) * (kv.ndim - 1))
 
         kv = jax.lax.fori_loop(0, n, write_page, kv)
-        last = logits[0, length - 1]
         tok = sample_token(last[None], jax.random.PRNGKey(seed), temp)
-        return kv, tok[0]
+        return kv, (tok[0], counters)
 
     def _step_fn(self, params, kv, tokens, positions, page_tables, lengths,
                  seed, temps):
@@ -268,29 +283,22 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models.transformer import (_dense, _ln, decode_layer,
-                                          sample_token)
-        from ..ops.flash_attention import decode_attention
+        from ..models.transformer import sample_token
 
-        cfg = self.cfg
         rows = jnp.arange(self.slots)
         pids = page_tables[rows, positions // self.page_size]
         offs = positions % self.page_size
-        x = params["embed"][tokens] + params["pos"][positions]
-        for i in range(cfg["layers"]):
-            lp = {k: w[i] for k, w in params["layers"].items()}
 
-            def attend(q, k_new, v_new, _i=i):
-                nonlocal kv
-                kv = kv.at[pids, _i, offs].set(
-                    jnp.concatenate([k_new, v_new], axis=-1))
-                return decode_attention(q, kv, _i, page_tables, lengths)
+        def attend(layer, query, row):
+            nonlocal kv
+            kv = kv.at[pids, layer, offs].set(row)
+            return self.model.attention(query, kv, layer, page_tables,
+                                        lengths)
 
-            x, _, _ = decode_layer(cfg, lp, x, attend)
-        x = _ln(x, params["final_g"], params["final_b"])
-        logits = _dense(x, params["dec_w"], params["dec_b"])
+        logits, counters = self.model.step(params, tokens, positions,
+                                           lengths > 0, attend)
         toks = sample_token(logits, jax.random.PRNGKey(seed), temps)
-        return kv, toks
+        return kv, (toks, counters)
 
     # -- program accounting (the engine.py compile path, decode-keyed) --
 
@@ -359,7 +367,7 @@ class DecodeEngine:
                             compile=is_compile, cache_hit=cache_hit):
             # argument upload and launch: returns before the device is done
             with obs.trace.span("decode.dispatch"):
-                kv, toks = fn(*call_args)
+                kv, out = fn(*call_args)
             self.kv = kv
             # the step's sampled tokens ARE the wire payload — this d2h is
             # the one accounted sync of the decode hot path
@@ -367,7 +375,10 @@ class DecodeEngine:
             # the wait for the device's last operation, then the copy back:
             # device-idle time under this span is the host not yet awake
             with obs.trace.span("decode.device_get"):
-                host = np.asarray(jax.device_get(toks))  # lint: disable=host-sync-on-hot-path
+                toks, counters = jax.device_get(out)  # lint: disable=host-sync-on-hot-path
+            host = np.asarray(toks)
+        self.last_counters = ({} if counters is None else dict(
+            zip(self.model.counters, (int(c) for c in counters))))
         # an operator's "which call recompiled, which deserialized" alarm,
         # one increment per first call of a signature (the decode.execute
         # span carries compile, cache_hit and the duration)
@@ -442,6 +453,7 @@ class DecodeEngine:
             out = {
                 "slots": self.slots,
                 "page_size": self.page_size,
+                "cache_row_bytes": self.cache_row_bytes,
                 "buckets": list(self.buckets),
                 "num_programs": len(self._programs),
                 "executions": self.exec_count,
@@ -565,6 +577,7 @@ class DecodeScheduler:
         self.cancelled = 0
         self.steps = 0
         self.tokens_out = 0
+        self.counted: Dict[str, int] = {}   # the model's counters, summed
         self._occupancy = 0.0
         self.stopped_clean = True
         self._thread = threading.Thread(target=self._loop,
@@ -753,9 +766,26 @@ class DecodeScheduler:
         self._occupancy = (occ if self.steps == 1
                            else 0.7 * self._occupancy + 0.3 * occ)
         obs.set_gauge("decode.occupancy", self._occupancy)
+        obs.set_gauge("decode.cache_row_bytes",
+                      getattr(eng, "cache_row_bytes", 0))
         obs.trace.complete("decode.step", t0, dt, active=len(stepping),
-                           joined=joined, left=left)
+                           joined=joined, left=left, **self._counted())
         return joined, len(stepping), left
+
+    def _counted(self) -> dict:
+        """What the model counted in the engine's last program call (an
+        expert layer's ``moe.*``; nothing for a model that counts nothing),
+        as span attributes — and added to the counters of the same names
+        (``*_max``: a gauge) and to ``stats()["counted"]``."""
+        counted = getattr(self.engine, "last_counters", None) or {}
+        for name, v in counted.items():
+            if name.endswith("_max"):
+                obs.set_gauge(name, v)
+                self.counted[name] = max(self.counted.get(name, 0), v)
+            else:
+                obs.inc(name, v)
+                self.counted[name] = self.counted.get(name, 0) + v
+        return counted
 
     def _step_seed(self) -> int:
         # deterministic per step-count: replays reproduce token-for-token
@@ -802,10 +832,11 @@ class DecodeScheduler:
                               g.t_admit - g.t_submit, ctx=g.ctx,
                               priority=g.priority)
             with obs.trace.span("decode.prefill", bucket=bucket,
-                                prompt_len=g.prompt_len):
+                                prompt_len=g.prompt_len) as prefill:
                 tok = self.engine.prefill(
                     g.tokens, self.engine.pool.table(g.seq),
                     temperature=g.temperature, seed=g.seed)
+                prefill.set(**self._counted())
             g.last_token = tok
             g.produced = 1
             self.tokens_out += 1
@@ -937,6 +968,7 @@ class DecodeScheduler:
                 "active": self._active(),
                 "occupancy": self._occupancy,
                 "draining": self._draining,
+                "counted": dict(self.counted),
             }
         out["engine"] = self.engine.stats()
         return out

@@ -1,0 +1,434 @@
+"""The latent-attention + routed-experts decoder (``models/mla_moe.py``,
+``ops/moe.py``, the latent kernels of ``ops/flash_attention.py``) against
+the plain reference ``benchmark/reference_mla_moe.py`` — the repo's one copy
+of the equations — at tiny sizes on the CPU, Pallas kernels interpreted.
+
+The mathematics is checked in float32 (the same bodies run on a float32
+tree), where the program must agree with the reference to rounding: any
+tolerance that would hide a missing term (the router's bias, the
+normalisation, the routed scale, YaRN's softmax scale, the latent norm) is
+too wide. The bfloat16 run is then held to a bfloat16-sized tolerance, and
+the expert choices that flip on near ties are counted and printed.
+"""
+import json
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark import reference_mla_moe as ref
+from mxnet_tpu.models import mla_moe
+from mxnet_tpu.models import transformer
+from mxnet_tpu.ops import flash_attention, moe
+from mxnet_tpu.serve import DecodeEngine
+from mxnet_tpu.serve.kvcache import SCRATCH_PAGE
+
+pytestmark = pytest.mark.decode
+
+SEED = 3000000019      # over 2**31, as the driver's are
+CFG = {
+    "vocab_size": 96, "hidden_size": 64, "num_layers": 3, "first_dense": 1,
+    "num_heads": 4, "qk_nope": 16, "qk_rope": 8, "v_head": 16, "kv_rank": 32,
+    "dense_width": 128, "expert_width": 32, "router_experts": 8,
+    "experts_first": 2, "experts_held": 4, "experts_per_token": 3,
+    "routed_scale": 2.5, "rms_eps": 1e-6, "max_length": 64,
+    "rope": {"theta": 10000, "factor": 40,
+             "original_max_position_embeddings": 16, "beta_fast": 32,
+             "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}}
+PAGE, SLOTS = 8, 2
+
+
+def f32(tree):
+    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
+
+
+def test_config_from_the_published_keys():
+    hf = {"vocab_size": 65536, "hidden_size": 4096, "num_hidden_layers": 6,
+          "first_k_dense_replace": 1, "num_attention_heads": 64,
+          "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+          "kv_lora_rank": 512, "intermediate_size": 16384,
+          "moe_intermediate_size": 2048, "num_experts": 32,
+          "num_experts_per_tok": 8, "routed_scaling_factor": 2.5,
+          "rms_norm_eps": 1e-6, "rope_theta": 10000,
+          "max_position_embeddings": 8192,
+          "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                           "mscale": 1, "mscale_all_dim": 1,
+                           "original_max_position_embeddings": 4096,
+                           "type": "deepseek_yarn"}}
+    cfg = mla_moe.config_from_hf(hf, router_experts=128)
+    assert (cfg["experts_held"], cfg["router_experts"]) == (32, 128)
+    assert cfg["kv_rank"] + cfg["qk_rope"] == 576
+    # sigma = 192**-0.5 (0.1 ln 40 + 1)**2
+    assert mla_moe.softmax_scale(cfg) == pytest.approx(0.1352338, rel=1e-6)
+    assert ref.softmax_scale(cfg) == mla_moe.softmax_scale(cfg)
+    np.testing.assert_array_equal(ref.yarn_inv_freq(cfg["rope"], 64),
+                                  mla_moe.yarn_inv_freq(cfg["rope"], 64))
+    # YaRN: the fastest pairs keep their frequency, the slowest are / 40
+    inv = mla_moe.yarn_inv_freq(cfg["rope"], 64)
+    assert inv[0] == 1.0 and inv[-1] == pytest.approx(
+        10000 ** (-62 / 64) / 40, rel=1e-6)
+
+
+def test_program_and_reference_make_the_same_weights():
+    params = mla_moe.init_params(CFG, SEED)
+    assert params["experts"]["gate_w"].dtype == jnp.bfloat16
+    held = CFG["experts_held"]
+    for layer in range(CFG["num_layers"]):
+        w = ref.layer_weights(CFG, SEED, layer)
+        stack = params["dense"] if layer < 1 else params["moe"]
+        for name, value in w.items():
+            if name.startswith("experts_"):   # every layer's on one axis
+                got = params["experts"][name[8:]][(layer - 1) * held:
+                                                  layer * held]
+            else:
+                got = stack[name][layer - (layer >= 1)]
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(value), name)
+    for name in ("embed", "head"):
+        np.testing.assert_array_equal(
+            np.asarray(params[name], np.float32),
+            np.asarray(ref.vocab_weights(CFG, SEED, name)))
+
+
+def _generate(engine, prompts, new_tokens, monkeypatch):
+    """Greedy generation through the engine's own programs, keeping the
+    logits every program sampled from. Returns (tokens, logits) per prompt."""
+    seen = []
+    sample = transformer.sample_token
+
+    def spy(logits, rng, temperature):
+        jax.debug.callback(lambda x: seen.append(np.asarray(x)), logits)
+        return sample(logits, rng, temperature)
+
+    monkeypatch.setattr(transformer, "sample_token", spy)
+    out = [([], []) for _ in prompts]
+    tables, last = [], []
+    for i, prompt in enumerate(prompts):
+        bucket = engine.bucket_for(len(prompt))
+        engine.pool.alloc(i, bucket // PAGE)
+        tok = engine.prefill(prompt, engine.pool.table(i))
+        jax.effects_barrier()
+        out[i][0].append(tok)
+        out[i][1].append(seen.pop()[0])
+        last.append(tok)
+    for step in range(1, new_tokens):
+        positions = np.array([len(p) + step - 1 for p in prompts], np.int32)
+        tables = np.full((SLOTS, engine.max_pages), SCRATCH_PAGE, np.int32)
+        for i in range(len(prompts)):
+            while len(engine.pool.table(i)) * PAGE <= positions[i]:
+                engine.pool.alloc(i, 1)
+            table = engine.pool.table(i)
+            tables[i, :len(table)] = table
+        toks = engine.step(np.array(last, np.int32), positions, tables,
+                           positions + 1, np.zeros((SLOTS,), np.float32))
+        jax.effects_barrier()
+        logits = seen.pop()
+        for i in range(len(prompts)):
+            out[i][0].append(int(toks[i]))
+            out[i][1].append(logits[i])
+        last = [int(t) for t in toks]
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_engine_prefill_then_paged_decode_against_the_reference(
+        dtype, monkeypatch):
+    """(a) Prefill (expanded attention through the flash forward) and then
+    paged decode (absorbed attention through the latent kernel, rows read
+    from the page pool) through ``DecodeEngine``'s own two programs, against
+    the reference's ONE full forward over prompt + generated ids.
+
+    float32: agreement to 2e-4 of logits of size ~0.2 (float32 rounding
+    through 3 layers; a dropped bias, scale or norm moves them by 1e-2 and
+    more). bfloat16: 0.03 absolute — one bfloat16 rounding is 2**-9 of a
+    value, the logits sum 64 such products after 3 layers of them; the
+    float32 run above is what vouches for the mathematics."""
+    monkeypatch.setenv("MXNET_DECODE_ATTN", "pallas")   # interpreted kernels
+    params = mla_moe.init_params(CFG, SEED)
+    if dtype == "float32":
+        params = f32(params)
+    model = mla_moe.MLAMoEDecodeModel(CFG, params=params)
+    engine = DecodeEngine(model, slots=SLOTS, page_size=PAGE, num_pages=17,
+                          prompt_buckets=[16, 32])
+    # 32 + 8 values a row, in one whole lane tile
+    assert engine.kv.shape == (17, 3, PAGE, 128) and engine.kv.dtype == dtype
+    assert engine.cache_row_bytes == 128 * (4 if dtype == "float32" else 2)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 96, n).astype(np.int32) for n in (13, 22)]
+    new = 12
+    out = _generate(engine, prompts, new, monkeypatch)
+    tol = 2e-4 if dtype == "float32" else 0.03
+    worst, not_first = 0.0, 0
+    for prompt, (tokens, logits) in zip(prompts, out):
+        seq = np.concatenate([prompt, tokens[:-1]])
+        want = np.asarray(ref.logits(CFG, SEED, seq))[len(prompt) - 1:]
+        got = np.stack(logits)
+        assert got.shape == want.shape == (new, 96)
+        worst = max(worst, float(np.abs(got - want).max()))
+        not_first += int((want.argmax(1) != np.array(tokens)).sum())
+    print(f"{dtype}: widest logit difference {worst:.3g}; {not_first} of "
+          f"{2 * new} served tokens are not the reference's first")
+    assert worst < tol
+    if dtype == "float32":
+        assert not_first == 0
+    # the counters came back with the tokens: 2 expert layers x 2 slots x 3
+    c = engine.last_counters
+    assert set(c) == {"moe." + name for name in moe.COUNTERS}
+    assert c["moe.assignments"] == 2 * SLOTS * 3 and c["moe.dropped"] == 0
+    assert 0 <= c["moe.held"] <= c["moe.assignments"]
+
+
+def test_absorbed_attention_is_the_expanded_attention():
+    """(b) One layer, float32: the last position of ``prefill_layer``
+    (expanded: per-head keys and values made from the latent) equals
+    ``decode_layer`` for that position over the cached rows (absorbed:
+    queries taken into the latent's space, values the latent itself). 1e-5:
+    the two forms reassociate the same float32 products."""
+    params = f32(mla_moe.init_params(CFG, SEED))
+    lp = {k: w[0] for k, w in params["dense"].items()}
+    model = mla_moe.MLAMoEDecodeModel(CFG, params=params)
+    s = 16
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (s, 64), jnp.float32)
+    cos, sin = model._angles(jnp.arange(s))
+    live = jnp.ones((s,), bool)
+    want, rows, _ = mla_moe.prefill_layer(CFG, lp, x, cos, sin, live, None)
+
+    def attend(query, row):          # dense softmax over the cached rows
+        np.testing.assert_allclose(row[0], rows[-1], atol=1e-6)
+        assert row.shape == (1, 128) and not np.asarray(row[:, 40:]).any()
+        sc = mla_moe.softmax_scale(CFG) * jnp.einsum("bhr,sr->bhs", query, rows)
+        return jnp.einsum("bhs,sc->bhc", jax.nn.softmax(sc, -1),
+                          rows[:, :CFG["kv_rank"]])
+
+    got, _ = mla_moe.decode_layer(CFG, lp, x[-1:], cos[-1:], sin[-1:],
+                                  live[-1:], None, attend)
+    np.testing.assert_allclose(got[0], want[-1], atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+def test_paged_latent_kernel_against_a_dense_gather(dtype, tol):
+    """(c) The Pallas kernel (interpreted) against gather-then-attend, on a
+    pool of several layers with shuffled pages, lengths that end inside a
+    page, on a page boundary and at 0 (an inactive slot: finite garbage).
+    bfloat16: the kernel rounds p to bfloat16 before p.c, as the flash
+    kernels do; 2e-2 of values of size ~1."""
+    rng = np.random.default_rng(0)
+    b, h, r, dv, page, layers, pages = 4, 8, 40, 32, 8, 3, 24
+    pool = jnp.asarray(rng.standard_normal((pages, layers, page, r)), dtype)
+    q = jnp.asarray(rng.standard_normal((b, h, r)), dtype)
+    table = jnp.asarray(rng.permutation(np.arange(1, pages))[:b * 5]
+                        .reshape(b, 5), jnp.int32)
+    lengths = jnp.asarray([37, 16, 0, 1], jnp.int32)
+    for layer in (0, 2):
+        got = flash_attention.flash_latent_decode_attention(
+            q, pool, layer, table, lengths, dv, 0.3, interpret=True)
+        want = flash_attention._latent_decode_attention_xla(
+            q, pool, layer, table, lengths, dv, 0.3)
+        assert got.shape == (b, h, dv) and np.isfinite(np.asarray(
+            got, np.float32)).all()
+        live = np.asarray(lengths) > 0
+        np.testing.assert_allclose(np.asarray(got, np.float32)[live],
+                                   np.asarray(want, np.float32)[live],
+                                   atol=tol)
+
+
+def test_flash_forward_with_narrower_values():
+    """The flash forward with d_qk 24 and d_v 16 against dense softmax."""
+    rng = np.random.default_rng(1)
+    q, k = (jnp.asarray(rng.standard_normal((1, 2, 32, 24)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((1, 2, 32, 16)), jnp.float32)
+    got = flash_attention.flash_attention(q, k, v, causal=True, scale=0.2,
+                                          block_q=16, block_k=8)
+    sc = 0.2 * jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    sc = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), sc, -jnp.inf)
+    want = jnp.einsum("bhqk,bhkv->bhqv", jax.nn.softmax(sc, -1), v)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def _share_cfg(first):
+    return dict(CFG, experts_first=first, experts_held=2)
+
+
+def _expert_layer_params(cfg, layer):
+    """(the layer's own leaves, every layer's experts, this layer's offset
+    among them), float32."""
+    params = f32(mla_moe.init_params(cfg, SEED))
+    j = layer - cfg["first_dense"]
+    return ({k: w[j] for k, w in params["moe"].items()}, params["experts"],
+            j * cfg["experts_held"])
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """(d) 8 experts over 4 shares of 2: the four shares' routed parts plus
+    the shared expert counted ONCE equal the uncut reference layer (float32,
+    1e-5). And the two mistakes this guards against do not: the shared
+    expert counted per share, gates normalised over the held experts only.
+    Layer 2's experts lie behind layer 1's in the one array: ``offset``."""
+    layer = 2
+    h = 0.7 * jax.random.normal(jax.random.PRNGKey(2), (24, 64), jnp.float32)
+    live = jnp.ones((24,), bool)
+    uncut = dict(CFG, experts_first=0, experts_held=8)
+    want = ref.expert_layer(uncut, ref.layer_weights(uncut, SEED, layer), h,
+                            "f32")
+    routed, whole, renormed = 0.0, 0.0, 0.0
+    for first in (0, 2, 4, 6):
+        p, experts, offset = _expert_layer_params(_share_cfg(first), layer)
+        assert offset == 2
+        chosen, gates = moe.route(h, p["router_w"], p["router_b"], 3, 2.5)
+        w = (experts["gate_w"], experts["up_w"], experts["down_w"])
+        y, c = moe.held_experts(h, chosen, gates, live, *w, first, 2, offset)
+        routed = routed + y
+        y_layer, _ = moe.expert_layer(h, p, experts, live, first=first, held=2,
+                                      k=3, scale=2.5, offset=offset)
+        whole = whole + y_layer
+        held = (chosen >= first) & (chosen < first + 2)
+        wrong = 2.5 * gates / jnp.maximum(
+            jnp.sum(jnp.where(held, gates, 0), -1, keepdims=True), 1e-9)
+        renormed = renormed + moe.held_experts(h, chosen, wrong, live, *w,
+                                               first, 2, offset)[0]
+        assert int(c[moe.COUNTERS.index("dropped")]) == 0
+    shared = moe.gated_mlp(h, p["shared_gate_w"], p["shared_up_w"],
+                           p["shared_down_w"])
+    np.testing.assert_allclose(routed + shared, want, atol=1e-5)
+    np.testing.assert_allclose(whole - 3 * shared, want, atol=1e-5)
+    assert float(jnp.abs(whole - want).max()) > 1e-3        # shared x 4
+    assert float(jnp.abs(renormed + shared - want).max()) > 1e-3
+
+
+def test_no_token_is_dropped_when_every_token_picks_one_expert():
+    """(e) A bias of +10 on held expert 3 sends all 40 tokens to it (and a
+    bias never weighs: the gates are still from the scores): its group is
+    the whole batch, nothing is dropped, the result is the reference's."""
+    layer, t = 2, 40
+    w = ref.layer_weights(CFG, SEED, layer)
+    w["router_b"] = w["router_b"].at[3].add(10.0)
+    h = 0.7 * jax.random.normal(jax.random.PRNGKey(3), (t, 64), jnp.float32)
+    want = ref.expert_layer(CFG, w, h, "f32")
+    p, experts, offset = _expert_layer_params(CFG, layer)
+    p["router_b"] = p["router_b"].at[3].add(10.0)
+    live = jnp.ones((t,), bool).at[-4:].set(False)   # 4 pad positions
+    y, c = moe.expert_layer(h, p, experts, live, first=2, held=4, k=3,
+                            scale=2.5, offset=offset)
+    c = dict(zip(moe.COUNTERS, (int(v) for v in c)))
+    assert c["assignments"] == 36 * 3 and c["load_max"] == 36
+    assert c["dropped"] == 0 and 36 <= c["held"] <= 36 * 3
+    np.testing.assert_allclose(y[:36], want[:36], atol=1e-5)
+    # pad positions are routed nowhere: only the shared expert answers
+    shared = moe.gated_mlp(h, p["shared_gate_w"], p["shared_up_w"],
+                           p["shared_down_w"])
+    np.testing.assert_allclose(y[36:], shared[36:], atol=1e-6)
+
+
+@pytest.mark.parametrize("knob,value", [("TOKEN_CHUNK", 16),
+                                        ("ROW_BLOCKS", 1), ("ROW_BLOCKS", 8)])
+def test_expert_layer_in_chunks_and_row_blocks_is_the_layer(
+        knob, value, monkeypatch):
+    """Tokens routed ``TOKEN_CHUNK`` at a time, and sorted rows multiplied
+    in 1 or 8 blocks instead of 4 (a group that straddles two blocks is
+    multiplied in both, each its own rows), give the same sum and the same
+    counters."""
+    p, experts, offset = _expert_layer_params(CFG, 1)
+    h = 0.7 * jax.random.normal(jax.random.PRNGKey(4), (48, 64), jnp.float32)
+    live = jnp.arange(48) < 41
+    args = dict(first=2, held=4, k=3, scale=2.5, offset=offset)
+    want, c_want = moe.expert_layer(h, p, experts, live, **args)
+    monkeypatch.setattr(moe, knob, value)
+    got, c_got = moe.expert_layer(h, p, experts, live, **args)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    c_want, c_got = (dict(zip(moe.COUNTERS, np.asarray(c).tolist()))
+                     for c in (c_want, c_got))
+    for name in ("assignments", "held", "dropped"):
+        assert c_got[name] == c_want[name]
+    assert c_got["load_max"] <= c_want["load_max"]
+
+
+def test_the_scheduler_hangs_the_models_counters_on_its_spans():
+    """What the expert layers counted comes back with the tokens and lands
+    on the ``decode.prefill`` / ``decode.step`` spans, in the counters of
+    the same names, in ``stats()["counted"]`` (kept with obs off too), and
+    ``decode.cache_row_bytes`` is a gauge. The per-head model's spans carry
+    no such attribute (``tests/test_decode_spans.py`` holds their keys)."""
+    from mxnet_tpu import obs
+    from mxnet_tpu.serve import DecodeScheduler
+
+    model = mla_moe.MLAMoEDecodeModel(CFG, seed=SEED)
+    engine = DecodeEngine(model, slots=SLOTS, page_size=PAGE, num_pages=17,
+                          prompt_buckets=[16])
+    obs.enable()
+    try:
+        sched = DecodeScheduler(engine)
+        try:
+            tokens = list(sched.generate(list(range(1, 12)), max_new_tokens=4))
+        finally:
+            sched.close()
+        spans = obs.trace.drain()
+        gauges = obs.metrics.registry.snapshot()
+    finally:
+        obs.disable()
+        obs.reset()
+    assert len(tokens) == 4
+    prefill = [s for s in spans if s["name"] == "decode.prefill"]
+    steps = [s for s in spans if s["name"] == "decode.step"]
+    assert len(prefill) == 1 and len(steps) == 3
+    keys = {"moe." + name for name in moe.COUNTERS}
+    for s in prefill + steps:
+        assert keys <= set(s["args"]) and s["args"]["moe.dropped"] == 0
+    # 11 live prompt positions x 3 choices x 2 expert layers; a step: 1 slot
+    assert prefill[0]["args"]["moe.assignments"] == 11 * 3 * 2
+    assert all(s["args"]["moe.assignments"] == 3 * 2 for s in steps)
+    counted = sched.stats()["counted"]
+    assert counted["moe.assignments"] == 11 * 6 + 3 * 6
+    assert counted["moe.held"] == sum(s["args"]["moe.held"]
+                                      for s in prefill + steps)
+    assert counted["moe.dropped"] == 0
+    flat = json.dumps(gauges)
+    assert "decode.cache_row_bytes" in flat and "moe.assignments" in flat
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_expert_choices_that_flip_on_near_ties_are_counted(dtype, monkeypatch):
+    """The router runs in float32 in the program, on activations that are
+    bfloat16: a token whose 3rd and 4th scores nearly tie can choose another
+    expert than the float32 reference does. Counted here over 40 positions x
+    2 expert layers, and printed: none in float32 (the same choices, or the
+    mathematics differs), a few in bfloat16 — a flipped choice swaps one
+    expert for one of nearly the same score, which is inside what the logit
+    tolerance of the engine test allows."""
+    chosen = []
+    route = moe.route
+
+    def spy(*args):
+        out = route(*args)
+        jax.debug.callback(lambda c: chosen.append(np.asarray(c)), out[0],
+                           ordered=True)
+        return out
+
+    monkeypatch.setattr(moe, "route", spy)
+    params = mla_moe.init_params(CFG, SEED)
+    if dtype == "float32":
+        params = f32(params)
+    model = mla_moe.MLAMoEDecodeModel(CFG, params=params)
+    tokens = np.random.default_rng(5).integers(0, 96, 40).astype(np.int32)
+    jax.jit(model.prefill)(params, jnp.asarray(tokens)[None], 40)
+    jax.effects_barrier()
+    assert len(chosen) == 2 and chosen[0].shape == (40, 3)
+
+    x = ref.vocab_weights(CFG, SEED, "embed")[tokens]
+    flips = 0
+    for layer in range(CFG["num_layers"]):
+        w = ref.layer_weights(CFG, SEED, layer)
+        if layer >= CFG["first_dense"]:
+            h = ref.rms_norm(x, w["attn_norm"], CFG["rms_eps"])
+            after = x + ref.attention(CFG, w, h, "f32")
+            _, want, _ = ref.route(CFG, w, ref.rms_norm(
+                after, w["mlp_norm"], CFG["rms_eps"]), "f32")
+            got = chosen[layer - CFG["first_dense"]]
+            flips += int((np.sort(got, 1) != np.sort(np.asarray(want), 1))
+                         .any(axis=1).sum())
+        x = ref.layer_forward(CFG, w, x, layer)
+    print(f"{dtype}: {flips} of 80 (position, layer) choices differ from "
+          "the float32 reference's")
+    assert flips == 0 if dtype == "float32" else flips <= 16
