@@ -520,11 +520,8 @@ def phase_latent():
 
 
 def _step_text(engine):
-    z = np.zeros((engine.slots,), np.int32)
-    tables = np.zeros((engine.slots, engine.max_pages), np.int32)
-    return engine._step_jit.lower(
-        engine._params, engine.kv, z, z, tables, z, np.uint32(0),
-        np.zeros((engine.slots,), np.float32)).as_text()
+    return engine._step_jit.lower(engine._params, engine.kv, engine.last,
+                                  engine.blank_step()).as_text()
 
 
 # ---------------------------------------------------------------------------

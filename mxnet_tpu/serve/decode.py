@@ -17,6 +17,14 @@ shapes serve every generation:
   paged-KV write + paged attention (ops/flash_attention.decode_attention),
   LM head, on-device greedy/temperature sampling.
 
+Each slot's last sampled token stays **on the device**: a ``(slots,)``
+int32 vector that every program takes and returns (the step whole, a
+prefill with its slot's first token written), so no call waits for a token
+to come back to the host in order to send it up again, and a call is
+**launched** (``launch_step``/``launch_prefill``: one packed int32 array
+uploaded, then the program queued) apart from where its result is **read**
+(``read``). ``step``/``prefill`` are the two together.
+
 The engine takes the **model by interface** (``DecodeEngine``'s docstring):
 what a cache row is, the prefill and step bodies and the paged read are the
 model's; pages, programs, accounting and the scheduler are shared by every
@@ -58,6 +66,7 @@ and ``Router.generate`` consume the same iterator protocol this module's
 """
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -73,6 +82,16 @@ from .kvcache import SCRATCH_PAGE, PagePool, PagesExhausted, pages_for
 
 __all__ = ["DecodeEngine", "DecodeScheduler", "StreamHandle",
            "default_decode_buckets"]
+
+# the step's packed rows: position, length, temperature bits, page table
+_POS, _LEN, _TEMP, _TABLE = 0, 1, 2, 3
+# a prefill's packed header; the page ids follow, then the padded prompt
+_P_LEN, _P_SLOT, _P_SEED, _P_TEMP, _P_PAGES = 0, 1, 2, 3, 4
+
+
+def _bits(value, dtype) -> int:
+    """``value`` as ``dtype`` (float32, uint32), its 32 bits as an int32."""
+    return int(np.array(value, dtype).view(np.int32))
 
 
 def default_decode_buckets(max_prompt: int, page_size: int) -> List[int]:
@@ -132,6 +151,24 @@ class DecodeEngine:
     progcache_dir : str, optional
         Explicit persistent program cache; defaults to the process-wide
         ``progcache.cache()`` (``MXNET_PROGCACHE=1``).
+
+    **What the programs take.** Both take four arguments, three of them
+    resident on the device — ``params``, the pool ``kv`` (donated), and
+    ``last``, the ``(slots,)`` int32 vector of every slot's last sampled
+    token — and ONE int32 array from the host, so a call is one upload:
+
+    - the step's ``(slots + 1, 3 + max_pages)``: a row per slot — position,
+      length (0: the slot is idle), the temperature's float32 bits, its
+      page table — and the sampling seed's bits first in the row after them
+      (``blank_step``);
+    - a prefill's ``(4 + S // page_size + S,)`` for bucket ``S``: prompt
+      length, slot, the seed's and the temperature's bits, the page ids,
+      the padded prompt.
+
+    Both return ``kv``, the new ``last`` (the step's tokens; a prefill's
+    first token written at its slot) and what the host fetches in one
+    transfer: the sampled tokens (a prefill's one) followed by the model's
+    counters.
     """
 
     def __init__(self, lm, params=None, *, slots: Optional[int] = None,
@@ -193,8 +230,13 @@ class DecodeEngine:
         self.kv = jax.jit(lambda: jnp.zeros(pool_shape, dtype),
                           out_shardings=pool)()
         self._prefill_jit, self._step_jit = self._jit_programs(pool, donate)
-        # what the model's bodies counted in the last program call, by
-        # name; fetched with the tokens, in the same transfer
+        # every slot's last sampled token, fed back on the device
+        self.last = jnp.zeros((self.slots,), jnp.int32)
+        self._blank_step = np.full((self.slots + 1, _TABLE + self.max_pages),
+                                   SCRATCH_PAGE, np.int32)
+        self._blank_step[:, :_TABLE] = 0
+        # what the model's bodies counted in the last program call read,
+        # by name; fetched with the tokens, in the same transfer
         self.last_counters: Dict[str, int] = {}
 
         # program accounting — mirrors InferenceEngine so the TraceLinter
@@ -234,26 +276,41 @@ class DecodeEngine:
         to ``pool`` (a ``Format``, or None for the backend's default)."""
         import jax
 
-        def jit(fn, n_args):
+        def jit(fn):
             return jax.jit(fn, donate_argnums=donate,
-                           in_shardings=(None, pool) + (None,) * (n_args - 2),
+                           in_shardings=(None, pool, None, None),
                            out_shardings=(pool, None))
 
-        return jit(self._prefill_fn, 7), jit(self._step_fn, 8)
+        return jit(self._prefill_fn), jit(self._step_fn)
 
-    def _prefill_fn(self, params, kv, tokens, length, page_ids, seed, temp):
-        """One padded prompt (1, S) → KV pages written, first token.
-        S is the bucket (multiple of page_size); ``page_ids``
-        (S // page_size,) are the sequence's pages in position order.
-        Pad positions scatter garbage rows — masked by ``length`` until
-        each slot is overwritten by a decode step."""
+    def _fetched(self, toks, counters):
+        """What the host reads of a call, as one int32 vector: the sampled
+        tokens, then the model's counters."""
+        import jax.numpy as jnp
+
+        if counters is None:
+            return toks
+        return jnp.concatenate([toks, jnp.stack(counters).astype(jnp.int32)])
+
+    def _prefill_fn(self, params, kv, last, packed):
+        """One padded prompt → KV pages written, first token (also written
+        into ``last`` at the prompt's slot). ``packed`` (class docstring)
+        holds the prompt padded to its bucket S (multiple of page_size) and
+        the sequence's S // page_size pages in position order. Pad
+        positions scatter garbage rows — masked by ``length`` until each
+        slot is overwritten by a decode step."""
         import jax
         import jax.numpy as jnp
 
         from ..models.transformer import sample_token
 
-        last, rows, counters = self.model.prefill(params, tokens, length)
-        n = tokens.shape[1] // self.page_size
+        n = (packed.shape[0] - _P_PAGES) // (self.page_size + 1)
+        length, slot = packed[_P_LEN], packed[_P_SLOT]
+        seed = jax.lax.bitcast_convert_type(packed[_P_SEED], jnp.uint32)
+        temp = jax.lax.bitcast_convert_type(packed[_P_TEMP], jnp.float32)
+        page_ids = packed[_P_PAGES:_P_PAGES + n]
+        tokens = packed[_P_PAGES + n:][None]
+        logits, rows, counters = self.model.prefill(params, tokens, length)
 
         # (L, S) + row → (L, n, page) + row, then page by page —
         # every layer's rows of the page in one update — in place at the
@@ -271,13 +328,13 @@ class DecodeEngine:
                 (page_ids[j],) + (0,) * (kv.ndim - 1))
 
         kv = jax.lax.fori_loop(0, n, write_page, kv)
-        tok = sample_token(last[None], jax.random.PRNGKey(seed), temp)
-        return kv, (tok[0], counters)
+        tok = sample_token(logits[None], jax.random.PRNGKey(seed), temp)
+        return kv, (last.at[slot].set(tok[0]), self._fetched(tok, counters))
 
-    def _step_fn(self, params, kv, tokens, positions, page_tables, lengths,
-                 seed, temps):
-        """One token for every slot. tokens/positions/lengths (B,),
-        page_tables (B, max_pages). Inactive slots carry length 0 and a
+    def _step_fn(self, params, kv, last, packed):
+        """One token for every slot: ``last`` (B,) are the tokens to embed,
+        ``packed`` (class docstring) the positions, lengths, temperatures
+        and page tables (B, max_pages). Inactive slots carry length 0 and a
         scratch page table — their writes land on the scratch page and
         their outputs are garbage the host discards."""
         import jax
@@ -285,6 +342,13 @@ class DecodeEngine:
 
         from ..models.transformer import sample_token
 
+        positions = packed[:self.slots, _POS]
+        lengths = packed[:self.slots, _LEN]
+        temps = jax.lax.bitcast_convert_type(packed[:self.slots, _TEMP],
+                                             jnp.float32)
+        page_tables = packed[:self.slots, _TABLE:]
+        seed = jax.lax.bitcast_convert_type(packed[self.slots, 0],
+                                            jnp.uint32)
         rows = jnp.arange(self.slots)
         pids = page_tables[rows, positions // self.page_size]
         offs = positions % self.page_size
@@ -295,10 +359,10 @@ class DecodeEngine:
             return self.model.attention(query, kv, layer, page_tables,
                                         lengths)
 
-        logits, counters = self.model.step(params, tokens, positions,
+        logits, counters = self.model.step(params, last, positions,
                                            lengths > 0, attend)
         toks = sample_token(logits, jax.random.PRNGKey(seed), temps)
-        return kv, (toks, counters)
+        return kv, (toks, self._fetched(toks, counters))
 
     # -- program accounting (the engine.py compile path, decode-keyed) --
 
@@ -312,17 +376,15 @@ class DecodeEngine:
             self._sig_key[sig] = pk
         return pk
 
-    def _execute(self, kind: str, label: str, jitted, args):
-        """Run one program call with full accounting: compile_log entry +
+    def _launch(self, kind: str, label: str, jitted, packed) -> tuple:
+        """Queue one program call with full accounting: compile_log entry +
         progcache get/put on a fresh signature, ``decode.*`` metrics, and
-        the pool array swap. Returns the sampled token(s) on host."""
-        import jax
-
-        sig = (kind,) + tuple(
-            (tuple(np.shape(a)), str(np.asarray(a).dtype)) for a in args)
+        the swap of the pool and the last-token vector for the call's
+        (not yet computed) results. Returns what :meth:`read` takes."""
+        sig = (kind, (tuple(packed.shape), str(packed.dtype)))
         is_compile = sig not in self._programs
         cache_hit = False
-        call_args = (self._params, self.kv) + tuple(args)
+        call_args = (self._params, self.kv, self.last, packed)
         if is_compile:
             entry = {"sig": sig, "kind": kind, "label": label,
                      "param_avals": self._param_avals}
@@ -365,20 +427,10 @@ class DecodeEngine:
         fn = self._aot.get(sig, jitted)
         with obs.trace.span("decode.execute", kind=kind, label=label,
                             compile=is_compile, cache_hit=cache_hit):
-            # argument upload and launch: returns before the device is done
+            # the one upload and the launch: returns before the device is
+            # done, and before it has begun if a call is still running
             with obs.trace.span("decode.dispatch"):
-                kv, out = fn(*call_args)
-            self.kv = kv
-            # the step's sampled tokens ARE the wire payload — this d2h is
-            # the one accounted sync of the decode hot path
-            copytrack.TRACKER.host_sync("serve.decode.device_get")
-            # the wait for the device's last operation, then the copy back:
-            # device-idle time under this span is the host not yet awake
-            with obs.trace.span("decode.device_get"):
-                toks, counters = jax.device_get(out)  # lint: disable=host-sync-on-hot-path
-            host = np.asarray(toks)
-        self.last_counters = ({} if counters is None else dict(
-            zip(self.model.counters, (int(c) for c in counters))))
+                self.kv, (self.last, fetched) = fn(*call_args)
         # an operator's "which call recompiled, which deserialized" alarm,
         # one increment per first call of a signature (the decode.execute
         # span carries compile, cache_hit and the duration)
@@ -389,7 +441,29 @@ class DecodeEngine:
         with self._stat_lock:
             self._programs[sig] = self._programs.get(sig, 0) + 1
             self.exec_count += 1
-        return host
+        return kind, label, fetched
+
+    def read(self, launched: tuple):
+        """Wait for a launched call and fetch what it sampled: ``(tokens,
+        counters)`` — a step's (slots,) int32 array or a prefill's int, and
+        what the model's bodies counted in that call, by name (also left in
+        ``last_counters``)."""
+        import jax
+
+        kind, label, fetched = launched
+        # the sampled tokens ARE the wire payload — this d2h is the one
+        # accounted sync of the decode hot path
+        copytrack.TRACKER.host_sync("serve.decode.device_get")
+        with obs.trace.span("decode.execute", kind=kind, label=label):
+            # the wait for the call's last operation, then the copy back:
+            # device-idle time under this span is the host not yet awake
+            with obs.trace.span("decode.device_get"):
+                host = jax.device_get(fetched)  # lint: disable=host-sync-on-hot-path
+        n = len(host) - len(self.model.counters)
+        self.last_counters = dict(zip(self.model.counters,
+                                      (int(c) for c in host[n:])))
+        return ((host[:n] if kind == "step" else int(host[0])),
+                self.last_counters)
 
     # -- host-facing calls ---------------------------------------------
 
@@ -401,36 +475,65 @@ class DecodeEngine:
             f"prompt length {prompt_len} exceeds max bucket "
             f"{self.buckets[-1]}")
 
-    def prefill(self, tokens: np.ndarray, page_ids: List[int], *,
-                temperature: float = 0.0, seed: int = 0) -> int:
-        """Prefill one prompt into its pages; returns the first sampled
-        token. ``tokens`` is the unpadded 1-D prompt; ``page_ids`` must
-        cover its bucket (``bucket_for(len) // page_size`` pages)."""
+    def launch_prefill(self, tokens: np.ndarray, page_ids: List[int], *,
+                       temperature: float = 0.0, seed: int = 0,
+                       slot: int = 0) -> tuple:
+        """Queue the prefill of one prompt into its pages; :meth:`read`
+        gives its first sampled token, which the program also writes into
+        the last-token vector at ``slot``. ``tokens`` is the unpadded 1-D
+        prompt; ``page_ids`` must cover its bucket (``bucket_for(len) //
+        page_size`` pages)."""
         tokens = np.asarray(tokens, np.uint32).astype(np.int32)
         n = int(tokens.shape[0])
         bucket = self.bucket_for(n)
-        if len(page_ids) != bucket // self.page_size:
+        pages = bucket // self.page_size
+        if len(page_ids) != pages:
             raise ServeError(
-                f"prefill needs {bucket // self.page_size} pages for "
-                f"bucket {bucket}, got {len(page_ids)}")
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = tokens
-        out = self._execute(
-            "prefill", f"prefill{bucket}", self._prefill_jit,
-            (padded, np.int32(n), np.asarray(page_ids, np.int32),
-             np.uint32(seed), np.float32(temperature)))
-        return int(out)
+                f"prefill needs {pages} pages for bucket {bucket}, got "
+                f"{len(page_ids)}")
+        packed = np.zeros((_P_PAGES + pages + bucket,), np.int32)
+        packed[:_P_PAGES] = (n, slot, _bits(seed, np.uint32),
+                             _bits(temperature, np.float32))
+        packed[_P_PAGES:_P_PAGES + pages] = page_ids
+        packed[_P_PAGES + pages:_P_PAGES + pages + n] = tokens
+        return self._launch("prefill", f"prefill{bucket}", self._prefill_jit,
+                            packed)
+
+    def prefill(self, tokens: np.ndarray, page_ids: List[int], *,
+                temperature: float = 0.0, seed: int = 0,
+                slot: int = 0) -> int:
+        """:meth:`launch_prefill` and :meth:`read` together: prefill one
+        prompt into its pages and return the first sampled token."""
+        return self.read(self.launch_prefill(
+            tokens, page_ids, temperature=temperature, seed=seed,
+            slot=slot))[0]
+
+    def blank_step(self) -> np.ndarray:
+        """A step's packed argument with every slot idle (length 0, its
+        page table all scratch) and seed 0, for the caller to fill: row
+        ``i`` is slot ``i``'s position, length, temperature bits and page
+        table; ``[slots, 0]`` the sampling seed's bits."""
+        return self._blank_step.copy()
+
+    def launch_step(self, packed: np.ndarray) -> tuple:
+        """Queue one continuous-batch decode step over the tokens the
+        device holds; :meth:`read` gives the (slots,) sampled tokens."""
+        return self._launch("step", "step", self._step_jit, packed)
 
     def step(self, tokens, positions, page_tables, lengths, temps, *,
              seed: int = 0) -> np.ndarray:
-        """One continuous-batch decode step; returns (slots,) int32
-        sampled tokens (garbage at inactive rows, i.e. lengths == 0)."""
-        return self._execute(
-            "step", "step", self._step_jit,
-            (np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
-             np.asarray(page_tables, np.int32),
-             np.asarray(lengths, np.int32), np.uint32(seed),
-             np.asarray(temps, np.float32)))
+        """One decode step from ``tokens`` given by the host, waited for;
+        returns (slots,) int32 sampled tokens (garbage at inactive rows,
+        i.e. lengths == 0)."""
+        packed = self.blank_step()
+        rows = packed[:self.slots]
+        rows[:, _POS] = positions
+        rows[:, _LEN] = lengths
+        rows[:, _TEMP] = np.asarray(temps, np.float32).view(np.int32)
+        rows[:, _TABLE:] = page_tables
+        packed[self.slots, 0] = _bits(seed, np.uint32)
+        self.last = np.asarray(tokens, np.int32)
+        return self.read(self.launch_step(packed))[0]
 
     def warmup(self) -> int:
         """Compile (or progcache-load) every prefill bucket plus the step
@@ -512,11 +615,14 @@ class StreamHandle:
 
 
 class _Gen:
-    """One generation's scheduler-side state."""
+    """One generation's scheduler-side state. ``launched`` counts the
+    tokens asked of the device (the prefill's and each step's), ``produced``
+    those that have come back and gone out."""
 
     __slots__ = ("seq", "tokens", "prompt_len", "max_new", "deadline",
-                 "priority", "temperature", "ctx", "handle", "produced",
-                 "last_token", "t_submit", "t_admit", "seed")
+                 "priority", "temperature", "temp_bits", "ctx", "handle",
+                 "slot", "launched", "produced", "retired", "t_submit",
+                 "t_admit", "seed")
 
     def __init__(self, seq, tokens, max_new, deadline, priority,
                  temperature, handle, seed):
@@ -527,24 +633,60 @@ class _Gen:
         self.deadline = deadline
         self.priority = priority
         self.temperature = temperature
+        self.temp_bits = _bits(temperature, np.float32)
         self.ctx = obs_context.current()
         self.handle = handle
+        self.slot = -1
+        self.launched = 0
         self.produced = 0
-        self.last_token = -1
+        self.retired = False
         self.t_submit = time.monotonic()
         self.t_admit = 0.0
         self.seed = seed
+
+
+class _InFlight:
+    """One launched program call whose result the scheduler has yet to
+    read, with the generations it yields a token for (``who``: a prefill's
+    one, a step's several) and its span's attributes."""
+
+    __slots__ = ("kind", "launched", "t_launch", "who", "attrs")
+
+    def __init__(self, kind, launched, t_launch, who, **attrs):
+        self.kind = kind
+        self.launched = launched
+        self.t_launch = t_launch
+        self.who = who
+        self.attrs = attrs
 
 
 class DecodeScheduler:
     """Token-level continuous batching over a :class:`DecodeEngine`.
 
     A single scheduler thread owns the engine: each loop iteration is one
-    ``step()`` — admit queued requests into free slots (prefill at the
-    step boundary), run ONE decode-step program over every active slot,
-    distribute the sampled tokens, retire finished/cancelled/expired
-    generations and free their pages. Requests therefore join and leave
-    the running batch between steps, never mid-program.
+    ``step()`` — admit queued requests into free slots (their prefills
+    launched at the step boundary), launch ONE decode-step program over
+    every active slot, then read what was launched before it — the last
+    turn's step, this turn's prefills — and distribute those tokens,
+    retiring finished/cancelled/expired generations and freeing their
+    pages. Requests therefore join and leave the running batch between
+    steps, never mid-program.
+
+    **The host's turn runs under the device's step**: step *n+1* is
+    launched before step *n*'s tokens are read, because nothing it is built
+    from needs them — the tokens are fed back on the device, and a slot's
+    position is its prompt length plus the tokens *launched* for it. What
+    only a read token tells (``eos_id``), and what is looked at when one is
+    handed over (cancel, deadline, back-pressure), is learned one step
+    late: that slot's step in flight is speculative and its token dropped
+    (``decode.dropped_speculative``). Its KV write lands in a page the
+    stream still owns, or in rows that ``lengths`` masks for the page's
+    next owner; and the device runs programs in launch order, so a later
+    prefill into a freed page writes after it.
+
+    The engine behind it is a :class:`DecodeEngine`, or anything with its
+    ``slots``/``max_pages``/``max_length``/``pool``/``bucket_for``,
+    ``blank_step``, ``launch_prefill``, ``launch_step`` and ``read``.
     """
 
     def __init__(self, engine: DecodeEngine, *, max_queue: int = 64,
@@ -575,9 +717,16 @@ class DecodeScheduler:
         self.submitted = 0
         self.completed = 0
         self.cancelled = 0
-        self.steps = 0
+        self.steps = 0              # steps read
+        self.steps_launched = 0
+        self.launched_ahead = 0     # ... while another step was in flight
+        self.dropped_speculative = 0
         self.tokens_out = 0
         self.counted: Dict[str, int] = {}   # the model's counters, summed
+        # launched and not yet read, oldest first; between turns at most
+        # the one step launched last
+        self._inflight: collections.deque = collections.deque()
+        self._t_arrived = 0.0       # when the last result read came back
         self._occupancy = 0.0
         self.stopped_clean = True
         self._thread = threading.Thread(target=self._loop,
@@ -679,7 +828,7 @@ class DecodeScheduler:
             while True:
                 with self._cv:
                     while (self._running and self._qsize() == 0
-                           and self._active() == 0):
+                           and self._active() == 0 and not self._inflight):
                         with obs.trace.span("decode.idle_wait"):
                             self._cv.wait(1.0)
                     if not self._running:
@@ -691,9 +840,10 @@ class DecodeScheduler:
             self._abort_all(ServeError("decode scheduler stopped"))
 
     def step(self) -> int:
-        """One continuous-batch step: admit → decode → distribute →
-        retire. Returns the number of tokens produced. This is the
-        decode data plane's hot root (analysis/dataplane.py)."""
+        """One continuous-batch turn: admit → launch the next step → read
+        what was launched before it → distribute → retire. Returns the
+        number of slots the launched step covers. This is the decode data
+        plane's hot root (analysis/dataplane.py)."""
         with obs.trace.span("decode.turn") as turn:
             joined, active, left = self._turn()
             turn.set(joined=joined, active=active, left=left)
@@ -702,21 +852,29 @@ class DecodeScheduler:
     def _turn(self):
         """The body of :meth:`step`; returns (joined, active, left)."""
         joined = self._admit(time.monotonic())
-        active = [(i, g) for i, g in enumerate(self._slots)
-                  if g is not None]
+        active = self._launch_step(joined)
+        # everything older than the step just launched: the last turn's
+        # step first, then this turn's prefills
+        left = 0
+        while len(self._inflight) > (1 if active else 0):
+            left += self._receive(self._inflight[0])
+            self._inflight.popleft()    # after it: drain() waits on this
+        return joined, active, left
+
+    def _launch_step(self, joined: int) -> int:
+        """Build and launch one decode step over every slot that has a
+        token still to ask for; returns how many. Nothing here waits for a
+        token: a slot's position follows from the count of tokens launched
+        for it."""
+        active = [g for g in self._slots if g is not None]
         if not active:
-            return joined, 0, 0
+            return 0
         eng = self.engine
         with obs.trace.span("decode.build", active=len(active)):
-            tokens = np.zeros((eng.slots,), np.int32)
-            positions = np.zeros((eng.slots,), np.int32)
-            lengths = np.zeros((eng.slots,), np.int32)
-            temps = np.zeros((eng.slots,), np.float32)
-            tables = np.full((eng.slots, eng.max_pages), SCRATCH_PAGE,
-                             np.int32)
+            packed = eng.blank_step()
             stepping = []
-            for i, g in active:
-                pos = g.prompt_len + g.produced - 1
+            for g in active:
+                pos = g.prompt_len + g.launched - 1
                 try:
                     table = self._ensure_pages(g, pos)
                 except PagesExhausted as e:
@@ -726,58 +884,86 @@ class DecodeScheduler:
                     self.shed += 1
                     self.shed_by_reason["pages"] += 1
                     obs.inc("decode.shed_pages")
-                    self._retire(i, g, "pages", error=e)
+                    self._retire(g, "pages", error=e)
                     continue
-                tokens[i] = g.last_token
-                positions[i] = pos
-                lengths[i] = pos + 1
-                temps[i] = g.temperature
-                tables[i, :len(table)] = table
-                stepping.append((i, g))
+                row = packed[g.slot]
+                row[_POS] = pos
+                row[_LEN] = pos + 1
+                row[_TEMP] = g.temp_bits
+                row[_TABLE:_TABLE + len(table)] = table
+                stepping.append(g)
+            packed[eng.slots, 0] = self._step_seed()
         if not stepping:
-            return joined, 0, 0
-        t0 = time.monotonic()
-        out = eng.step(tokens, positions, tables, lengths, temps,
-                       seed=self._step_seed())
-        dt = time.monotonic() - t0
-        left = 0
+            return 0
+        ahead = int(any(f.kind == "step" for f in self._inflight))
+        t_launch = time.monotonic()
+        launched = eng.launch_step(packed)
+        for g in stepping:
+            g.launched += 1
+            self._release_if_last(g)
+        self.steps_launched += 1
+        self.launched_ahead += ahead
+        obs.inc("decode.launched_ahead", ahead)
+        self._inflight.append(_InFlight("step", launched, t_launch, stepping,
+                                        joined=joined, ahead=ahead))
+        return len(stepping)
+
+    def _receive(self, flight: _InFlight) -> int:
+        """Read one launched call and hand its tokens over; returns how
+        many generations left the batch on them. The spans say *what the
+        call cost the streams*: from the later of its launch and the
+        arrival of the result before it, to its own arrival."""
+        out, counted = self.engine.read(flight.launched)
         now = time.monotonic()
+        t0 = max(flight.t_launch, self._t_arrived)
+        self._t_arrived = now
+        attrs = dict(flight.attrs, **self._count(counted))
+        if flight.kind == "prefill":
+            obs.trace.complete("decode.prefill", flight.t_launch,
+                               now - flight.t_launch, **attrs)
+            return int(self._token(flight.who[0], out, now))
+        left = 0
         with obs.trace.span("decode.distribute") as distribute:
-            for i, g in stepping:
-                tok = int(out[i])
-                g.last_token = tok
-                g.produced += 1
-                self.tokens_out += 1
-                if g.ctx is not None and g.ctx.sampled:
-                    obs.trace.complete("decode.token", t0, dt, ctx=g.ctx,
-                                       index=g.produced, slot=i)
-                if not g.handle._emit(("token", tok, g.produced)):
-                    self._retire(i, g, "backpressure",
-                                 error=RequestRejected(
-                                     "stream consumer too slow (token "
-                                     "buffer full)"))
-                    left += 1
-                    continue
-                if self._done(g, tok, now):
-                    left += 1
+            for g in flight.who:
+                left += self._token(g, int(out[g.slot]), now, t0)
             distribute.set(left=left)
         self.steps += 1
-        occ = len(stepping) / eng.slots
+        occ = len(flight.who) / self.engine.slots
         self._occupancy = (occ if self.steps == 1
                            else 0.7 * self._occupancy + 0.3 * occ)
         obs.set_gauge("decode.occupancy", self._occupancy)
         obs.set_gauge("decode.cache_row_bytes",
-                      getattr(eng, "cache_row_bytes", 0))
-        obs.trace.complete("decode.step", t0, dt, active=len(stepping),
-                           joined=joined, left=left, **self._counted())
-        return joined, len(stepping), left
+                      getattr(self.engine, "cache_row_bytes", 0))
+        obs.trace.complete("decode.step", t0, now - t0,
+                           active=len(flight.who), left=left, **attrs)
+        return left
 
-    def _counted(self) -> dict:
-        """What the model counted in the engine's last program call (an
-        expert layer's ``moe.*``; nothing for a model that counts nothing),
-        as span attributes — and added to the counters of the same names
-        (``*_max``: a gauge) and to ``stats()["counted"]``."""
-        counted = getattr(self.engine, "last_counters", None) or {}
+    def _token(self, g: _Gen, tok: int, now: float,
+               t0: Optional[float] = None) -> bool:
+        """One token of ``g`` has come back (``t0``: since when its step
+        was what the stream waited for; None for a prefill's). True if
+        ``g`` leaves the batch on it."""
+        if g.retired:
+            # asked for before the host knew that the stream had ended
+            self.dropped_speculative += 1
+            obs.inc("decode.dropped_speculative")
+            return False
+        g.produced += 1
+        self.tokens_out += 1
+        if t0 is not None and g.ctx is not None and g.ctx.sampled:
+            obs.trace.complete("decode.token", t0, now - t0, ctx=g.ctx,
+                               index=g.produced, slot=g.slot)
+        if not g.handle._emit(("token", tok, g.produced)):
+            self._retire(g, "backpressure", error=RequestRejected(
+                "stream consumer too slow (token buffer full)"))
+            return True
+        return self._done(g, tok, now)
+
+    def _count(self, counted: dict) -> dict:
+        """What the model counted in the call just read (an expert layer's
+        ``moe.*``; nothing for a model that counts nothing), as span
+        attributes — and added to the counters of the same names (``*_max``:
+        a gauge) and to ``stats()["counted"]``."""
         for name, v in counted.items():
             if name.endswith("_max"):
                 obs.set_gauge(name, v)
@@ -788,12 +974,14 @@ class DecodeScheduler:
         return counted
 
     def _step_seed(self) -> int:
-        # deterministic per step-count: replays reproduce token-for-token
-        return (self.steps * 1000003 + 12345) & 0x7FFFFFFF
+        # deterministic per count of steps launched: replays reproduce
+        # token-for-token
+        return (self.steps_launched * 1000003 + 12345) & 0x7FFFFFFF
 
     def _admit(self, now: float) -> int:
-        """Move queued generations into free slots (prefill at the step
-        boundary). Page exhaustion leaves the request queued."""
+        """Move queued generations into free slots and launch their
+        prefills (at the step boundary). Page exhaustion leaves the
+        request queued."""
         admitted = []
         with obs.trace.span("decode.admit") as admit, self._cv:
             free = [i for i, g in enumerate(self._slots) if g is None]
@@ -822,8 +1010,8 @@ class DecodeScheduler:
                         free = []
                         break
                     lane.pop(0)
-                    slot = free.pop(0)
-                    self._slots[slot] = g
+                    g.slot = free.pop(0)
+                    self._slots[g.slot] = g
                     admitted.append((g, bucket))
             admit.set(admitted=len(admitted))
         for g, bucket in admitted:
@@ -831,23 +1019,32 @@ class DecodeScheduler:
             obs.trace.complete("decode.queue_wait", g.t_submit,
                               g.t_admit - g.t_submit, ctx=g.ctx,
                               priority=g.priority)
-            with obs.trace.span("decode.prefill", bucket=bucket,
-                                prompt_len=g.prompt_len) as prefill:
-                tok = self.engine.prefill(
-                    g.tokens, self.engine.pool.table(g.seq),
-                    temperature=g.temperature, seed=g.seed)
-                prefill.set(**self._counted())
-            g.last_token = tok
-            g.produced = 1
-            self.tokens_out += 1
-            if not g.handle._emit(("token", tok, 1)):
-                idx = self._slots.index(g)
-                self._retire(idx, g, "backpressure",
-                             error=RequestRejected(
-                                 "stream consumer too slow"))
-                continue
-            self._done(g, tok, time.monotonic())
+            launched = self.engine.launch_prefill(
+                g.tokens, self.engine.pool.table(g.seq),
+                temperature=g.temperature, seed=g.seed, slot=g.slot)
+            g.launched = 1
+            self._release_if_last(g)
+            self._inflight.append(_InFlight(
+                "prefill", launched, g.t_admit, [g], bucket=bucket,
+                prompt_len=g.prompt_len))
         return len(admitted)
+
+    def _release_if_last(self, g: _Gen):
+        """A stream's last token by ``max_new_tokens`` or the model's length
+        is known as it is launched: its slot and pages go back then, not a
+        turn later when the token is read, so the next request's prefill
+        is queued right behind that step (the device runs in launch order:
+        nothing writes a freed page before the step has read it)."""
+        if (g.launched >= g.max_new
+                or g.prompt_len + g.launched >= self.engine.max_length):
+            self._release(g)
+
+    def _release(self, g: _Gen):
+        """Give back ``g``'s slot and pages, once. EVERY exit path funnels
+        here — the page-leak guarantee lives in this one place."""
+        if self._slots[g.slot] is g:
+            self._slots[g.slot] = None
+            self.engine.pool.free(g.seq)
 
     def _ensure_pages(self, g: _Gen, pos: int) -> List[int]:
         """Grow ``g``'s page table to cover position ``pos`` (at most one
@@ -861,35 +1058,35 @@ class DecodeScheduler:
 
     def _done(self, g: _Gen, tok: int, now: float) -> bool:
         """Post-token retirement checks, in precedence order."""
-        idx = self._slots.index(g)
         if self.eos_id is not None and tok == self.eos_id:
-            self._retire(idx, g, "eos")
+            self._retire(g, "eos")
             return True
         if g.produced >= g.max_new:
-            self._retire(idx, g, "length")
+            self._retire(g, "length")
             return True
         if g.prompt_len + g.produced >= self.engine.max_length:
-            self._retire(idx, g, "overflow")
+            self._retire(g, "overflow")
             return True
         if g.deadline is not None and now >= g.deadline:
             self.shed_by_reason["deadline"] += 1
             self.shed += 1
             obs.inc("decode.shed_deadline")
-            self._retire(idx, g, "deadline", error=DeadlineExceeded(
+            self._retire(g, "deadline", error=DeadlineExceeded(
                 f"deadline expired after {g.produced} tokens"))
             return True
         if g.handle.cancelled():
-            self._retire(idx, g, "cancelled")
+            self._retire(g, "cancelled")
             return True
         return False
 
-    def _retire(self, slot: int, g: _Gen, reason: str,
+    def _retire(self, g: _Gen, reason: str,
                 error: Optional[ServeError] = None):
-        """Leave the batch: free pages, emit the terminal event, complete
-        the request span. EVERY exit path funnels here — the page-leak
-        guarantee lives in this one place."""
-        self._slots[slot] = None
-        self.engine.pool.free(g.seq)
+        """Leave the batch: slot and pages back (``_release``, if its last
+        launch has not done so already), the terminal event, the request
+        span. A token of ``g`` still in flight is dropped when it
+        arrives."""
+        g.retired = True
+        self._release(g)
         if reason == "cancelled":
             self.cancelled += 1
         else:
@@ -911,9 +1108,12 @@ class DecodeScheduler:
             queued = [g for lane in self._lanes for g in lane]
             for lane in self._lanes:
                 del lane[:]
-        for i, g in enumerate(list(self._slots)):
-            if g is not None:
-                self._retire(i, g, "aborted", error=exc)
+        # resident, or released with its last token still on its way
+        inflight = [g for f in self._inflight for g in f.who]
+        self._inflight.clear()
+        for g in [g for g in self._slots if g is not None] + inflight:
+            if not g.retired:
+                self._retire(g, "aborted", error=exc)
         for g in queued:
             g.handle._emit(("error", exc))
 
@@ -926,7 +1126,7 @@ class DecodeScheduler:
         with self._cv:
             self._draining = True
             self._cv.notify_all()
-            while self._qsize() or self._active():
+            while self._qsize() or self._active() or self._inflight:
                 rem = deadline - time.monotonic()
                 if rem <= 0:
                     return False
@@ -963,6 +1163,11 @@ class DecodeScheduler:
                 "shed": self.shed,
                 "shed_by_reason": dict(self.shed_by_reason),
                 "steps": self.steps,
+                "steps_launched": self.steps_launched,
+                "launched_ahead": self.launched_ahead,
+                "launched_ahead_share": (self.launched_ahead
+                                         / max(1, self.steps_launched)),
+                "dropped_speculative": self.dropped_speculative,
                 "tokens_out": self.tokens_out,
                 "queued": self._qsize(),
                 "active": self._active(),

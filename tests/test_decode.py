@@ -183,7 +183,10 @@ def test_page_pool_gauge_tracks_usage():
 class _FakeDecodeEngine:
     """Scheduler-facing engine stub: token streams are a pure function of
     the prompt (prefill = sum(prompt) % 1000, then +1 mod 997 per step),
-    so join/leave mixing is decidable without racing real XLA."""
+    so join/leave mixing is decidable without racing real XLA. Like the
+    real engine it keeps every slot's last token itself, and a launched
+    call is done ``delay`` after the one before it (one queue, in order):
+    ``read`` waits for that."""
 
     def __init__(self, slots=2, page_size=4, num_pages=64, max_length=64,
                  delay=0.0):
@@ -199,6 +202,10 @@ class _FakeDecodeEngine:
         self.delay = delay
         self.compile_log = []
         self.prefill_order = []
+        self.last = np.zeros((slots,), np.int32)
+        self.steps = []         # every launched step's packed argument
+        self.calls = []         # "prefill"/"step" launched, "read", in order
+        self._done_at = 0.0
 
     def bucket_for(self, n):
         for b in self.buckets:
@@ -206,18 +213,32 @@ class _FakeDecodeEngine:
                 return b
         raise RequestRejected(f"prompt length {n} exceeds max bucket")
 
-    def prefill(self, tokens, page_ids, *, temperature=0.0, seed=0):
-        if self.delay:
-            time.sleep(self.delay)
+    def _queued(self, out):
+        self._done_at = max(self._done_at, time.monotonic()) + self.delay
+        return self._done_at, out
+
+    def launch_prefill(self, tokens, page_ids, *, temperature=0.0, seed=0,
+                       slot=0):
         tok = int(np.sum(tokens) % 1000)
         self.prefill_order.append(tok)
-        return tok
+        self.calls.append("prefill")
+        self.last[slot] = tok
+        return self._queued(tok)
 
-    def step(self, tokens, positions, page_tables, lengths, temps, *,
-             seed=0):
-        if self.delay:
-            time.sleep(self.delay)
-        return ((np.asarray(tokens, np.int64) + 1) % 997).astype(np.int32)
+    def blank_step(self):
+        return np.zeros((self.slots + 1, 3 + self.max_pages), np.int32)
+
+    def launch_step(self, packed):
+        self.steps.append(packed)
+        self.calls.append("step")
+        self.last = ((self.last.astype(np.int64) + 1) % 997).astype(np.int32)
+        return self._queued(self.last.copy())
+
+    def read(self, launched):
+        done_at, out = launched
+        self.calls.append("read")
+        time.sleep(max(0.0, done_at - time.monotonic()))
+        return out, {}
 
     def warmup(self):
         return 0
@@ -390,6 +411,292 @@ def test_page_exhaustion_queues_then_sheds_running_stream():
         eng2.pool.assert_baseline()
     finally:
         sched2.close()
+
+
+# -- the next step is launched before the last one's tokens are read --------
+
+def test_steps_are_launched_ahead_and_counted():
+    """In a steady batch every step but the first is launched while the
+    one before it is still in flight, and its position is counted from the
+    tokens launched, not from those that have come back."""
+    eng = _FakeDecodeEngine(slots=2, delay=0.005)
+    sched = DecodeScheduler(eng)
+    try:
+        assert list(sched.generate([1, 2], max_new_tokens=9)) == \
+            _fake_seq([1, 2], 9)
+        assert sched.drain(timeout=5)
+        st = sched.stats()
+        assert st["steps"] == st["steps_launched"] == 8
+        assert st["launched_ahead"] == 7
+        assert st["launched_ahead_share"] == 7 / 8
+        assert st["dropped_speculative"] == 0
+        # slot 0's row of every step: position and length follow the count
+        assert [(int(p[0, 0]), int(p[0, 1])) for p in eng.steps] == [
+            (2 + i, 3 + i) for i in range(8)]
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+def test_a_finished_streams_slot_is_refilled_behind_its_last_step():
+    """A stream's last step by ``max_new_tokens`` is known as it is
+    launched, so its slot and pages go back then: the queued request's
+    prefill is launched behind that step, before the step's token has been
+    read, and no step runs with the slot empty."""
+    eng = _FakeDecodeEngine(slots=1, delay=0.02)
+    sched = DecodeScheduler(eng)
+    try:
+        a = sched.submit([1], max_new_tokens=3)
+        b = sched.submit([2], max_new_tokens=2)
+        got = {a: [], b: []}
+        for h in (a, b):
+            while not got[h] or got[h][-1][0] == "token":
+                got[h].append(h.get(timeout=5))
+        assert [ev[1] for ev in got[a][:-1]] == _fake_seq([1], 3)
+        assert [ev[1] for ev in got[b][:-1]] == _fake_seq([2], 2)
+        assert sched.drain(timeout=5)
+        # A: prefill, two steps; B's prefill and step go out before A's
+        # last step is read
+        assert eng.calls == ["prefill", "step", "read", "step", "read",
+                             "prefill", "step", "read", "read", "read"]
+        assert sched.stats()["dropped_speculative"] == 0
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+def test_eos_ends_the_stream_and_drops_the_step_in_flight():
+    eng = _FakeDecodeEngine(slots=2, delay=0.005)
+    sched = DecodeScheduler(eng, eos_id=8)
+    obs.enable()
+    try:
+        # 5, 6, 7, 8 = EOS: nothing after it, though a step was in flight
+        assert list(sched.generate([5], max_new_tokens=30)) == [5, 6, 7, 8]
+        assert sched.drain(timeout=5)
+        st = sched.stats()
+        assert eng.pool.used() == 0
+        assert st["dropped_speculative"] == 1 and st["tokens_out"] == 4
+        assert st["steps_launched"] == st["steps"] == 4
+        counters = obs.metrics.snapshot()["counters"]
+        assert counters["decode.dropped_speculative"] == 1
+        assert counters["decode.launched_ahead"] == st["launched_ahead"] == 3
+    finally:
+        sched.close()
+
+
+def test_eos_on_the_real_engine_frees_pages_the_next_stream_reuses(engine):
+    prompt = np.array([3, 1, 4, 1, 5], np.int32)
+    other = np.array([2, 7, 1, 8, 2, 8, 1, 8, 2], np.int32)
+    plain = DecodeScheduler(engine)
+    try:
+        want = list(plain.generate(prompt, max_new_tokens=6))
+        want_other = list(plain.generate(other, max_new_tokens=6))
+    finally:
+        plain.close()
+    # the first token that is new to the stream ends it
+    cut = next(i for i in range(1, 6) if want[i] not in want[:i])
+    sched = DecodeScheduler(engine, eos_id=want[cut])
+    try:
+        assert list(sched.generate(prompt, max_new_tokens=6)) == \
+            want[:cut + 1]
+        # the dropped step wrote a row into a page that is free again:
+        # whoever takes the page next is not disturbed by it
+        stop = next((i for i, t in enumerate(want_other)
+                     if t == want[cut]), 5)
+        assert list(sched.generate(other, max_new_tokens=6)) == \
+            want_other[:stop + 1]
+        assert sched.drain(timeout=10)
+        assert sched.stats()["dropped_speculative"] >= 1
+        assert engine.pool.used() == 0
+    finally:
+        sched.close()
+    engine.pool.assert_baseline()
+
+
+@pytest.mark.parametrize("reason", ["cancelled", "deadline", "backpressure"])
+def test_late_learned_exits_retire_with_a_step_in_flight(reason):
+    """What the host learns only when a token is handed over costs the
+    slot one speculative step: the stream retires through ``_retire``, the
+    token of the step already launched is dropped, no page leaks, and the
+    batch keeps running for the stream beside it."""
+    eng = _FakeDecodeEngine(slots=2, delay=0.01)
+    sched = DecodeScheduler(eng)
+    try:
+        beside = sched.submit([7], max_new_tokens=40)
+        h = sched.submit([1, 2, 3], max_new_tokens=40,
+                         deadline_ms=60 if reason == "deadline" else None)
+        if reason == "backpressure":
+            emit = h._emit
+            h._emit = lambda ev: (ev[0] != "token" or ev[2] <= 3) and emit(ev)
+        events = []
+        while not events or events[-1][0] == "token":
+            events.append(h.get(timeout=5))
+            if reason == "cancelled" and len(events) == 3:
+                h.cancel()
+        tokens = [ev[1] for ev in events if ev[0] == "token"]
+        assert tokens and tokens == _fake_seq([1, 2, 3], len(tokens))
+        last = events[-1]
+        if reason == "cancelled":
+            assert last[:2] == ("end", "cancelled") and last[2] == len(tokens)
+        elif reason == "deadline":
+            assert isinstance(last[1], DeadlineExceeded)
+        else:
+            assert isinstance(last[1], RequestRejected) and len(tokens) == 3
+        _wait(lambda: sched.stats()["dropped_speculative"] == 1,
+              msg="the step in flight read and dropped")
+        got = []
+        while not got or got[-1][0] == "token":
+            got.append(beside.get(timeout=5))
+        assert [ev[1] for ev in got[:-1]] == _fake_seq([7], 40)
+        assert sched.drain(timeout=5)
+        st = sched.stats()
+        assert st["dropped_speculative"] == 1
+        assert st["shed"] == sum(st["shed_by_reason"].values())
+        assert st["cancelled"] == (reason == "cancelled")
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+def test_page_exhaustion_with_a_step_in_flight_sheds_the_stream_that_grows():
+    # 3 pages of 4 positions: A and B hold one each, both outgrow it at
+    # position 4, and there is one page left
+    eng = _FakeDecodeEngine(slots=2, page_size=4, num_pages=4,
+                            max_length=64, delay=0.01)
+    sched = DecodeScheduler(eng)
+    try:
+        a = sched.submit([1, 2, 3], max_new_tokens=6)
+        b = sched.submit([4, 5, 6], max_new_tokens=6)
+        got = {a: [], b: []}
+        for h in (a, b):
+            while not got[h] or got[h][-1][0] == "token":
+                got[h].append(h.get(timeout=5))
+        # A, first in the batch, grew; B was shed while its first step was
+        # in flight, and that step's token is dropped, not delivered late
+        assert [ev[1] for ev in got[a][:-1]] == _fake_seq([1, 2, 3], 6)
+        assert got[a][-1][:2] == ("end", "length")
+        assert [ev[1] for ev in got[b][:-1]] == _fake_seq([4, 5, 6], 1)
+        assert isinstance(got[b][-1][1], PagesExhausted)
+        assert sched.drain(timeout=5)
+        st = sched.stats()
+        assert st["shed_by_reason"]["pages"] == st["shed"] == 1
+        assert st["dropped_speculative"] == 1
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+class _Recorded:
+    """Every call the scheduler launches on ``engine``, in launch order."""
+
+    def __init__(self, engine, monkeypatch):
+        self.calls = []
+        launch_prefill, launch_step = engine.launch_prefill, engine.launch_step
+
+        def prefill(tokens, page_ids, **kw):
+            self.calls.append(("prefill", np.array(tokens), list(page_ids),
+                               kw))
+            return launch_prefill(tokens, page_ids, **kw)
+
+        def step(packed):
+            self.calls.append(("step", packed.copy()))
+            return launch_step(packed)
+
+        monkeypatch.setattr(engine, "launch_prefill", prefill)
+        monkeypatch.setattr(engine, "launch_step", step)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_launch_ahead_equals_the_engine_stepped_synchronously(
+        lm, monkeypatch, temperature):
+    """Seven requests through two slots — joining as others leave — with
+    the scheduler launching ahead and the tokens fed back on the device,
+    against the same calls made one at a time through ``DecodeEngine.step``
+    with the tokens carried by the host: the same tokens, greedy and
+    sampled (same seeds)."""
+    eng = DecodeEngine(lm, slots=2, page_size=8, num_pages=16,
+                       prompt_buckets=[8, 16])
+    eng.warmup()
+    recorded = _Recorded(eng, monkeypatch)
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(1, 90, n).astype(np.int32)
+               for n in (3, 9, 5, 12, 2, 7, 16)]
+    wants = [5, 9, 2, 7, 1, 12, 4]
+    sched = DecodeScheduler(eng)
+    try:
+        handles = [sched.submit(p, max_new_tokens=n, temperature=temperature,
+                                seed=100 + i)
+                   for i, (p, n) in enumerate(zip(prompts, wants))]
+        got = []
+        for h in handles:
+            events = []
+            while not events or events[-1][0] == "token":
+                events.append(h.get(timeout=30))
+            assert events[-1][:2] == ("end", "length")
+            got.append([ev[1] for ev in events[:-1]])
+        assert sched.drain(timeout=10)
+        st = sched.stats()
+    finally:
+        sched.close()
+    monkeypatch.undo()
+    assert [len(g) for g in got] == wants
+    assert st["dropped_speculative"] == 0
+    assert st["launched_ahead"] >= st["steps_launched"] - 3
+    eng.pool.assert_baseline()
+
+    # the same calls, each waited for, the tokens going through the host
+    last = np.zeros((eng.slots,), np.int32)
+    owner = [None] * eng.slots
+    streams = {}
+    for call in recorded.calls:
+        if call[0] == "prefill":
+            _, tokens, page_ids, kw = call
+            tok = eng.prefill(tokens, page_ids, **kw)
+            (i,) = [i for i, p in enumerate(prompts)
+                    if np.array_equal(p, tokens)]
+            owner[kw["slot"]], streams[i] = i, [tok]
+            last[kw["slot"]] = tok
+            continue
+        packed = call[1]
+        rows = packed[:eng.slots]
+        toks = eng.step(last, rows[:, 0], rows[:, 3:], rows[:, 1],
+                        rows[:, 2].view(np.float32),
+                        seed=int(packed[eng.slots, 0]))
+        for slot in np.flatnonzero(rows[:, 1]):
+            streams[owner[slot]].append(int(toks[slot]))
+            last[slot] = toks[slot]
+    assert [streams[i] for i in range(len(prompts))] == got
+    if temperature:
+        # and the sampled streams are not the greedy ones
+        greedy = DecodeScheduler(eng)
+        try:
+            assert got[5] != list(greedy.generate(prompts[5],
+                                                  max_new_tokens=12))
+        finally:
+            greedy.close()
+
+
+def test_program_count_after_warmup_and_after_traffic(lm):
+    eng = DecodeEngine(lm, slots=2, page_size=8, num_pages=16,
+                       prompt_buckets=[8, 16])
+    eng.warmup()
+    assert eng.stats()["num_programs"] == len(eng.buckets) + 1 == 3
+    sched = DecodeScheduler(eng)
+    try:
+        for n in (2, 8, 11, 16):
+            assert len(list(sched.generate(list(range(1, n + 1)),
+                                           max_new_tokens=5))) == 5
+    finally:
+        sched.close()
+    stats = eng.stats()
+    assert stats["num_programs"] == len(eng.buckets) + 1
+    assert len(eng.compile_log) == 3
+    # one host array a call, whatever the traffic: the programs' signatures
+    assert sorted(stats["programs"]) == sorted(repr(s) for s in (
+        ("prefill", ((4 + 1 + 8,), "int32")),
+        ("prefill", ((4 + 2 + 16,), "int32")),
+        ("step", ((2 + 1, 3 + eng.max_pages), "int32"))))
+    assert TraceLinter().check_decode_engine(eng) == []
 
 
 # ---------------------------------------------------------------------------

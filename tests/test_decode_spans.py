@@ -2,12 +2,16 @@
 live span on the profiler's clock (docs/OBSERVABILITY.md "Decode spans").
 
 A turn of ``DecodeScheduler.step`` is ``decode.turn`` ⊃ {``decode.admit``,
-``decode.prefill`` per admission, ``decode.build``, ``decode.execute`` ⊃
-{``decode.dispatch``, ``decode.device_get``}, ``decode.distribute``}; the
-idle scheduler waits under ``decode.idle_wait``. Each is also a
-``jax.profiler.TraceAnnotation``, so a ``jax.profiler`` trace taken with
-telemetry on carries them on its ``/host:CPU`` plane. With telemetry off
-nothing is recorded and nothing is constructed.
+``decode.build``, ``decode.execute`` ⊃ ``decode.dispatch`` per launch (the
+admitted prefills, then the next step), ``decode.execute`` ⊃
+``decode.device_get`` per read (the step launched a turn earlier, then this
+turn's prefills), ``decode.distribute``}; the idle scheduler waits under
+``decode.idle_wait``. Each is also a ``jax.profiler.TraceAnnotation``, so a
+``jax.profiler`` trace taken with telemetry on carries them on its
+``/host:CPU`` plane. ``decode.prefill`` and ``decode.step`` are recorded
+when their result is read (``complete()``): from a prefill's launch, or
+from when a step became what the streams wait for, to the arrival. With
+telemetry off nothing is recorded and nothing is constructed.
 """
 import glob
 import os
@@ -22,9 +26,9 @@ from mxnet_tpu.serve import DecodeEngine, DecodeScheduler
 
 pytestmark = [pytest.mark.decode, pytest.mark.obs]
 
-LIVE = {"decode.turn", "decode.admit", "decode.prefill", "decode.build",
-        "decode.execute", "decode.dispatch", "decode.device_get",
-        "decode.distribute", "decode.idle_wait"}
+LIVE = {"decode.turn", "decode.admit", "decode.build", "decode.execute",
+        "decode.dispatch", "decode.device_get", "decode.distribute",
+        "decode.idle_wait"}
 REMOVED = ("decode.token_seconds", "decode.execute_seconds",
            "decode.deserialize_seconds", "decode.compile_seconds")
 
@@ -69,8 +73,10 @@ def _serve_one(engine, prompt=(1, 2, 3), max_new_tokens=3):
 
 @pytest.fixture
 def served(engine):
-    """The spans of one served request (3 tokens: a turn that admits and
-    prefills, and one more turn), as the ring's dicts."""
+    """The spans of one served request (3 tokens: a turn that launches the
+    prefill and the first step and reads the prefill; one that launches
+    the second step and reads the first; one that reads the second), as
+    the ring's dicts."""
     obs.enable()
     _serve_one(engine)
     obs.disable()
@@ -94,31 +100,42 @@ def _admitting_turn(spans):
     return turn
 
 
+def _executes(turn, spans):
+    """The turn's ``decode.execute`` spans in order, each as (kind, the
+    one span it holds: "dispatch" for a launch, "device_get" for a read)."""
+    out = []
+    for execute in sorted(_inside(turn, spans, "decode.execute"),
+                          key=lambda s: s["ts"]):
+        held = [name for name in ("dispatch", "device_get")
+                if _inside(execute, spans, "decode." + name)]
+        assert len(held) == 1, held
+        out.append((execute["args"]["kind"], held[0]))
+    return out
+
+
 def test_a_turn_contains_its_phases_on_one_thread(served):
     turn = _admitting_turn(served)
-    inside = {name: _inside(turn, served, name) for name in LIVE}
-    for name in ("decode.admit", "decode.prefill", "decode.build",
-                 "decode.distribute"):
+    inside = {name: _inside(turn, served, name)
+              for name in LIVE | {"decode.prefill"}}
+    for name in ("decode.admit", "decode.prefill", "decode.build"):
         assert len(inside[name]) == 1, name
-    # one program for the prefill, one for the step; each execute holds
-    # exactly one dispatch followed by one device_get
-    assert len(inside["decode.execute"]) == 2
-    for execute in inside["decode.execute"]:
-        (dispatch,) = _inside(execute, served, "decode.dispatch")
-        (get,) = _inside(execute, served, "decode.device_get")
-        assert _end(dispatch) <= get["ts"]
+    # launch and read are two calls: the prefill and the first step are
+    # launched before anything is read, and the step stays in flight
+    assert _executes(turn, served) == [
+        ("prefill", "dispatch"), ("step", "dispatch"),
+        ("prefill", "device_get")]
+    launch, step, read = sorted(inside["decode.execute"],
+                                key=lambda s: s["ts"])
+    # the prefill span runs from its launch to its token
     prefill, = inside["decode.prefill"]
-    first, second = sorted(inside["decode.execute"], key=lambda s: s["ts"])
-    assert first["args"]["kind"] == "prefill" and _inside(
-        prefill, served, "decode.execute") == [first]
-    assert second["args"]["kind"] == "step"
+    assert prefill["ts"] <= launch["ts"] and _end(read) <= _end(prefill)
     # and in the order the turn runs them
     admit, = inside["decode.admit"]
     build, = inside["decode.build"]
-    distribute, = inside["decode.distribute"]
-    assert (_end(admit) <= prefill["ts"] and _end(prefill) <= build["ts"]
-            and _end(build) <= second["ts"]
-            and _end(second) <= distribute["ts"])
+    assert (_end(admit) <= launch["ts"] and _end(launch) <= build["ts"]
+            and _end(build) <= step["ts"] and _end(step) <= read["ts"])
+    # a prefill's token is handed over outside decode.distribute
+    assert not inside["decode.distribute"]
     assert not inside["decode.idle_wait"] and not inside["decode.turn"][1:]
 
 
@@ -130,32 +147,69 @@ def test_prefill_span_carries_bucket_and_prompt_len(engine):
     assert prefill["args"] == {"bucket": 16, "prompt_len": 11}
 
 
+def test_the_next_step_is_launched_before_the_last_one_is_read(served):
+    turns = sorted((s for s in served if s["name"] == "decode.turn"),
+                   key=lambda s: s["ts"])
+    assert [_executes(t, served) for t in turns[1:]] == [
+        [("step", "dispatch"), ("step", "device_get")],
+        [("step", "device_get")]]
+    second = turns[1]
+    (build,) = _inside(second, served, "decode.build")
+    launch, read = sorted(_inside(second, served, "decode.execute"),
+                          key=lambda s: s["ts"])
+    (distribute,) = _inside(second, served, "decode.distribute")
+    assert (_end(build) <= launch["ts"] and _end(launch) <= read["ts"]
+            and _end(read) <= distribute["ts"])
+    assert [t["args"] for t in turns] == [
+        {"joined": 1, "active": 1, "left": 0},
+        {"joined": 0, "active": 1, "left": 0},
+        {"joined": 0, "active": 0, "left": 1}]
+
+
 def test_decode_step_keeps_its_attributes_and_endpoints(served):
     """``decode_step_ms`` and ``serve_occupancy_pct`` read ``decode.step``:
-    it still closes around ``eng.step`` alone — after the arrays are
-    built, before the tokens go out — with active/joined/left."""
-    steps = [s for s in served if s["name"] == "decode.step"]
-    turns = [s for s in served if s["name"] == "decode.turn"]
-    assert len(steps) == len(turns) == 2
-    for step in steps:
-        assert set(step["args"]) == {"active", "joined", "left"}
-        (turn,) = [t for t in turns if t["ts"] <= step["ts"]
-                   and _end(step) <= _end(t)]
-        assert step["args"] == turn["args"]
-        (build,) = _inside(turn, served, "decode.build")
-        (distribute,) = _inside(turn, served, "decode.distribute")
-        (execute,) = [e for e in _inside(turn, served, "decode.execute")
-                      if e["args"]["kind"] == "step"]
-        assert (_end(build) <= step["ts"] <= execute["ts"]
-                and _end(execute) <= _end(step) <= distribute["ts"])
-    assert [s["args"]["joined"] for s in sorted(steps, key=lambda s: s["ts"])
-            ] == [1, 0]
+    one per step read, with active/joined/left/ahead, ending when its
+    tokens arrive — after the read, before they go out — and beginning
+    where the result before it arrived (or at its own launch, if later):
+    the steps tile the timeline, whatever the depth of the pipe."""
+    steps = sorted((s for s in served if s["name"] == "decode.step"),
+                   key=lambda s: s["ts"])
+    turns = sorted((s for s in served if s["name"] == "decode.turn"),
+                   key=lambda s: s["ts"])
+    assert len(steps) == 2 and len(turns) == 3
+    launches = []
+    for step, launched_in, read_in in zip(steps, turns, turns[1:]):
+        assert set(step["args"]) == {"active", "joined", "left", "ahead"}
+        # attributed to the step: joined as its launching turn admitted,
+        # left as the turn that read it retired
+        assert step["args"]["joined"] == launched_in["args"]["joined"]
+        assert step["args"]["left"] == read_in["args"]["left"]
+        (launch,) = [e for e in _inside(launched_in, served, "decode.execute")
+                     if e["args"]["kind"] == "step"
+                     and _inside(e, served, "decode.dispatch")]
+        (read,) = [e for e in _inside(read_in, served, "decode.execute")
+                   if _inside(e, served, "decode.device_get")
+                   and e["args"]["kind"] == "step"]
+        (distribute,) = _inside(read_in, served, "decode.distribute")
+        assert _end(read) <= _end(step) <= distribute["ts"]
+        assert launch["ts"] <= step["ts"]
+        launches.append(launch)
+    first, second = steps
+    # the first step was launched behind the prefill: it is what the stream
+    # waits for from the prefill's arrival on; the second from the first's
+    (prefill,) = [s for s in served if s["name"] == "decode.prefill"]
+    assert first["ts"] == pytest.approx(_end(prefill), abs=1e-4)
+    assert second["ts"] == pytest.approx(_end(first), abs=1e-6)
+    assert _end(launches[1]) <= _end(first)       # launched ahead
+    assert [s["args"]["joined"] for s in steps] == [1, 0]
+    assert [s["args"]["ahead"] for s in steps] == [0, 1]
 
 
 def test_live_span_attributes_are_plain_ints(served):
     want = {"decode.turn": {"joined", "active", "left"},
             "decode.admit": {"admitted"}, "decode.build": {"active"},
             "decode.prefill": {"bucket", "prompt_len"},
+            "decode.step": {"active", "joined", "left", "ahead"},
             "decode.distribute": {"left"}}
     seen = set()
     for span in served:
@@ -168,7 +222,7 @@ def test_live_span_attributes_are_plain_ints(served):
     assert seen == set(want)
     last = max((s for s in served if s["name"] == "decode.turn"),
                key=lambda s: s["ts"])
-    assert last["args"] == {"joined": 0, "active": 1, "left": 1}
+    assert last["args"] == {"joined": 0, "active": 0, "left": 1}
 
 
 def test_idle_scheduler_waits_under_idle_wait(engine):
@@ -215,8 +269,8 @@ def test_every_live_span_is_one_annotation_of_its_name(engine, counted):
     live = sorted(s["name"] for s in spans if s["name"] in LIVE)
     assert sorted(counted) == live and set(live) >= LIVE - {"decode.idle_wait"}
     # the retroactive spans stay in the ring and are not bridged
-    assert {"decode.step", "decode.queue_wait", "decode.generate"} <= {
-        s["name"] for s in spans} - set(counted)
+    assert {"decode.step", "decode.prefill", "decode.queue_wait",
+            "decode.generate"} <= {s["name"] for s in spans} - set(counted)
 
 
 def test_profiler_trace_holds_the_turn_on_its_host_plane(engine, tmp_path):
@@ -239,12 +293,12 @@ def test_profiler_trace_holds_the_turn_on_its_host_plane(engine, tmp_path):
                 by_name.setdefault(e.name, []).append(
                     (e.start_ns, e.start_ns + e.duration_ns))
     assert set(by_name) >= LIVE - {"decode.idle_wait"}
-    assert len(by_name["decode.turn"]) == 2
+    assert len(by_name["decode.turn"]) == 3
     # on the profiler's clock too, a device_get lies inside a turn
     for start, end in by_name["decode.device_get"]:
         assert any(s <= start and end <= e for s, e in by_name["decode.turn"])
     # complete() spans are retroactive: the ring has them, the trace not
-    assert "decode.step" not in by_name
+    assert "decode.step" not in by_name and "decode.prefill" not in by_name
 
 
 def test_removed_histograms_are_gone_and_the_compile_counters_stay(
@@ -268,5 +322,9 @@ def test_removed_histograms_are_gone_and_the_compile_counters_stay(
                    for kind in snap.values())
     executes = [e["args"] for e in obs.trace.drain()
                 if e["name"] == "decode.execute"]
-    assert sum(a["compile"] and not a["cache_hit"] for a in executes) == 2
-    assert sum(a["cache_hit"] for a in executes) == 2
+    # (a launch's span says whether its program was built or loaded; a
+    # read's has nothing to say of that)
+    launches = [a for a in executes if "compile" in a]
+    assert len(launches) * 2 == len(executes)
+    assert sum(a["compile"] and not a["cache_hit"] for a in launches) == 2
+    assert sum(a["cache_hit"] for a in launches) == 2

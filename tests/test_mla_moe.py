@@ -388,6 +388,48 @@ def test_the_scheduler_hangs_the_models_counters_on_its_spans():
     assert "decode.cache_row_bytes" in flat and "moe.assignments" in flat
 
 
+def test_counters_sit_on_the_step_that_produced_them():
+    """With the next step launched before the last one is read, a result's
+    counters still land on the span of the call that produced them: two
+    streams of 6 and 3 tokens, so steps over two slots and then over one,
+    and every ``decode.step`` reads 3 choices x 2 expert layers for each
+    slot IT stepped. The totals take in the last step in flight."""
+    from mxnet_tpu import obs
+    from mxnet_tpu.serve import DecodeScheduler
+
+    model = mla_moe.MLAMoEDecodeModel(CFG, seed=SEED)
+    engine = DecodeEngine(model, slots=SLOTS, page_size=PAGE, num_pages=17,
+                          prompt_buckets=[16])
+    obs.enable()
+    try:
+        sched = DecodeScheduler(engine)
+        try:
+            handles = [sched.submit(list(range(1, 12)), max_new_tokens=6),
+                       sched.submit(list(range(3, 9)), max_new_tokens=3)]
+            assert sched.drain(timeout=60)
+            stats = sched.stats()
+        finally:
+            sched.close()
+        spans = obs.trace.drain()
+    finally:
+        obs.disable()
+        obs.reset()
+    assert stats["tokens_out"] == 9 and stats["dropped_speculative"] == 0
+    assert handles[0].get(timeout=1)[0] == "token"
+    prefills = [s["args"] for s in spans if s["name"] == "decode.prefill"]
+    steps = [s["args"] for s in spans if s["name"] == "decode.step"]
+    assert sorted(p["moe.assignments"] for p in prefills) == [6 * 6, 11 * 6]
+    assert {s["active"] for s in steps} == {1, 2}
+    assert sum(s["active"] for s in steps) == 5 + 2
+    for s in steps:
+        assert s["moe.assignments"] == 6 * s["active"]
+    assert stats["steps_launched"] == len(steps)
+    assert stats["launched_ahead"] == sum(s["ahead"] for s in steps) > 0
+    for name in ("moe.assignments", "moe.held", "moe.dropped"):
+        assert stats["counted"][name] == sum(s[name]
+                                             for s in prefills + steps)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_expert_choices_that_flip_on_near_ties_are_counted(dtype, monkeypatch):
     """The router runs in float32 in the program, on activations that are

@@ -95,17 +95,25 @@ def _tpu_program(engine, one_chip, kind, monkeypatch):
                                     engine._params)
     kv = spec(engine.kv.shape, engine.kv.dtype, sharding=pool)
     i32, slots, page = jnp.int32, engine.slots, engine.page_size
+    last = spec((slots,), i32)
+    # the one array a call uploads (``DecodeEngine``'s docstring)
     if kind == "step":
-        lowered = step.lower(
-            params, kv, spec((slots,), i32), spec((slots,), i32),
-            spec((slots, engine.max_pages), i32), spec((slots,), i32),
-            spec((), jnp.uint32), spec((slots,)))
+        packed = spec(engine.blank_step().shape, i32)
+        lowered = step.lower(params, kv, last, packed)
     else:
         bucket = engine.buckets[0]
-        lowered = prefill.lower(
-            params, kv, spec((1, bucket), i32), spec((), i32),
-            spec((bucket // page,), i32), spec((), jnp.uint32), spec(()))
+        packed = spec((4 + bucket // page + bucket,), i32)
+        lowered = prefill.lower(params, kv, last, packed)
     return lowered, lowered.compile()
+
+
+def _host_arguments(engine, lowered):
+    """The program's arguments less those that rest on the device between
+    calls: the weights, the pool, the last-token vector."""
+    import jax
+
+    resident = len(jax.tree_util.tree_leaves(engine._params)) + 2
+    return jax.tree_util.tree_leaves(lowered.args_info)[resident:]
 
 
 def _k_slice_bytes(engine):
@@ -142,6 +150,11 @@ def test_tpu_step_program_reads_the_pool_where_it_lies(
     layer), the parameter and the result."""
     lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") == CFG["layers"]
+    # one upload a step: positions, lengths, temperatures, page tables and
+    # the seed come up as ONE int32 array; the tokens never leave the device
+    (packed,) = _host_arguments(engine, lowered)
+    assert (packed.shape, str(packed.dtype)) == (
+        (SLOTS + 1, 3 + engine.max_pages), "int32")
     cost = obs.device.analyze_compiled(compiled)
     assert cost["bytes_accessed"] > 0
     assert cost["temp_bytes"] < _k_slice_bytes(engine), cost
@@ -167,8 +180,9 @@ def test_tpu_prefill_program_writes_the_pool_in_place(
     the whole pool to ``{4,2,3,1,0}`` and back (12 heads pad an 8-row tile;
     the scatter would rather tile the page axis): two pool-shaped ``copy``
     instructions, which is what the lines are searched for."""
-    _lowered, compiled = _tpu_program(engine, one_chip, "prefill",
-                                      monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill",
+                                     monkeypatch)
+    assert len(_host_arguments(engine, lowered)) == 1
     cost = obs.device.analyze_compiled(compiled)
     assert cost["temp_bytes"] < _k_slice_bytes(engine), cost
     lines = _pool_lines(compiled, engine)
