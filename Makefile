@@ -5,7 +5,7 @@
 PYTHON ?= python
 export JAX_PLATFORMS ?= cpu
 
-.PHONY: lint lint-tests test test-fast chaos chaos-serve elastic async perf obs health serve serve-bench serve_mesh dossier tsan prof progcache coldstart train-obs copytrack decode
+.PHONY: lint lint-tests test test-fast chaos chaos-serve elastic async perf obs health serve serve_mesh tsan prof progcache train-obs copytrack decode
 
 # repo self-lint: framework invariants + the concurrency-correctness pass
 # (lock-order cycles, blocking-under-lock, CV/thread discipline, wire
@@ -17,20 +17,16 @@ lint:
 # runtime concurrency sanitizer (docs/ANALYSIS.md "Concurrency lint"):
 # re-run the serve-fleet SIGKILL and elastic-rejoin chaos suites with the
 # instrumented locks on and the deadlock watchdog armed — every chaos run
-# doubles as a lock-order sanitizer run — then report sanitizer overhead
+# doubles as a lock-order sanitizer run
 tsan:
 	MXNET_TSAN=1 MXNET_TSAN_STALL_S=30 $(PYTHON) -m pytest tests/test_tsan.py tests/test_fleet.py -q -p no:cacheprovider
 	MXNET_TSAN=1 MXNET_TSAN_STALL_S=30 $(PYTHON) -m pytest tests/test_elastic.py -q -p no:cacheprovider
-	$(PYTHON) tools/tsan_bench.py
 
 # data-plane sanitizer (docs/ANALYSIS.md "Data-plane lint"): the dataplane
-# lint test subset with the MXNET_COPYTRACK runtime twin exercised e2e,
-# then a COPYTRACK-instrumented serve smoke that prints the wire-hop cost
-# table (p50 hop cost, bytes copied / serialize calls / host syncs per
-# request) — the measured denominator for the zero-copy rewrite
+# lint test subset with the MXNET_COPYTRACK runtime twin exercised e2e
+# (bytes copied / serialize calls / host syncs per request of one INFER hop)
 copytrack:
 	$(PYTHON) -m pytest tests/ -q -m dataplane -p no:cacheprovider
-	$(PYTHON) tools/serve_bench.py --wire-hop --duration 4
 
 # the static-analysis test subset (graph/trace/sharding/repo lint)
 lint-tests:
@@ -49,88 +45,59 @@ chaos:
 	$(PYTHON) -m pytest tests/ -q -m chaos -p no:cacheprovider
 
 # serving-fleet + platform-outage chaos (docs/ROBUSTNESS.md "Serving
-# fleet"): the full fleet/platform suite incl. the slow SIGKILL flagship,
-# then a measured availability run — open-loop load over a 3-replica fleet,
-# one replica hard-killed mid-run, error rate + p50/p99 reported
-# before/during/after the kill window
+# fleet"): the full fleet/platform suite incl. the slow SIGKILL flagship
 chaos-serve:
 	$(PYTHON) -m pytest tests/test_fleet.py tests/test_platform.py -q -p no:cacheprovider
-	$(PYTHON) tools/serve_bench.py --chaos --duration 9 --qps 80
 
 # elastic-training suite (docs/ROBUSTNESS.md "Elastic training"): worker
 # membership/heartbeats, generation-scoped barriers released over
 # survivors, PS snapshot+WAL durability, checkpointed rejoin — incl. the
-# slow flagships (1-of-3 worker SIGKILL mid-epoch; PS SIGKILL mid-push);
-# then the measured recovery/rejoin/overhead numbers
+# slow flagships (1-of-3 worker SIGKILL mid-epoch; PS SIGKILL mid-push)
 elastic:
 	$(PYTHON) -m pytest tests/ -q -m elastic -p no:cacheprovider
-	$(PYTHON) tools/elastic_bench.py
 
 # bounded-staleness async training (docs/ROBUSTNESS.md "Asynchronous
 # training"): committed-clock protocol + gated pull, straggler-verdict
 # actuation (staleness widen / shard recut), hierarchical reduction,
-# async exactly-once across a PS SIGKILL, sync-vs-async convergence;
-# then the measured step-time decoupling leg
+# async exactly-once across a PS SIGKILL, sync-vs-async convergence
 async:
 	$(PYTHON) -m pytest tests/ -q -m async -p no:cacheprovider
-	$(PYTHON) tools/elastic_bench.py --async
 
 # dispatch-overhead guarantees (docs/PERFORMANCE.md): the perf-marked tests
-# assert a Trainer.step updates all params in <=2 compiled programs, then
-# profile_step.py prints the full per-phase dispatch breakdown
+# assert a Trainer.step updates all params in <=2 compiled programs
+# (profiler.count_dispatches), and the device plane's memory gates
 perf:
 	$(PYTHON) -m pytest tests/ -q -m perf -p no:cacheprovider
-	$(PYTHON) tools/profile_step.py --model resnet50_v1
 
 # runtime telemetry suite (docs/OBSERVABILITY.md): span tracer, metrics
 # registry, instrumented step phases, chaos-event tagging, PLUS the
 # distributed plane — trace-context propagation over both wires, the
 # OP_TELEMETRY collection plane, Prometheus exposition, SLO math, and the
 # cross-process chaos flagship (2 ProcReplicas, one SIGKILLed, one merged
-# timeline); then the measured cost of leaving tracing on (sample 0.1)
+# timeline)
 obs:
 	$(PYTHON) -m pytest tests/ -q -m obs -p no:cacheprovider
-	$(PYTHON) tools/serve_bench.py --obs-overhead --duration 4
 
 # black-box plane (docs/OBSERVABILITY.md "Tail sampling" / "Continuous
 # profiling" / "Flight recorder"): tail-based retention policy units +
 # cross-process verdict plumbing, the sampling profiler, crash flight
-# recorder + DUMP opcode, torn-tail tolerance; then the measured cost of
-# leaving tail buffering + 67 Hz profiling on (<5% gated in bench.py)
+# recorder + DUMP opcode, torn-tail tolerance
 prof:
 	$(PYTHON) -m pytest tests/ -q -m blackbox -p no:cacheprovider
-	$(PYTHON) tools/serve_bench.py --prof-overhead --duration 4
-
-# perf-regression dossier (docs/PERFORMANCE.md "Perf-regression dossier"):
-# the device-plane perf gates (memory steady state, regression
-# classification, dispatch bound with cost capture on), then
-# bench_compare over whatever BENCH_r*.json artifacts sit in the repo root
-# (none are committed: the driver's PERF_LEDGER.jsonl is the record now).
-# The CLI exits 2 on regressions/anomalies, 3 on platform gaps and 1 with
-# nothing to read, so the report is informational here; CI gates on the
-# pytest half.
-dossier:
-	$(PYTHON) -m pytest tests/test_device_obs.py -q -m perf -p no:cacheprovider
-	-$(PYTHON) tools/bench_compare.py
 
 # training-health plane (docs/OBSERVABILITY.md "Training health"): sentinel
 # detector units, the dispatch-bound proof (stats cost 0 extra program
 # executions), the NaN-provenance blame pass, the chaos flagship (injected
-# NaN -> breach -> blame -> auto-rollback -> bitwise-identical replay);
-# then the measured cost of leaving the sentinel on at default sampling
+# NaN -> breach -> blame -> auto-rollback -> bitwise-identical replay)
 health:
 	$(PYTHON) -m pytest tests/ -q -m health -p no:cacheprovider
-	$(PYTHON) tools/health_bench.py
 
 # training-fleet telemetry plane (docs/OBSERVABILITY.md "Training-fleet
 # telemetry"): detector pure-function units, heartbeat-piggybacked parts,
 # PS OP_TELEMETRY exactly-once, merged rank timeline with a corpse lane,
-# hot-key boundedness, the chaos-slow flagship; then the measured
-# straggler-detection latency + the <5%-gated step-accounting overhead
+# hot-key boundedness, the chaos-slow flagship
 train-obs:
 	$(PYTHON) -m pytest tests/ -q -m train_obs -p no:cacheprovider
-	$(PYTHON) tools/elastic_bench.py --straggler
-	$(PYTHON) tools/elastic_bench.py --train-obs
 
 # persistent AOT program cache (docs/PERFORMANCE.md "Program cache and
 # cold start"): key-derivation/hit/miss/reject units, bitwise parity of
@@ -139,30 +106,17 @@ train-obs:
 progcache:
 	$(PYTHON) -m pytest tests/ -q -m progcache -p no:cacheprovider
 
-# cold-vs-warm cold-start A/B on CPU with the gated assertion (warm start
-# performs ZERO fresh XLA compiles — every compile_log entry a cache_hit;
-# strictly fewer compiles than cold), so a program-key-stability
-# regression fails here, not a TPU round later
-coldstart: progcache
-	$(PYTHON) tools/serve_bench.py --cold
-
 # serving suite: compiled engine program bound, SLO scheduler, endpoint
 # lifecycle + chaos degradation (docs/SERVING.md)
 serve:
 	$(PYTHON) -m pytest tests/ -q -m serve -p no:cacheprovider
 
-# load generator: closed-loop + open-loop p50/p99 vs offered load
-serve-bench:
-	$(PYTHON) tools/serve_bench.py --model mlp --duration 5
-
 # autoregressive decode engine (docs/SERVING.md "Autoregressive decode"):
 # paged-KV alloc/free/leak units, the two-program compile bound proof,
 # continuous-batch join/leave, the streaming wire roundtrip with chaos
-# drop/dup and the mid-stream kill, progcache-warm replica; then the
-# open-loop decode bench (tokens/s + per-token p99 under churn)
+# drop/dup and the mid-stream kill, progcache-warm replica
 decode:
 	$(PYTHON) -m pytest tests/ -q -m decode -p no:cacheprovider
-	$(PYTHON) tools/serve_bench.py --decode --duration 4
 
 # mesh-sharded serving + elastic autoscale suite on the 8-device CPU mesh:
 # tensor-parallel engines, replica groups on mesh slices, quarantine→
@@ -170,4 +124,3 @@ decode:
 # (docs/SERVING.md "Mesh-sharded serving and elastic autoscaling")
 serve_mesh:
 	$(PYTHON) -m pytest tests/ -q -m serve_mesh -p no:cacheprovider
-	$(PYTHON) tools/serve_bench.py --scale --duration 3

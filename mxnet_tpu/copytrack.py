@@ -17,10 +17,10 @@ copy), ``wire.serialize_calls`` / ``wire.serialize_bytes`` (array→wire
 packs), ``hotpath.host_syncs`` (device→host materialization points, by
 site). They feed two consumers:
 
-- ``copytrack.snapshot()`` — always available while enabled; the
-  ``bench.py`` ``wire_hop`` leg divides deltas by request count to get
-  bytes-copied-per-request, the committed denominator for ROADMAP item
-  4's "≥2× hop-cost reduction";
+- ``copytrack.snapshot()`` — always available while enabled; a caller
+  divides deltas by its request count to get bytes copied, serialize
+  calls and host syncs per request (``tests/test_dataplane_lint.py``
+  holds the counts of one INFER hop);
 - the ``mxnet_tpu.obs`` metrics registry (same counter names) when
   telemetry is ALSO on — so the numbers ride STATS replies, Prometheus
   exposition, and merged fleet timelines for free.

@@ -28,9 +28,9 @@ per-op device stats and an ``aggregate_stats`` memory table (TBV, SURVEY.md
   (``flops / dt / peak``) and a roofline class — compute-bound when the
   program's operational intensity (FLOP/byte) clears the machine balance
   point (peak FLOPs / peak bandwidth), bandwidth-bound otherwise — per
-  phase (forward/backward/update/serve.execute). ``bench.py`` feeds the
-  measured matmul peak in via :func:`set_peak` so the attribution uses the
-  same denominator as the measured MFU it sits next to.
+  phase (forward/backward/update/serve.execute). :func:`set_peak` pins
+  another denominator. This is the compiler's FLOP count over host wall
+  time, NOT the ledger's ``train_mfu_pct`` (``benchmark/flops.py``).
 - **Live-memory telemetry** (:func:`sample`): a sampled ``device.live_bytes``
   gauge (device ``memory_stats()`` where the backend reports it, the
   ``jax.live_arrays()`` sum elsewhere), exported as a Perfetto counter
@@ -41,8 +41,8 @@ per-op device stats and an ``aggregate_stats`` memory table (TBV, SURVEY.md
 
 Activation follows the obs contract — zero-cost when off: capture runs
 when telemetry is enabled (``obs.enable()`` / ``MXNET_OBS=1``) or when
-``MXNET_DEVICE_COST=1`` forces it (how ``bench.py`` captures program costs
-without paying span overhead); ``MXNET_DEVICE_COST=0`` forces it off even
+``MXNET_DEVICE_COST=1`` forces it (program costs captured without
+paying span overhead); ``MXNET_DEVICE_COST=0`` forces it off even
 with telemetry on (the escape hatch if an exotic backend rejects AOT
 lowering).
 """
@@ -82,8 +82,8 @@ def active() -> bool:
 # ---------------------------------------------------------------------------
 
 # Published single-chip peaks keyed by jax's ``device_kind``: (dense bf16
-# TFLOP/s, HBM GB/s). THE one table — bench.py imports it. A TPU kind that
-# is not listed raises where a peak is asked for: a default would put some
+# TFLOP/s, HBM GB/s); the benchmark keeps its own in benchmark/peaks.json
+# (ROADMAP D13). A TPU kind that is not listed raises: a default would put some
 # other chip's ceiling under this one's numbers.
 DEVICE_PEAKS = {
     "TPU v5 lite": (197.0, 819.0),  # Google Cloud documentation, "TPU v5e"
@@ -97,7 +97,7 @@ _peak_override: list = [None, None]        # [tflops, gbps]
 
 def set_peak(tflops: Optional[float] = None, gbps: Optional[float] = None):
     """Pin the peak compute rate (TFLOP/s) and/or memory bandwidth (GB/s)
-    used by MFU/roofline math — bench.py sets the measured matmul peak."""
+    used by MFU/roofline math, e.g. a measured matmul peak."""
     if tflops is not None:
         _peak_override[0] = float(tflops)
     if gbps is not None:
@@ -142,7 +142,7 @@ def get_peak() -> Tuple[float, float]:
 
 # (site, label) → cost record. Sites: "update" (fused engine), "serve",
 # "executor", "cachedop", "train_step". The registry the attribution path
-# and bench.py read back; bounded by program count (itself bounded by the
+# and cost_of() read back; bounded by program count (itself bounded by the
 # engines' cache-key accounting).
 _COSTS: Dict[Tuple[str, str], dict] = {}
 _lock = threading.Lock()

@@ -42,8 +42,8 @@ only as barrier-timeout error text. This module closes that gap:
   wall-clock anchors; SIGKILL'd ranks contribute their JSONL corpses.
 
 Everything is gated by the one ``MXNET_OBS`` discipline (zero-cost when
-off; ``MXNET_OBS_FLEET=0`` vetoes just this plane) and the overhead is
-measured, not assumed (``train_obs_overhead`` leg in bench.py, <5%).
+off; ``MXNET_OBS_FLEET=0`` vetoes just this plane). What it costs a
+step when on: not measured on the chip.
 """
 from __future__ import annotations
 

@@ -23,10 +23,10 @@ that thread's **active span phase** (the tracer's per-thread span stack —
 - :meth:`SamplingProfiler.recent` — the raw last-N-seconds sample ring
   (the flight recorder's slice).
 
-Overhead is measured, not assumed: ``tools/serve_bench.py
---prof-overhead`` / the bench.py ``prof_overhead`` leg run the serve
-closed loop with the profiler (and tail buffering) off vs on and gate the
-delta under 5%.
+Overhead on the chip: not measured. The benchmark's traced runs
+(``benchmark/run.py --trace 1``) enable ``obs`` spans, not this sampler,
+so no cell of ``BENCHMARK.json`` reads what leaving it on costs
+(``PERF.md`` section 7).
 """
 from __future__ import annotations
 

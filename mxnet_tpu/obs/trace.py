@@ -195,7 +195,7 @@ class Tracer:
     def stream_path(self) -> Optional[str]:
         """The JSONL path currently streamed to (None when not streaming)
         — lets a tool that must toggle telemetry restore the caller's
-        stream afterwards (serve_bench.run_obs_overhead)."""
+        stream afterwards."""
         return self._stream_path
 
     @property

@@ -39,7 +39,7 @@ def aggregate_active() -> bool:
 
 # ---------------------------------------------------------------------------
 # Dispatch counting — the honest "how many compiled device programs did this
-# step execute" metric behind tools/profile_step.py and the perf tests.
+# step execute" metric behind the perf tests (``pytest -m perf``).
 # Hook points: ndarray.invoke (each eager op is one compiled execution),
 # the fused update engine, Executor forward/backward, CachedOp calls, and
 # NDArray.asnumpy (device→host transfers).  Works on any backend, CPU

@@ -273,7 +273,7 @@ class CacheEntry(NamedTuple):
 
 def _obs_count(name: str, **attrs) -> None:
     # metrics/events only when telemetry records; the cache's own stats
-    # dict counts unconditionally (serve_bench / tests read those)
+    # dict counts unconditionally (STATS replies and tests read those)
     from . import obs
 
     if obs.enabled():

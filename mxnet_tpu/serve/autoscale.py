@@ -31,8 +31,8 @@ function (tests/test_autoscale.py):
   and drives the pool. One join in flight at a time — bring-up includes
   XLA warmup, and deciding again while a replica is mid-join would
   overshoot. Every decision lands in ``self.events`` and the
-  ``autoscale.*`` metrics/events, so a load ramp's scale-out is a measured
-  artifact (``tools/serve_bench.py --ramp``), not a claim.
+  ``autoscale.*`` metrics/events, so a load ramp's scale-out can be read
+  back from the record afterwards, decision by decision.
 """
 from __future__ import annotations
 

@@ -29,7 +29,7 @@ Layers (bottom up):
   a second replica once it exceeds ``hedge_ms`` and the deadline still
   allows; first reply wins), and the **fleet-atomic two-phase reload**.
 - :class:`FleetServer` — a :class:`ServeServer` whose "batcher" is the
-  Router: same wire protocol, so ``ServeClient`` / ``serve_bench`` /
+  Router: same wire protocol, so ``ServeClient``, a load generator and
   chaos rules drive a fleet exactly like a single replica, and the STATS
   endpoint reports per-replica breaker/failover state.
 
@@ -1548,8 +1548,8 @@ class FleetServer(ServeServer):
     the server's batcher, so INFER routes with failover/hedging, READY
     reflects live replicas + the fleet version, RELOAD is the fleet-atomic
     two-phase flip, and STATS returns per-replica breaker/failover state —
-    all on the unchanged serve wire protocol (``ServeClient``,
-    ``serve_bench``, and the chaos rule table work as-is)."""
+    all on the unchanged serve wire protocol (``ServeClient``, a load
+    generator, and the chaos rule table work as-is)."""
 
     def __init__(self, router: Router, host: str = "127.0.0.1",
                  port: int = 0, *, default_timeout: float = 30.0):

@@ -355,8 +355,8 @@ class ServeServer:
                "pid": os.getpid()}
         if include_metrics:
             # ONE schema for every numeric runtime signal: the full
-            # registry snapshot rides STATS, so serve_bench /
-            # fleet_report / the SLO monitor read the same counters the
+            # registry snapshot rides STATS, so load generators,
+            # fleet_report and the SLO monitor read the same counters the
             # process records — no ad-hoc parallel bookkeeping. (The
             # telemetry path passes False: its part already carries the
             # snapshot, a second copy would just double the payload.)

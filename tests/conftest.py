@@ -74,7 +74,7 @@ def pytest_configure(config):
         "shared key derivation, hit/miss/reject structure, cache-hit "
         "bitwise parity, replica restart warm-from-disk "
         "(docs/PERFORMANCE.md \"Program cache and cold start\"); run via "
-        "`pytest -m progcache` or `make progcache`/`make coldstart`")
+        "`pytest -m progcache` or `make progcache`")
     config.addinivalue_line(
         "markers", "async: bounded-staleness async-training tests — "
         "committed clocks, the staleness-gated pull, straggler-verdict "

@@ -562,22 +562,6 @@ def test_signal_dump_does_not_deadlock_on_held_locks(tmp_path):
     assert len(dumps) == 1
 
 
-def test_prof_overhead_bench_restores_callers_tail_buffer():
-    """run_prof_overhead swaps its own tail buffer in for the 'on'
-    segments — on exit the CALLER's buffer (policy, retained log and all)
-    must come back, not the bench's (regression: the bench buffer stayed
-    installed whenever the caller had tail mode on)."""
-    import serve_bench
-    obs.enable()
-    mine = tail.enable()
-    mine.policy = tail.RetentionPolicy(slow_ms=123.0)
-    res = serve_bench.run_prof_overhead(duration=0.6, segments=1)
-    assert res["qps_on"] > 0
-    assert tail.enabled() and tail.buffer() is mine
-    assert tail.buffer().policy.slow_ms == 123.0
-    assert obs.enabled()  # the caller's telemetry resumed too
-
-
 # ---------------------------------------------------------------------------
 # 8. flagship: fleet under load — tail retention + SIGKILL bundle
 # ---------------------------------------------------------------------------

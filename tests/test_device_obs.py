@@ -15,12 +15,7 @@ Covers the tentpole contracts of the device plane:
 3. **Leak detection** — the steady-state detector flags a deliberately
    retained array list and stays quiet over a 20-step steady-state fit
    (the ``pytest -m perf`` memory gate).
-4. **Regression dossier** — classification unit tests on synthetic
-   trajectories (improvement / regression / gap / within-noise) and a
-   five-round CLI acceptance: the bf16-piped inversion is flagged, r05 is
-   a platform gap (never a 100% regression), and the exit code
-   distinguishes regression / clean / gap.
-5. **Profiler window guards** — double ``start_trace``/``stop_trace`` are
+4. **Profiler window guards** — double ``start_trace``/``stop_trace`` are
    idempotent and land as tagged obs events in the span timeline.
 """
 import json
@@ -36,7 +31,6 @@ from mxnet_tpu import symbol as sym
 from mxnet_tpu.io import NDArrayIter
 from mxnet_tpu.module import Module
 from mxnet_tpu.obs import device as obs_device
-from mxnet_tpu.obs import regress
 
 pytestmark = pytest.mark.obs
 
@@ -111,7 +105,7 @@ def test_fused_engine_compile_log_carries_device_cost(obs_on):
     eng.apply([0], [w], [g], [None])
     assert len(eng.compile_log) == 1  # steady state: no retrace
     _assert_cost_fields(eng.compile_log[0], "fused")
-    # the cost registry mirrors the record for attribution + bench.py
+    # the cost registry mirrors the record for attribution
     assert obs_device.cost_of("update", "SGD")["flops"] > 0
     # execute spans carry analytic attribution (the compile call doesn't)
     execs = [e for e in obs.trace.events()
@@ -378,125 +372,6 @@ def test_synthetic_leak_math():
 
 
 # ---------------------------------------------------------------------------
-# 4. regression dossier (synthetic trajectories)
-# ---------------------------------------------------------------------------
-
-def _fake_round(tmp_path, n, value=None, extra=None, rc=0, error=None):
-    parsed = {"metric": "resnet50_v1 fp32 train throughput", "value": value,
-              "unit": "images/sec", "vs_baseline": None}
-    if extra is not None:
-        parsed["extra"] = extra
-    if error:
-        parsed["error"] = error
-    p = tmp_path / f"BENCH_r{n:02d}.json"
-    p.write_text(json.dumps({"n": n, "rc": rc, "parsed": parsed}))
-    return str(p)
-
-
-@pytest.mark.perf
-def test_regress_classifies_improvement_regression_and_noise(tmp_path):
-    paths = [
-        _fake_round(tmp_path, 1, value=100.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 2, value=120.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 3, value=121.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 4, value=90.0, extra={"fp32_spread": 0.02}),
-    ]
-    d = regress.dossier(paths)
-    t = d["gains"]["resnet50_fp32_ips"]["transitions"]
-    assert [x["class"] for x in t] == ["improvement", "within_noise",
-                                      "regression"]
-    assert d["status"] == "regression"
-    assert d["exit_code"] == regress.EXIT_REGRESSION
-
-
-@pytest.mark.perf
-def test_regress_within_spread_band_is_noise_not_regression(tmp_path):
-    # a 6% drop inside a 10% measured spread must NOT classify as a
-    # regression — the band comes from the artifact's own honesty field
-    paths = [
-        _fake_round(tmp_path, 1, value=100.0, extra={"fp32_spread": 0.10}),
-        _fake_round(tmp_path, 2, value=94.0, extra={"fp32_spread": 0.03}),
-    ]
-    d = regress.dossier(paths)
-    t = d["gains"]["resnet50_fp32_ips"]["transitions"]
-    assert [x["class"] for x in t] == ["within_noise"]
-    assert d["status"] == "clean"
-    assert d["exit_code"] == regress.EXIT_CLEAN
-
-
-@pytest.mark.perf
-def test_regress_platform_gap_never_reads_as_regression(tmp_path):
-    paths = [
-        _fake_round(tmp_path, 1, value=100.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 2, rc=1,
-                    error="device enumeration timed out — backend hung"),
-        _fake_round(tmp_path, 3, value=101.0, extra={"fp32_spread": 0.02}),
-    ]
-    d = regress.dossier(paths)
-    assert d["rounds"][1]["gap"]
-    series = d["gains"]["resnet50_fp32_ips"]["series"]
-    assert series[1] == {"round": 2, "gap": True}
-    # the transition skips the gap and compares r1 -> r3: within noise
-    t = d["gains"]["resnet50_fp32_ips"]["transitions"]
-    assert len(t) == 1 and t[0]["class"] == "within_noise"
-    assert t[0]["from_round"] == 1 and t[0]["to_round"] == 3
-    assert d["status"] == "gap"
-    assert d["exit_code"] == regress.EXIT_GAP
-
-
-@pytest.mark.perf
-def test_regress_flags_bf16_piped_inversion(tmp_path):
-    paths = [_fake_round(
-        tmp_path, 1, value=100.0,
-        extra={"fp32_spread": 0.02, "resnet50_piped_ips": 170.0,
-               "resnet50_piped_bf16_ips": 75.0})]
-    d = regress.dossier(paths)
-    checks = {a["check"] for a in d["anomalies"]}
-    assert "bf16_piped_inversion" in checks
-    assert d["exit_code"] == regress.EXIT_REGRESSION
-
-
-@pytest.mark.perf
-def test_bench_compare_cli_on_a_trajectory(tmp_path, capsys):
-    """The acceptance run over a five-round trajectory: a bf16-piped
-    inversion flagged in r04, r05 a platform gap carrying its error text,
-    regression-class exit code."""
-    import bench_compare
-
-    arts = [
-        _fake_round(tmp_path, 1, value=100.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 2, value=120.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 3, value=121.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 4, value=122.0,
-                    extra={"fp32_spread": 0.02, "resnet50_piped_ips": 170.0,
-                           "resnet50_piped_bf16_ips": 75.0}),
-        _fake_round(tmp_path, 5, rc=1,
-                    error="device enumeration: platform_unavailable: no "
-                          "response within 90s watchdog"),
-    ]
-    code = bench_compare.main(arts)
-    out = capsys.readouterr().out
-    assert code == regress.EXIT_REGRESSION
-    assert "bf16_piped_inversion" in out
-    assert "GAP" in out and "r05" in out
-    assert "no response within 90s watchdog" in out
-
-
-@pytest.mark.perf
-def test_bench_compare_json_output(tmp_path, capsys):
-    paths = [
-        _fake_round(tmp_path, 1, value=100.0, extra={"fp32_spread": 0.02}),
-        _fake_round(tmp_path, 2, value=130.0, extra={"fp32_spread": 0.02}),
-    ]
-    import bench_compare
-
-    code = bench_compare.main(paths + ["--json"])
-    assert code == regress.EXIT_CLEAN
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["status"] == "clean"
-
-
-# ---------------------------------------------------------------------------
 # perf gate: the dispatch bound holds with cost capture ON
 # ---------------------------------------------------------------------------
 
@@ -521,7 +396,7 @@ def test_fused_dispatch_bound_holds_with_capture(obs_on):
 
 
 # ---------------------------------------------------------------------------
-# 5. profiler window guards
+# 4. profiler window guards
 # ---------------------------------------------------------------------------
 
 def test_profiler_double_start_stop_is_idempotent(tmp_path, obs_on):

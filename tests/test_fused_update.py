@@ -4,12 +4,11 @@
   per-parameter eager oracle (MXNET_FUSED_UPDATE=0), fp32 tight / bf16 loose,
   including the AMP loss-scale skip-step and clip-by-global-norm fusions;
 - the dispatch guarantee: a gluon Trainer.step updates a resnet50_v1's 161
-  parameters in <= 2 compiled device programs (tools/profile_step.py);
+  parameters in <= 2 compiled device programs (profiler.count_dispatches);
 - checkpoint round-trips of the device-resident optimizer state stay bitwise;
 - the TraceLinter's update-retrace-churn rule.
 """
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -19,9 +18,6 @@ from mxnet_tpu import autograd, nd, profiler
 from mxnet_tpu import optimizer as opt_mod
 from mxnet_tpu.gluon import Trainer, nn
 from mxnet_tpu.ndarray import NDArray
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tools"))
 
 SHAPES = [(5, 4), (3,), (2, 3, 2)]
 
@@ -439,33 +435,53 @@ def test_tracelinter_no_churn_on_lr_schedule():
 
 
 # ---------------------------------------------------------------------------
-# the dispatch-count guarantee (profile_step.py harness, CPU-friendly)
+# the dispatch-count guarantee (counts programs, so the CPU can hold it)
 # ---------------------------------------------------------------------------
+
+def _update_phase_dispatches(model, optimizer, fused):
+    """(number of parameters, DispatchCounts of one ``Trainer.step``'s
+    update phase) for a model-zoo net after two whole steps."""
+    from mxnet_tpu.gluon.model_zoo import vision
+
+    prev = _fixed_env("1" if fused else "0")
+    try:
+        net = vision.get_model(model)
+        net.initialize()
+        x = nd.ones((1, 3, 32, 32))
+        net(x)  # materialize deferred shapes before counting params
+        tr = Trainer(net.collect_params(), optimizer,
+                     {"learning_rate": 0.01})
+        for step in range(3):
+            with autograd.record():
+                out = net(x)
+                loss = (out * out).sum()
+            loss.backward()
+            with profiler.count_dispatches() as c:
+                tr.step(1)
+        return len(tr._params), c  # the third step's: nothing compiles
+    finally:
+        _fixed_env(prev)
+
 
 @pytest.mark.perf
 def test_resnet50_update_dispatches():
     """The acceptance bar: a Trainer.step over resnet50_v1 (161 params)
     executes <= 2 compiled device programs in its update phase (vs one per
     parameter on the eager path)."""
-    import profile_step
-
-    res = profile_step.profile_model("resnet50_v1", batch_size=1,
-                                     image_size=32, optimizer="sgd",
-                                     eager=False, warmup=2)
-    assert res["n_params"] == 161
-    assert res["update"]["total_compiled"] <= 2, res["update"]
+    n_params, c = _update_phase_dispatches("resnet50_v1", "sgd", fused=True)
+    assert n_params == 161
+    assert c.total_compiled <= 2, c.as_dict()
 
 
 @pytest.mark.perf
 def test_profile_step_eager_comparison_small():
-    """The harness's eager/fused comparison itself (small net, fast)."""
-    import profile_step
-
-    res = profile_step.profile_model("resnet18_v1", batch_size=1,
-                                     image_size=32, optimizer="adam",
-                                     eager=True, warmup=2)
-    assert res["update"]["total_compiled"] <= 2
-    assert res["update_eager"]["total_compiled"] >= res["n_params"]
+    """The eager/fused comparison (small net, fast): the eager path runs
+    at least one program per parameter, the fused engine at most two."""
+    n_params, fused = _update_phase_dispatches("resnet18_v1", "adam",
+                                               fused=True)
+    assert fused.total_compiled <= 2, fused.as_dict()
+    _, eager = _update_phase_dispatches("resnet18_v1", "adam", fused=False)
+    assert eager.total_compiled >= n_params, eager.as_dict()
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,6 @@ docs/PERFORMANCE.md "Program cache and cold start").
   same cache dir becomes ready with zero fresh XLA compiles.
 """
 import os
-import sys
 import time
 
 import numpy as np
@@ -30,9 +29,6 @@ from mxnet_tpu import nd, profiler, progcache
 from mxnet_tpu import optimizer as opt_mod
 from mxnet_tpu import serve
 from mxnet_tpu import symbol as sym
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tools"))
 
 pytestmark = pytest.mark.progcache
 
@@ -426,11 +422,44 @@ def test_module_fit_then_checkpoint_prewarm_hits(cache_dir, tmp_path):
 @pytest.mark.slow
 @pytest.mark.chaos
 def test_proc_replica_restart_warms_from_cache(tmp_path):
-    import serve_bench
+    from mxnet_tpu.model import save_checkpoint
+    from mxnet_tpu.serve.fleet import ReplicaPool
 
-    res = serve_bench.run_cold_bench(model="mlp", max_batch_size=4,
-                                     keep_artifact=str(tmp_path))
-    assert res["ok"], res
-    assert res["fresh_compiles_cold"] == 3  # buckets(4) = [1, 2, 4]
-    assert res["fresh_compiles_warm"] == 0
-    assert res["cache_hits_warm"] == res["compiles_warm"] == 3
+    net, arg = _mlp()
+    prefix = str(tmp_path / "model")
+    save_checkpoint(prefix, 0, net,
+                    {k: nd.array(v) for k, v in arg.items()}, {})
+    # one CPU device a replica: the suite's eight-device emulation changes
+    # XLA:CPU codegen so bucket kernels hash-collide across programs, and
+    # progcache (correctly) refuses executables that are not self-contained
+    pool = ReplicaPool.spawn(
+        prefix, 1,
+        args=["--epoch", "0", "--warmup-shape", "4", "--max-batch-size", "4"],
+        env={"JAX_PLATFORMS": "cpu", "MXNET_PROGCACHE": "1",
+             "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
+             "MXNET_PROGCACHE_DIR": str(tmp_path / "progcache")},
+        probe_interval=0.2, backoff_base=0.1, backoff_cap=1.0,
+        ready_timeout=180).start()
+
+    def compile_counters():
+        cli = serve.ServeClient(*pool.members()[0].addr, timeout=5.0)
+        try:
+            eng = cli.stats()["engine"]
+        finally:
+            cli.close()
+        return int(eng["compiles"]), int(eng["cache_hits"])
+
+    try:
+        compiles, hits = compile_counters()
+        assert compiles - hits == 3  # cold: buckets(4) = [1, 2, 4]
+        pool.kill(0)  # SIGKILL: no graceful cache flush
+        m0 = pool.members()[0]
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline and not (
+                m0.restarts >= 1 and m0.state == "ready"):
+            time.sleep(0.2)
+        assert m0.restarts >= 1 and m0.state == "ready"
+        compiles, hits = compile_counters()
+        assert hits == compiles == 3  # the respawn compiled nothing fresh
+    finally:
+        pool.stop()

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """trace_report.py — terminal breakdown of one or MANY obs traces.
 
-Reads chrome-trace ``trace.json`` files (``mx.obs.export(...)`` /
-``tools/profile_step.py --trace-out`` / ``tools/fleet_report.py``),
+Reads chrome-trace ``trace.json`` files (``mx.obs.export(...)`` or the
+merged timeline that ``tools/fleet_report.py`` writes),
 JSONL event streams (``MXNET_OBS_JSONL=...`` — including the per-replica
 ``replica-<pid>.jsonl`` evidence a SIGKILL'd fleet member leaves behind),
 and/or **flight-recorder bundles** (``obs/blackbox.py`` —
