@@ -2,13 +2,15 @@
 host was under a span named ``args.under`` and under none named
 ``args.outside`` / the traced window, from the run's own ``.xplane.pb``.
 
-The device's idle time is the complement of the union of its ``XLA Ops``
-intervals between its first and its last operation; the host's spans are
-the ``/host:CPU`` events whose name starts with ``args.prefix`` (the
-program's live spans, bridged to the profiler's clock). Beside the number
-the reader prints where all of the idle time went: one line per span name
-with the idle seconds under it, the innermost span winning, and the
-remainder as ``unmarked``.
+The device's idle time is what the union of its ``XLA Ops`` intervals
+leaves of the traced window: the same intervals, cut to the same
+``bench.window`` span, that ``reduce_trace.reduce`` sums and divides by (in
+a trace without that span, the gaps between its first and its last
+operation). The host's spans are the ``/host:CPU`` events whose name starts
+with ``args.prefix`` (the program's live spans, bridged to the profiler's
+clock). Beside the number the reader prints where all of the idle time
+went: one line per span name with the idle seconds under it, the innermost
+span winning, and the remainder as ``unmarked``.
 
 The observations hold the reduced trace, not its path. The runner's
 ``reduce_trace.profile`` leaves the file under
@@ -52,12 +54,11 @@ def load(path, prefix):
     hosts = [p for p in planes if p.name == reduce_trace.HOST_PLANE]
     if not devices or not hosts:
         return None
-    busy = reduce_trace.union(
-        (e.start_ns, e.start_ns + e.duration_ns) for line in devices[0].lines
-        if line.name == reduce_trace.OPS_LINE for e in line.events)
+    window = reduce_trace.window_of(reduce_trace.host_marks(planes))
+    _, busy, _, _ = reduce_trace.busy_in(devices[0], window)
     if not busy:
         return None
-    gaps = [[e0, s1] for (_, e0), (s1, _) in zip(busy, busy[1:])]
+    gaps = reduce_trace.idle_in(busy, window)
     spans = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
                    for plane in hosts for line in plane.lines
                    for e in line.events if e.name.startswith(prefix))

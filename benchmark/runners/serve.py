@@ -221,12 +221,20 @@ def run(run):
             f"tokens received, {len(ttft)} first-token samples, {len(gaps)} "
             f"gap samples")
     metrics = {"serve_tokens_per_s": tokens_in_window / (t1 - t0),
-               "itl_p95_ms": percentile(gaps, 0.95)}
-    observations["ttft_ms"] = ttft
+               "itl_p95_ms": percentile(gaps, 0.95),
+               "itl_p99_ms": percentile(gaps, 0.99)}
+    observations["ttft_ms"], observations["itl_ms"] = ttft, gaps
     run.log("ttft ms p50 %.1f p95 %.1f max %.1f | itl ms p50 %.1f p95 %.1f "
             "max %.1f" % (percentile(ttft, 0.5), percentile(ttft, 0.95),
                           max(ttft), percentile(gaps, 0.5),
                           metrics["itl_p95_ms"], max(gaps)))
+    eighths = np.histogram([t for r in records for t in r["times"]], bins=8,
+                           range=(t0, t1))[0] * 8 / (t1 - t0)
+    run.log("itl ms mean %.3f p90 %.2f p97 %.2f p99 %.2f | tokens/s by "
+            "eighth of the window: %s" % (
+                sum(gaps) / len(gaps), percentile(gaps, 0.9),
+                percentile(gaps, 0.97), metrics["itl_p99_ms"],
+                " ".join("%.0f" % rate for rate in eighths)))
     stalls = sorted((b - t0, b - a) for r in records
                     for a, b in zip(r["times"], r["times"][1:])
                     if t0 <= b < t1 and b - a > 0.5)

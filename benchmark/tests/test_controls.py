@@ -60,13 +60,14 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(
         capsys, monkeypatch):
     from mxnet_tpu.serve import DecodeEngine
 
-    real = DecodeEngine.step
+    real = DecodeEngine.read    # where a call's sampled tokens reach the host
 
-    def altered(self, *args, **kw):
-        return (real(self, *args, **kw) + 1) % self.cfg["vocab"]
+    def altered(self, launched):
+        tokens, counters = real(self, launched)
+        return (tokens + 1) % self.cfg["vocab"], counters
 
     assert last_line(capsys, SERVE_CELL)["correct"] is True
-    monkeypatch.setattr(DecodeEngine, "step", altered)
+    monkeypatch.setattr(DecodeEngine, "read", altered)
     assert last_line(capsys, SERVE_CELL)["correct"] is False
 
 

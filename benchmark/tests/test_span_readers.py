@@ -152,3 +152,26 @@ def test_the_new_metric_files_name_readers_that_take_their_args():
         reader = __import__(f"benchmark.readers.{spec['reader']}",
                             fromlist=["read"])
         assert reader.read({}, spec["args"]) is None    # a run with no spans
+
+
+def test_the_percentile_reader_takes_the_runners_rank_and_none_from_nothing():
+    from benchmark.readers import percentile
+    from benchmark.runners.serve import percentile as runners
+
+    gaps = [7.0] * 93 + [15.5, 15.9, 22.8, 23.1, 23.4, 32.1, 33.0]
+    args = {"observation": "itl_ms", "q": 0.95}
+    assert percentile.read({"itl_ms": gaps}, args) == 15.9 == runners(gaps, 0.95)
+    assert percentile.read({"itl_ms": gaps[::-1]}, dict(args, q=0.99)) == 32.1
+    assert percentile.read({"itl_ms": [4.0]}, args) == 4.0
+    assert percentile.read({"itl_ms": []}, args) is None
+    assert percentile.read({}, args) is None
+
+
+@pytest.mark.parametrize("name", ["ttft_p50_ms", "decode_step_ms", "prefill_ms"])
+def test_a_metric_split_by_what_it_moves_is_read_the_same_way(name):
+    root = os.path.join(os.path.dirname(HERE), "layer_metrics")
+    with open(os.path.join(root, name + ".json")) as f:
+        one = json.load(f)
+    with open(os.path.join(root, name + ".itl99.json")) as f:
+        other = json.load(f)
+    assert (one["reader"], one["args"]) == (other["reader"], other["args"])

@@ -2,8 +2,9 @@
 
 A cell's ``traffic`` block (in ``workloads/<cell>.json``) is data; nothing
 here knows a cell by name. Every seed gives the same *sizes* (shapes, the
-multiset of request lengths) in another order with other token ids, so that
-runs with different seeds do the same amount of work.
+multiset of request lengths; with ``pair_seed``, of prompt and output
+lengths together) in another order with other token ids, so that runs with
+different seeds do the same amount of work.
 """
 from __future__ import annotations
 
@@ -59,11 +60,25 @@ def serve_requests(traffic: dict, model: dict, seed: int) -> list:
     """``requests`` requests, each a dict of ``prompt`` (int32 ids) and
     ``max_new_tokens``; clients take them in order and start over at the
     end. Lengths are lognormal (median, sigma, clip) and prompt + output
-    never passes ``max_length``."""
+    never passes ``max_length``. With ``pair_seed`` every seed gives the
+    same multiset of (prompt length, output length) pairs; without it, the
+    same two multisets of lengths, paired by the seed."""
     rng = _rng(seed, 1)
     n = traffic["requests"]
-    plen = _lognormal_lengths(rng, n, *traffic["prompt_len"])
-    olen = _lognormal_lengths(rng, n, *traffic["output_len"])
+    if "pair_seed" in traffic:
+        # which output length goes with which prompt is work, not order: a
+        # decode step reads every live token's context, so a seed that put
+        # long outputs behind long prompts ran a slower cell (PERF.md, PR
+        # 34). The pairs are drawn once, from the cell's own number, and
+        # the seed gives only their order.
+        pairs = _rng(traffic["pair_seed"], 3)
+        plen = _lognormal_lengths(pairs, n, *traffic["prompt_len"])
+        olen = _lognormal_lengths(pairs, n, *traffic["output_len"])
+        order = rng.permutation(n)
+        plen, olen = plen[order], olen[order]
+    else:
+        plen = _lognormal_lengths(rng, n, *traffic["prompt_len"])
+        olen = _lognormal_lengths(rng, n, *traffic["output_len"])
     olen = np.minimum(olen, model["max_length"] - plen)
     ids = _zipf_ids(rng, model["vocab_size"], traffic["zipf_a"],
                     (int(plen.sum()),))
