@@ -151,6 +151,7 @@ def phase_kernels():
 
     from mxnet_tpu.ops.attention import plain_attention
     from mxnet_tpu.ops.flash_attention import (_decode_attention_xla,
+                                               decode_page_group,
                                                flash_attention,
                                                flash_decode_attention)
 
@@ -183,33 +184,47 @@ def phase_kernels():
         for name, g, gr in zip(("dq", "dk", "dv"), grads, grads_ref):
             _check_close(f"flash bwd {name}", g, gr, 4e-2)
 
-        # paged decode: the serve phase's pool geometry (every layer in the
-        # one pool, K and V side by side), ragged lengths, one inner layer
-        n_seq, max_pages, layers, layer = SLOTS, 2048 // PAGE, 3, 1
-        n_pages = n_seq * max_pages + 1
-        lengths = np.array([0, 1, 15, 16, 17, 1000, 2047, 2048], np.int32)
-        rng = np.random.RandomState(0)
-        table = (rng.permutation(n_pages - 1) + 1).astype(np.int32) \
-            .reshape(n_seq, max_pages)
-        live = lengths > 0   # a length-0 row is an idle slot: garbage out
-        decode = jax.jit(flash_decode_attention, static_argnums=2)
-        decode_ref = jax.jit(
-            lambda q, pool, *a: _decode_attention_xla(
-                q, pool, layer, *a, 1.0 / np.sqrt(d)))
-        for dtype, tol in ((jnp.float32, 1e-4), (jnp.bfloat16, 2e-2)):
+        # paged decode, every layer in the one pool, K and V side by side,
+        # ragged lengths, one inner layer: the serve phase's geometry in
+        # both dtypes, then gpt2m-serve-closed's own (16 slots, a table of
+        # 64 pages of 16, the 24-layer float32 pool)
+        ragged = [0, 1, 15, 16, 17, 1000, 2047, 2048]
+        cell = [0, 1, 15, 16, 17, 127, 128, 129, 248, 255, 256, 500, 768,
+                1000, 1023, 1024]
+        for n_seq, max_pages, layers, heads, lengths, dtype, tol in (
+                (SLOTS, 2048 // PAGE, 3, h, ragged, jnp.float32, 1e-4),
+                # 16 heads: the kernel copies a 16-bit pool's pages out
+                # only where the heads fill whole packed tiles
+                (SLOTS, 2048 // PAGE, 3, 16, ragged, jnp.bfloat16, 2e-2),
+                (16, 1024 // PAGE, 24, 16, cell, jnp.float32, 1e-4)):
+            layer = layers // 2
+            n_pages = n_seq * max_pages + 1
+            lengths = np.array(lengths, np.int32)
+            rng = np.random.RandomState(0)
+            table = (rng.permutation(n_pages - 1) + 1).astype(np.int32) \
+                .reshape(n_seq, max_pages)
+            live = lengths > 0   # a length-0 row is an idle slot: garbage out
+            decode = jax.jit(flash_decode_attention, static_argnums=2)
+            decode_ref = jax.jit(
+                lambda q, pool, *a: _decode_attention_xla(
+                    q, pool, layer, *a, 1.0 / np.sqrt(d)))
             kq, kp = jax.random.split(jax.random.PRNGKey(1), 2)
-            qd = jax.random.normal(kq, (n_seq, h, d), dtype)
-            pool = jax.random.normal(kp, (n_pages, layers, PAGE, h, 2 * d),
-                                     dtype)
+            qd = jax.random.normal(kq, (n_seq, heads, d), dtype)
+            pool = jax.random.normal(
+                kp, (n_pages, layers, PAGE, heads, 2 * d), dtype)
+            group = decode_page_group(pool.shape, max_pages)
+            _require(group > 1, f"one page a grid step: page group {group}")
             _require_mosaic(decode, qd, pool, layer, table, lengths)
             got = np.asarray(decode(qd, pool, layer, table, lengths),
                              np.float32)
             want = np.asarray(decode_ref(qd, pool, table, lengths),
                               np.float32)
             _require(np.all(np.isfinite(got)), "decode kernel: non-finite")
-            _check_close(f"paged decode (8,12,64) x {max_pages} pages "
+            _check_close(f"paged decode ({n_seq},{heads},64) x {max_pages} "
+                         f"pages in groups of {group}, {layers}-layer pool "
                          f"{jnp.dtype(dtype).name}", got[live], want[live],
                          tol)
+            del pool
 
 
 # ---------------------------------------------------------------------------
@@ -386,7 +401,7 @@ def phase_serve():
             lm, slots=SLOTS, page_size=PAGE,
             num_pages=SLOTS * max_len // PAGE + 1, prompt_buckets=BUCKETS)
         engine.warmup()
-        attn = decode_attention_impl()
+        attn = decode_attention_impl(engine.kv)
         print(f"   decode_attn: {attn}", flush=True)
 
         rng = np.random.RandomState(2)
@@ -444,6 +459,13 @@ def phase_serve():
                  f"the step program allocates {program['temp_bytes']} bytes "
                  f"of temporaries, one layer's K of the pool ({k_slice}) or "
                  "more: it slices, copies or lays the pool out again")
+        # ... in groups of pages: what one decode step costs in grid steps
+        paged = stats["paged_kernel"]
+        print(f"   paged kernel: {paged}", flush=True)
+        _require((attn == "pallas") == (paged is not None)
+                 and (paged is None or paged["page_group"] > 1),
+                 f"decode_attn says {attn} but stats()['paged_kernel'] is "
+                 f"{paged}")
     return attn
 
 

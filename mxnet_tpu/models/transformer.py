@@ -497,6 +497,20 @@ class TransformerDecodeModel:
 
         return decode_attention(query, pool, layer, page_table, lengths)
 
+    def paged_kernel(self, pool, slots, max_pages):
+        """``DecodeEngine.stats()["paged_kernel"]``: the pages the paged
+        kernel reads a grid step over this pool and a page table this wide,
+        and the grid steps of one decode step (a call a layer); None where
+        ``attention`` gathers through XLA instead."""
+        from ..ops.flash_attention import (decode_attention_impl,
+                                           decode_page_group)
+
+        if decode_attention_impl(pool) != "pallas":
+            return None
+        group = decode_page_group(pool.shape, max_pages)
+        return {"page_group": group,
+                "grid_steps": slots * -(-max_pages // group) * self.layers}
+
 
 def lm_decode_step(cfg, params, tokens, kv, positions):
     """One decode step over dense KV (the paged engine's reference).

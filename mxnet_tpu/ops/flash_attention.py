@@ -48,7 +48,7 @@ from jax import lax
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "decode_attention", "decode_attention_impl",
            "flash_decode_attention", "latent_decode_attention",
-           "flash_latent_decode_attention"]
+           "flash_latent_decode_attention", "decode_page_group"]
 
 _NEG_INF = -1e30  # avoids -inf NaN propagation inside the kernel
 _LOG2E = math.log2(math.e)
@@ -527,10 +527,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 # K row and V row for one head side by side on the minor axis — and both
 # paths below take it whole, with the layer as a static index: nothing
 # between a layer's KV write and its attention slices, copies or lays out
-# again any part of the pool. The Pallas kernel never gathers:
-# scalar-prefetched page tables drive the BlockSpec index_map, so grid
-# step (b, j) streams block ``(table[b, j], layer)`` — that page's K and V
-# in one contiguous DMA — straight from the pool: attention IS the gather.
+# again any part of the pool. The Pallas kernel never gathers: the pool
+# stays in HBM, and grid step (b, j) copies the blocks
+# ``(table[b, j*G + g], layer)`` of its group's live pages — a page's K
+# and V in one contiguous DMA each, addressed through the scalar-prefetched
+# page table — straight from the pool: attention IS the gather.
 # Off-TPU (and for the reference/parity tests) the XLA path gathers the
 # page table's pages of that layer, and only those, instead.
 # ---------------------------------------------------------------------------
@@ -560,29 +561,100 @@ def _decode_attention_xla(q, pool, layer, page_table, lengths, scale):
                       precision=prec).astype(q.dtype)
 
 
+# What a group of pages may take in VMEM as the kernel works on it, in
+# float32: a megabyte spreads a grid step's fixed cost (the step itself, one
+# update of the softmax statistics) over eight 128 KB pages, and the two
+# buffers the pages are copied into plus the products' temporaries stay a
+# fraction of the 16 MB a kernel may hold.
+_DECODE_GROUP_BYTES = 1024 * 1024
+
+
+def decode_page_group(pool_shape, max_pages):
+    """G, the pages :func:`flash_decode_attention` reads a grid step: the
+    largest group whose ``(page, H, 2D)`` blocks fit ``_DECODE_GROUP_BYTES``
+    as the kernel computes on them (float32, whatever the pool holds; the
+    heads padded to 8 sublanes, the row to 128 lanes), at least 1 and at
+    most ``max_pages``. A function of the pool's shape and the page
+    table's width alone; no caller sets it."""
+    page, h, row = pool_shape[2:]
+    block = page * -(-h // 8) * 8 * -(-row // 128) * 128 * 4
+    return int(max(1, min(_DECODE_GROUP_BYTES // block, max_pages)))
+
+
 def flash_decode_attention(q, pool, layer, page_table, lengths, scale=None,
                            interpret=False):
     """Pallas paged decode attention. Shapes as ``decode_attention``.
 
-    Grid (B, max_pages): the page axis is innermost-sequential, so the
-    per-sequence online-softmax statistics (log2 domain, f32) live in VMEM
-    scratch across page steps; ``pl.when`` skips pages past the
-    sequence's length, and the last step normalizes. The pool is the
-    kernel's operand as it lies: the block of grid step (b, j) is
-    ``pool[table[b, j], layer]``, the layer axis squeezed."""
+    Grid ``(B, ceil(max_pages / G))``, G from :func:`decode_page_group`:
+    grid step (b, j) works on the G pages ``table[b, j*G : (j+1)*G]`` of
+    layer ``layer`` and takes the online-softmax statistics (log2 domain,
+    f32, in VMEM scratch across a sequence's steps) ONCE over their
+    ``G * page`` rows: one max, one rescale of the output block. Both axes
+    run in order, the group axis innermost; ``pl.when`` skips a group
+    wholly past the sequence's length, rows past it inside a live group are
+    masked, and the last step normalizes.
+
+    The pool is the kernel's operand as it lies, left in HBM: the kernel
+    copies ``pool[table[b, j*G + g], layer]`` — a page's K and V, one
+    contiguous DMA — for the LIVE pages of a group only, into one of two
+    VMEM buffers, and a live group starts the copies of the next live
+    group (this sequence's next, or the first of the next sequence that
+    holds anything) before it waits for its own: the copies run under the
+    arithmetic, across sequences too, and a page past a sequence's length
+    is neither fetched nor looked up in the table (a table whose width G
+    does not divide needs no padding).
+
+    ``layer`` reaches the kernel as a prefetched scalar, so the calls of a
+    step program's layers are ONE traced and lowered kernel (a program of
+    24 layers would otherwise spend seconds of every start lowering 24
+    copies that differ in one constant)."""
+    scale = float(scale) if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    return _paged_decode(
+        q, pool, jnp.full((1,), int(layer), jnp.int32),
+        page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+        decode_page_group(pool.shape, page_table.shape[1]), scale, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _paged_decode(q, pool, layer, page_table, lengths, group, scale,
+                  interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
     page = pool.shape[2]
-    layer = int(layer)
     max_pages = page_table.shape[1]
-    scale = float(scale) if scale is not None else 1.0 / math.sqrt(d)
+    n_groups = -(-max_pages // group)
+    rows = group * page
     s2_scale = scale * _LOG2E
 
-    def kernel(pt_ref, len_ref, q_ref, kv_ref, o_ref, m_ref, l_ref):
+    def kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref, buf, sem,
+               m_ref, l_ref, turn):
+        # turn[0]: the buffer the next live group's pages are copied into;
+        # turn[1]: 1 once a group has started copies (every live group
+        # after the first finds its own already on their way)
         seq = pl.program_id(0)
         j = pl.program_id(1)
+
+        def visible(sq):
+            return jnp.minimum(len_ref[sq], max_pages * page)
+
+        def copies(sq, grp, slot, act):
+            n_live = (visible(sq) + page - 1) // page
+            for g in range(group):
+                @pl.when(grp * group + g < n_live)
+                def _copy(g=g):
+                    act(pltpu.make_async_copy(
+                        pool_ref.at[pt_ref[sq, grp * group + g], layer_ref[0]],
+                        buf.at[slot, g], sem.at[slot]))
+
+        @pl.when((seq == 0) & (j == 0))
+        def _first():
+            turn[0] = 0
+            turn[1] = 0
+            # a live group's dead pages are read too (weight 0): what no
+            # copy ever landed on must be finite
+            buf[...] = jnp.zeros_like(buf)
 
         @pl.when(j == 0)
         def _init():
@@ -590,75 +662,114 @@ def flash_decode_attention(q, pool, layer, page_table, lengths, scale=None,
             l_ref[...] = jnp.zeros_like(l_ref)
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        length = len_ref[seq]
-        n_live = (length + page - 1) // page
+        length = visible(seq)
 
-        @pl.when(j < n_live)
-        def _block():
+        @pl.when(j * rows < length)
+        def _group():
+            slot = turn[0]
+
+            @pl.when(turn[1] == 0)
+            def _cold():
+                copies(seq, j, slot, lambda dma: dma.start())
+                turn[1] = 1
+
+            more = (j + 1) * rows < length
+            nxt = lax.cond(
+                more, lambda: seq,
+                lambda: lax.while_loop(
+                    lambda sq: (sq < b) & (len_ref[jnp.minimum(sq, b - 1)]
+                                           <= 0),
+                    lambda sq: sq + 1, seq + 1))
+
+            @pl.when(nxt < b)
+            def _prefetch():
+                copies(jnp.minimum(nxt, b - 1), jnp.where(more, j + 1, 0),
+                       1 - slot, lambda dma: dma.start())
+
+            copies(seq, j, slot, lambda dma: dma.wait())
+            turn[0] = 1 - slot
             # One query row per head is a mat-vec: no MXU shape fits it
-            # (Mosaic has no batched dot without an M dimension), and the
-            # step is bound by streaming the page, not by arithmetic. So
-            # the products run on the VPU in f32, in the pool's own
+            # (Mosaic has no batched dot without an M dimension). So the
+            # products run on the VPU in f32, in the pool's own
             # (page, H, 2D) layout — heads on sublanes, K then V on lanes,
             # per-head statistics as (H, 1) columns — with no transpose or
-            # relayout of the page.
+            # relayout of a page; the group's pages follow one another on
+            # the leading axis.
             qv = q_ref[0].astype(jnp.float32)              # (H, D)
-            blk = kv_ref[0].astype(jnp.float32)            # (page, H, 2D)
-            kb = blk[..., :d]                              # (page, H, D)
-            sc = jnp.sum(kb * qv[None], axis=-1,           # (page, H, 1),
+            blk = buf[slot].astype(jnp.float32).reshape(rows, h, 2 * d)
+            kb = blk[..., :d]                              # (rows, H, D)
+            sc = jnp.sum(kb * qv[None], axis=-1,           # (rows, H, 1),
                          keepdims=True) * s2_scale         # log2 domain
-            pos = j * page + lax.broadcasted_iota(jnp.int32, (page, h, 1), 0)
+            pos = j * rows + lax.broadcasted_iota(jnp.int32, (rows, h, 1), 0)
             sc = jnp.where(pos < length, sc, _NEG_INF)
             m_prev = m_ref[:, 0:1]                         # (H, 1)
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
             alpha = jnp.exp2(m_prev - m_new)
-            p = jnp.exp2(sc - m_new[None])
-            p = jnp.where(sc <= _NEG_INF / 2, 0.0, p)      # (page, H, 1)
+            # the group holds a live row, so m_new is finite and a masked
+            # row's exp2 is 0
+            p = jnp.exp2(sc - m_new[None])                 # (rows, H, 1)
             l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=0)
             # p weighs the whole row; the V half of the (H, 2D) sum is the
-            # update, so the one lane shift is of the sum, not of the page
+            # update, so the one lane shift is of the sum, not of the pages
             pv = jnp.sum(p * blk, axis=0)[:, d:]           # (H, D)
             o_ref[0] = o_ref[0] * alpha + pv
             m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
             l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-        @pl.when(j == max_pages - 1)
+        @pl.when(j == n_groups - 1)
         def _norm():
             # length-0 rows (inactive slots) never accumulate: clamp keeps
             # their garbage finite instead of 0/0
             o_ref[0] = o_ref[0] / jnp.maximum(l_ref[:, 0:1], 1e-30)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, max_pages),
+        num_scalar_prefetch=3,
+        grid=(b, n_groups),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda sq, j, pt, ln: (sq, 0, 0)),
-            pl.BlockSpec((1, None, page, h, 2 * d),
-                         lambda sq, j, pt, ln: (pt[sq, j], layer, 0, 0, 0)),
+            pl.BlockSpec((1, h, d), lambda sq, j, pt, ln, ly: (sq, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # the pool, where it lies
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda sq, j, pt, ln: (sq, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, d), lambda sq, j, pt, ln, ly: (sq, 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM((2, group, page, h, 2 * d), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((h, 128), jnp.float32),   # running max (log2)
             pltpu.VMEM((h, 128), jnp.float32),   # running denominator
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        # a group hands its buffer turn and its copies on to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
+        name="paged_decode",
+    )(page_table, lengths, layer, q, pool)
     return out.astype(q.dtype)
 
 
-def decode_attention_impl() -> str:
+def decode_attention_impl(pool=None) -> str:
     """The path :func:`decode_attention` takes in this process: ``pallas``
     or ``xla``. ``MXNET_DECODE_ATTN`` names one outright; ``auto`` (the
     default) is the Pallas kernel on a TPU backend and the XLA gather
-    everywhere else."""
+    everywhere else — also, where a per-head ``pool`` (its ``shape`` and
+    ``dtype``) is given, for one whose pages the kernel cannot copy out of
+    it: Mosaic slices an HBM array in whole tiles only, which a ``(H, 2D)``
+    row fills if 2D is a multiple of 128 lanes and, for a 16-bit pool, H a
+    multiple of 8 (a float32 pool: any H)."""
     impl = os.environ.get("MXNET_DECODE_ATTN", "auto")
     if impl == "auto":
-        return "xla" if _use_interpret() else "pallas"
+        if _use_interpret():
+            return "xla"
+        if pool is not None:
+            itemsize = jnp.dtype(pool.dtype).itemsize
+            whole = pool.shape[4] % 128 == 0 and (
+                itemsize == 4 or (itemsize == 2 and pool.shape[3] % 8 == 0))
+            return "pallas" if whole else "xla"
+        return "pallas"
     if impl not in ("pallas", "xla"):
         raise ValueError(
             f"MXNET_DECODE_ATTN={impl!r}: expected auto, pallas or xla")
@@ -675,11 +786,14 @@ def decode_attention(q, pool, layer, page_table, lengths, scale=None):
     address ``(page, layer)`` themselves); page_table (B, max_pages) int32
     — page ids in position order (pad unused slots with any valid page,
     e.g. scratch page 0); lengths (B,) int32 — positions visible per
-    sequence (0 = inactive row, output garbage). Returns (B, H, D).
-    :func:`decode_attention_impl` picks the path.
+    sequence (0 = inactive row, output garbage; capped at the table's
+    ``max_pages * page_size``). Returns (B, H, D).
+    :func:`decode_attention_impl` picks the path; the Pallas one reads the
+    table's pages in groups of G a grid step, G found from the pool's
+    shape (:func:`decode_page_group`), never set by a caller.
     """
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if decode_attention_impl() == "pallas":
+    if decode_attention_impl(pool) == "pallas":
         return flash_decode_attention(q, pool, layer, page_table, lengths,
                                       scale=scale,
                                       interpret=_use_interpret())

@@ -571,6 +571,13 @@ class DecodeEngine:
         # say that no program slices, copies or lays the pool out again
         out["step_program"] = step and {
             k: step.get(k, 0) for k in ("temp_bytes", "bytes_accessed")}
+        # what that step's paged reads cost in grid steps, from the shapes
+        # its kernel sees: the model's to say (None for one with no
+        # per-head pool, or whose step gathers through XLA)
+        describe = getattr(self.model, "paged_kernel", None)
+        out["paged_kernel"] = describe(
+            self.kv, self.slots, self.max_pages) if (
+                step and describe) else None
         out["pool"] = self.pool.stats()
         if self._progcache is not None:
             out["progcache"] = dict(self._progcache.stats,

@@ -835,20 +835,148 @@ def test_decode_attention_parity(impl, layer):
     np.testing.assert_allclose(out[live], want[live], rtol=2e-5, atol=2e-5)
 
 
+def _set_page_group(monkeypatch, pool, max_pages, group):
+    """Make the kernel read ``group`` pages a grid step over this tiny pool
+    by shrinking the one thing G follows besides the shapes, the VMEM
+    budget: a page's block is whole (8, 128) float32 tiles."""
+    from mxnet_tpu.ops import flash_attention
+
+    page, heads, row = pool.shape[2:]
+    block = page * -(-heads // 8) * 8 * -(-row // 128) * 128 * 4
+    monkeypatch.setattr(flash_attention, "_DECODE_GROUP_BYTES",
+                        block * group)
+    assert flash_attention.decode_page_group(pool.shape,
+                                             max_pages) == group
+
+
+@pytest.mark.parametrize("group", [4, 2, 3])
 @pytest.mark.parametrize("impl", ["xla", "dispatch", "pallas"])
-def test_decode_attention_reads_only_its_layer(impl):
+def test_decode_attention_reads_only_its_layer(impl, group, monkeypatch):
     """Rewriting every OTHER layer's pages (and the dead tail of the pages
-    a row holds) changes no bit of layer 1's attention: the paths address
-    ``(page, layer)``, not a copy or a neighbour of it."""
+    a row holds, which with pages read in groups of 2, 3 or all 4 is also
+    the dead tail of a group) changes no bit of layer 1's attention: the
+    paths address ``(page, layer)``, not a copy or a neighbour of it."""
     q, pool, table, lengths = _paged_case()
+    _set_page_group(monkeypatch, pool, table.shape[1], group)
+    lengths[1] = 8            # row 1 lists pages 3-6 and holds 3, 4 only
     fn = _paged_impl(impl)
     before = np.asarray(fn(q, pool, 1, table, lengths))
     other = pool.copy()
     other[:, [0, 2]] = -other[:, [0, 2]] + 3.0
     other[2, 1, 1:] = 7.0     # row 0 holds 5 positions: page 2 from slot 1
+    other[[5, 6], 1] = 9.0    # whole dead pages inside row 1's group
+    other[SCRATCH_PAGE, 1] = -5.0   # what pads every table
     after = np.asarray(fn(q, other, 1, table, lengths))
     live = lengths > 0
     np.testing.assert_array_equal(before[live], after[live])
+
+
+def _grouped_case(max_pages, group, page=4):
+    """Every length a group boundary can meet — 0, 1, a page less one, a
+    page, a group less one, a group, a group and one, the full table —
+    over a shared three-layer pool; tables in position order, padded with
+    the scratch page, one row reusing a page another row holds."""
+    rng = np.random.RandomState(7)
+    heads, dim = 2, 8
+    full = max_pages * page
+    lengths = np.minimum(
+        [0, 1, page - 1, page, group * page - 1, group * page,
+         group * page + 1, full], full).astype(np.int32)
+    n_pages = 1 + len(lengths) * max_pages
+    q = rng.randn(len(lengths), heads, dim).astype(np.float32)
+    pool = rng.randn(n_pages, 3, page, heads, 2 * dim).astype(np.float32)
+    table = np.full((len(lengths), max_pages), SCRATCH_PAGE, np.int32)
+    ids = iter(rng.permutation(n_pages - 1) + 1)
+    for i, ln in enumerate(lengths):
+        n = -(-int(ln) // page)
+        table[i, :n] = [next(ids) for _ in range(n)]
+    table[3, 0] = table[7, 0]     # shared with the full row
+    return q, pool, table, lengths
+
+
+@pytest.mark.parametrize("max_pages,group", [
+    (4, 1), (4, 2), (6, 3), (8, 4),      # G divides the table's width
+    (5, 2), (7, 3), (9, 4), (5, 4),      # it does not: a short last group
+    (3, 3), (8, 8)])                     # one group: G = max_pages
+def test_decode_attention_page_groups(max_pages, group, monkeypatch):
+    """The Pallas kernel with G pages a grid step agrees with numpy and
+    with the XLA gather at every length a group boundary can meet."""
+    from mxnet_tpu.ops.flash_attention import _decode_attention_xla
+
+    q, pool, table, lengths = _grouped_case(max_pages, group)
+    _set_page_group(monkeypatch, pool, max_pages, group)
+    live = lengths > 0
+    for layer in (0, 2):
+        out = np.asarray(_paged_impl("pallas")(q, pool, layer, table,
+                                               lengths))
+        assert out.shape == q.shape and np.all(np.isfinite(out))
+        want = _paged_reference(q, pool, layer, table, lengths)
+        np.testing.assert_allclose(out[live], want[live], rtol=2e-5,
+                                   atol=2e-5)
+        xla = np.asarray(_decode_attention_xla(
+            q, pool, layer, table, lengths, 1.0 / math.sqrt(q.shape[-1])))
+        np.testing.assert_allclose(out[live], xla[live], rtol=2e-5,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("pool_shape,max_pages,group", [
+    ((1025, 24, 16, 16, 128), 64, 8),      # gpt2m-serve-closed
+    ((1025, 24, 16, 16, 128), 5, 5),       # a table narrower than that
+    ((1025, 2, 16, 12, 128), 128, 8),      # 12 heads pad to 16 sublanes
+    ((1025, 2, 16, 8, 128), 128, 16),
+    ((1025, 2, 16, 8, 64), 128, 16),       # 64 lanes pad to 128
+    ((9, 3, 4, 2, 16), 4, 4),              # tiny: the whole table
+    ((4, 1, 128, 16, 128), 3, 1),          # a 1 MiB page: alone
+    ((4, 1, 256, 16, 128), 3, 1),          # larger than the budget: still 1
+    ((4, 1, 64, 16, 128), 3, 2)])
+def test_decode_page_group_follows_the_shapes(pool_shape, max_pages, group):
+    """G is the largest group whose pages fit 1 MiB of VMEM as float32
+    tiles, at least 1, at most the page table's width."""
+    from mxnet_tpu.ops.flash_attention import decode_page_group
+
+    assert decode_page_group(pool_shape, max_pages) == group
+
+
+@pytest.mark.parametrize("shape,dtype,env,interpret,path", [
+    ((1025, 24, 16, 16, 128), "float32", "auto", False, "pallas"),
+    ((1025, 2, 16, 12, 128), "float32", "auto", False, "pallas"),
+    ((1025, 2, 16, 25, 128), "float32", "auto", False, "pallas"),
+    ((1025, 2, 16, 16, 128), "bfloat16", "auto", False, "pallas"),
+    ((1025, 2, 16, 12, 128), "bfloat16", "auto", False, "xla"),   # H % 8
+    ((1025, 2, 16, 16, 64), "float32", "auto", False, "xla"),     # 64 lanes
+    ((1025, 2, 16, 16, 128), "int8", "auto", False, "xla"),
+    ((1025, 2, 16, 16, 128), "float32", "auto", True, "xla"),     # off-TPU
+    ((1025, 2, 16, 16, 64), "float32", "pallas", True, "pallas"),  # named
+    ((1025, 24, 16, 16, 128), "float32", "xla", False, "xla")])
+def test_auto_takes_the_kernel_only_where_it_can_copy_whole_pages(
+        shape, dtype, env, interpret, path, monkeypatch):
+    """``auto`` on a TPU is the Pallas kernel for a pool whose ``(H, 2D)``
+    rows fill whole tiles (what Mosaic can slice out of HBM for the
+    kernel's own copies) and the XLA gather for any other; a path named
+    outright is taken as named."""
+    import jax
+
+    from mxnet_tpu.ops import flash_attention
+
+    monkeypatch.setenv("MXNET_DECODE_ATTN", env)
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: interpret)
+    pool = jax.ShapeDtypeStruct(shape, dtype)
+    assert flash_attention.decode_attention_impl(pool) == path
+
+
+def test_decode_attention_one_page_a_grid_step_at_the_real_budget():
+    """A geometry whose page fills the budget alone (1 MiB, float32) runs
+    with G = 1: a page a grid step, as before pages came in groups."""
+    rng = np.random.RandomState(11)
+    page, heads, dim = 128, 16, 64
+    q = rng.randn(3, heads, dim).astype(np.float32)
+    pool = rng.randn(4, 1, page, heads, 2 * dim).astype(np.float32)
+    table = np.array([[1, 2, 3], [3, SCRATCH_PAGE, SCRATCH_PAGE],
+                      [SCRATCH_PAGE] * 3], np.int32)
+    lengths = np.array([3 * page, 5, 0], np.int32)
+    out = np.asarray(_paged_impl("pallas")(q, pool, 0, table, lengths))
+    want = _paged_reference(q, pool, 0, table, lengths)
+    np.testing.assert_allclose(out[:2], want[:2], rtol=2e-5, atol=2e-5)
 
 
 def _engine_with_random_pool(lm):
@@ -932,6 +1060,34 @@ def test_engine_greedy_matches_dense_reference(stack, lm):
         ref.append(nxt)
         toks.append(nxt)
     assert got == ref
+
+
+@pytest.mark.parametrize("group", [1, 3, 8])
+def test_engine_through_the_grouped_kernel_serves_the_dense_tokens(
+        lm, group, monkeypatch):
+    """The engine's step through the Pallas kernel (interpreted here) with
+    1, 3 (which does not divide the 8-page table) or all 8 pages a grid
+    step serves the dense reference's greedy tokens, for contexts that end
+    inside a group's first page and that run across groups; the counter
+    names the group and the step's grid steps."""
+    monkeypatch.setenv("MXNET_DECODE_ATTN", "pallas")
+    eng = DecodeEngine(lm, slots=2, page_size=8, num_pages=17,
+                       prompt_buckets=[8, 32])
+    _set_page_group(monkeypatch, eng.kv, eng.max_pages, group)
+    sched = DecodeScheduler(eng, max_new_tokens=4)
+    cfg, params = decode_config(lm), decode_params(lm)
+    for prompt in ([5, 4, 3], list(range(1, 24))):
+        got = list(sched.generate(np.asarray(prompt, np.int32),
+                                  max_new_tokens=4))
+        toks = list(prompt)
+        for _ in range(4):
+            logits, _k, _v = lm_prefill(
+                cfg, params, np.asarray([toks], np.int32))
+            toks.append(int(np.argmax(np.asarray(logits[0, len(toks) - 1]))))
+        assert got == toks[len(prompt):]
+    assert eng.stats()["paged_kernel"] == {
+        "page_group": group, "grid_steps": 2 * -(-8 // group) * 2}
+    eng.pool.assert_baseline()
 
 
 def test_concurrent_streams_bitwise_equal_sequential(stack):

@@ -59,8 +59,7 @@ def one_chip():
         compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def engine():
+def _engine(num_pages=NUM_PAGES):
     u, v = CFG["units"], CFG["vocab"]
     layer = {"qkv_w": (3 * u, u), "qkv_b": (3 * u,), "proj_w": (u, u),
              "proj_b": (u,), "ln1_g": (u,), "ln1_b": (u,),
@@ -74,7 +73,12 @@ def engine():
                          for k, s in layer.items()}
                         for _ in range(CFG["layers"])]
     return DecodeEngine(CFG, params=params, slots=SLOTS, page_size=PAGE,
-                        num_pages=NUM_PAGES, prompt_buckets=[BUCKET])
+                        num_pages=num_pages, prompt_buckets=[BUCKET])
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return _engine()
 
 
 def _tpu_program(engine, one_chip, kind, monkeypatch):
@@ -149,7 +153,9 @@ def test_tpu_step_program_reads_the_pool_where_it_lies(
     are the in-place scatters (one per layer), the Mosaic calls (one per
     layer), the parameter and the result."""
     lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
-    assert lowered.as_text().count("tpu_custom_call") == CFG["layers"]
+    # the layers' calls share ONE traced and lowered kernel (the layer is a
+    # prefetched scalar); the compiled program calls it once a layer (below)
+    assert lowered.as_text().count("tpu_custom_call") == 1
     # one upload a step: positions, lengths, temperatures, page tables and
     # the seed come up as ONE int32 array; the tokens never leave the device
     (packed,) = _host_arguments(engine, lowered)
@@ -208,6 +214,55 @@ def test_step_program_stats_on_this_backend(engine):
     assert 0 < program["temp_bytes"] < _k_slice_bytes(engine)
     assert program["bytes_accessed"] > 0
     engine.pool.assert_baseline()
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_paged_kernel_stats_say_what_a_step_costs_in_grid_steps(
+        impl, monkeypatch):
+    """``stats()["paged_kernel"]``: the pages the paged kernel reads a grid
+    step and the grid steps of one decode step, set when the step is
+    built — here 8 pages (the whole table of a 128-position model: G is at
+    most ``max_pages``), so slots x 1 group x layers; None until then, and
+    for a step that gathers through XLA. ``step_program`` keeps its keys."""
+    monkeypatch.setenv("MXNET_DECODE_ATTN", impl)
+    small = _engine(num_pages=17)
+    assert small.max_pages == 8
+    assert small.stats()["paged_kernel"] is None
+    small.warmup()
+    stats = small.stats()
+    assert set(stats["step_program"]) == {"temp_bytes", "bytes_accessed"}
+    assert stats["paged_kernel"] == (
+        {"page_group": 8, "grid_steps": SLOTS * 1 * CFG["layers"]}
+        if impl == "pallas" else None)
+
+
+def test_tpu_paged_kernel_reads_a_group_of_pages_at_the_cell_geometry(
+        one_chip, monkeypatch):
+    """The paged kernel alone at ``gpt2m-serve-closed``'s own geometry —
+    16 slots, a table of 64 pages of 16, the 24-layer float32 pool of 1025
+    pages (3.2 GB: shapes only here) — goes through Mosaic with 8 pages
+    (1 MiB) a grid step: 16 x 8 x 24 = 3,072 grid steps a decode step,
+    where one page a step made 24,576; and it makes no temporary."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    slots, max_pages, layers, heads, dim = 16, 64, 24, 16, 64
+    pool_shape = (1025, layers, PAGE, heads, 2 * dim)
+    group = flash_attention.decode_page_group(pool_shape, max_pages)
+    assert group == 8
+    assert slots * -(-max_pages // group) * layers == 3072
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(flash_attention.decode_attention,
+                      static_argnums=2).lower(
+        spec((slots, heads, dim)), spec(pool_shape), layers - 1,
+        spec((slots, max_pages), jnp.int32), spec((slots,), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    assert obs.device.analyze_compiled(compiled)["temp_bytes"] == 0
 
 
 # -- the latent pool: one bfloat16 row a position, no head axis ----------------
