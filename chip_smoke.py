@@ -541,9 +541,131 @@ def phase_latent():
                  "it slices, copies or lays the pool out again")
 
 
+# published widths of the gated-delta model's caches: 2 cached heads x 256 in a
+# flat bfloat16 row, pages of 256; 32 value heads x 128 x 128 float32 of state
+GDN = {"vocab_size": 4096, "hidden_size": 1024, "num_layers": 4,
+       "full_interval": 2, "num_heads": 16, "num_kv_heads": 2, "head_dim": 256,
+       "rotary_dim": 64, "rope_theta": 10000000, "linear_key_heads": 16,
+       "linear_value_heads": 32, "linear_key_dim": 128,
+       "linear_value_dim": 128, "conv_width": 4, "expert_width": 512,
+       "router_experts": 32, "experts_first": 8, "experts_held": 8,
+       "experts_per_token": 8, "rms_eps": 1e-6, "max_length": 2048}
+
+
+def phase_state():
+    """State beside pages: the gated-delta model through the engine's two
+    programs, and its three kernels against XLA over what the engine wrote."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import gdn_moe
+    from mxnet_tpu.ops import gated_delta, gqa_attention
+    from mxnet_tpu.serve import DecodeEngine
+    from mxnet_tpu.serve.kvcache import SCRATCH_PAGE
+
+    with _Phase("state"):
+        slots, page = 4, 256
+        model = gdn_moe.GDNMoEDecodeModel(GDN, seed=3)
+        # a pool of 538 MB: against the step's other temporaries (a layer's
+        # slices of the stacked weights, 65 MB here) one layer's share of it
+        # is what a copy of pool or state would stand out from
+        engine = DecodeEngine(model, slots=slots, page_size=page,
+                              num_pages=513, prompt_buckets=[512])
+        engine.warmup()
+        _require(_step_text(engine).count(_MOSAIC) == 2,
+                 "the step has not one gdn_decode and one gqa_decode kernel")
+        rng = np.random.RandomState(4)
+        prompt = rng.randint(0, GDN["vocab_size"], 300)
+        engine.pool.alloc(0, 2)
+        table = engine.pool.table(0)
+        tok = engine.prefill(prompt, table, slot=1)
+        _require(engine.last_counters["moe.dropped"] == 0
+                 and engine.last_counters["moe.assignments"] == 4 * 300 * 8,
+                 f"prefill counted {engine.last_counters}")
+        tables = np.full((slots, engine.max_pages), SCRATCH_PAGE, np.int32)
+        tables[1, :len(table)] = table
+        z = np.zeros((slots,), np.int32)
+        idle = np.asarray(engine.state["s"][0])
+        for i in range(4):
+            pos, lengths, toks = z.copy(), z.copy(), z.copy()
+            pos[1], lengths[1], toks[1] = 300 + i, 301 + i, tok
+            tok = int(engine.step(toks, pos, tables, lengths,
+                                  np.zeros((slots,), np.float32))[1])
+            _require(engine.last_counters["moe.dropped"] == 0
+                     and engine.last_counters["moe.assignments"] == 4 * 8,
+                     f"step counted {engine.last_counters}")
+        _require(np.array_equal(idle, np.asarray(engine.state["s"][0])),
+                 "a step touched the state of an idle slot")
+        # the paged kernel against the gather, over the rows just written
+        q = jax.random.normal(jax.random.PRNGKey(5), (slots, 2, 8, 256),
+                              jnp.bfloat16)
+        args = (q, engine.kv, 1, jnp.asarray(tables),
+                jnp.asarray([0, 304, 0, 0], jnp.int32), 256 ** -0.5)
+        kernel = jax.jit(gqa_attention.flash_gqa_decode_attention,
+                         static_argnums=(2, 5))
+        _require_mosaic(kernel, *args)
+        got = np.asarray(kernel(*args), np.float32)[1]
+        want = np.asarray(jax.jit(gqa_attention._gqa_decode_xla,
+                                  static_argnums=(2, 5))(*args),
+                          np.float32)[1]
+        _check_close("grouped-KV paged decode (2 x 8 x 256, bfloat16) over "
+                     "304 positions", got, want, 3e-2)
+        # the flash forward against itself token by token is the engine's own
+        # check; here the kernel against dense attention at 512 positions
+        qp = jax.random.normal(jax.random.PRNGKey(6), (2, 8, 512, 256),
+                               jnp.bfloat16)
+        kp, vp = (jax.random.normal(jax.random.PRNGKey(k), (2, 512, 256),
+                                    jnp.bfloat16) for k in (7, 8))
+        flash = jax.jit(gqa_attention.gqa_flash_attention)
+        _require_mosaic(flash, qp, kp, vp)
+        sc = jnp.einsum("hgqd,hkd->hgqk", qp, kp,
+                        preferred_element_type=jnp.float32) / 16.0
+        sc = jnp.where(jnp.tril(jnp.ones((512, 512), bool)), sc, -jnp.inf)
+        dense = jnp.einsum("hgqk,hkd->hgqd", jax.nn.softmax(sc, axis=-1),
+                           vp.astype(jnp.float32))
+        _check_close("grouped-KV flash forward (16 on 2 heads of 256)",
+                     np.asarray(flash(qp, kp, vp), np.float32),
+                     np.asarray(dense), 3e-2)
+        # the one-token delta rule against XLA on the engine's own state
+        b, h, d = slots, 32, 128
+        rows = [jax.random.normal(jax.random.PRNGKey(9 + i), (b, h, d),
+                                  jnp.float32) for i in range(3)]
+        qd, kd = (r / jnp.linalg.norm(r, axis=-1, keepdims=True)
+                  for r in rows[:2])
+        g = -jax.random.uniform(jax.random.PRNGKey(12), (b, h)) * 3
+        beta = jax.random.uniform(jax.random.PRNGKey(13), (b, h))
+        live = jnp.asarray([False, True, True, False])
+        states = jnp.array(engine.state["s"])
+        step = jax.jit(gated_delta.delta_rule_step,
+                       static_argnames=("layer", "impl"))
+        want_o, want_s = step(states, layer=1, q=qd, k=kd, v=rows[2], g=g,
+                              beta=beta, live=live, impl="xla")
+        _require_mosaic(jax.jit(lambda *a: gated_delta.delta_rule_step(
+            a[0], 1, *a[1:])), states, qd, kd, rows[2], g, beta, live)
+        got_o, got_s = step(states, layer=1, q=qd, k=kd, v=rows[2], g=g,
+                            beta=beta, live=live, impl="pallas")
+        _check_close("one-token delta rule, outputs (32 x 128)",
+                     np.asarray(got_o)[1:3], np.asarray(want_o)[1:3], 1e-4)
+        _check_close("one-token delta rule, states (32 x 128 x 128)",
+                     np.asarray(got_s)[:slots], np.asarray(want_s)[:slots],
+                     1e-4)
+        engine.pool.free(0)
+        engine.pool.assert_baseline()
+        program = engine.stats()["step_program"]
+        share = (engine.kv.nbytes + sum(a.nbytes for a in
+                                        engine.state.values())) // GDN["num_layers"]
+        print(f"   pool {engine.kv.shape} {engine.kv.dtype} + state "
+              f"{engine.stats()['state']}: step temp_bytes "
+              f"{program['temp_bytes']}, one layer's share {share} bytes",
+              flush=True)
+        _require(program["temp_bytes"] < share,
+                 f"the step allocates {program['temp_bytes']} bytes: it "
+                 "copies the pool or the state")
+
+
 def _step_text(engine):
-    return engine._step_jit.lower(engine._params, engine.kv, engine.last,
-                                  engine.blank_step()).as_text()
+    return engine._step_jit.lower(engine._params, engine.kv, engine.state,
+                                  engine.last, engine.blank_step()).as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -558,6 +680,7 @@ def main():
     losses, train_attn = phase_train()
     decode_attn = phase_serve()
     phase_latent()
+    phase_state()
     multichip_attn = phase_multichip(losses[0])
     print("summary " + json.dumps({
         "phases": _Phase.results,

@@ -1,0 +1,273 @@
+"""The gated delta rule — a linear-attention layer's recurrence — and the
+causal depthwise convolution in front of it, both with the state a serving
+engine carries from call to call.
+
+Per value head, with a state ``S`` (key x value, float32) and per token a
+query ``q`` and key ``k`` (key wide), a value ``v`` (value wide), a log decay
+``g <= 0`` and a write strength ``beta``::
+
+    S <- exp(g) S;  r = S^T k;  d = beta (v - r);  S <- S + k d^T;  o = S^T q
+
+- :func:`delta_rule_recurrent` — that, token by token under ``lax.scan``: the
+  plain form, for tests and small sizes.
+- :func:`delta_rule_chunked` — the same numbers over a prompt in chunks of
+  ``CHUNK`` tokens: inside a chunk the tokens' writes are solved together (a
+  unit lower-triangular system, by forward substitution — rows inside 16-wide
+  blocks, then the blocks —: stable whatever the keys), and only the chunks
+  follow one another. XLA einsums in float32.
+  Returns the outputs and the final state, which a prefill hands to the step.
+- :func:`delta_rule_step` — one token for every slot of a decode batch, as a
+  Pallas kernel (``gdn_decode``): the state array of every slot and layer is
+  the kernel's operand and result **in place**, and a grid step reads and
+  writes one slot's state of one layer once. A slot that is not live reads
+  and writes the array's last slot (scratch) instead.
+- :func:`causal_conv` / :func:`causal_conv_step` — ``out_t = sum_j w_j
+  x_(t-W+1+j)`` per channel (no bias), over a sequence from a zero history,
+  and for one new input with the last ``W - 1`` inputs carried as the tail.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+__all__ = ["CHUNK", "causal_conv", "causal_conv_step", "delta_rule_recurrent",
+           "delta_rule_chunked", "delta_rule_step"]
+
+CHUNK = 64          # tokens solved together (the family's habit)
+_HI = lax.Precision.HIGHEST
+
+
+def causal_conv(x, w, length=None):
+    """x (S, C) from a zero history, w (W, C): ``(out (S, C) float32, tail
+    (W - 1, C))`` — the tail is the last ``W - 1`` inputs before position
+    ``length`` (default S), zeros where the sequence is shorter: what
+    :func:`causal_conv_step` carries on from."""
+    s, width = x.shape[0], w.shape[0]
+    padded = jnp.concatenate([jnp.zeros((width - 1, x.shape[1]), x.dtype), x])
+    wf = w.astype(jnp.float32)
+    out = sum(wf[j] * lax.dynamic_slice_in_dim(padded, j, s).astype(jnp.float32)
+              for j in range(width))
+    end = s if length is None else length
+    return out, lax.dynamic_slice_in_dim(padded, end, width - 1)
+
+
+def causal_conv_step(x, tail, w):
+    """One new input per sequence: x (B, C), tail (B, W - 1, C) its last
+    inputs, w (W, C) -> (out (B, C) float32, new tail)."""
+    window = jnp.concatenate([tail, x[:, None].astype(tail.dtype)], axis=1)
+    out = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32)[None],
+                  axis=1)
+    return out, window[:, 1:]
+
+
+def delta_rule_recurrent(q, k, v, g, beta, state=None):
+    """q, k (S, H, dk), v (S, H, dv), g, beta (S, H), state (H, dk, dv) or
+    None (zeros) -> (o (S, H, dv), final state); float32."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    if state is None:
+        state = jnp.zeros((q.shape[1], q.shape[2], v.shape[2]), f32)
+
+    def token(s, xs):
+        qt, kt, vt, gt, bt = xs
+        s = s * jnp.exp(gt)[:, None, None]
+        r = jnp.einsum("hkv,hk->hv", s, kt, precision=_HI)
+        d = bt[:, None] * (vt - r)
+        s = s + kt[:, :, None] * d[:, None, :]
+        return s, jnp.einsum("hkv,hk->hv", s, qt, precision=_HI)
+
+    state, o = lax.scan(token, state.astype(f32), (q, k, v, g, beta))
+    return o, state
+
+
+def _rows_inverse(m):
+    """``(I + M)^-1`` for M (..., C, C) strictly lower triangular, row by row
+    (forward substitution; row i needs the rows before it)."""
+    c = m.shape[-1]
+
+    def row(i, t):
+        r = lax.dynamic_index_in_dim(t, i, axis=-2, keepdims=False)
+        r = r + jnp.einsum("...j,...jk->...k", r, t, precision=_HI)
+        return lax.dynamic_update_index_in_dim(t, r, i, axis=-2)
+
+    return lax.fori_loop(1, c, row, -m) + jnp.eye(c, dtype=m.dtype)
+
+
+def _unit_lower_inverse(m, block=16):
+    """``(I + M)^-1`` for M (..., C, C) strictly lower triangular: the
+    ``block``-wide diagonal blocks row by row (:func:`_rows_inverse`: a row
+    loop over the whole matrix reads all of it C times, which was 29 % of a
+    16 k prefill on the chip), then block forward substitution for what lies
+    under them, ``T_ab = -T_aa sum_(b <= c < a) M_ac T_cb``: small batched
+    products, every piece a substitution (no power of M is formed)."""
+    c = m.shape[-1]
+    if c <= block or c % block:
+        return _rows_inverse(m)
+    nb = c // block
+    blocks = m.reshape(m.shape[:-2] + (nb, block, nb, block))
+
+    def at(a, b):
+        return blocks[..., a, :, b, :]
+
+    diag = _rows_inverse(jnp.stack([at(a, a) for a in range(nb)], axis=-3))
+    t = [[diag[..., a, :, :] if a == b else None for b in range(nb)]
+         for a in range(nb)]
+    for b in range(nb):
+        for a in range(b + 1, nb):
+            acc = sum(jnp.einsum("...ij,...jk->...ik", at(a, k), t[k][b],
+                                 precision=_HI) for k in range(b, a))
+            t[a][b] = -jnp.einsum("...ij,...jk->...ik", t[a][a], acc,
+                                  precision=_HI)
+    zero = jnp.zeros_like(t[0][0])
+    return jnp.concatenate([jnp.concatenate(
+        [t[a][b] if b <= a else zero for b in range(nb)], axis=-1)
+        for a in range(nb)], axis=-2)
+
+
+def delta_rule_chunked(q, k, v, g, beta, state=None, chunk=CHUNK):
+    """:func:`delta_rule_recurrent`'s numbers, ``chunk`` tokens at a time.
+    Shapes as there; S need not be a multiple of ``chunk`` (the pad writes
+    nothing: beta 0, g 0). A token with ``beta`` 0 and ``g`` 0 leaves the
+    state as it was, which is how a caller masks the positions past a
+    prompt's length."""
+    f32 = jnp.float32
+    s, h, dk = q.shape
+    dv = v.shape[2]
+    n = -(-s // chunk)
+    pad = n * chunk - s
+
+    def chunks(a):     # (S, H, ...) -> (H, n, chunk, ...)
+        a = jnp.pad(a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        return jnp.moveaxis(a.reshape((n, chunk) + a.shape[1:]), 2, 0)
+
+    q, k, v, g, beta = chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta)
+    gc = jnp.cumsum(g, axis=-1)                               # (H, n, C)
+    idx = jnp.arange(chunk)
+    lower = idx[:, None] >= idx[None, :]
+    # exp(gc_i - gc_j) for i >= j; the other half would overflow
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))
+    kb, vb = k * beta[..., None], v * beta[..., None]
+    strict = idx[:, None] > idx[None, :]
+    m = jnp.where(strict, jnp.einsum("hnik,hnjk->hnij", kb, k, precision=_HI)
+                  * decay, 0.0)
+    t = _unit_lower_inverse(m)
+    u = jnp.einsum("hnij,hnjv->hniv", t, vb, precision=_HI)
+    w = jnp.einsum("hnij,hnjk->hnik", t, kb * jnp.exp(gc)[..., None],
+                   precision=_HI)
+    qk = jnp.einsum("hnik,hnjk->hnij", q, k, precision=_HI) * decay
+    last = gc[..., -1:]                                       # (H, n, 1)
+    q_in = q * jnp.exp(gc)[..., None]        # against the state coming in
+    k_out = k * jnp.exp(last - gc)[..., None]  # into the state going out
+    if state is None:
+        state = jnp.zeros((h, dk, dv), f32)
+
+    def one(st, xs):
+        u_n, w_n, qk_n, q_n, k_n, last_n = xs
+        v_new = u_n - jnp.einsum("hik,hkv->hiv", w_n, st, precision=_HI)
+        o = (jnp.einsum("hik,hkv->hiv", q_n, st, precision=_HI)
+             + jnp.einsum("hij,hjv->hiv", qk_n, v_new, precision=_HI))
+        st = (st * jnp.exp(last_n)[..., None]
+              + jnp.einsum("hik,hiv->hkv", k_n, v_new, precision=_HI))
+        return st, o
+
+    per_chunk = tuple(jnp.moveaxis(a, 1, 0)
+                      for a in (u, w, qk, q_in, k_out, last))
+    state, o = lax.scan(one, state.astype(f32), per_chunk)    # o (n, H, C, dv)
+    o = jnp.moveaxis(o, 1, 2).reshape(n * chunk, h, dv)
+    return o[:s], state
+
+
+def _step_xla(states, layer, q, k, v, g, beta, live):
+    """:func:`delta_rule_step` by gather and scatter (tests, other
+    backends)."""
+    b = q.shape[0]
+    s = states[:b, layer] * jnp.exp(g)[..., None, None]
+    r = jnp.einsum("bhkv,bhk->bhv", s, k, precision=_HI)
+    d = beta[..., None] * (v - r)
+    s = s + k[..., :, None] * d[..., None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HI)
+    keep = live[:, None, None, None]
+    return o, states.at[:b, layer].set(jnp.where(keep, s, states[:b, layer]))
+
+
+def delta_rule_step(states, layer, q, k, v, g, beta, live, impl="pallas",
+                    interpret=False):
+    """One token for each of B slots. states ``(slots + 1, layers, H, dk,
+    dv)`` float32 — slot i's state of every layer, the last slot scratch —
+    is read and written at ``[:, layer]`` in place (donate it); ``layer`` a
+    Python int; q, k (B, H, dk), v (B, H, dv), g, beta (B, H) float32; live
+    (B,) bool. Returns (o (B, H, dv) float32, states). A slot that is not
+    live keeps its state (the kernel works on the scratch slot for it) and
+    its output is garbage."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    if impl != "pallas":
+        return _step_xla(states, layer, q, k, v, g, beta, live)
+    b, h, dv = v.shape
+    slot = jnp.where(live, jnp.arange(b), states.shape[0] - 1).astype(jnp.int32)
+    wide = (b, h, dv)
+    return _gdn_decode(
+        states, jnp.full((1,), int(layer), jnp.int32), slot,
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), v,
+        jnp.broadcast_to(jnp.exp(g)[..., None], wide),
+        jnp.broadcast_to(beta[..., None], wide), interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(8,))
+def _gdn_decode(states, layer, slot, q_t, k_t, v, decay, beta, interpret):
+    """Grid (B,): grid step b holds slot ``slot[b]``'s state of ``layer``,
+    all H heads (H x dk x dv float32: 2 MB at 32 x 128 x 128), in VMEM, in
+    and out through the pipeline, the states array aliased to the result.
+    q_t, k_t (B, dk, H): a head's query and key are a COLUMN, broadcast along
+    the lanes against the state's (dk sublanes, dv lanes); v, decay, beta
+    (B, H, dv) rows, broadcast along the sublanes. The products run on the
+    VPU: one row of work a head (a mat-vec) gives the MXU nothing to do, and
+    the kernel is bound by the bytes of the state."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, dk, h = q_t.shape
+    dv = v.shape[2]
+
+    def kernel(layer_ref, slot_ref, s_ref, q_ref, k_ref, v_ref, a_ref, b_ref,
+               o_ref, out_ref):
+        del layer_ref, slot_ref
+        for i in range(h):
+            kc, qc = k_ref[:, i:i + 1], q_ref[:, i:i + 1]      # (dk, 1)
+            s = s_ref[i] * a_ref[i:i + 1, :]                   # (dk, dv)
+            r = jnp.sum(s * kc, axis=0, keepdims=True)         # (1, dv)
+            d = b_ref[i:i + 1, :] * (v_ref[i:i + 1, :] - r)
+            s = s + kc * d
+            out_ref[i] = s
+            o_ref[i:i + 1, :] = jnp.sum(s * qc, axis=0, keepdims=True)
+
+    def per_slot(shape):
+        return pl.BlockSpec((None,) + shape, lambda i, ly, sl: (i, 0, 0))
+
+    state_spec = pl.BlockSpec((None, None, h, dk, dv),
+                              lambda i, ly, sl: (sl[i], ly[0], 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[state_spec, per_slot((dk, h)), per_slot((dk, h)),
+                  per_slot((h, dv)), per_slot((h, dv)), per_slot((h, dv))],
+        out_specs=[per_slot((h, dv)), state_spec],
+    )
+    o, states = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        # operand 2 (after the two prefetched scalars) is result 1
+        input_output_aliases={2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=interpret,
+        name="gdn_decode",
+    )(layer, slot, states, q_t, k_t, v, decay, beta)
+    return o, states

@@ -10,6 +10,8 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
   expert choice flips on a near tie, so the choice is made in the precision
   of the reference): ``s = sigmoid(h.W_r)``; chosen = top-k of ``s + b`` (the
   bias chooses, never weighs); ``g = scale * s_chosen / sum(s_chosen)``.
+  :func:`route_softmax` is the other family's: ``p = softmax(h.W_r)`` over all
+  the experts, the k largest, ``g = p_chosen / sum(p_chosen)``; no bias.
 - :func:`held_experts` — dropless: the (token, choice) assignments that fall
   on held experts are sorted by expert (the others, and the tokens that are
   not live, sort behind them), the sorted rows go through a grouped product
@@ -29,7 +31,8 @@ from jax import lax
 
 from . import flash_attention
 
-__all__ = ["route", "held_experts", "expert_layer", "gated_mlp", "COUNTERS"]
+__all__ = ["route", "route_softmax", "held_experts", "expert_layer",
+           "gated_mlp", "COUNTERS"]
 
 # per call: live (token, choice) pairs; those on held experts; most tokens on
 # one held expert; held experts with at least one token; held pairs that no
@@ -73,6 +76,16 @@ def route(h, router_w, router_b, k: int, scale: float):
     _, chosen = lax.top_k(s + router_b.astype(jnp.float32), k)
     picked = jnp.take_along_axis(s, chosen, axis=1)
     return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def route_softmax(h, router_w, k: int):
+    """h (T, D) -> (chosen (T, k), gates (T, k) float32): the k largest of
+    ``softmax(h.W_r)`` over every expert, renormalised to sum 1."""
+    p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32),
+                               router_w.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST), axis=-1)
+    picked, chosen = lax.top_k(p, k)
+    return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
 def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
@@ -129,21 +142,31 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
 
 
 def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
-                 scale: float, offset=0):
+                 scale: float = 1.0, offset=0):
     """One expert layer over h (T, D): the held experts' routed part plus
     the shared expert. ``p``: ``router_w`` (D, E_all), ``router_b`` (E_all,),
     ``shared_{gate,up,down}_w``; ``experts``: ``gate_w``, ``up_w``,
-    ``down_w`` as :func:`held_experts` takes them, with ``offset``. Tokens go
-    at most ``TOKEN_CHUNK`` at a time. Returns (y (T, D) float32,
-    counters)."""
+    ``down_w`` as :func:`held_experts` takes them, with ``offset``. A layer
+    with no ``router_b`` routes by :func:`route_softmax` (``scale`` unused);
+    one with ``shared_s_w`` (D,) weighs its shared expert by
+    ``sigmoid(h.w_s)``. Tokens go at most ``TOKEN_CHUNK`` at a time. Returns
+    (y (T, D) float32, counters)."""
     def chunk(args):
         hc, lc = args
-        chosen, gates = route(hc, p["router_w"], p["router_b"], k, scale)
+        if "router_b" in p:
+            chosen, gates = route(hc, p["router_w"], p["router_b"], k, scale)
+        else:
+            chosen, gates = route_softmax(hc, p["router_w"], k)
         y, c = held_experts(hc, chosen, gates, lc, experts["gate_w"],
                             experts["up_w"], experts["down_w"], first, held,
                             offset)
-        return y + gated_mlp(hc, p["shared_gate_w"], p["shared_up_w"],
-                             p["shared_down_w"]), c
+        shared = gated_mlp(hc, p["shared_gate_w"], p["shared_up_w"],
+                           p["shared_down_w"])
+        if "shared_s_w" in p:
+            shared = shared * jax.nn.sigmoid(jnp.dot(
+                hc.astype(jnp.float32), p["shared_s_w"].astype(jnp.float32),
+                precision=lax.Precision.HIGHEST))[:, None]
+        return y + shared, c
 
     t = h.shape[0]
     n = -(-t // TOKEN_CHUNK)          # the fewest equal chunks that fit

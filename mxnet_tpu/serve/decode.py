@@ -30,7 +30,19 @@ what a cache row is, the prefill and step bodies and the paged read are the
 model's; pages, programs, accounting and the scheduler are shared by every
 model (``models.transformer.TransformerDecodeModel`` — K and V of every head
 side by side, float32; ``models.mla_moe.MLAMoEDecodeModel`` — one latent
-row and no head axis, bfloat16).
+row and no head axis, bfloat16; ``models.gdn_moe.GDNMoEDecodeModel`` — a
+flat grouped-KV row in a quarter of its layers, and in the others a
+fixed-size recurrent state per sequence).
+
+**Two kinds of cache.** Beside its rows a model may declare **per-slot
+state** (``model.state``: name -> (shape per slot, dtype)): what a layer
+keeps per sequence whatever its length. The engine holds one array a name,
+``(slots + 1,) + shape`` (the last slot scratch), resident and donated with
+the pool; a prefill returns its sequence's state and the engine writes it
+over its slot's — overwrites, never adds: whatever the slot's last owner or
+a step launched ahead for it left there is gone —; the step is handed every
+slot's with ``live`` and returns them, a slot that is not live untouched.
+Only the model's ``paged_layers`` have a layer of the pool.
 
 Growing a sequence never changes a program shape: the KV pool is one
 fixed array ``(pages, layers, page_size) + model.cache_row`` of
@@ -121,17 +133,23 @@ class DecodeEngine:
         A **decode model**: an object with ``cfg`` (a dict with
         ``max_length``), ``params`` (a tree of device arrays, passed to the
         programs as their first argument), ``layers``, ``cache_row`` and
-        ``cache_dtype`` (the pool is ``(pages, layers, page_size) +
-        cache_row`` of that dtype), ``counters`` (full names, as
+        ``cache_dtype`` (the pool is ``(pages, paged layers, page_size) +
+        cache_row`` of that dtype; ``paged_layers``, how many of the layers
+        keep rows there, is ``layers`` unless the model says), optionally
+        ``state`` (module docstring; a model with none compiles to the
+        programs it had before state existed), ``counters`` (full names, as
         ``"moe.held"``, of the int32 values its bodies return beside the
         logits; may be empty), and three pure
         functions: ``prefill(params, tokens (1, S), length) -> (last logits
-        (V,), rows (layers, S) + cache_row, counters or None)``,
+        (V,), rows (paged layers, S) + cache_row, counters or None)``,
         ``step(params, tokens (B,), positions (B,), live (B,), attend) ->
-        (logits (B, V), counters or None)`` where ``attend(layer, query,
-        row)`` writes ``row`` (B,) + cache_row at the step's positions and
-        returns ``attention(query, pool, layer, page_tables, lengths)``, and
-        that ``attention`` itself, the paged read of one layer.
+        (logits (B, V), counters or None)`` where ``attend(paged layer,
+        query, row)`` writes ``row`` (B,) + cache_row at the step's positions
+        and returns ``attention(query, pool, layer, page_tables, lengths)``,
+        and that ``attention`` itself, the paged read of one layer. With
+        ``state``, ``prefill`` returns the sequence's state (name -> shape
+        per slot) as a fourth value, and ``step`` takes the engine's arrays
+        as a sixth argument and returns them as a third value.
         Anything else is taken for a ``TransformerLM`` (an initialized
         block, or its config dict when ``params`` is given) and wrapped in
         ``models.transformer.TransformerDecodeModel``.
@@ -152,10 +170,12 @@ class DecodeEngine:
         Explicit persistent program cache; defaults to the process-wide
         ``progcache.cache()`` (``MXNET_PROGCACHE=1``).
 
-    **What the programs take.** Both take four arguments, three of them
-    resident on the device — ``params``, the pool ``kv`` (donated), and
-    ``last``, the ``(slots,)`` int32 vector of every slot's last sampled
-    token — and ONE int32 array from the host, so a call is one upload:
+    **What the programs take.** Both take five arguments, four of them
+    resident on the device — ``params``, the pool ``kv`` and the per-slot
+    ``state`` (both donated; ``state`` is empty for a model that declares
+    none), and ``last``, the ``(slots,)`` int32 vector of every slot's last
+    sampled token — and ONE int32 array from the host, so a call is one
+    upload:
 
     - the step's ``(slots + 1, 3 + max_pages)``: a row per slot — position,
       length (0: the slot is idle), the temperature's float32 bits, its
@@ -165,7 +185,7 @@ class DecodeEngine:
       length, slot, the seed's and the temperature's bits, the page ids,
       the padded prompt.
 
-    Both return ``kv``, the new ``last`` (the step's tokens; a prefill's
+    Both return ``kv``, ``state``, the new ``last`` (the step's tokens; a prefill's
     first token written at its slot) and what the host fetches in one
     transfer: the sampled tokens (a prefill's one) followed by the model's
     counters.
@@ -216,20 +236,42 @@ class DecodeEngine:
             (tuple(a.shape), str(a.dtype))
             for a in jax.tree_util.tree_leaves(self._params))
         row, dtype = tuple(self.model.cache_row), self.model.cache_dtype
-        pool_shape = (self.num_pages, self.model.layers, self.page_size) + row
+        self.paged_layers = int(getattr(self.model, "paged_layers",
+                                        self.model.layers))
+        pool_shape = (self.num_pages, self.paged_layers, self.page_size) + row
         self.cache_row_bytes = int(np.prod(row)) * jnp.dtype(dtype).itemsize
+        # per-slot state beside the pages: one more slot than the batch has,
+        # the last scratch (where a step's idle slots read and write)
+        declared = {name: ((self.slots + 1,) + tuple(shape), jnp.dtype(dt))
+                    for name, (shape, dt) in
+                    (getattr(self.model, "state", None) or {}).items()}
+        self.state_bytes = sum(int(np.prod(shape[1:])) * dt.itemsize
+                               for shape, dt in declared.values())
         if jax.default_backend() == "tpu":
-            pool = self._row_major(
-                pool_shape,
-                jax.tree_util.tree_leaves(self._params)[0].sharding)
+            sharding = jax.tree_util.tree_leaves(self._params)[0].sharding
+            pool = self._row_major(pool_shape, sharding)
+            held = {name: self._row_major(shape, sharding)
+                    for name, (shape, _) in declared.items()}
             # donating the pool buffer makes the KV writes in-place on TPU;
             # CPU/GPU test backends would only warn about it
-            donate = (1,)
+            donate = (1, 2)
         else:
-            pool, donate = None, ()
+            pool, held, donate = None, {name: None for name in declared}, ()
         self.kv = jax.jit(lambda: jnp.zeros(pool_shape, dtype),
                           out_shardings=pool)()
-        self._prefill_jit, self._step_jit = self._jit_programs(pool, donate)
+        self.state = {name: jax.jit(lambda s=shape, d=dt: jnp.zeros(s, d),
+                                    out_shardings=held[name])()
+                      for name, (shape, dt) in declared.items()}
+        if held and pool is not None:
+            # the programs take each array in the layout it HAS: a zeros
+            # program loaded from the compile cache has been seen to give
+            # the client's own choice, not the row-major one asked for
+            # (an array that is not whole tiles wide: PR 29 saw it of the
+            # pool, PR 38 of a state array); a model shapes its state in
+            # whole tiles so that the two are the same
+            held = {name: a.format for name, a in self.state.items()}
+        self._prefill_jit, self._step_jit = self._jit_programs(pool, held,
+                                                               donate)
         # every slot's last sampled token, fed back on the device
         self.last = jnp.zeros((self.slots,), jnp.int32)
         self._blank_step = np.full((self.slots + 1, _TABLE + self.max_pages),
@@ -257,6 +299,10 @@ class DecodeEngine:
             type(self.model).__name__, tuple(sorted(self.cfg.items())),
             self.slots, self.page_size, self.num_pages, self.max_pages,
             tuple(self.buckets), self._param_avals)
+        if declared:
+            self._key_statics += (self.paged_layers, tuple(
+                (name, shape, str(dt)) for name, (shape, dt)
+                in sorted(declared.items())))
 
     # -- pure device programs ------------------------------------------
 
@@ -271,15 +317,16 @@ class DecodeEngine:
 
         return Format(Layout(tuple(range(len(shape)))), sharding)
 
-    def _jit_programs(self, pool, donate):
+    def _jit_programs(self, pool, held, donate):
         """(prefill, step) jitted with the pool argument and result held
-        to ``pool`` (a ``Format``, or None for the backend's default)."""
+        to ``pool`` and the state's to ``held`` (name -> ``Format``; None
+        for the backend's default)."""
         import jax
 
         def jit(fn):
             return jax.jit(fn, donate_argnums=donate,
-                           in_shardings=(None, pool, None, None),
-                           out_shardings=(pool, None))
+                           in_shardings=(None, pool, held, None, None),
+                           out_shardings=(pool, held, None))
 
         return jit(self._prefill_fn), jit(self._step_fn)
 
@@ -292,9 +339,10 @@ class DecodeEngine:
             return toks
         return jnp.concatenate([toks, jnp.stack(counters).astype(jnp.int32)])
 
-    def _prefill_fn(self, params, kv, last, packed):
-        """One padded prompt → KV pages written, first token (also written
-        into ``last`` at the prompt's slot). ``packed`` (class docstring)
+    def _prefill_fn(self, params, kv, state, last, packed):
+        """One padded prompt → KV pages written, its state written over its
+        slot's, first token (also written into ``last`` at the prompt's
+        slot). ``packed`` (class docstring)
         holds the prompt padded to its bucket S (multiple of page_size) and
         the sequence's S // page_size pages in position order. Pad
         positions scatter garbage rows — masked by ``length`` until each
@@ -310,7 +358,13 @@ class DecodeEngine:
         temp = jax.lax.bitcast_convert_type(packed[_P_TEMP], jnp.float32)
         page_ids = packed[_P_PAGES:_P_PAGES + n]
         tokens = packed[_P_PAGES + n:][None]
-        logits, rows, counters = self.model.prefill(params, tokens, length)
+        logits, rows, counters, *own = self.model.prefill(params, tokens,
+                                                          length)
+        if state:   # the sequence's own, over whatever its slot held
+            state = {name: jax.lax.dynamic_update_slice(
+                held, own[0][name][None].astype(held.dtype),
+                (slot,) + (0,) * (held.ndim - 1))
+                for name, held in state.items()}
 
         # (L, S) + row → (L, n, page) + row, then page by page —
         # every layer's rows of the page in one update — in place at the
@@ -318,7 +372,7 @@ class DecodeEngine:
         # head counts off the 8-row tile XLA's TPU scatter wants the pool
         # in a layout of its own, and converts the whole pool there and
         # back.)
-        rows = rows.reshape((self.model.layers, n, self.page_size)
+        rows = rows.reshape((self.paged_layers, n, self.page_size)
                             + rows.shape[2:])
 
         def write_page(j, kv):
@@ -329,9 +383,10 @@ class DecodeEngine:
 
         kv = jax.lax.fori_loop(0, n, write_page, kv)
         tok = sample_token(logits[None], jax.random.PRNGKey(seed), temp)
-        return kv, (last.at[slot].set(tok[0]), self._fetched(tok, counters))
+        return kv, state, (last.at[slot].set(tok[0]),
+                           self._fetched(tok, counters))
 
-    def _step_fn(self, params, kv, last, packed):
+    def _step_fn(self, params, kv, state, last, packed):
         """One token for every slot: ``last`` (B,) are the tokens to embed,
         ``packed`` (class docstring) the positions, lengths, temperatures
         and page tables (B, max_pages). Inactive slots carry length 0 and a
@@ -359,10 +414,14 @@ class DecodeEngine:
             return self.model.attention(query, kv, layer, page_tables,
                                         lengths)
 
-        logits, counters = self.model.step(params, last, positions,
-                                           lengths > 0, attend)
+        if state:
+            logits, counters, state = self.model.step(
+                params, last, positions, lengths > 0, attend, state)
+        else:
+            logits, counters = self.model.step(params, last, positions,
+                                               lengths > 0, attend)
         toks = sample_token(logits, jax.random.PRNGKey(seed), temps)
-        return kv, (toks, self._fetched(toks, counters))
+        return kv, state, (toks, self._fetched(toks, counters))
 
     # -- program accounting (the engine.py compile path, decode-keyed) --
 
@@ -384,7 +443,7 @@ class DecodeEngine:
         sig = (kind, (tuple(packed.shape), str(packed.dtype)))
         is_compile = sig not in self._programs
         cache_hit = False
-        call_args = (self._params, self.kv, self.last, packed)
+        call_args = (self._params, self.kv, self.state, self.last, packed)
         if is_compile:
             entry = {"sig": sig, "kind": kind, "label": label,
                      "param_avals": self._param_avals}
@@ -430,7 +489,7 @@ class DecodeEngine:
             # the one upload and the launch: returns before the device is
             # done, and before it has begun if a call is still running
             with obs.trace.span("decode.dispatch"):
-                self.kv, (self.last, fetched) = fn(*call_args)
+                self.kv, self.state, (self.last, fetched) = fn(*call_args)
         # an operator's "which call recompiled, which deserialized" alarm,
         # one increment per first call of a signature (the decode.execute
         # span carries compile, cache_hit and the duration)
@@ -557,6 +616,12 @@ class DecodeEngine:
                 "slots": self.slots,
                 "page_size": self.page_size,
                 "cache_row_bytes": self.cache_row_bytes,
+                "paged_layers": self.paged_layers,
+                # what a slot holds beside its pages, whatever its length
+                "state_bytes": self.state_bytes,
+                "state": {name: {"shape": list(a.shape),
+                                 "dtype": str(a.dtype)}
+                          for name, a in self.state.items()},
                 "buckets": list(self.buckets),
                 "num_programs": len(self._programs),
                 "executions": self.exec_count,
@@ -902,6 +967,14 @@ class DecodeScheduler:
             packed[eng.slots, 0] = self._step_seed()
         if not stepping:
             return 0
+        # what this step's caches cost in bytes: rows read grow with the
+        # live contexts, state read and written does not
+        cache = {
+            "cache.paged_bytes": int(packed[:eng.slots, _LEN].sum())
+            * getattr(eng, "cache_row_bytes", 0)
+            * getattr(eng, "paged_layers", 0),
+            "cache.state_bytes": 2 * getattr(eng, "state_bytes", 0)
+            * len(stepping)}
         ahead = int(any(f.kind == "step" for f in self._inflight))
         t_launch = time.monotonic()
         launched = eng.launch_step(packed)
@@ -912,7 +985,7 @@ class DecodeScheduler:
         self.launched_ahead += ahead
         obs.inc("decode.launched_ahead", ahead)
         self._inflight.append(_InFlight("step", launched, t_launch, stepping,
-                                        joined=joined, ahead=ahead))
+                                        joined=joined, ahead=ahead, **cache))
         return len(stepping)
 
     def _receive(self, flight: _InFlight) -> int:
@@ -941,6 +1014,10 @@ class DecodeScheduler:
         obs.set_gauge("decode.occupancy", self._occupancy)
         obs.set_gauge("decode.cache_row_bytes",
                       getattr(self.engine, "cache_row_bytes", 0))
+        obs.set_gauge("decode.state_bytes",
+                      getattr(self.engine, "state_bytes", 0))
+        obs.set_gauge("decode.paged_layers",
+                      getattr(self.engine, "paged_layers", 0))
         obs.trace.complete("decode.step", t0, now - t0,
                            active=len(flight.who), left=left, **attrs)
         return left

@@ -179,7 +179,11 @@ def test_decode_step_keeps_its_attributes_and_endpoints(served):
     assert len(steps) == 2 and len(turns) == 3
     launches = []
     for step, launched_in, read_in in zip(steps, turns, turns[1:]):
-        assert set(step["args"]) == {"active", "joined", "left", "ahead"}
+        assert set(step["args"]) == {"active", "joined", "left", "ahead",
+                                     "cache.paged_bytes", "cache.state_bytes"}
+        # a model with no per-slot state: rows read, no state touched
+        assert step["args"]["cache.paged_bytes"] > 0
+        assert step["args"]["cache.state_bytes"] == 0
         # attributed to the step: joined as its launching turn admitted,
         # left as the turn that read it retired
         assert step["args"]["joined"] == launched_in["args"]["joined"]
@@ -209,7 +213,8 @@ def test_live_span_attributes_are_plain_ints(served):
     want = {"decode.turn": {"joined", "active", "left"},
             "decode.admit": {"admitted"}, "decode.build": {"active"},
             "decode.prefill": {"bucket", "prompt_len"},
-            "decode.step": {"active", "joined", "left", "ahead"},
+            "decode.step": {"active", "joined", "left", "ahead",
+                            "cache.paged_bytes", "cache.state_bytes"},
             "decode.distribute": {"left"}}
     seen = set()
     for span in served:

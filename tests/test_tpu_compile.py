@@ -94,29 +94,34 @@ def _tpu_program(engine, one_chip, kind, monkeypatch):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     pool = engine._row_major(engine.kv.shape, one_chip)
-    prefill, step = engine._jit_programs(pool, donate=(1,))
+    held = {name: engine._row_major(a.shape, one_chip)
+            for name, a in engine.state.items()}
+    prefill, step = engine._jit_programs(pool, held, donate=(1, 2))
     params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
                                     engine._params)
     kv = spec(engine.kv.shape, engine.kv.dtype, sharding=pool)
+    state = {name: spec(a.shape, a.dtype, sharding=held[name])
+             for name, a in engine.state.items()}
     i32, slots, page = jnp.int32, engine.slots, engine.page_size
     last = spec((slots,), i32)
     # the one array a call uploads (``DecodeEngine``'s docstring)
     if kind == "step":
         packed = spec(engine.blank_step().shape, i32)
-        lowered = step.lower(params, kv, last, packed)
+        lowered = step.lower(params, kv, state, last, packed)
     else:
         bucket = engine.buckets[0]
         packed = spec((4 + bucket // page + bucket,), i32)
-        lowered = prefill.lower(params, kv, last, packed)
+        lowered = prefill.lower(params, kv, state, last, packed)
     return lowered, lowered.compile()
 
 
 def _host_arguments(engine, lowered):
     """The program's arguments less those that rest on the device between
-    calls: the weights, the pool, the last-token vector."""
+    calls: the weights, the pool, the per-slot state, the last-token vector."""
     import jax
 
-    resident = len(jax.tree_util.tree_leaves(engine._params)) + 2
+    resident = (len(jax.tree_util.tree_leaves(engine._params)) + 2
+                + len(engine.state))
     return jax.tree_util.tree_leaves(lowered.args_info)[resident:]
 
 
@@ -341,3 +346,156 @@ def test_tpu_latent_prefill_program_writes_the_pool_in_place(
                 if " copy(" in line or " fusion(" in line], lines
     assert any(" while(" in line for line in lines), lines
     assert cost["alias_bytes"] >= engine.kv.nbytes
+
+
+# -- state beside pages: gated delta layers and a flat grouped-KV row ----------
+# Published widths of the ``gdn_moe`` kind (heads of 256, 2 cached; value heads
+# 32 x 128 x 128 float32 of state), at a depth, expert count and vocabulary
+# small enough to compile in seconds.
+
+GDN = {"vocab_size": 1024, "hidden_size": 512, "num_layers": 4,
+       "full_interval": 2, "num_heads": 16, "num_kv_heads": 2, "head_dim": 256,
+       "rotary_dim": 64, "rope_theta": 10000000, "linear_key_heads": 16,
+       "linear_value_heads": 32, "linear_key_dim": 128,
+       "linear_value_dim": 128, "conv_width": 4, "expert_width": 256,
+       "router_experts": 16, "experts_first": 4, "experts_held": 4,
+       "experts_per_token": 4, "rms_eps": 1e-6, "max_length": 2048}
+
+
+@pytest.fixture(scope="module")
+def gdn_engine():
+    import jax
+
+    from mxnet_tpu.models import gdn_moe
+
+    shapes = jax.eval_shape(lambda: gdn_moe.init_params(GDN, 0))
+    model = gdn_moe.GDNMoEDecodeModel(GDN, params=shapes)
+    return DecodeEngine(model, slots=8, page_size=256, num_pages=8 * 8 + 1,
+                        prompt_buckets=[1024])
+
+
+def _as_on_a_tpu(monkeypatch):
+    """The model picks its kernels as it does on a TPU (the backend here is
+    cpu: interpret mode, and the XLA paths under ``auto``)."""
+    from mxnet_tpu.models import gdn_moe
+    from mxnet_tpu.ops import gqa_attention
+
+    for module in (flash_attention, gqa_attention, gdn_moe):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    monkeypatch.setenv("MXNET_DECODE_ATTN", "pallas")
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_tpu_grouped_kv_kernel_at_the_published_geometry(dtype, one_chip,
+                                                         monkeypatch):
+    """The grouped-KV paged kernel at ``qwen3next-serve-closed-long``'s own
+    geometry — 32 slots, a table of 68 pages of 256, 16 query heads on 2
+    cached heads of 256, the flat 1024-wide row — goes through Mosaic in 16
+    and in 32 bits (a head axis of 2 would not: R12) and makes no
+    temporary; so does the flash forward of a 16,384-position prompt, whose
+    keys and values are streamed, not resident."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gqa_attention
+
+    _as_on_a_tpu(monkeypatch)
+
+    def spec(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lowered = jax.jit(gqa_attention.gqa_decode_attention,
+                      static_argnums=2).lower(
+        spec((32, 2, 8, 256)), spec((32 * 68 + 1, 2, 256, 1024)), 1,
+        spec((32, 68), jnp.int32), spec((32,), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert "gqa_decode" in lowered.as_text()
+    assert obs.device.analyze_compiled(lowered.compile())["temp_bytes"] == 0
+    if dtype == "bfloat16":
+        lowered = jax.jit(gqa_attention.gqa_flash_attention).lower(
+            spec((2, 8, 16384, 256)), spec((2, 16384, 256)),
+            spec((2, 16384, 256)))
+        assert lowered.as_text().count("tpu_custom_call") == 1
+        assert obs.device.analyze_compiled(
+            lowered.compile())["temp_bytes"] == 0
+
+
+def test_tpu_delta_rule_kernel_updates_the_state_in_place(one_chip,
+                                                          monkeypatch):
+    """The one-token delta-rule kernel at the published geometry — 33 slots
+    x 6 layers x 32 x 128 x 128 float32 (415 MB: shapes only here) — goes
+    through Mosaic, and the states array is its operand and its result: the
+    donated argument is aliased, and nothing state-sized is allocated."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gated_delta
+
+    def spec(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, h, d = 32, 32, 128
+    states = spec((b + 1, 6, h, d, d))
+    lowered = jax.jit(
+        lambda s, q, k, v, g, beta, live: gated_delta.delta_rule_step(
+            s, 4, q, k, v, g, beta, live), donate_argnums=0).lower(
+        states, spec((b, h, d)), spec((b, h, d)), spec((b, h, d)),
+        spec((b, h)), spec((b, h)), spec((b,), jnp.bool_))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert "gdn_decode" in lowered.as_text()
+    cost = obs.device.analyze_compiled(lowered.compile())
+    nbytes = (b + 1) * 6 * h * d * d * 4
+    assert cost["alias_bytes"] >= nbytes
+    assert cost["temp_bytes"] < nbytes // ((b + 1) * 6)   # under one slot's
+
+
+def test_tpu_step_program_with_state_beside_pages(gdn_engine, one_chip,
+                                                  monkeypatch):
+    """The step of the gated-delta model compiled for a v5e: the pool
+    ``(65, 2, 256, 1024)`` bfloat16 counts the two PAGED layers only and
+    rests row-major; the per-slot state ``(9, 2, 32, 128, 128)`` float32 is
+    donated beside it; one Mosaic call a kind of kernel (``gdn_decode`` and
+    ``gqa_decode``, each traced once for both of its layers); and
+    ``temp_bytes`` stays under one layer's share of pool plus state: no
+    program copies either."""
+    engine = gdn_engine
+    assert engine.kv.shape == (65, 2, 256, 1024) and engine.paged_layers == 2
+    assert engine.cache_row_bytes == 2048
+    assert engine.state["s"].shape == (9, 2, 32, 128, 128)
+    assert engine.state["tail"].shape == (9, 2, 192, 128)   # 3 x 8192
+    assert engine.state_bytes == 2 * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "gdn_decode" in text and "gqa_decode" in text
+    cost = obs.device.analyze_compiled(compiled)
+    held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
+    assert cost["temp_bytes"] < held // GDN["num_layers"], cost
+    assert cost["alias_bytes"] >= held
+    lines = _pool_lines(compiled, engine)
+    assert ("{3,2,1,0:T(8,128)(2,1)}" in lines[0]
+            and "parameter(" in lines[0]), lines[0]
+    assert not [line for line in lines if " copy(" in line], lines
+    # the state too: written where it lies, by the kernel and by nothing else
+    state_shape = "f32[9,2,32,128,128]"
+    entry = compiled.as_text()
+    entry = entry[entry.index("\nENTRY ") + 1:]
+    assert not [line for line in entry.splitlines()
+                if state_shape in line and " copy(" in line]
+
+
+def test_tpu_prefill_program_hands_its_state_to_the_slot(gdn_engine, one_chip,
+                                                         monkeypatch):
+    """A 1024-position prefill of the gated-delta model for a v5e: the
+    grouped flash forward goes through Mosaic (one body: the periods are
+    scanned), pool and state are donated and written in place."""
+    engine = gdn_engine
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
+    assert "gqa_prefill" in lowered.as_text()
+    cost = obs.device.analyze_compiled(compiled)
+    held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
+    assert cost["alias_bytes"] >= held
+    lines = _pool_lines(compiled, engine)
+    assert not [line for line in lines if " copy(" in line], lines
