@@ -18,6 +18,10 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
               bfloat16 values in 640 columns) through ``DecodeEngine``: the paged latent
               kernel against the XLA gather on the chip, the step program's
               temporaries under one layer's latents, nothing dropped;
+- *experts*   ``ops.moe.held_experts`` at the two expert cells' prefill-chunk
+              and decode-step geometries against a plain masked loop in
+              bfloat16 on the chip: the error, nothing dropped, and the
+              rows handed to the grouped products over the rows held;
 - *multichip* with >= 4 chips: the same trainer on dp2 x tp2, then on
               dp2 x sp2 at seq 4096 (ring attention, the Pallas kernel
               inside shard_map). Otherwise reported ``not_run``.
@@ -572,8 +576,10 @@ def phase_state():
         engine = DecodeEngine(model, slots=slots, page_size=page,
                               num_pages=513, prompt_buckets=[512])
         engine.warmup()
-        _require(_step_text(engine).count(_MOSAIC) == 2,
-                 "the step has not one gdn_decode and one gqa_decode kernel")
+        # and the two of the held experts (their buffer, their rows' way back)
+        _require(_step_text(engine).count(_MOSAIC) == 4,
+                 "the step has not one kernel each of gdn_decode, gqa_decode, "
+                 "moe_rows and moe_rows_back")
         rng = np.random.RandomState(4)
         prompt = rng.randint(0, GDN["vocab_size"], 300)
         engine.pool.alloc(0, 2)
@@ -669,6 +675,62 @@ def _step_text(engine):
 
 
 # ---------------------------------------------------------------------------
+# experts: the held experts' rows at the cells' own geometries
+# ---------------------------------------------------------------------------
+
+# cell: (k, hidden, expert width, experts held, experts the router scores,
+# tokens of a chunk of its longest prefill): the gated-delta cell's 16,384
+# prefill goes in chunks of 4,096, the latent-attention cell's 7,168 in 3,584
+EXPERT_CELLS = {"gdn": (10, 2048, 512, 128, 512, 4096),
+                "latent": (8, 4096, 2048, 32, 128, 3584)}
+
+
+def phase_experts():
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import moe
+
+    def masked_loop(h, chosen, gates, gate_w, up_w, down_w):
+        """Every held expert over all tokens, weighed by its gate or 0."""
+        def one(y, xs):
+            i, g, u, dn = xs
+            w = jnp.sum(jnp.where(chosen == i, gates, 0.0), axis=1)
+            return y + w[:, None] * moe.gated_mlp(h, g, u, dn), None
+        y, _ = jax.lax.scan(one, jnp.zeros(h.shape, jnp.float32),
+                            (jnp.arange(gate_w.shape[0]), gate_w, up_w,
+                             down_w))
+        return y
+
+    with _Phase("experts"):
+        for cell, (k, d, f, held, scored, chunk) in EXPERT_CELLS.items():
+            keys = jax.random.split(jax.random.PRNGKey(k), 5)
+            w = [0.03 * jax.random.normal(key, shape, jnp.bfloat16)
+                 for key, shape in zip(keys, ((held, d, f), (held, d, f),
+                                              (held, f, d)))]
+            for what, t in ((f"{cell} chunk", chunk), (f"{cell} step", 32)):
+                h = jax.random.normal(keys[3], (t, d), jnp.bfloat16)
+                picked, chosen = jax.lax.top_k(
+                    jax.random.uniform(keys[4], (t, scored)), k)
+                gates = picked / jnp.sum(picked, -1, keepdims=True)
+                y, counted = jax.jit(moe.held_experts, static_argnums=(7, 8))(
+                    h, chosen, gates, jnp.ones((t,), bool), *w, 0, held)
+                counted = dict(zip(moe.COUNTERS, np.asarray(counted).tolist()))
+                _check_close(f"held experts, {what} ({t} x {k} pairs, "
+                             f"{counted['held']} held)", y,
+                             jax.jit(masked_loop)(h, chosen, gates, *w), 2e-2)
+                _require(counted["dropped"] == 0, f"{what}: {counted}")
+                c = moe.row_block(t * k)
+                print(f"   {what}: rows_run {counted['rows_run']} / held "
+                      f"{counted['held']} = "
+                      f"{counted['rows_run'] / max(counted['held'], 1):.2f}, "
+                      f"blocks of {c}", flush=True)
+                _require(counted["held"] <= counted["rows_run"]
+                         < counted["held"] + c,
+                         f"{what}: a block behind the last held row ran")
+
+
+# ---------------------------------------------------------------------------
 
 def main():
     import jax
@@ -681,6 +743,7 @@ def main():
     decode_attn = phase_serve()
     phase_latent()
     phase_state()
+    phase_experts()
     multichip_attn = phase_multichip(losses[0])
     print("summary " + json.dumps({
         "phases": _Phase.results,

@@ -12,18 +12,25 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
   bias chooses, never weighs); ``g = scale * s_chosen / sum(s_chosen)``.
   :func:`route_softmax` is the other family's: ``p = softmax(h.W_r)`` over all
   the experts, the k largest, ``g = p_chosen / sum(p_chosen)``; no bias.
-- :func:`held_experts` — dropless: the (token, choice) assignments that fall
-  on held experts are sorted by expert (the others, and the tokens that are
-  not live, sort behind them), the sorted rows go through a grouped product
-  per matrix (``jax.lax.ragged_dot``, which XLA:TPU lowers to a Mosaic
-  grouped matmul driven by the group sizes: rows behind the last group cost
-  no tile), ``ROW_BLOCKS`` blocks of rows one after the other, and each token
-  sums its own rows back, weighted. The static row bound is ``tokens x k``;
-  no capacity factor, nothing dropped.
+- :func:`held_experts` — dropless, and its work follows the rows the held
+  experts have, not the static bound ``tokens x k``: the (token, choice)
+  pairs are sorted by expert (those on experts held elsewhere, and the
+  tokens that are not live, sort behind the held rows); a block of
+  :func:`row_block` sorted rows at a time is gathered and goes through a
+  grouped product per matrix (``jax.lax.ragged_dot``, which XLA:TPU lowers
+  to a Mosaic grouped matmul driven by the group sizes), as many blocks as
+  hold a held row — a trip count read from the data —, each block's
+  float32 rows written once, a row as one contiguous piece, into a buffer
+  nobody clears; and ONE pass back (``_rows_back``, a Pallas kernel): a held
+  row is fetched once, on its way into its token's weighted sum, and a pair
+  no held expert has is not fetched at all. Nothing is dropped: when every
+  pair falls on a held expert every block runs.
 - :func:`expert_layer` — routed part + shared expert, and the counters
   (``COUNTERS``) that ``serve/decode.py`` hangs on its spans.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -32,13 +39,13 @@ from jax import lax
 from . import flash_attention
 
 __all__ = ["route", "route_softmax", "held_experts", "expert_layer",
-           "gated_mlp", "COUNTERS"]
+           "gated_mlp", "row_block", "COUNTERS"]
 
 # per call: live (token, choice) pairs; those on held experts; most tokens on
 # one held expert; held experts with at least one token; held pairs that no
-# row was computed for (must be 0)
-COUNTERS = ("assignments", "held", "load_max", "touched", "dropped")
-ROW_BLOCKS = 4       # blocks the sorted rows are multiplied in (held_experts)
+# row was computed for (must be 0); rows handed to the grouped products
+COUNTERS = ("assignments", "held", "load_max", "touched", "dropped",
+            "rows_run")
 TOKEN_CHUNK = 4096   # most tokens routed and multiplied at a time: bounds the
 #                      sorted rows (tokens x k of them) and their products
 
@@ -88,6 +95,113 @@ def route_softmax(h, router_w, k: int):
     return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
+def row_block(rows: int) -> int:
+    """Rows of a block of the sorted (token, choice) pairs, from their
+    number. Over a prompt chunk: four of the grouped kernel's largest row
+    tile (512), so the products' tiles are full and the last block run holds
+    at most 2,047 rows behind the last held one. Where the rows are fewer: a
+    quarter of them, in whole sublane tiles — the kernel's row tile follows
+    the rows it is handed, and with a decode step's few rows an expert one
+    256-row tile for every touched expert costs more than reading its
+    weights."""
+    return min(4 * 512, -(-rows // 32) * 8)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _unwritten(shape, after, interpret):
+    """A float32 array nobody has written: the block loop's buffer. Rows of
+    it are written a block at a time and a row that was not is never read,
+    so it need not be cleared (clearing it is a pass over ``tokens x k``
+    rows, most of which no held expert has). ``after`` is any value of the
+    call, so that the array is made where it is used. Like ``_rows_back``
+    under a jit of its own: a program's layers share ONE traced and lowered
+    kernel."""
+    import jax.experimental.pallas as pl
+
+    return pl.pallas_call(
+        lambda after_ref, rows_ref: None,
+        out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        interpret=interpret, name="moe_rows")(after)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _rows_back(out, where, weight, interpret):
+    """``y[t] = sum_j weight[t, j] * out[where[t, j]]`` over the pairs with
+    ``where >= 0``, in the order of j, float32. out (N, S, L) is a row of D
+    values as S whole (8, 128) tiles' worth — ONE contiguous piece of the
+    array, where a row of an (N, D) array is D / 128 pieces of 512 bytes —;
+    where, weight (T, k). Returns (T, S, L).
+
+    The kernel takes 32 tokens a grid step: it starts one copy a live pair,
+    out of ``out`` where it lies into a (k, 32) grid of rows in VMEM, waits
+    for as many, and sums each token's k rows times their weights. A pair
+    that no held expert has is not fetched (its slot keeps an older row, or
+    the zeros it started with, and is not summed)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, k = where.shape
+    _, s, lanes = out.shape
+    tt = min(32, t)
+    pad = -t % tt
+    where = jnp.pad(where, ((0, pad), (0, 0)), constant_values=-1)
+    weight = jnp.pad(weight, ((0, pad), (0, 0)))
+
+    def kernel(where_ref, weight_ref, out_ref, y_ref, buf, sem):
+        first = pl.program_id(0) * tt
+
+        @pl.when(first == 0)
+        def _clear():
+            buf[...] = jnp.zeros_like(buf)
+
+        def start(tok, n):
+            for j in range(k):
+                row = where_ref[(first + tok) * k + j]
+
+                @pl.when(row >= 0)
+                def _copy(j=j, row=row):
+                    pltpu.make_async_copy(out_ref.at[row], buf.at[j, tok],
+                                          sem.at[0]).start()
+                n = n + (row >= 0).astype(jnp.int32)
+            return n
+
+        def wait(_, carry):
+            # every copy is one row: any descriptor of that size waits for one
+            pltpu.make_async_copy(out_ref.at[0], buf.at[0, 0],
+                                  sem.at[0]).wait()
+            return carry
+
+        lax.fori_loop(0, lax.fori_loop(0, tt, start, jnp.int32(0)), wait, 0)
+
+        def token(tok, carry):
+            acc = jnp.zeros((s, lanes), jnp.float32)
+            for j in range(k):
+                w = weight_ref[(first + tok) * k + j]
+                acc = acc + jnp.where(w != 0, buf[j, tok], 0.0) * w
+            y_ref[tok] = acc
+            return carry
+
+        lax.fori_loop(0, tt, token, 0)
+
+    y = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=((t + pad) // tt,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tt, s, lanes), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((k, tt, s, lanes), jnp.float32),
+                            pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=jax.ShapeDtypeStruct((t + pad, s, lanes), jnp.float32),
+        # the buffer is cleared once, at the first grid step
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="moe_rows_back",
+    )(where.reshape(-1), weight.reshape(-1), out)
+    return y[:t]
+
+
 def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
                  held: int, offset=0):
     """The held experts' part of the routed sum. h (T, D); chosen, gates
@@ -99,46 +213,54 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
     slice of it would be copied out for the kernel). Returns (y (T, D)
     float32, counters (len(COUNTERS),) int32)."""
     t, k = chosen.shape
+    d = h.shape[1]
     local = chosen - first
     on_held = (local >= 0) & (local < held) & live[:, None]
     group = jnp.where(on_held, local, held).reshape(-1)   # not held: last
-    order = jnp.argsort(group, stable=True)               # sorted by expert
-    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-    rows = h[order // k]                                  # (T*k, D)
+    # sorted by expert: the held experts' rows first, in the experts' order
+    in_order, order = lax.sort_key_val(
+        group, jnp.arange(t * k, dtype=jnp.int32))
+    ends = jnp.searchsorted(in_order, jnp.arange(held, dtype=group.dtype),
+                            side="right").astype(jnp.int32)
+    sizes = jnp.diff(ends, prepend=0)
+    n_held = ends[-1]
 
-    # The sorted rows go through the grouped products ROW_BLOCKS blocks at
-    # a time: the kernel's row tile follows the rows it is handed (up to
-    # 512), and at a decode step's few rows an expert one 256-row tile a
-    # touched expert costs more than reading its weights. A block behind
-    # the last held row has empty groups and costs no tile.
-    n_blocks = ROW_BLOCKS if (t * k) % ROW_BLOCKS == 0 else 1
-    c = t * k // n_blocks
-    ends = jnp.cumsum(sizes)
+    # The work follows the rows the held experts have. A block of c sorted
+    # rows at a time is gathered and goes through the three grouped
+    # products, as many blocks as hold a held row: the trip count is read
+    # from the data, and a block behind the last held row is not run.
+    c = row_block(t * k)
+    most = -(-t * k // c)
+    run = -(-n_held // c)
+    token = jnp.pad(order // k, (0, most * c - t * k))
+    lanes = 128 if d % 128 == 0 else d
+    shape = (d // lanes, lanes)      # a row as whole (8, 128) tiles' worth
 
-    def block(xs):
-        j, rows_j = xs
+    def block(j, out):
         lo = j * c
+        rows = h[lax.dynamic_slice(token, (lo,), (c,))]
         inside = (jnp.clip(ends, lo, lo + c)
                   - jnp.clip(ends - sizes, lo, lo + c))   # of each group
         groups = lax.dynamic_update_slice(
             jnp.zeros((gate_w.shape[0],), jnp.int32), inside, (offset,))
-        a = _grouped(rows_j, gate_w, groups)
-        b = _grouped(rows_j, up_w, groups)
-        return _grouped((jax.nn.silu(a) * b).astype(h.dtype), down_w, groups)
+        a = _grouped(rows, gate_w, groups)
+        b = _grouped(rows, up_w, groups)
+        o = _grouped((jax.nn.silu(a) * b).astype(h.dtype), down_w, groups)
+        return lax.dynamic_update_slice(out, o.reshape((c,) + shape),
+                                        (lo, 0, 0))
 
-    out = lax.map(block, (jnp.arange(n_blocks),
-                          rows.reshape(n_blocks, c, -1))).reshape(t * k, -1)
-    n_held = jnp.sum(sizes)
-    weight = jnp.where(on_held, gates, 0.0).reshape(-1)[order]
-    out = jnp.where((jnp.arange(t * k) < n_held)[:, None],
-                    out * weight[:, None], 0.0)
-    # each token sums its own k rows: the inverse permutation, a gather (the
-    # same sum as a scatter-add, in one fixed order)
-    y = out[jnp.argsort(order)].reshape(t, k, -1).sum(axis=1)
+    out = lax.fori_loop(0, run, block,
+                        _unwritten((most * c,) + shape, ends,
+                                   flash_attention._use_interpret()))
+    # One pass back: a held row is read once, on its way into its token's
+    # sum; a pair no held expert has is not read at all.
+    where = jnp.where(on_held, jnp.argsort(order).reshape(t, k), -1)
+    weight = jnp.where(on_held, gates, 0.0).astype(jnp.float32)
+    y = _rows_back(out, where, weight, flash_attention._use_interpret())
     counters = jnp.stack([
         jnp.sum(live) * k, n_held, jnp.max(sizes), jnp.sum(sizes > 0),
-        jnp.sum(on_held) - jnp.minimum(n_held, t * k)])
-    return y, counters.astype(jnp.int32)
+        jnp.sum(on_held) - jnp.minimum(n_held, t * k), run * c])
+    return y.reshape(t, d), counters.astype(jnp.int32)
 
 
 def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,

@@ -537,6 +537,9 @@ def test_pages_and_slots_return_to_baseline(ending, scheduler):
         assert [next(stream) for _ in range(3)]
         stream.close()
     else:
+        # the programs are built first: a stream waits its deadline and 5 s
+        # more for a token, and a cold engine on a busy host compiles longer
+        assert len(list(scheduler.generate(prompt, max_new_tokens=2))) == 2
         with pytest.raises(DeadlineExceeded):
             for _ in scheduler.generate(prompt, max_new_tokens=40,
                                         deadline_ms=150.0):

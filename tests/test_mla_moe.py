@@ -322,27 +322,93 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert():
     np.testing.assert_allclose(y[36:], shared[36:], atol=1e-6)
 
 
-@pytest.mark.parametrize("knob,value", [("TOKEN_CHUNK", 16),
-                                        ("ROW_BLOCKS", 1), ("ROW_BLOCKS", 8)])
-def test_expert_layer_in_chunks_and_row_blocks_is_the_layer(
-        knob, value, monkeypatch):
-    """Tokens routed ``TOKEN_CHUNK`` at a time, and sorted rows multiplied
-    in 1 or 8 blocks instead of 4 (a group that straddles two blocks is
-    multiplied in both, each its own rows), give the same sum and the same
-    counters."""
-    p, experts, offset = _expert_layer_params(CFG, 1)
-    h = 0.7 * jax.random.normal(jax.random.PRNGKey(4), (48, 64), jnp.float32)
-    live = jnp.arange(48) < 41
-    args = dict(first=2, held=4, k=3, scale=2.5, offset=offset)
-    want, c_want = moe.expert_layer(h, p, experts, live, **args)
-    monkeypatch.setattr(moe, knob, value)
-    got, c_got = moe.expert_layer(h, p, experts, live, **args)
-    np.testing.assert_allclose(got, want, atol=1e-6)
-    c_want, c_got = (dict(zip(moe.COUNTERS, np.asarray(c).tolist()))
-                     for c in (c_want, c_got))
-    for name in ("assignments", "held", "dropped"):
-        assert c_got[name] == c_want[name]
-    assert c_got["load_max"] <= c_want["load_max"]
+def _loaded_pairs(load, t, k, held, first, c):
+    """(chosen (t, k), live (t,)) that put the held experts' rows where the
+    block loop of ``held_experts`` has to decide: ``first .. first + held
+    - 1`` are held, experts ``0 .. first - 1`` are not."""
+    chosen = np.zeros((t, k), np.int32)           # expert 0: held elsewhere
+    live = np.ones((t,), bool)
+    if load == "one_expert":                      # every pair on ONE expert
+        chosen[:] = first + 1
+    elif load in ("under_edge", "over_edge"):     # a held row off a block edge
+        n = c - 1 if load == "under_edge" else c + 1
+        chosen.reshape(-1)[:n] = first + np.arange(n) % held
+    elif load == "partly_live":
+        chosen[:] = (np.arange(t * k).reshape(t, k) * 7) % (first + held)
+        live = np.arange(t) % 3 != 1
+    else:
+        assert load == "no_held_pair"
+    return jnp.asarray(chosen), jnp.asarray(live)
+
+
+@pytest.mark.parametrize("case", [
+    "TOKEN_CHUNK-16",
+    # tokens, k (10 is not a whole sublane tile, 8 is), load
+    "32-10-one_expert", "32-10-no_held_pair", "32-10-under_edge",
+    "32-10-over_edge", "32-10-partly_live",
+    "32-8-one_expert", "32-8-no_held_pair", "32-8-under_edge",
+    "32-8-over_edge", "32-8-partly_live",
+    "200-10-under_edge", "200-10-over_edge", "200-8-partly_live"])
+def test_expert_layer_in_chunks_and_row_blocks_is_the_layer(case, monkeypatch):
+    """Tokens routed ``TOKEN_CHUNK`` at a time give the same sum and the
+    same counters. And the sorted rows multiplied a block at a time, as many
+    blocks as hold a held row, give the reference's plain masked loop
+    (float32, ``benchmark/reference_mla_moe.py``) with nothing dropped and
+    ``rows_run`` = blocks run x block, for the loads that decide the loop:
+    every pair on one held expert (every block runs), none on a held expert
+    (no block runs, ``y`` exactly 0), the held rows one short of and one past
+    a block's edge, tokens that are not live."""
+    if case == "TOKEN_CHUNK-16":
+        p, experts, offset = _expert_layer_params(CFG, 1)
+        h = 0.7 * jax.random.normal(jax.random.PRNGKey(4), (48, 64),
+                                    jnp.float32)
+        live = jnp.arange(48) < 41
+        args = dict(first=2, held=4, k=3, scale=2.5, offset=offset)
+        want, c_want = moe.expert_layer(h, p, experts, live, **args)
+        monkeypatch.setattr(moe, "TOKEN_CHUNK", 16)
+        got, c_got = moe.expert_layer(h, p, experts, live, **args)
+        np.testing.assert_allclose(got, want, atol=1e-6)
+        c_want, c_got = (dict(zip(moe.COUNTERS, np.asarray(c).tolist()))
+                         for c in (c_want, c_got))
+        for name in ("assignments", "held", "dropped"):
+            assert c_got[name] == c_want[name]
+        assert c_got["load_max"] <= c_want["load_max"]
+        assert c_got["rows_run"] >= c_got["held"]
+        return
+    t, k, load = case.split("-")
+    t, k, first, held, d, f = int(t), int(k), 3, 5, 64, 32
+    c = moe.row_block(t * k)
+    assert c < t * k
+    chosen, live = _loaded_pairs(load, t, k, held, first, c)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    h = 0.7 * jax.random.normal(keys[0], (t, d), jnp.float32)
+    gates = jax.random.uniform(keys[1], (t, k), jnp.float32, 0.1, 1.0)
+    w = {name: 0.2 * jax.random.normal(key, (held,) + shape, jnp.float32)
+         for name, key, shape in (("experts_gate_w", keys[2], (d, f)),
+                                  ("experts_up_w", keys[3], (d, f)),
+                                  ("experts_down_w", keys[4], (f, d)))}
+    # the reference's loop over the same pairs: its router is stood in for
+    monkeypatch.setattr(ref, "route", lambda *_: (None, chosen, gates))
+    want = ref.expert_layer({}, w, h, "f32", held=(first, held), shared=False)
+    want = jnp.where(live[:, None], want, 0.0)
+    # the held experts' groups lie behind another layer's in the one array
+    stacked = [jnp.concatenate([jnp.zeros_like(w[name]), w[name]])
+               for name in ("experts_gate_w", "experts_up_w",
+                            "experts_down_w")]
+    y, counted = moe.held_experts(h, chosen, gates, live, *stacked, first,
+                                  held, held)
+    counted = dict(zip(moe.COUNTERS, np.asarray(counted).tolist()))
+    on_held = np.asarray((chosen >= first) & live[:, None])
+    assert counted["held"] == on_held.sum() and counted["dropped"] == 0
+    assert counted["rows_run"] == -(-counted["held"] // c) * c
+    assert counted["rows_run"] == {
+        "one_expert": t * k, "no_held_pair": 0, "under_edge": c,
+        "over_edge": 2 * c}.get(load, counted["rows_run"])
+    if load == "no_held_pair":
+        assert not np.asarray(y).any()
+    else:
+        assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(y, want, atol=1e-5)
 
 
 def test_the_scheduler_hangs_the_models_counters_on_its_spans():
@@ -384,6 +450,9 @@ def test_the_scheduler_hangs_the_models_counters_on_its_spans():
     assert counted["moe.held"] == sum(s["args"]["moe.held"]
                                       for s in prefill + steps)
     assert counted["moe.dropped"] == 0
+    assert counted["moe.rows_run"] == sum(
+        s["args"]["moe.rows_run"] for s in prefill + steps)
+    assert counted["moe.rows_run"] >= counted["moe.held"] > 0
     flat = json.dumps(gauges)
     assert "decode.cache_row_bytes" in flat and "moe.assignments" in flat
 

@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 from mxnet_tpu import obs
-from mxnet_tpu.ops import flash_attention
+from mxnet_tpu.ops import flash_attention, moe
 from mxnet_tpu.serve import DecodeEngine
 
 pytestmark = pytest.mark.decode
@@ -455,7 +455,8 @@ def test_tpu_step_program_with_state_beside_pages(gdn_engine, one_chip,
     ``(65, 2, 256, 1024)`` bfloat16 counts the two PAGED layers only and
     rests row-major; the per-slot state ``(9, 2, 32, 128, 128)`` float32 is
     donated beside it; one Mosaic call a kind of kernel (``gdn_decode`` and
-    ``gqa_decode``, each traced once for both of its layers); and
+    ``gqa_decode``, each traced once for both of its layers, and the two of
+    ``held_experts``, once for all four); and
     ``temp_bytes`` stays under one layer's share of pool plus state: no
     program copies either."""
     engine = gdn_engine
@@ -467,8 +468,10 @@ def test_tpu_step_program_with_state_beside_pages(gdn_engine, one_chip,
     _as_on_a_tpu(monkeypatch)
     lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
     text = lowered.as_text()
-    assert text.count("tpu_custom_call") == 2
-    assert "gdn_decode" in text and "gqa_decode" in text
+    # the held experts' buffer and their rows' way back (``ops/moe.py``) too
+    assert text.count("tpu_custom_call") == 4
+    assert all(name in text for name in ("gdn_decode", "gqa_decode",
+                                         "moe_rows", "moe_rows_back"))
     cost = obs.device.analyze_compiled(compiled)
     held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
     assert cost["temp_bytes"] < held // GDN["num_layers"], cost
@@ -499,3 +502,86 @@ def test_tpu_prefill_program_hands_its_state_to_the_slot(gdn_engine, one_chip,
     assert cost["alias_bytes"] >= held
     lines = _pool_lines(compiled, engine)
     assert not [line for line in lines if " copy(" in line], lines
+
+
+# -- the held experts' rows over a prompt, at the cells' own geometry -----------
+
+def _cell_engine(config, bucket):
+    """The engine of a benchmark cell at its published geometry (the cell's
+    own ``model`` block), two slots of pool: shapes only, nothing is made."""
+    import json
+    import os
+
+    import jax
+
+    from mxnet_tpu.models import gdn_moe, mla_moe
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "configs", config + ".json")
+    with open(path) as f:
+        cfg = json.load(f)["model"]
+    kind = {"gdn_moe_lm": gdn_moe, "mla_moe_lm": mla_moe}[cfg.pop("kind")]
+    shapes = jax.eval_shape(lambda: kind.init_params(cfg, 0))
+    model = (gdn_moe.GDNMoEDecodeModel if kind is gdn_moe
+             else mla_moe.MLAMoEDecodeModel)(cfg, params=shapes)
+    return cfg, DecodeEngine(model, slots=2, page_size=256,
+                             num_pages=2 * bucket // 256 + 1,
+                             prompt_buckets=[bucket])
+
+
+@pytest.mark.parametrize("config,bucket", [("qwen3-next-80b-a3b", 16384),
+                                           ("sarvam-105b", 7168)])
+def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
+                                                      monkeypatch):
+    """The longest prefill of each expert cell compiled for a v5e at the
+    published widths. ``held_experts`` sorts a chunk's ``tokens x k`` pairs
+    by expert and brings the products' float32 rows back to their tokens:
+    no ``f32[tokens x k, D]`` value is copied or laid out again (the
+    ``reshape`` to ``(tokens, k, D)``, k = 10 on the sublane axis, was 7 % of
+    a 16 k prefill), and the two kernels of the way back are in the program.
+
+    What ``held_experts`` moves is bounded where every term can be reckoned
+    from the shapes, in the function compiled alone at the chunk's geometry
+    (the compiler counts a loop's body once and a kernel's operands whole):
+    the three products read the experts' array, 3 x G x D x F x 2 bytes,
+    and all the rest — the sort, the gather in, one block's products, its
+    rows written, the one read of the rows on their way back, ``y`` — stays
+    under TWO passes over ``tokens x k x D`` float32. Weighing, permuting,
+    laying out and summing that array one after the other was over eight."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg, engine = _cell_engine(config, bucket)
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
+    chunks = -(-bucket // moe.TOKEN_CHUNK)
+    t, k, d = bucket // chunks, cfg["experts_per_token"], cfg["hidden_size"]
+    text = compiled.as_text()
+    rows = re.escape(f"f32[{t * k},{d}]")
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rows + r"\S* (reshape|copy|transpose)\(", line)
+             or f"f32[{t},{k},{d}]" in line or f"f32[{k},{t},{d}]" in line]
+    assert not moved, moved
+    assert "moe_rows_back" in text and "moe_rows" in lowered.as_text()
+
+    f, held = cfg["expert_width"], cfg["experts_held"]
+    groups = 2 * held                       # this layer's behind another's
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    alone = jax.jit(
+        lambda h, chosen, gates, live, gate_w, up_w, down_w, offset:
+        moe.held_experts(h, chosen, gates, live, gate_w, up_w, down_w,
+                         cfg["experts_first"], held, offset)).lower(
+        spec((t, d), jnp.bfloat16), spec((t, k), jnp.int32),
+        spec((t, k), jnp.float32), spec((t,), jnp.bool_),
+        spec((groups, d, f), jnp.bfloat16), spec((groups, d, f), jnp.bfloat16),
+        spec((groups, f, d), jnp.bfloat16), spec((), jnp.int32)).compile()
+    cost = obs.device.analyze_compiled(alone)
+    bound = 3 * groups * d * f * 2 + 2 * t * k * d * 4
+    assert cost["bytes_accessed"] < bound, (cost, bound)
+    # and its temporaries are the buffer of rows and little else
+    assert cost["temp_bytes"] < 1.5 * t * k * d * 4, cost
