@@ -18,6 +18,16 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
               bfloat16 values in 640 columns) through ``DecodeEngine``: the paged latent
               kernel against the XLA gather on the chip, the step program's
               temporaries under one layer's latents, nothing dropped;
+- *ssm*       the Mamba-2 + relu^2-experts model (``models.ssm_moe``) at its
+              published widths, four layers, 128 slots, through
+              ``DecodeEngine``, in memory filled with NaN beforehand (the
+              stored experts' zeros are zeros), with ONE slot live (the other
+              127 on the kernel's scratch block): its state after the prefill,
+              then after each step every slot's state and tail against the
+              same step with the one-token update in XLA, then against 304
+              tokens prefilled; ``ssm_decode`` against XLA on random states of
+              64 x 64 x 128 float32; the step's temporaries under one layer's
+              share of pool plus state;
 - *experts*   ``ops.moe.held_experts`` at the two expert cells' prefill-chunk
               and decode-step geometries against a plain masked loop in
               bfloat16 on the chip: the error, nothing dropped, and the
@@ -669,6 +679,202 @@ def phase_state():
                  "copies the pool or the state")
 
 
+# the published widths of NVIDIA-Nemotron-3-Nano-30B-A3B, four layers (one
+# Mamba-2 twice, one expert, one attention), a quarter of the chip's experts
+SSM = {"vocab_size": 4096, "vocab_first": 0, "hidden_size": 2688,
+       "pattern": "MEM*", "num_heads": 32, "num_kv_heads": 2, "head_dim": 128,
+       "ssm_heads": 64, "ssm_head_dim": 64, "ssm_groups": 8, "ssm_state": 128,
+       "conv_width": 4, "chunk_size": 128, "time_step_min": 0.001,
+       "time_step_max": 0.1, "time_step_floor": 0.0001, "expert_width": 1856,
+       "shared_width": 3712, "router_experts": 32, "experts_first": 8,
+       "experts_held": 8, "experts_per_token": 6, "routed_scale": 2.5,
+       "rms_eps": 1e-5, "max_length": 1024}
+
+
+def phase_ssm():
+    """State-space layers beside a 2-head pool at 128 slots: the Mamba-2
+    model through the engine's two programs, and ``ssm_decode`` against XLA
+    over the states the engine holds."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import ssm_moe
+    from mxnet_tpu.ops import mamba2
+    from mxnet_tpu.serve import DecodeEngine
+    from mxnet_tpu.serve.kvcache import SCRATCH_PAGE
+
+    with _Phase("ssm"):
+        slots, page = 128, 256
+        _dirty_memory()
+        model = ssm_moe.SSMMoEDecodeModel(SSM, seed=5)
+        _require(model.params["experts"]["up_w"].shape == (8, 3072, 2048),
+                 "the held experts are not stored in whole tiles of 512")
+        # zeros behind the published 2688 x 1856, whatever the memory held
+        # (NaN is not zero): XLA:TPU clears no buffer that a loop fills
+        for name, (d, f) in (("up_w", (2688, 1856)), ("down_w", (1856, 2688))):
+            w = model.params["experts"][name]
+            _require(not bool(jnp.any(w[:, d:] != 0) | jnp.any(w[:, :, f:] != 0)),
+                     f"experts {name}: not zeros behind {d} x {f}")
+        _require(all(bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+                     for a in jax.tree_util.tree_leaves(model.params)),
+                 "the seeded weights are not all finite")
+        engine = DecodeEngine(model, slots=slots, page_size=page,
+                              num_pages=slots * 4 + 1, prompt_buckets=[512])
+        engine.warmup()
+        _require(_step_text(engine).count(_MOSAIC) == 4,
+                 "the step has not one kernel each of ssm_decode, gqa_decode, "
+                 "moe_rows and moe_rows_back")
+        rng = np.random.RandomState(6)
+        prompt = rng.randint(0, SSM["vocab_size"], 300)
+        engine.pool.alloc(0, 2)
+        table = engine.pool.table(0)
+        tok = engine.prefill(prompt, table, slot=77)
+        _require(engine.last_counters["moe.dropped"] == 0
+                 and engine.last_counters["moe.assignments"] == 300 * 6,
+                 f"prefill counted {engine.last_counters}")
+        held = {name: np.asarray(a[77], np.float32)
+                for name, a in engine.state.items()}
+        for name, a in held.items():
+            print(f"   slot 77 after a 300-token prefill: max |{name}| "
+                  f"{np.abs(a).max():.3e}", flush=True)
+            _require(np.isfinite(a).all() and np.abs(a).max() > 0,
+                     f"the prefill left the slot's {name} zero or not finite")
+        tables = np.full((slots, engine.max_pages), SCRATCH_PAGE, np.int32)
+        tables[77, :len(table)] = table
+        # the step again as a program of its own with the one-token update
+        # in XLA (a gather, a scatter, no donation), fed what the engine's
+        # step is fed: after every step every slot's state and tail are
+        # compared -- the live one, slot 0 (which holds the warm-up
+        # prompt's) and the 126 that hold zeros, all idle slots on the one
+        # scratch block in the kernel
+        ssm_moe.decode_attention_impl, impl = (lambda: "xla",
+                                               ssm_moe.decode_attention_impl)
+        try:
+            # (a function of its own: jax would hand a second jit of the
+            # same method the program it traced for the engine)
+            twin = jax.jit(lambda *args: engine._step_fn(*args)).lower(
+                engine._params, engine.kv, engine.state, engine.last,
+                engine.blank_step())
+        finally:
+            ssm_moe.decode_attention_impl = impl
+        _require(twin.as_text().count(_MOSAIC) == 3,
+                 "the step's twin still holds the ssm_decode kernel")
+        twin = twin.compile()
+        kv_t = jnp.array(engine.kv)
+        state_t = {name: jnp.array(a) for name, a in engine.state.items()}
+
+        def gaps(name):   # per slot (scratch left out), engine against twin
+            a, b = engine.state[name][:slots], state_t[name][:slots]
+            return np.asarray(jnp.max(jnp.abs(
+                a.astype(jnp.float32) - b.astype(jnp.float32)),
+                axis=tuple(range(1, a.ndim))))
+
+        steps = [tok]
+        for i in range(4):
+            packed = engine.blank_step()    # row i: position, length, ...
+            packed[77, 0], packed[77, 1] = 300 + i, 301 + i
+            packed[:slots, 3:] = tables
+            last = np.zeros((slots,), np.int32)
+            last[77] = steps[-1]
+            kv_t, state_t, (toks_t, _) = twin(engine._params, kv_t, state_t,
+                                              jnp.asarray(last), packed)
+            engine.last = jnp.asarray(last)
+            steps.append(int(engine.read(engine.launch_step(packed))[0][77]))
+            _require(engine.last_counters["moe.dropped"] == 0
+                     and engine.last_counters["moe.assignments"] == 6,
+                     f"step counted {engine.last_counters}")
+            for name, tol in (("s", 1e-3), ("tail", 2e-2)):
+                now = np.asarray(engine.state[name][77], np.float32)
+                gap, top = gaps(name), np.abs(now).max()
+                idle = np.delete(gap, 77).max()
+                print(f"   step {i + 1}: slot 77 max |{name}| {top:.3e}, "
+                      f"moved by {np.abs(now - held[name]).max():.3e}; "
+                      f"against the XLA step: slot 77 {gap[77]:.3e}, the "
+                      f"idle slots {idle:.3e}", flush=True)
+                _require(np.isfinite(now).all() and top > 0
+                         and np.abs(now - held[name]).max() > 0,
+                         f"step {i + 1} left slot 77's {name} zero, not "
+                         "finite or as it was")
+                _require(gap[77] <= tol * top,
+                         f"step {i + 1}: slot 77's {name} is {gap[77]:.3e} "
+                         "from the XLA step's")
+                _require(idle == 0, f"step {i + 1} touched an idle slot's "
+                                    f"{name}")
+                held[name] = now
+            _require(steps[-1] == int(toks_t[77]),
+                     f"step {i + 1} sampled {steps[-1]}, its twin "
+                     f"{int(toks_t[77])}")
+        del kv_t, state_t
+        # and the recurrence against the scan over a prompt: the same 304
+        # tokens prefilled into another slot leave the state the four steps
+        # left, and do not touch this one
+        engine.pool.alloc(1, 2)
+        again = engine.prefill(np.concatenate([prompt, steps[:4]]),
+                               engine.pool.table(1), slot=5)
+        _check_close("the state after 300 prefilled + 4 stepped tokens "
+                     "against 304 prefilled",
+                     np.asarray(engine.state["s"][77]),
+                     np.asarray(engine.state["s"][5]), 2e-2)
+        _require(np.array_equal(np.asarray(engine.state["s"][77]), held["s"]),
+                 "a prefill into slot 5 touched slot 77")
+        print(f"   tokens stepped {steps[1:]}, after 304 prefilled {again}",
+              flush=True)
+        engine.pool.free(1)
+        # the one-token update against XLA at the published geometry: every
+        # slot's 64 x 64 x 128 float32 of layer 1, half of the slots live
+        keys = jax.random.split(jax.random.PRNGKey(9), 6)
+        x = jax.random.normal(keys[0], (slots, 64, 64), jnp.float32)
+        delta = jnp.exp(jax.random.uniform(keys[1], (slots, 64), minval=-6.0,
+                                           maxval=0.0))
+        a = -jnp.exp(jax.random.uniform(keys[2], (64,), maxval=2.7))
+        b, c = (jax.random.normal(k, (slots, 8, 128), jnp.float32)
+                for k in keys[3:5])
+        live = jax.random.uniform(keys[5], (slots,)) < 0.5
+        states = jax.random.normal(jax.random.PRNGKey(10),
+                                   engine.state["s"].shape, jnp.float32)
+        step = jax.jit(mamba2.ssm_step, static_argnames=("layer", "impl"))
+        want_y, want_s = step(states, layer=1, x=x, delta=delta, a=a, b=b,
+                              c=c, live=live, impl="xla")
+        _require_mosaic(jax.jit(lambda *t: mamba2.ssm_step(t[0], 1, *t[1:])),
+                        states, x, delta, a, b, c, live)
+        got_y, got_s = step(states, layer=1, x=x, delta=delta, a=a, b=b, c=c,
+                            live=live, impl="pallas")
+        on = np.asarray(live)
+        _check_close("one-token state-space update, outputs (64 x 64)",
+                     np.asarray(got_y)[on], np.asarray(want_y)[on], 1e-4)
+        _check_close("one-token state-space update, states (64 x 64 x 128)",
+                     np.asarray(got_s)[:slots], np.asarray(want_s)[:slots],
+                     1e-4)
+        engine.pool.free(0)
+        engine.pool.assert_baseline()
+        program = engine.stats()["step_program"]
+        share = (engine.kv.nbytes + sum(
+            t.nbytes for t in engine.state.values())) // len(SSM["pattern"])
+        print(f"   pool {engine.kv.shape} {engine.kv.dtype} + state "
+              f"{engine.stats()['state']}: step temp_bytes "
+              f"{program['temp_bytes']}, one layer's share {share} bytes",
+              flush=True)
+        _require(program["temp_bytes"] < share,
+                 f"the step allocates {program['temp_bytes']} bytes: it "
+                 "copies the pool or the state")
+
+
+def _dirty_memory():
+    """Fill what is free of the device's memory with NaN and free it again.
+    A fresh process finds zeros where it never wrote; a long-lived one does
+    not, and a program that reads what it did not write then reads NaN."""
+    import jax
+    import jax.numpy as jnp
+
+    stats = jax.devices()[0].memory_stats() or {}
+    free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+    if free > 0:
+        jnp.full((int(free * 0.9) // 4,), jnp.nan,
+                 jnp.float32).block_until_ready()
+    print(f"   {free * 0.9 / 1e9:.1f} GB of free device memory filled with "
+          "NaN and freed", flush=True)
+
+
 def _step_text(engine):
     return engine._step_jit.lower(engine._params, engine.kv, engine.state,
                                   engine.last, engine.blank_step()).as_text()
@@ -679,10 +885,14 @@ def _step_text(engine):
 # ---------------------------------------------------------------------------
 
 # cell: (k, hidden, expert width, experts held, experts the router scores,
-# tokens of a chunk of its longest prefill): the gated-delta cell's 16,384
-# prefill goes in chunks of 4,096, the latent-attention cell's 7,168 in 3,584
-EXPERT_CELLS = {"gdn": (10, 2048, 512, 128, 512, 4096),
-                "latent": (8, 4096, 2048, 32, 128, 3584)}
+# tokens of a chunk of its longest prefill, slots of its step, whether an
+# expert has a gate matrix): the gated-delta cell's 16,384 prefill goes in
+# chunks of 4,096, the latent-attention cell's 7,168 in 3,584; the
+# state-space cell's experts are two products a row, 2688 x 1856 stored as
+# 3072 x 2048 (whole tiles of 512, zeros behind), and its step has 128 slots
+EXPERT_CELLS = {"gdn": (10, 2048, 512, 128, 512, 4096, 32, True),
+                "latent": (8, 4096, 2048, 32, 128, 3584, 32, True),
+                "ssm": (6, 2688, 1856, 32, 128, 2048, 128, False)}
 
 
 def phase_experts():
@@ -692,29 +902,38 @@ def phase_experts():
     from mxnet_tpu.ops import moe
 
     def masked_loop(h, chosen, gates, gate_w, up_w, down_w):
-        """Every held expert over all tokens, weighed by its gate or 0."""
+        """Every held expert over all tokens, weighed by its gate or 0
+        (``gate_w`` None: experts of two products)."""
         def one(y, xs):
-            i, g, u, dn = xs
+            i, *mats = xs
             w = jnp.sum(jnp.where(chosen == i, gates, 0.0), axis=1)
-            return y + w[:, None] * moe.gated_mlp(h, g, u, dn), None
+            mlp = moe.relu2_mlp if gate_w is None else moe.gated_mlp
+            return y + w[:, None] * mlp(h, *mats), None
+        mats = (up_w, down_w) if gate_w is None else (gate_w, up_w, down_w)
         y, _ = jax.lax.scan(one, jnp.zeros(h.shape, jnp.float32),
-                            (jnp.arange(gate_w.shape[0]), gate_w, up_w,
-                             down_w))
+                            (jnp.arange(up_w.shape[0]),) + mats)
         return y
 
     with _Phase("experts"):
-        for cell, (k, d, f, held, scored, chunk) in EXPERT_CELLS.items():
+        for cell, (k, d, f, held, scored, chunk, step,
+                   gated) in EXPERT_CELLS.items():
             keys = jax.random.split(jax.random.PRNGKey(k), 5)
             w = [0.03 * jax.random.normal(key, shape, jnp.bfloat16)
                  for key, shape in zip(keys, ((held, d, f), (held, d, f),
                                               (held, f, d)))]
-            for what, t in ((f"{cell} chunk", chunk), (f"{cell} step", 32)):
+            stored = w
+            if not gated:         # two matrices, stored in tiles of 512
+                pd, pf = -d % 512, -f % 512
+                stored = [None, jnp.pad(w[1], ((0, 0), (0, pd), (0, pf))),
+                          jnp.pad(w[2], ((0, 0), (0, pf), (0, pd)))]
+                w = [None] + w[1:]
+            for what, t in ((f"{cell} chunk", chunk), (f"{cell} step", step)):
                 h = jax.random.normal(keys[3], (t, d), jnp.bfloat16)
                 picked, chosen = jax.lax.top_k(
                     jax.random.uniform(keys[4], (t, scored)), k)
                 gates = picked / jnp.sum(picked, -1, keepdims=True)
                 y, counted = jax.jit(moe.held_experts, static_argnums=(7, 8))(
-                    h, chosen, gates, jnp.ones((t,), bool), *w, 0, held)
+                    h, chosen, gates, jnp.ones((t,), bool), *stored, 0, held)
                 counted = dict(zip(moe.COUNTERS, np.asarray(counted).tolist()))
                 _check_close(f"held experts, {what} ({t} x {k} pairs, "
                              f"{counted['held']} held)", y,
@@ -743,6 +962,7 @@ def main():
     decode_attn = phase_serve()
     phase_latent()
     phase_state()
+    phase_ssm()
     phase_experts()
     multichip_attn = phase_multichip(losses[0])
     print("summary " + json.dumps({

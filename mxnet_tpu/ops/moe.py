@@ -27,6 +27,12 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
   pair falls on a held expert every block runs.
 - :func:`expert_layer` — routed part + shared expert, and the counters
   (``COUNTERS``) that ``serve/decode.py`` hangs on its spans.
+
+**Two forms of expert**, told apart by the leaves a model hands over: with a
+gate matrix three products, ``(silu(h.W_g) * h.W_u).W_d``
+(:func:`gated_mlp`); without one two, ``relu(h.W_u)^2.W_d``
+(:func:`relu2_mlp`) — for the held experts' grouped products and for the
+shared expert alike.
 """
 from __future__ import annotations
 
@@ -39,7 +45,7 @@ from jax import lax
 from . import flash_attention
 
 __all__ = ["route", "route_softmax", "held_experts", "expert_layer",
-           "gated_mlp", "row_block", "COUNTERS"]
+           "gated_mlp", "relu2_mlp", "row_block", "COUNTERS"]
 
 # per call: live (token, choice) pairs; those on held experts; most tokens on
 # one held expert; held experts with at least one token; held pairs that no
@@ -58,6 +64,11 @@ def gated_mlp(h, gate_w, up_w, down_w):
     """``(silu(h.W_g) * h.W_u).W_d``; float32 accumulation, result f32."""
     a = (jax.nn.silu(_mm(h, gate_w)) * _mm(h, up_w)).astype(h.dtype)
     return _mm(a, down_w)
+
+
+def relu2_mlp(h, up_w, down_w):
+    """``relu(h.W_u)^2.W_d``; float32 accumulation, result f32."""
+    return _mm(jnp.square(jax.nn.relu(_mm(h, up_w))).astype(h.dtype), down_w)
 
 
 def _grouped(rows, w, sizes):
@@ -206,6 +217,10 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
                  held: int, offset=0):
     """The held experts' part of the routed sum. h (T, D); chosen, gates
     (T, k); live (T,) bool. The weights are (G, D, F), (G, D, F), (G, F, D)
+    (``gate_w`` None: experts of two products, ``relu(h.W_u)^2.W_d``;
+    D and F may be STORED wider than ``h`` and the mathematics, zeros
+    behind: XLA:TPU's grouped kernel takes the largest of 512, 256, 128
+    that divides each, and at 128 it runs at a seventh of the memory's rate)
     with the ``held`` experts ``first .. first + held - 1`` at groups
     ``offset .. offset + held - 1`` (every expert layer's experts can lie in
     one array, ``offset`` = layer x held, possibly traced: the grouped kernel
@@ -214,6 +229,9 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
     float32, counters (len(COUNTERS),) int32)."""
     t, k = chosen.shape
     d = h.shape[1]
+    wide = up_w.shape[1]    # the hidden size the experts are STORED at
+    if wide != d:           # zeros behind the model's: the rows follow suit
+        h = jnp.pad(h, ((0, 0), (0, wide - d)))
     local = chosen - first
     on_held = (local >= 0) & (local < held) & live[:, None]
     group = jnp.where(on_held, local, held).reshape(-1)   # not held: last
@@ -233,8 +251,8 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
     most = -(-t * k // c)
     run = -(-n_held // c)
     token = jnp.pad(order // k, (0, most * c - t * k))
-    lanes = 128 if d % 128 == 0 else d
-    shape = (d // lanes, lanes)      # a row as whole (8, 128) tiles' worth
+    lanes = 128 if wide % 128 == 0 else wide
+    shape = (wide // lanes, lanes)   # a row as whole (8, 128) tiles' worth
 
     def block(j, out):
         lo = j * c
@@ -242,10 +260,13 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
         inside = (jnp.clip(ends, lo, lo + c)
                   - jnp.clip(ends - sizes, lo, lo + c))   # of each group
         groups = lax.dynamic_update_slice(
-            jnp.zeros((gate_w.shape[0],), jnp.int32), inside, (offset,))
-        a = _grouped(rows, gate_w, groups)
-        b = _grouped(rows, up_w, groups)
-        o = _grouped((jax.nn.silu(a) * b).astype(h.dtype), down_w, groups)
+            jnp.zeros((up_w.shape[0],), jnp.int32), inside, (offset,))
+        if gate_w is None:
+            a = jnp.square(jax.nn.relu(_grouped(rows, up_w, groups)))
+        else:
+            a = (jax.nn.silu(_grouped(rows, gate_w, groups))
+                 * _grouped(rows, up_w, groups))
+        o = _grouped(a.astype(h.dtype), down_w, groups)
         return lax.dynamic_update_slice(out, o.reshape((c,) + shape),
                                         (lo, 0, 0))
 
@@ -260,7 +281,7 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
     counters = jnp.stack([
         jnp.sum(live) * k, n_held, jnp.max(sizes), jnp.sum(sizes > 0),
         jnp.sum(on_held) - jnp.minimum(n_held, t * k), run * c])
-    return y.reshape(t, d), counters.astype(jnp.int32)
+    return y.reshape(t, wide)[:, :d], counters.astype(jnp.int32)
 
 
 def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
@@ -268,7 +289,9 @@ def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
     """One expert layer over h (T, D): the held experts' routed part plus
     the shared expert. ``p``: ``router_w`` (D, E_all), ``router_b`` (E_all,),
     ``shared_{gate,up,down}_w``; ``experts``: ``gate_w``, ``up_w``,
-    ``down_w`` as :func:`held_experts` takes them, with ``offset``. A layer
+    ``down_w`` as :func:`held_experts` takes them, with ``offset``; a layer
+    with no ``gate_w`` / ``shared_gate_w`` has experts of two products
+    (module docstring). A layer
     with no ``router_b`` routes by :func:`route_softmax` (``scale`` unused);
     one with ``shared_s_w`` (D,) weighs its shared expert by
     ``sigmoid(h.w_s)``. Tokens go at most ``TOKEN_CHUNK`` at a time. Returns
@@ -279,11 +302,14 @@ def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
             chosen, gates = route(hc, p["router_w"], p["router_b"], k, scale)
         else:
             chosen, gates = route_softmax(hc, p["router_w"], k)
-        y, c = held_experts(hc, chosen, gates, lc, experts["gate_w"],
+        y, c = held_experts(hc, chosen, gates, lc, experts.get("gate_w"),
                             experts["up_w"], experts["down_w"], first, held,
                             offset)
-        shared = gated_mlp(hc, p["shared_gate_w"], p["shared_up_w"],
-                           p["shared_down_w"])
+        if "shared_gate_w" in p:
+            shared = gated_mlp(hc, p["shared_gate_w"], p["shared_up_w"],
+                               p["shared_down_w"])
+        else:
+            shared = relu2_mlp(hc, p["shared_up_w"], p["shared_down_w"])
         if "shared_s_w" in p:
             shared = shared * jax.nn.sigmoid(jnp.dot(
                 hc.astype(jnp.float32), p["shared_s_w"].astype(jnp.float32),

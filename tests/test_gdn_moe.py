@@ -528,7 +528,7 @@ def _baseline(sched):
 
 
 @pytest.mark.parametrize("ending", ["finish", "cancel", "deadline"])
-def test_pages_and_slots_return_to_baseline(ending, scheduler):
+def test_pages_and_slots_return_to_baseline(ending, scheduler, monkeypatch):
     prompt = np.arange(5, 25, dtype=np.int32)
     if ending == "finish":
         assert len(list(scheduler.generate(prompt, max_new_tokens=9))) == 9
@@ -540,10 +540,22 @@ def test_pages_and_slots_return_to_baseline(ending, scheduler):
         # the programs are built first: a stream waits its deadline and 5 s
         # more for a token, and a cold engine on a busy host compiles longer
         assert len(list(scheduler.generate(prompt, max_new_tokens=2))) == 2
-        with pytest.raises(DeadlineExceeded):
+        # and a result takes 10 ms to read, so that 40 tokens cannot come
+        # inside the 150 ms however fast the host is: the deadline ends the
+        # stream, not its length (a reader that sleeps does not slow the
+        # scheduler: on an idle host all 40 came in under 150 ms, and the
+        # stream ended by length)
+        read = scheduler.engine.read
+
+        def slow_read(launched):
+            time.sleep(0.01)
+            return read(launched)
+
+        with monkeypatch.context() as slowed, pytest.raises(DeadlineExceeded):
+            slowed.setattr(scheduler.engine, "read", slow_read)
             for _ in scheduler.generate(prompt, max_new_tokens=40,
                                         deadline_ms=150.0):
-                time.sleep(0.03)
+                pass
     assert _baseline(scheduler)
     # and the slot serves the next request as a fresh engine would
     again = list(scheduler.generate(prompt[:11], max_new_tokens=6))

@@ -377,10 +377,10 @@ def gdn_engine():
 def _as_on_a_tpu(monkeypatch):
     """The model picks its kernels as it does on a TPU (the backend here is
     cpu: interpret mode, and the XLA paths under ``auto``)."""
-    from mxnet_tpu.models import gdn_moe
+    from mxnet_tpu.models import gdn_moe, ssm_moe
     from mxnet_tpu.ops import gqa_attention
 
-    for module in (flash_attention, gqa_attention, gdn_moe):
+    for module in (flash_attention, gqa_attention, gdn_moe, ssm_moe):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
     monkeypatch.setenv("MXNET_DECODE_ATTN", "pallas")
 
@@ -585,3 +585,151 @@ def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
     assert cost["bytes_accessed"] < bound, (cost, bound)
     # and its temporaries are the buffer of rows and little else
     assert cost["temp_bytes"] < 1.5 * t * k * d * 4, cost
+
+
+# -- state-space layers beside a 2-head pool, relu^2 experts --------------------
+
+# NVIDIA-Nemotron-3-Nano-30B-A3B's published widths, four layers, few experts
+SSM = {"vocab_size": 1024, "vocab_first": 0, "hidden_size": 2688,
+       "pattern": "MEM*", "num_heads": 32, "num_kv_heads": 2, "head_dim": 128,
+       "ssm_heads": 64, "ssm_head_dim": 64, "ssm_groups": 8, "ssm_state": 128,
+       "conv_width": 4, "chunk_size": 128, "time_step_min": 0.001,
+       "time_step_max": 0.1, "time_step_floor": 0.0001, "expert_width": 1856,
+       "shared_width": 3712, "router_experts": 16, "experts_first": 4,
+       "experts_held": 4, "experts_per_token": 6, "routed_scale": 2.5,
+       "rms_eps": 1e-5, "max_length": 1024}
+
+
+@pytest.fixture(scope="module")
+def ssm_engine():
+    import jax
+
+    from mxnet_tpu.models import ssm_moe
+
+    shapes = jax.eval_shape(lambda: ssm_moe.init_params(SSM, 0))
+    model = ssm_moe.SSMMoEDecodeModel(SSM, params=shapes)
+    return DecodeEngine(model, slots=128, page_size=256, num_pages=128 * 4 + 1,
+                        prompt_buckets=[512])
+
+
+def test_tpu_state_space_kernel_updates_the_state_in_place(one_chip):
+    """The one-token state-space kernel at the cell's own geometry — 129 slots
+    x 8 layers x (8, 128, 512) float32 (64 heads of 64 x 128 a layer; 2.2 GB:
+    shapes only here) — goes through Mosaic under its own name, and the
+    states array is its operand and its result: the donated argument is
+    aliased, and nothing state-sized is allocated."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import mamba2
+
+    def spec(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, layers = 128, 8
+    lowered = jax.jit(
+        lambda s, x, delta, a, bb, c, live: mamba2.ssm_step(
+            s, 5, x, delta, a, bb, c, live), donate_argnums=0).lower(
+        spec((b + 1, layers, 8, 128, 512)), spec((b, 64, 64)), spec((b, 64)),
+        spec((64,)), spec((b, 8, 128)), spec((b, 8, 128)),
+        spec((b,), jnp.bool_))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert "ssm_decode" in lowered.as_text()
+    cost = obs.device.analyze_compiled(lowered.compile())
+    nbytes = (b + 1) * layers * 64 * 64 * 128 * 4
+    assert cost["alias_bytes"] >= nbytes
+    assert cost["temp_bytes"] < nbytes // ((b + 1) * layers)  # under one slot's
+
+
+@pytest.mark.parametrize("tokens", [128, 2048])
+def test_tpu_two_product_experts_read_their_weights_where_they_lie(
+        tokens, one_chip, monkeypatch):
+    """``held_experts`` with no gate matrix at the cell's geometry — a step of
+    128 slots and a 2,048-token prompt, 6 choices, 8 layers' 32 experts of
+    2688 x 1856 STORED 3072 x 2048 in one array (3.2 GB a matrix: shapes
+    only) — compiled for a v5e: two grouped products, each in tiles of 512 x
+    512 of its weights, and no copy of an array of experts. XLA:TPU tiles
+    each size by the largest of 512, 256, 128 that divides it: at the
+    published 1856 (14.5 lane tiles) it copies the whole up-projection array
+    before the loop, every call, and at 2688 x 1920 it runs tiles of 128 x
+    128 (13 % of the memory's rate on the chip, PR 40):
+    ``models/ssm_moe.py`` stores whole tiles of 512."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models.ssm_moe import stored_width
+
+    _as_on_a_tpu(monkeypatch)
+    k, d, held = 6, 2688, 32
+    wide, f = stored_width(d), stored_width(1856)
+    assert (wide, f) == (3072, 2048)
+    groups = 8 * held
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda h, chosen, gates, live, up_w, down_w, offset:
+        moe.held_experts(h, chosen, gates, live, None, up_w, down_w, 0, held,
+                         offset)).lower(
+        spec((tokens, d), jnp.bfloat16), spec((tokens, k), jnp.int32),
+        spec((tokens, k), jnp.float32), spec((tokens,), jnp.bool_),
+        spec((groups, wide, f), jnp.bfloat16),
+        spec((groups, f, wide), jnp.bfloat16), spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 2
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
+    assert tilings and all(t[1:] == ("512", "512") for t in tilings), tilings
+    cost = obs.device.analyze_compiled(compiled)
+    one_matrix = groups * wide * f * 2
+    # the rows' buffer and a block's products, far from a matrix of experts
+    assert cost["temp_bytes"] < one_matrix // 4, cost
+    assert not [line[:160] for line in text.splitlines()
+                if f"bf16[{groups}," in line and " copy(" in line]
+
+
+def test_tpu_step_program_with_state_space_layers_at_128_slots(
+        ssm_engine, one_chip, monkeypatch):
+    """The step of the state-space model compiled for a v5e at 128 slots: the
+    pool ``(513, 1, 256, 512)`` bfloat16 counts the ONE paged layer and
+    rests row-major; the per-slot state ``(129, 2, 8, 128, 512)`` float32 is
+    donated beside it; one Mosaic call a kind of kernel (``ssm_decode``
+    traced once for both of its layers, ``gqa_decode``, and the two of
+    ``held_experts``); ``temp_bytes`` stays under one layer's share of pool
+    plus state; and nothing copies the state."""
+    engine = ssm_engine
+    assert engine.kv.shape == (513, 1, 256, 512) and engine.paged_layers == 1
+    assert engine.cache_row_bytes == 1024
+    assert engine.state["s"].shape == (129, 2, 8, 128, 512)
+    assert engine.state["tail"].shape == (129, 2, 144, 128)   # 3 x 6144
+    assert engine.state_bytes == 2 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert all(name in text for name in ("ssm_decode", "gqa_decode",
+                                         "moe_rows", "moe_rows_back"))
+    cost = obs.device.analyze_compiled(compiled)
+    held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
+    assert cost["temp_bytes"] < held // len(SSM["pattern"]), cost
+    assert cost["alias_bytes"] >= held
+    entry = compiled.as_text()
+    entry = entry[entry.index("\nENTRY ") + 1:]
+    assert not [line[:160] for line in entry.splitlines()
+                if "f32[129,2,8,128,512]" in line and " copy(" in line]
+
+
+def test_tpu_prefill_program_with_state_space_layers(ssm_engine, one_chip,
+                                                     monkeypatch):
+    """A 512-position prefill of the state-space model for a v5e: every layer
+    under one scan that switches on its kind (the grouped flash forward goes
+    through Mosaic once), pool and state donated and written in place."""
+    engine = ssm_engine
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
+    assert lowered.as_text().count("gqa_prefill") >= 1
+    cost = obs.device.analyze_compiled(compiled)
+    held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
+    assert cost["alias_bytes"] >= held
