@@ -117,6 +117,23 @@ def _check_close(what, got, ref, tol):
     _require(err <= tol, f"{what}: rel err {err:.3e} > {tol:.0e}")
 
 
+def _check_mostly_close(what, got, ref, tol, but=0.01):
+    """:func:`_check_close` over all values but the worst ``but`` of them.
+    Two programs that cut one computation differently round differently, and
+    where a near tie of the router then falls the other way one token's
+    values move as far as values go: a fault (a state not carried, a
+    position off by one) moves them all."""
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    _require(np.all(np.isfinite(got)), f"{what}: non-finite values")
+    diff = np.abs(got - ref)
+    scale = max(np.max(np.abs(ref)), 1e-30)
+    err = float(np.quantile(diff, 1.0 - but) / scale)
+    print(f"   {what}: rel err {err:.2e} over {100 * (1 - but):.0f} % of the "
+          f"values (tol {tol:.0e}), {float(diff.max() / scale):.2e} over all",
+          flush=True)
+    _require(err <= tol, f"{what}: rel err {err:.3e} > {tol:.0e}")
+
+
 # ---------------------------------------------------------------------------
 # device
 # ---------------------------------------------------------------------------
@@ -568,7 +585,9 @@ GDN = {"vocab_size": 4096, "hidden_size": 1024, "num_layers": 4,
 
 def phase_state():
     """State beside pages: the gated-delta model through the engine's two
-    programs, and its three kernels against XLA over what the engine wrote."""
+    programs — a prompt in pieces against the same prompt whole, in memory
+    filled with NaN first —, and its three kernels against XLA over what the
+    engine wrote."""
     import jax
     import jax.numpy as jnp
 
@@ -579,37 +598,97 @@ def phase_state():
 
     with _Phase("state"):
         slots, page = 4, 256
+        _dirty_memory()
         model = gdn_moe.GDNMoEDecodeModel(GDN, seed=3)
         # a pool of 538 MB: against the step's other temporaries (a layer's
         # slices of the stacked weights, 65 MB here) one layer's share of it
         # is what a copy of pool or state would stand out from
-        engine = DecodeEngine(model, slots=slots, page_size=page,
-                              num_pages=513, prompt_buckets=[512])
-        engine.warmup()
+
+        def build(buckets):
+            built = DecodeEngine(model, slots=slots, page_size=page,
+                                 num_pages=513, prompt_buckets=buckets)
+            # what slot 1 held before must not reach its prompt: NaN there
+            built.state = {name: jax.jit(lambda a: a.at[1].set(jnp.nan),
+                                         out_shardings=a.format)(a)
+                           for name, a in built.state.items()}
+            built.warmup()
+            return built
+
+        # the model offers ``prefill_from``: the prompt goes in pieces of the
+        # smallest bucket, through ONE prefill program
+        engine = build([256, 512])
+        _require(engine.prefill_piece == 256 and engine.buckets == [256]
+                 and engine.stats()["num_programs"] == 2,
+                 f"pieces of 256 expected: {engine.stats()}")
         # and the two of the held experts (their buffer, their rows' way back)
         _require(_step_text(engine).count(_MOSAIC) == 4,
                  "the step has not one kernel each of gdn_decode, gqa_decode, "
                  "moe_rows and moe_rows_back")
         rng = np.random.RandomState(4)
         prompt = rng.randint(0, GDN["vocab_size"], 300)
-        engine.pool.alloc(0, 2)
-        table = engine.pool.table(0)
-        tok = engine.prefill(prompt, table, slot=1)
-        _require(engine.last_counters["moe.dropped"] == 0
-                 and engine.last_counters["moe.assignments"] == 4 * 300 * 8,
-                 f"prefill counted {engine.last_counters}")
         tables = np.full((slots, engine.max_pages), SCRATCH_PAGE, np.int32)
-        tables[1, :len(table)] = table
         z = np.zeros((slots,), np.int32)
+
+        def serve(eng):
+            """The prompt into slot 1, then 4 steps: (tokens, the slot's
+            state after the prompt, its 300 rows of every paged layer)."""
+            eng.pool.alloc(0, 2)
+            table = eng.pool.table(0)
+            counted = {}
+            for start in range(0, 512, eng.prefill_piece):
+                tok, c = eng.read(eng.launch_prefill(prompt, table, slot=1,
+                                                     start=start))
+                counted = {k: counted.get(k, 0) + v for k, v in c.items()}
+            _require(counted["moe.dropped"] == 0
+                     and counted["moe.assignments"] == 4 * 300 * 8,
+                     f"prefill counted {counted}")
+            after = {name: np.asarray(a[1], np.float32)
+                     for name, a in eng.state.items()}
+            rows = np.asarray(eng.kv[np.asarray(table)], np.float32)
+            rows = np.moveaxis(rows, 1, 0).reshape(rows.shape[1], 512, -1)
+            tables[1, :len(table)] = table
+            toks = [tok]
+            for i in range(4):
+                pos, lengths, last = z.copy(), z.copy(), z.copy()
+                pos[1], lengths[1], last[1] = 300 + i, 301 + i, toks[-1]
+                toks.append(int(eng.step(last, pos, tables, lengths,
+                                         np.zeros((slots,), np.float32))[1]))
+                _require(eng.last_counters["moe.dropped"] == 0
+                         and eng.last_counters["moe.assignments"] == 4 * 8,
+                         f"step counted {eng.last_counters}")
+            return toks, after, rows[:, :300]
+
+        # the same prompt as ONE piece (start 0, nothing before it) ...
+        whole = build([512])
+        want_toks, want_state, want_rows = serve(whole)
+        whole.pool.free(0)
+        del whole
+        # ... and through the model's ``prefill``, the whole-prompt kernel
+        padded = np.zeros((1, 512), np.int32)
+        padded[0, :300] = prompt
+        logits, plain_rows, _, plain = jax.jit(model.prefill)(
+            model.params, jnp.asarray(padded), 300)
         idle = np.asarray(engine.state["s"][0])
-        for i in range(4):
-            pos, lengths, toks = z.copy(), z.copy(), z.copy()
-            pos[1], lengths[1], toks[1] = 300 + i, 301 + i, tok
-            tok = int(engine.step(toks, pos, tables, lengths,
-                                  np.zeros((slots,), np.float32))[1])
-            _require(engine.last_counters["moe.dropped"] == 0
-                     and engine.last_counters["moe.assignments"] == 4 * 8,
-                     f"step counted {engine.last_counters}")
+        toks, after, rows = serve(engine)
+        _require(all(np.all(np.isfinite(a)) for a in after.values())
+                 and np.all(np.isfinite(rows)),
+                 "a prompt in pieces: the slot's state or rows not finite")
+        for what, got, want in (
+                ("state s", after["s"], want_state["s"]),
+                ("tail", after["tail"], want_state["tail"]),
+                ("rows", rows, want_rows),
+                ("state s (prefill)", after["s"], np.asarray(plain["s"])),
+                ("tail (prefill)", after["tail"],
+                 np.asarray(plain["tail"], np.float32)),
+                ("rows (prefill)", rows,
+                 np.asarray(plain_rows[:, :300], np.float32))):
+            _check_mostly_close(
+                f"300 tokens in pieces of 256 against whole: {what}", got,
+                want, 2e-2)
+        _require(toks == want_toks and toks[0] == int(jnp.argmax(logits)),
+                 f"a prompt in pieces served {toks}, whole {want_toks}, "
+                 f"prefill's first {int(jnp.argmax(logits))}")
+        table = engine.pool.table(0)
         _require(np.array_equal(idle, np.asarray(engine.state["s"][0])),
                  "a step touched the state of an idle slot")
         # the paged kernel against the gather, over the rows just written

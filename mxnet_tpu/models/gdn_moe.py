@@ -52,7 +52,8 @@ from jax import lax
 
 from ..ops import gated_delta, moe
 from ..ops.flash_attention import _use_interpret, decode_attention_impl
-from ..ops.gqa_attention import gqa_decode_attention, gqa_flash_attention
+from ..ops.gqa_attention import (gqa_decode_attention, gqa_flash_attention,
+                                 gqa_flash_attention_from)
 from .mla_moe import _normal_bf16
 
 __all__ = ["config_from_hf", "init_params", "GDNMoEDecodeModel"]
@@ -359,44 +360,90 @@ class GDNMoEDecodeModel:
         """tokens (1, S), length () -> (logits at ``length - 1`` (V,)
         float32, rows (paged layers, S, 2 KV D), counters, the sequence's
         state ``{"s", "tail"}`` after ``length`` tokens)."""
+        return self._prompt(params, tokens, 0, length, None, None)
+
+    def prefill_from(self, params, tokens, start, length, prior, state):
+        """A prompt continued: tokens (1, C) are positions ``start .. start +
+        C - 1`` of a prompt of ``length`` (``start`` () int32, a multiple of
+        C; C a multiple of the delta rule's chunk, so that the pieces cut
+        the prompt where the rule's own scan cuts it), ``state`` the
+        sequence's own ``{"s", "tail"}`` as the piece before left it —
+        taken for zeros where ``start`` is 0, whatever it holds —, and
+        ``prior(paged layer) -> (T, 2 KV D)`` the rows of positions 0 .. T -
+        1 as the pool has them, of which those ``< start`` are read. Returns
+        what :meth:`prefill` returns: the logits at ``length - 1`` (of the
+        last piece alone: garbage before), the piece's rows, its counters,
+        the state after the piece."""
+        return self._prompt(params, tokens, start, length, prior, state)
+
+    def _prompt(self, params, tokens, start, length, prior, state):
+        """The body of :meth:`prefill` (``prior`` None: the whole prompt
+        from position 0) and :meth:`prefill_from`."""
         cfg = self.cfg
         s, every = tokens.shape[1], cfg["full_interval"]
         periods = self.layers // every
-        positions = jnp.arange(s)
+        positions = start + jnp.arange(s)
         cos, sin = self._angles(positions)
         live = positions < length
         x = params["embed"][tokens[0]]
         experts = params["experts"]
+        kvh, d = cfg["num_kv_heads"], cfg["head_dim"]
+        s_in = tail_in = None
+        if prior is not None:
+            def own(a, shape):
+                # a slot's leftovers never reach a new prompt: where(), not
+                # a product, so that not even a NaN does
+                return jnp.where(start == 0, 0, a).astype(a.dtype).reshape(
+                    (periods, every - 1) + shape)
+
+            s_in = own(state["s"], state["s"].shape[1:])
+            tail_in = own(state["tail"], self._tail)
+
+        def heads(flat):    # (T, KV D) -> (KV, T, D)
+            return jnp.swapaxes(flat.reshape(-1, kvh, d), 0, 1)
 
         def delta_layer(x, xs):
-            lp, mp, i = xs
+            lp, mp, i, s0, tail0 = xs
             lp = dict(lp, attn_norm=mp["attn_norm"])
             qkv, z, b, a = _delta_inputs(cfg, lp, x)
-            conv, tail = gated_delta.causal_conv(qkv, lp["conv_w"], length)
+            conv, tail = gated_delta.causal_conv(qkv, lp["conv_w"],
+                                                 length - start, tail0)
             q, k, v, g, beta = _delta_heads(cfg, lp, conv, b, a)
             # a position past the prompt writes nothing into the state
             g = jnp.where(live[:, None], g, 0.0)
             beta = jnp.where(live[:, None], beta, 0.0)
-            o, state = gated_delta.delta_rule_chunked(q, k, v, g, beta)
+            o, state = gated_delta.delta_rule_chunked(q, k, v, g, beta, s0)
             x = _delta_output(cfg, lp, x, o, z)
             x, counters = _mlp(cfg, mp, x, live, experts, i)
             return x, (state, tail, counters)
 
         def period(x, xs):
-            dp, fp, mp, j = xs
+            dp, fp, mp, j, s0, tail0 = xs
             first = j * every
             x, (states, tails, c_delta) = lax.scan(
                 delta_layer, x,
                 (dp, {k: w[:every - 1] for k, w in mp.items()},
-                 first + jnp.arange(every - 1)))
+                 first + jnp.arange(every - 1), s0, tail0))
             mp = {k: w[every - 1] for k, w in mp.items()}
             fp = dict(fp, attn_norm=mp["attn_norm"])
             q, gate, row = _full_projections(cfg, fp, x, cos, sin)
-            kvh, d = cfg["num_kv_heads"], cfg["head_dim"]
-            k = jnp.swapaxes(row[:, :kvh * d].reshape(s, kvh, d), 0, 1)
-            v = jnp.swapaxes(row[:, kvh * d:].reshape(s, kvh, d), 0, 1)
-            o = gqa_flash_attention(jnp.moveaxis(q, 0, 2), k, v,
-                                    scale=self._scale())    # (KV, G, S, D)
+            q = jnp.moveaxis(q, 0, 2)
+            k, v = heads(row[:, :kvh * d]), heads(row[:, kvh * d:])
+            if prior is None:
+                o = gqa_flash_attention(q, k, v, scale=self._scale())
+            else:
+                # the pool's rows before the piece (what lies behind them
+                # there is not read: zeros), then the piece's own
+                before = prior(j)
+                before = jnp.where(
+                    (jnp.arange(before.shape[0]) < start)[:, None], before, 0)
+                k = lax.dynamic_update_slice(heads(before[:, :kvh * d]), k,
+                                             (0, start, 0))
+                v = lax.dynamic_update_slice(heads(before[:, kvh * d:]), v,
+                                             (0, start, 0))
+                o = gqa_flash_attention_from(q, k, v, start,
+                                             scale=self._scale())
+            # o (KV, G, S, D)
             x = _full_output(fp, x, jnp.moveaxis(o, 2, 0).reshape(s, -1), gate)
             x, c_full = _mlp(cfg, mp, x, live, experts, first + every - 1)
             counters = moe.merge_counters(
@@ -409,11 +456,12 @@ class GDNMoEDecodeModel:
 
         x, (rows, states, tails, counters) = lax.scan(
             period, x, (by_period(params["delta"], every - 1), params["full"],
-                        by_period(params["moe"], every), jnp.arange(periods)))
+                        by_period(params["moe"], every), jnp.arange(periods),
+                        s_in, tail_in))
         state = {"s": states.reshape((-1,) + states.shape[2:]),
                  "tail": tails.reshape((-1,) + self.state["tail"][0][1:])}
-        return (self._head(params, x[length - 1]), rows,
-                moe.merge_counters(counters), state)
+        return (self._head(params, x[jnp.clip(length - 1 - start, 0, s - 1)]),
+                rows, moe.merge_counters(counters), state)
 
     def step(self, params, tokens, positions, live, attend, state):
         """tokens, positions (B,), live (B,) bool; ``attend(paged layer,

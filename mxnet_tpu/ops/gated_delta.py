@@ -22,8 +22,9 @@ query ``q`` and key ``k`` (key wide), a value ``v`` (value wide), a log decay
   writes one slot's state of one layer once. A slot that is not live reads
   and writes the array's last slot (scratch) instead.
 - :func:`causal_conv` / :func:`causal_conv_step` — ``out_t = sum_j w_j
-  x_(t-W+1+j)`` per channel (no bias), over a sequence from a zero history,
-  and for one new input with the last ``W - 1`` inputs carried as the tail.
+  x_(t-W+1+j)`` per channel (no bias), over a sequence from a zero history
+  (or from the tail an earlier piece of it left), and for one new input with
+  the last ``W - 1`` inputs carried as the tail.
 """
 from __future__ import annotations
 
@@ -40,13 +41,16 @@ CHUNK = 64          # tokens solved together (the family's habit)
 _HI = lax.Precision.HIGHEST
 
 
-def causal_conv(x, w, length=None):
-    """x (S, C) from a zero history, w (W, C): ``(out (S, C) float32, tail
-    (W - 1, C))`` — the tail is the last ``W - 1`` inputs before position
-    ``length`` (default S), zeros where the sequence is shorter: what
-    :func:`causal_conv_step` carries on from."""
+def causal_conv(x, w, length=None, history=None):
+    """x (S, C), w (W, C): ``(out (S, C) float32, tail (W - 1, C))`` — the
+    tail is the last ``W - 1`` inputs before position ``length`` (default S),
+    zeros where the sequence is shorter: what :func:`causal_conv_step`
+    carries on from. ``history`` (W - 1, C): the inputs before ``x`` — the
+    tail an earlier piece of the sequence left —, zeros by default."""
     s, width = x.shape[0], w.shape[0]
-    padded = jnp.concatenate([jnp.zeros((width - 1, x.shape[1]), x.dtype), x])
+    if history is None:
+        history = jnp.zeros((width - 1, x.shape[1]), x.dtype)
+    padded = jnp.concatenate([history.astype(x.dtype), x])
     wf = w.astype(jnp.float32)
     out = sum(wf[j] * lax.dynamic_slice_in_dim(padded, j, s).astype(jnp.float32)
               for j in range(width))

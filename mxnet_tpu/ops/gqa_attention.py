@@ -1,5 +1,5 @@
 """Grouped-KV attention for a serving engine: G query heads read one cached
-head. Two Pallas kernels, in a module of their own so that the kernels of
+head. Three Pallas kernels, in a module of their own so that the kernels of
 ``flash_attention.py`` keep the lines they are cached under.
 
 The query heads of a group are what a decode step lacks everywhere else: an
@@ -14,6 +14,12 @@ meet a page's keys as one ``(G, D) x (D, page)`` product on the MXU.
   blocks wholly above the diagonal are skipped and not fetched. Keys and
   values are streamed, never resident: 16,384 positions of 256-wide heads
   would not fit VMEM the way ``flash_attention``'s forward holds them.
+- :func:`gqa_flash_attention_from` — the same forward for a PIECE of a
+  prompt (kernel ``gqa_prefill_from``, at the end of the module so that the
+  others keep their lines): the query rows are positions ``start .. start +
+  C - 1``, the keys and values every position from 0, ``start`` a prefetched
+  scalar. Same blocks in the same order as the whole forward runs them for
+  these rows, so the same numbers.
 - :func:`gqa_decode_attention` — one query position a sequence against a
   paged pool whose row is FLAT: ``(pages, layers, page, 2 * KV * D)``, a
   position's keys of every cached head, then its values, side by side on the
@@ -38,7 +44,7 @@ from .flash_attention import (_LOG2E, _NEG_INF, _dot_prec, _dotT, _pick_block,
                               _use_interpret, decode_attention_impl)
 
 __all__ = ["gqa_flash_attention", "gqa_decode_attention",
-           "flash_gqa_decode_attention"]
+           "flash_gqa_decode_attention", "gqa_flash_attention_from"]
 
 
 def _online_update(sc, v_blk, m_ref, l_ref, acc_ref, prec):
@@ -243,3 +249,90 @@ def gqa_decode_attention(q, pool, layer, page_table, lengths, scale=None):
         return flash_gqa_decode_attention(q, pool, layer, page_table, lengths,
                                           scale, interpret=_use_interpret())
     return _gqa_decode_xla(q, pool, layer, page_table, lengths, scale)
+
+
+def gqa_flash_attention_from(q, k, v, start, scale=None, block_q=128,
+                             block_k=512):
+    """Causal attention of a piece of one sequence against all of it so far.
+    q (KV, G, C, D): the query rows of positions ``start .. start + C - 1``
+    (``start`` () int32, traced); k, v (KV, T, D): the keys and values of
+    positions ``0 .. T - 1``, those the piece sees (``< start + C``) filled
+    in and every row finite. Returns (KV, G, C, D) in q's dtype. With
+    ``start`` 0 and T = C it is :func:`gqa_flash_attention`."""
+    scale = float(scale) if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    return _gqa_forward_from(
+        jnp.reshape(start, (1,)).astype(jnp.int32), q, k, v, scale,
+        _pick_block(q.shape[2], block_q), _pick_block(k.shape[1], block_k),
+        _use_interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _gqa_forward_from(start, q, k, v, scale, bq, bk, interpret):
+    """:func:`_gqa_forward` with the query rows ``start`` positions down the
+    diagonal: grid (cached heads, query blocks, key blocks over ALL T
+    positions); key blocks above a query block's diagonal — and so every
+    block past ``start + C`` — are skipped and not fetched."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kvh, g, c_len, d = q.shape
+    nq, nk = c_len // bq, k.shape[1] // bk
+    rows = g * bq
+    c = scale * _LOG2E
+    prec = _dot_prec(q.dtype)
+
+    def kernel(start_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+        i, j = pl.program_id(1), pl.program_id(2)
+        first = start_ref[0] + i * bq      # the query block's first position
+
+        @pl.when(j == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def scores():
+            return _dotT(q_ref[...].reshape(rows, d), k_ref[...], prec) * c
+
+        below = (j + 1) * bk - 1 <= first
+
+        @pl.when(below)
+        def _full():
+            _online_update(scores(), v_ref[...], m_ref, l_ref, acc_ref, prec)
+
+        @pl.when(jnp.logical_not(below) & (j <= (first + bq - 1) // bk))
+        def _diagonal():
+            pos = first + lax.broadcasted_iota(jnp.int32, (g, bq, bk), 1)
+            col = j * bk + lax.broadcasted_iota(jnp.int32, (g, bq, bk), 2)
+            sc = jnp.where((pos >= col).reshape(rows, bk), scores(), _NEG_INF)
+            _online_update(sc, v_ref[...], m_ref, l_ref, acc_ref, prec)
+
+        @pl.when(j == nk - 1)
+        def _norm():
+            o_ref[...] = (acc_ref[...] / l_ref[:, 0:1]).reshape(
+                g, bq, d).astype(o_ref.dtype)
+
+    def kv_spec():
+        return pl.BlockSpec((None, bk, d), lambda h, i, j, st: (
+            h, jnp.minimum(j, (st[0] + i * bq + bq - 1) // bk), 0))
+
+    def q_spec():
+        return pl.BlockSpec((None, g, bq, d), lambda h, i, j, st: (h, 0, i, 0))
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(kvh, nq, nk),
+            in_specs=[q_spec(), kv_spec(), kv_spec()],
+            out_specs=q_spec(),
+            scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32),
+                            pltpu.VMEM((rows, 128), jnp.float32),
+                            pltpu.VMEM((rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=interpret,
+        name="gqa_prefill_from",
+    )(start, q, k, v)

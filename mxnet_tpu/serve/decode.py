@@ -11,7 +11,12 @@ shapes serve every generation:
   ``lax.scan`` over their stacked weights: one compiled layer body, a
   program twenty times smaller to compile, cache and load than 24
   unrolled), every layer's K/V written in place into the page pool at the
-  sequence's page ids, first token sampled on-device;
+  sequence's page ids, first token sampled on-device. For a model that can
+  **continue a prompt from a position** (``prefill_from``, below) there is
+  ONE such program instead, of the smallest bucket's size: a prompt goes
+  through it a *piece* at a time, the scheduler launching one piece a turn
+  in front of that turn's decode step, so that no stream waits behind more
+  of another caller's prompt than a piece;
 - a **single decode-step program** (one trace, period): one new position
   for every slot of the fixed continuous batch — embed, per-layer
   paged-KV write + paged attention (ops/flash_attention.decode_attention),
@@ -99,6 +104,9 @@ __all__ = ["DecodeEngine", "DecodeScheduler", "StreamHandle",
 _POS, _LEN, _TEMP, _TABLE = 0, 1, 2, 3
 # a prefill's packed header; the page ids follow, then the padded prompt
 _P_LEN, _P_SLOT, _P_SEED, _P_TEMP, _P_PAGES = 0, 1, 2, 3, 4
+# a piece's: the piece's first position after them, then the sequence's whole
+# page table and the piece's tokens
+_P_START, _P_TABLE = 4, 5
 
 
 def _bits(value, dtype) -> int:
@@ -150,6 +158,22 @@ class DecodeEngine:
         ``state``, ``prefill`` returns the sequence's state (name -> shape
         per slot) as a fourth value, and ``step`` takes the engine's arrays
         as a sixth argument and returns them as a third value.
+        Optionally a fourth function, ``prefill_from(params, tokens (1, C),
+        start, length, prior, state)``: ``prefill`` for the positions
+        ``start .. start + C - 1`` (``start`` () int32, a multiple of C; live
+        where ``< length``, the prompt's whole length) of a prompt whose
+        earlier pieces have been through it — ``state`` the sequence's own
+        state (name -> shape per slot) as the piece before left it, to be
+        taken for zeros where ``start`` is 0 whatever it holds (``{}`` for a
+        model that declares none), and ``prior(paged layer)`` the rows of
+        positions ``0 .. max_prompt - 1`` of that layer as the pool has them
+        through the sequence's page table (those ``< start`` are the earlier
+        pieces'; the rest is not to be read). It returns what ``prefill``
+        returns, the state always; the logits mean something in the last
+        piece only. The engine of such a model feeds EVERY prompt in pieces
+        of its smallest bucket (``prefill_piece``): ``buckets`` is then that
+        one size, a prompt is padded to a multiple of it, and the largest
+        bucket given stays as ``max_prompt``, the longest prompt admitted.
         Anything else is taken for a ``TransformerLM`` (an initialized
         block, or its config dict when ``params`` is given) and wrapped in
         ``models.transformer.TransformerDecodeModel``.
@@ -165,7 +189,9 @@ class DecodeEngine:
         ``MXNET_DECODE_PAGES`` (64).
     prompt_buckets : list of int, optional
         Prefill pad targets; defaults to ``default_decode_buckets`` over
-        the model's max_length (capped at the pool's capacity).
+        the model's max_length (capped at the pool's capacity). For a model
+        with ``prefill_from``: the smallest is the piece, the largest the
+        longest prompt, and all are multiples of the smallest.
     progcache_dir : str, optional
         Explicit persistent program cache; defaults to the process-wide
         ``progcache.cache()`` (``MXNET_PROGCACHE=1``).
@@ -183,7 +209,10 @@ class DecodeEngine:
       (``blank_step``);
     - a prefill's ``(4 + S // page_size + S,)`` for bucket ``S``: prompt
       length, slot, the seed's and the temperature's bits, the page ids,
-      the padded prompt.
+      the padded prompt;
+    - a piece's ``(5 + max_prompt // page_size + C,)``: the same four, then
+      ``start``, the sequence's whole page table (scratch behind its pages)
+      and the piece's tokens.
 
     Both return ``kv``, ``state``, the new ``last`` (the step's tokens; a prefill's
     first token written at its slot) and what the host fetches in one
@@ -225,6 +254,17 @@ class DecodeEngine:
                 raise ValueError(
                     f"prompt bucket {b} must be a positive multiple of "
                     f"page_size={self.page_size} and <= {max_prompt}")
+        # a model that can continue a prompt (``prefill_from``) is fed in
+        # pieces of the smallest bucket: the one pad target there is then
+        self.prefill_piece = (buckets[0] if hasattr(self.model, "prefill_from")
+                              else None)
+        self.max_prompt = buckets[-1]       # the longest prompt admitted
+        if self.prefill_piece:
+            if any(b % buckets[0] for b in buckets):
+                raise ValueError(
+                    f"prompt buckets {buckets} of a model fed in pieces must "
+                    f"be multiples of the smallest")
+            buckets = buckets[:1]
         self.buckets = buckets
         self.pool = PagePool(self.num_pages, self.page_size)
 
@@ -299,6 +339,8 @@ class DecodeEngine:
             type(self.model).__name__, tuple(sorted(self.cfg.items())),
             self.slots, self.page_size, self.num_pages, self.max_pages,
             tuple(self.buckets), self._param_avals)
+        if self.prefill_piece:
+            self._key_statics += ("pieces", self.max_prompt)
         if declared:
             self._key_statics += (self.paged_layers, tuple(
                 (name, shape, str(dt)) for name, (shape, dt)
@@ -328,7 +370,8 @@ class DecodeEngine:
                            in_shardings=(None, pool, held, None, None),
                            out_shardings=(pool, held, None))
 
-        return jit(self._prefill_fn), jit(self._step_fn)
+        return (jit(self._piece_fn if self.prefill_piece
+                    else self._prefill_fn), jit(self._step_fn))
 
     def _fetched(self, toks, counters):
         """What the host reads of a call, as one int32 vector: the sampled
@@ -348,30 +391,52 @@ class DecodeEngine:
         positions scatter garbage rows — masked by ``length`` until each
         slot is overwritten by a decode step."""
         import jax
-        import jax.numpy as jnp
 
         from ..models.transformer import sample_token
 
         n = (packed.shape[0] - _P_PAGES) // (self.page_size + 1)
-        length, slot = packed[_P_LEN], packed[_P_SLOT]
-        seed = jax.lax.bitcast_convert_type(packed[_P_SEED], jnp.uint32)
-        temp = jax.lax.bitcast_convert_type(packed[_P_TEMP], jnp.float32)
+        length, slot, seed, temp = self._header(packed)
         page_ids = packed[_P_PAGES:_P_PAGES + n]
         tokens = packed[_P_PAGES + n:][None]
         logits, rows, counters, *own = self.model.prefill(params, tokens,
                                                           length)
         if state:   # the sequence's own, over whatever its slot held
-            state = {name: jax.lax.dynamic_update_slice(
-                held, own[0][name][None].astype(held.dtype),
-                (slot,) + (0,) * (held.ndim - 1))
-                for name, held in state.items()}
+            state = self._write_state(state, own[0], slot)
+        kv = self._write_pages(kv, rows, page_ids)
+        tok = sample_token(logits[None], jax.random.PRNGKey(seed), temp)
+        return kv, state, (last.at[slot].set(tok[0]),
+                           self._fetched(tok, counters))
 
-        # (L, S) + row → (L, n, page) + row, then page by page —
-        # every layer's rows of the page in one update — in place at the
-        # sequence's page ids. (One scatter would say the same; but for
-        # head counts off the 8-row tile XLA's TPU scatter wants the pool
-        # in a layout of its own, and converts the whole pool there and
-        # back.)
+    @staticmethod
+    def _header(packed):
+        """(length, slot, seed, temperature) of a prefill's packed array."""
+        import jax
+        import jax.numpy as jnp
+
+        return (packed[_P_LEN], packed[_P_SLOT],
+                jax.lax.bitcast_convert_type(packed[_P_SEED], jnp.uint32),
+                jax.lax.bitcast_convert_type(packed[_P_TEMP], jnp.float32))
+
+    @staticmethod
+    def _write_state(state, own, slot):
+        """Every state array with the sequence's ``own`` over ``slot``'s."""
+        import jax
+
+        return {name: jax.lax.dynamic_update_slice(
+            held, own[name][None].astype(held.dtype),
+            (slot,) + (0,) * (held.ndim - 1))
+            for name, held in state.items()}
+
+    def _write_pages(self, kv, rows, page_ids):
+        """``rows`` (L, S) + row → (L, n, page) + row, then page by page —
+        every layer's rows of the page in one update — in place at
+        ``page_ids`` (n). (One scatter would say the same; but for head
+        counts off the 8-row tile XLA's TPU scatter wants the pool in a
+        layout of its own, and converts the whole pool there and back.)"""
+        import jax
+        import jax.numpy as jnp
+
+        n = page_ids.shape[0]
         rows = rows.reshape((self.paged_layers, n, self.page_size)
                             + rows.shape[2:])
 
@@ -381,10 +446,42 @@ class DecodeEngine:
                 kv, jnp.swapaxes(page, 0, 1),
                 (page_ids[j],) + (0,) * (kv.ndim - 1))
 
-        kv = jax.lax.fori_loop(0, n, write_page, kv)
+        return jax.lax.fori_loop(0, n, write_page, kv)
+
+    def _piece_fn(self, params, kv, state, last, packed):
+        """One piece of a prompt, for a model with ``prefill_from``:
+        ``packed`` (class docstring) holds the piece's first position, the
+        sequence's page table and the piece's tokens. The slot's state is
+        read, handed to the model and written back; the rows of the pieces
+        before are read through the page table, the piece's own written into
+        its pages; the last piece's token goes into ``last`` at the slot."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..models.transformer import sample_token
+
+        piece, page = self.prefill_piece, self.page_size
+        n = self.max_prompt // page
+        length, slot, seed, temp = self._header(packed)
+        start = packed[_P_START]
+        table = packed[_P_TABLE:_P_TABLE + n]
+        tokens = packed[_P_TABLE + n:][None]
+
+        def prior(layer):   # positions 0 .. max_prompt - 1 of one layer
+            return kv[table, layer].reshape((n * page,) + kv.shape[3:])
+
+        own = {name: jax.lax.dynamic_index_in_dim(held, slot, keepdims=False)
+               for name, held in state.items()}
+        logits, rows, counters, own = self.model.prefill_from(
+            params, tokens, start, length, prior, own)
+        state = self._write_state(state, own, slot)
+        kv = self._write_pages(kv, rows, jax.lax.dynamic_slice_in_dim(
+            table, start // page, piece // page))
         tok = sample_token(logits[None], jax.random.PRNGKey(seed), temp)
-        return kv, state, (last.at[slot].set(tok[0]),
-                           self._fetched(tok, counters))
+        return kv, state, (
+            last.at[slot].set(jnp.where(start + piece >= length, tok[0],
+                                        last[slot])),
+            self._fetched(tok, counters))
 
     def _step_fn(self, params, kv, state, last, packed):
         """One token for every slot: ``last`` (B,) are the tokens to embed,
@@ -527,21 +624,28 @@ class DecodeEngine:
     # -- host-facing calls ---------------------------------------------
 
     def bucket_for(self, prompt_len: int) -> int:
-        for b in self.buckets:
-            if b >= prompt_len:
-                return b
-        raise RequestRejected(
-            f"prompt length {prompt_len} exceeds max bucket "
-            f"{self.buckets[-1]}")
+        """The positions a prompt of ``prompt_len`` is padded to (and needs
+        pages for): its bucket, or for a model fed in pieces the next
+        multiple of the piece."""
+        if prompt_len > self.max_prompt:
+            raise RequestRejected(
+                f"prompt length {prompt_len} exceeds max bucket "
+                f"{self.max_prompt}")
+        if self.prefill_piece:
+            return -(-prompt_len // self.prefill_piece) * self.prefill_piece
+        return next(b for b in self.buckets if b >= prompt_len)
 
     def launch_prefill(self, tokens: np.ndarray, page_ids: List[int], *,
                        temperature: float = 0.0, seed: int = 0,
-                       slot: int = 0) -> tuple:
+                       slot: int = 0, start: int = 0) -> tuple:
         """Queue the prefill of one prompt into its pages; :meth:`read`
         gives its first sampled token, which the program also writes into
         the last-token vector at ``slot``. ``tokens`` is the unpadded 1-D
         prompt; ``page_ids`` must cover its bucket (``bucket_for(len) //
-        page_size`` pages)."""
+        page_size`` pages). For a model fed in pieces (``prefill_piece``)
+        ONE call queues ONE piece, the positions from ``start`` (a multiple
+        of the piece; the pieces of a prompt in order, into the same slot),
+        and only the last piece's token means anything."""
         tokens = np.asarray(tokens, np.uint32).astype(np.int32)
         n = int(tokens.shape[0])
         bucket = self.bucket_for(n)
@@ -550,9 +654,26 @@ class DecodeEngine:
             raise ServeError(
                 f"prefill needs {pages} pages for bucket {bucket}, got "
                 f"{len(page_ids)}")
+        header = (n, slot, _bits(seed, np.uint32),
+                  _bits(temperature, np.float32))
+        piece = self.prefill_piece
+        if start and not piece:
+            raise ServeError("this engine's model is prefilled whole")
+        if piece:
+            if start % piece or not 0 <= start < bucket:
+                raise ServeError(f"no piece of {piece} starts at {start} in "
+                                 f"a prompt padded to {bucket}")
+            table = _P_TABLE + self.max_prompt // self.page_size
+            packed = np.zeros((table + piece,), np.int32)
+            packed[:_P_TABLE] = header + (start,)
+            packed[_P_TABLE:table] = SCRATCH_PAGE
+            packed[_P_TABLE:_P_TABLE + pages] = page_ids
+            part = tokens[start:start + piece]
+            packed[table:table + len(part)] = part
+            return self._launch("prefill", f"prefill{piece}",
+                                self._prefill_jit, packed)
         packed = np.zeros((_P_PAGES + pages + bucket,), np.int32)
-        packed[:_P_PAGES] = (n, slot, _bits(seed, np.uint32),
-                             _bits(temperature, np.float32))
+        packed[:_P_PAGES] = header
         packed[_P_PAGES:_P_PAGES + pages] = page_ids
         packed[_P_PAGES + pages:_P_PAGES + pages + n] = tokens
         return self._launch("prefill", f"prefill{bucket}", self._prefill_jit,
@@ -562,10 +683,15 @@ class DecodeEngine:
                 temperature: float = 0.0, seed: int = 0,
                 slot: int = 0) -> int:
         """:meth:`launch_prefill` and :meth:`read` together: prefill one
-        prompt into its pages and return the first sampled token."""
-        return self.read(self.launch_prefill(
-            tokens, page_ids, temperature=temperature, seed=seed,
-            slot=slot))[0]
+        prompt into its pages — every piece of it, for a model fed in
+        pieces — and return the first sampled token."""
+        piece = self.prefill_piece
+        for start in (range(0, self.bucket_for(len(tokens)), piece) if piece
+                      else (0,)):
+            tok = self.read(self.launch_prefill(
+                tokens, page_ids, temperature=temperature, seed=seed,
+                slot=slot, start=start))[0]
+        return tok
 
     def blank_step(self) -> np.ndarray:
         """A step's packed argument with every slot idle (length 0, its
@@ -623,6 +749,10 @@ class DecodeEngine:
                                  "dtype": str(a.dtype)}
                           for name, a in self.state.items()},
                 "buckets": list(self.buckets),
+                # the positions of one prefill call where a prompt is fed
+                # in pieces (then the one bucket), else None
+                "prefill_piece": self.prefill_piece,
+                "max_prompt": self.max_prompt,
                 "num_programs": len(self._programs),
                 "executions": self.exec_count,
                 "compiles": len(self.compile_log),
@@ -689,12 +819,13 @@ class StreamHandle:
 class _Gen:
     """One generation's scheduler-side state. ``launched`` counts the
     tokens asked of the device (the prefill's and each step's), ``produced``
-    those that have come back and gone out."""
+    those that have come back and gone out; ``fed`` the prompt's positions
+    handed to the device so far, where it goes in pieces."""
 
     __slots__ = ("seq", "tokens", "prompt_len", "max_new", "deadline",
                  "priority", "temperature", "temp_bits", "ctx", "handle",
                  "slot", "launched", "produced", "retired", "t_submit",
-                 "t_admit", "seed")
+                 "t_admit", "seed", "fed")
 
     def __init__(self, seq, tokens, max_new, deadline, priority,
                  temperature, handle, seed):
@@ -710,6 +841,7 @@ class _Gen:
         self.handle = handle
         self.slot = -1
         self.launched = 0
+        self.fed = 0
         self.produced = 0
         self.retired = False
         self.t_submit = time.monotonic()
@@ -720,7 +852,8 @@ class _Gen:
 class _InFlight:
     """One launched program call whose result the scheduler has yet to
     read, with the generations it yields a token for (``who``: a prefill's
-    one, a step's several) and its span's attributes."""
+    one — none for a piece that is not its prompt's last —, a step's
+    several) and its span's attributes."""
 
     __slots__ = ("kind", "launched", "t_launch", "who", "attrs")
 
@@ -744,6 +877,15 @@ class DecodeScheduler:
     pages. Requests therefore join and leave the running batch between
     steps, never mid-program.
 
+    **A prompt in pieces.** Where the engine feeds its model a prompt in
+    pieces (``engine.prefill_piece``: the model can continue a prompt), an
+    admitted generation — slot and ALL of its prompt's pages taken, as ever —
+    waits in a FIFO of *prefilling* generations, and a turn launches at most
+    ONE piece, of the oldest, in front of its step: no stream waits behind
+    more than a piece. A prefilling slot rides the steps idle; its last
+    piece makes it a decoding slot. Cancel and deadline are looked at before
+    each piece.
+
     **The host's turn runs under the device's step**: step *n+1* is
     launched before step *n*'s tokens are read, because nothing it is built
     from needs them — the tokens are fed back on the device, and a slot's
@@ -758,7 +900,8 @@ class DecodeScheduler:
 
     The engine behind it is a :class:`DecodeEngine`, or anything with its
     ``slots``/``max_pages``/``max_length``/``pool``/``bucket_for``,
-    ``blank_step``, ``launch_prefill``, ``launch_step`` and ``read``.
+    ``blank_step``, ``launch_prefill``, ``launch_step`` and ``read`` (and,
+    with a ``prefill_piece``, a ``launch_prefill`` that takes ``start``).
     """
 
     def __init__(self, engine: DecodeEngine, *, max_queue: int = 64,
@@ -794,6 +937,12 @@ class DecodeScheduler:
         self.launched_ahead = 0     # ... while another step was in flight
         self.dropped_speculative = 0
         self.tokens_out = 0
+        self.admitted = 0
+        self.prefill_pieces = 0     # prefill calls: one an admission, or
+        #                             one a piece where prompts go in pieces
+        self._piece = getattr(engine, "prefill_piece", None)
+        # admitted, their prompts not yet all on the device; oldest first
+        self._prefilling: collections.deque = collections.deque()
         self.counted: Dict[str, int] = {}   # the model's counters, summed
         # launched and not yet read, oldest first; between turns at most
         # the one step launched last
@@ -814,10 +963,13 @@ class DecodeScheduler:
     def _active(self) -> int:
         return sum(1 for g in self._slots if g is not None)
 
-    def _shed(self, why: str, exc: ServeError):
+    def _count_shed(self, why: str):
         self.shed += 1
         self.shed_by_reason[why] += 1
         obs.inc(f"decode.shed_{why}")
+
+    def _shed(self, why: str, exc: ServeError):
+        self._count_shed(why)
         obs.tail.note(shed=why)
         raise exc
 
@@ -912,8 +1064,9 @@ class DecodeScheduler:
             self._abort_all(ServeError("decode scheduler stopped"))
 
     def step(self) -> int:
-        """One continuous-batch turn: admit → launch the next step → read
-        what was launched before it → distribute → retire. Returns the
+        """One continuous-batch turn: admit → launch one piece of a prompt
+        (where prompts go in pieces) → launch the next step → read what was
+        launched before it → distribute → retire. Returns the
         number of slots the launched step covers. This is the decode data
         plane's hot root (analysis/dataplane.py)."""
         with obs.trace.span("decode.turn") as turn:
@@ -924,9 +1077,11 @@ class DecodeScheduler:
     def _turn(self):
         """The body of :meth:`step`; returns (joined, active, left)."""
         joined = self._admit(time.monotonic())
+        if self._prefilling:
+            self._feed()
         active = self._launch_step(joined)
         # everything older than the step just launched: the last turn's
-        # step first, then this turn's prefills
+        # step first, then this turn's prefills (or its one piece)
         left = 0
         while len(self._inflight) > (1 if active else 0):
             left += self._receive(self._inflight[0])
@@ -938,7 +1093,8 @@ class DecodeScheduler:
         token still to ask for; returns how many. Nothing here waits for a
         token: a slot's position follows from the count of tokens launched
         for it."""
-        active = [g for g in self._slots if g is not None]
+        # (a slot whose prompt is still going in rides the step idle)
+        active = [g for g in self._slots if g is not None and g.launched]
         if not active:
             return 0
         eng = self.engine
@@ -953,9 +1109,7 @@ class DecodeScheduler:
                     # shedding a RUNNING stream, not a queued one: freeing
                     # its pages is what lets the rest of the batch keep
                     # stepping
-                    self.shed += 1
-                    self.shed_by_reason["pages"] += 1
-                    obs.inc("decode.shed_pages")
+                    self._count_shed("pages")
                     self._retire(g, "pages", error=e)
                     continue
                 row = packed[g.slot]
@@ -1001,7 +1155,7 @@ class DecodeScheduler:
         if flight.kind == "prefill":
             obs.trace.complete("decode.prefill", flight.t_launch,
                                now - flight.t_launch, **attrs)
-            return int(self._token(flight.who[0], out, now))
+            return sum(self._token(g, out, now) for g in flight.who)
         left = 0
         with obs.trace.span("decode.distribute") as distribute:
             for g in flight.who:
@@ -1079,9 +1233,7 @@ class DecodeScheduler:
                         continue
                     if g.deadline is not None and now >= g.deadline:
                         lane.pop(0)
-                        self.shed += 1
-                        self.shed_by_reason["deadline"] += 1
-                        obs.inc("decode.shed_deadline")
+                        self._count_shed("deadline")
                         g.handle._emit(("error", DeadlineExceeded(
                             "deadline expired in decode queue")))
                         continue
@@ -1103,15 +1255,56 @@ class DecodeScheduler:
             obs.trace.complete("decode.queue_wait", g.t_submit,
                               g.t_admit - g.t_submit, ctx=g.ctx,
                               priority=g.priority)
-            launched = self.engine.launch_prefill(
-                g.tokens, self.engine.pool.table(g.seq),
-                temperature=g.temperature, seed=g.seed, slot=g.slot)
+            if self._piece:
+                self._prefilling.append(g)
+            else:
+                self._launch_prefill(g, bucket, g.t_admit)
+        if admitted:
+            self.admitted += len(admitted)
+            obs.inc("decode.admitted", len(admitted))
+        return len(admitted)
+
+    def _launch_prefill(self, g: _Gen, bucket: int, t_launch: float):
+        """Launch ``g``'s prompt, or where prompts go in pieces the next
+        piece of it (``bucket``: the positions of the call); the call that
+        holds the prompt's end makes ``g`` a decoding slot."""
+        start = g.fed
+        where = {"start": start} if self._piece else {}
+        launched = self.engine.launch_prefill(
+            g.tokens, self.engine.pool.table(g.seq),
+            temperature=g.temperature, seed=g.seed, slot=g.slot, **where)
+        g.fed = start + bucket
+        self.prefill_pieces += 1
+        obs.inc("decode.prefill_pieces")
+        last = g.fed >= g.prompt_len
+        if last:
             g.launched = 1
             self._release_if_last(g)
-            self._inflight.append(_InFlight(
-                "prefill", launched, g.t_admit, [g], bucket=bucket,
-                prompt_len=g.prompt_len))
-        return len(admitted)
+        self._inflight.append(_InFlight(
+            "prefill", launched, t_launch, [g] if last else [], bucket=bucket,
+            prompt_len=g.prompt_len, start=start,
+            pieces=-(-g.prompt_len // bucket)))
+
+    def _feed(self):
+        """One piece of the oldest prefilling generation that is still
+        wanted; cancel and deadline are looked at here, a piece apart, as a
+        decoding stream's are a token apart."""
+        while self._prefilling:
+            g = self._prefilling[0]
+            now = time.monotonic()
+            if g.handle.cancelled() and not g.retired:
+                self._retire(g, "cancelled")
+            elif (g.deadline is not None and now >= g.deadline
+                  and not g.retired):
+                self._count_shed("deadline")
+                self._retire(g, "deadline", error=DeadlineExceeded(
+                    f"deadline expired {g.fed} positions into the prompt"))
+            if not g.retired:
+                self._launch_prefill(g, self._piece, now)
+                if g.launched:      # that was its last piece
+                    self._prefilling.popleft()
+                return
+            self._prefilling.popleft()
 
     def _release_if_last(self, g: _Gen):
         """A stream's last token by ``max_new_tokens`` or the model's length
@@ -1152,9 +1345,7 @@ class DecodeScheduler:
             self._retire(g, "overflow")
             return True
         if g.deadline is not None and now >= g.deadline:
-            self.shed_by_reason["deadline"] += 1
-            self.shed += 1
-            obs.inc("decode.shed_deadline")
+            self._count_shed("deadline")
             self._retire(g, "deadline", error=DeadlineExceeded(
                 f"deadline expired after {g.produced} tokens"))
             return True
@@ -1195,6 +1386,7 @@ class DecodeScheduler:
         # resident, or released with its last token still on its way
         inflight = [g for f in self._inflight for g in f.who]
         self._inflight.clear()
+        self._prefilling.clear()
         for g in [g for g in self._slots if g is not None] + inflight:
             if not g.retired:
                 self._retire(g, "aborted", error=exc)
@@ -1253,6 +1445,11 @@ class DecodeScheduler:
                                          / max(1, self.steps_launched)),
                 "dropped_speculative": self.dropped_speculative,
                 "tokens_out": self.tokens_out,
+                "admitted": self.admitted,
+                # prefill calls, and the positions of one where prompts go
+                # in pieces (None: a prompt is one call)
+                "prefill_pieces": self.prefill_pieces,
+                "prefill_piece": self._piece,
                 "queued": self._qsize(),
                 "active": self._active(),
                 "occupancy": self._occupancy,

@@ -72,6 +72,11 @@ def _clean():
 
 @pytest.fixture(scope="module")
 def lm():
+    # the same weights in every run (conftest's per-test seed is drawn from
+    # the host's entropy, and this fixture is built inside whichever test
+    # asks for it first): what a test below reads of the served tokens must
+    # not change from run to run
+    mx.random.seed(20260)
     model = transformer_lm(vocab_size=97, units=32, hidden_size=64,
                            num_layers=2, num_heads=4, max_length=64,
                            dropout=0.0)
@@ -493,8 +498,11 @@ def test_eos_on_the_real_engine_frees_pages_the_next_stream_reuses(engine):
         want_other = list(plain.generate(other, max_new_tokens=6))
     finally:
         plain.close()
-    # the first token that is new to the stream ends it
-    cut = next(i for i in range(1, 6) if want[i] not in want[:i])
+    # the first token that is new to the stream ends it; a model that only
+    # repeats itself (3 random initialisations of 200 serve 5, 5, 5, ...:
+    # the StopIteration that failed this test under the driver) ends at its
+    # first token, behind which a step is in flight just the same
+    cut = next((i for i in range(1, 6) if want[i] not in want[:i]), 0)
     sched = DecodeScheduler(engine, eos_id=want[cut])
     try:
         assert list(sched.generate(prompt, max_new_tokens=6)) == \
@@ -581,6 +589,190 @@ def test_page_exhaustion_with_a_step_in_flight_sheds_the_stream_that_grows():
         st = sched.stats()
         assert st["shed_by_reason"]["pages"] == st["shed"] == 1
         assert st["dropped_speculative"] == 1
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+# -- a prompt goes in pieces, one a turn, between the steps ------------------
+
+class _FakePieceEngine(_FakeDecodeEngine):
+    """The stub as an engine whose model can continue a prompt: a prompt is
+    padded to a multiple of ``piece`` and ``launch_prefill`` queues ONE piece
+    of it; the last piece's token is the prompt's. ``calls`` says which."""
+
+    def __init__(self, piece=4, **kw):
+        super().__init__(**kw)
+        self.prefill_piece = piece
+        self.max_prompt = self.buckets[-1]
+        self.buckets = [piece]
+        self.pieces = []        # (slot, start) of every piece launched
+
+    def bucket_for(self, n):
+        if n > self.max_prompt:
+            raise RequestRejected(f"prompt length {n} exceeds max bucket")
+        return -(-n // self.prefill_piece) * self.prefill_piece
+
+    def launch_prefill(self, tokens, page_ids, *, temperature=0.0, seed=0,
+                       slot=0, start=0):
+        assert start % self.prefill_piece == 0 and start < len(tokens)
+        assert len(page_ids) == self.bucket_for(len(tokens)) // self.page_size
+        self.pieces.append((slot, start))
+        if start + self.prefill_piece < len(tokens):
+            self.calls.append("piece")
+            return self._queued(-1)
+        return super().launch_prefill(tokens, page_ids, slot=slot)
+
+
+def _events(handle, timeout=5):
+    events = []
+    while not events or events[-1][0] == "token":
+        events.append(handle.get(timeout=timeout))
+    return events
+
+
+def test_a_prompt_goes_one_piece_a_turn_between_the_steps():
+    """A 14-token prompt in pieces of 4 beside a decoding stream: four
+    pieces, never two of them between two steps; the prefilling slot rides
+    those steps idle and joins the batch with its last piece; the decoding
+    stream's tokens are what it gets alone."""
+    eng = _FakePieceEngine(piece=4, slots=2, delay=0.003)
+    sched = DecodeScheduler(eng)
+    obs.enable()
+    try:
+        a = sched.submit([5], max_new_tokens=40)
+        _wait(lambda: sched.stats()["steps"] >= 2, msg="A decoding")
+        prompt = list(range(1, 15))
+        b = sched.submit(prompt, max_new_tokens=6)
+        got_b, got_a = _events(b), _events(a)
+        assert [ev[1] for ev in got_a[:-1]] == _fake_seq([5], 40)
+        assert [ev[1] for ev in got_b[:-1]] == _fake_seq(prompt, 6)
+        assert sched.drain(timeout=5)
+        assert eng.pieces == [(0, 0)] + [(1, s) for s in (0, 4, 8, 12)]
+        launched = [c for c in eng.calls if c != "read"]
+        first = launched.index("piece")
+        between = "".join(c[0] for c in launched[first:])
+        # p(iece) s(tep) p s p s p(refill, the last piece) s ...
+        assert between.startswith("pspspsps") and "pp" not in between
+        # B's slot idle in the steps between its pieces, live after the last
+        lengths = [int(p[1, 1]) for p in eng.steps]
+        joined = lengths.index(15)
+        assert lengths[:joined] == [0] * joined and joined >= 3 + 2
+        assert lengths[joined:joined + 5] == [15, 16, 17, 18, 19]
+        st = sched.stats()
+        assert st["prefill_piece"] == 4 and st["admitted"] == 2
+        assert st["prefill_pieces"] == 5
+        counters = obs.metrics.snapshot()["counters"]
+        assert counters["decode.prefill_pieces"] == 5
+        assert counters["decode.admitted"] == 2
+        spans = [s for s in obs.trace.drain() if s["name"] == "decode.prefill"]
+        assert [(s["args"]["start"], s["args"]["pieces"], s["args"]["bucket"],
+                 s["args"]["prompt_len"]) for s in spans] == [
+            (0, 1, 4, 1)] + [(s, 4, 4, 14) for s in (0, 4, 8, 12)]
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+def test_pieces_go_one_a_turn_with_no_stream_decoding_and_in_admission_order():
+    """Two prompts admitted in one turn: the older one's pieces first, then
+    the other's, every turn one piece — with nothing decoding the turn is the
+    piece alone."""
+    eng = _FakePieceEngine(piece=4, slots=2, delay=0.002)
+    sched = DecodeScheduler(eng)
+    try:
+        a = sched.submit(list(range(1, 10)), max_new_tokens=6)
+        b = sched.submit(list(range(2, 8)), max_new_tokens=2)
+        got_a, got_b = _events(a), _events(b)
+        assert [ev[1] for ev in got_a[:-1]] == _fake_seq(range(1, 10), 6)
+        assert [ev[1] for ev in got_b[:-1]] == _fake_seq(range(2, 8), 2)
+        assert sched.drain(timeout=5)
+        slot_a = eng.pieces[0][0]
+        assert eng.pieces == [(slot_a, 0), (slot_a, 4), (slot_a, 8),
+                              (1 - slot_a, 0), (1 - slot_a, 4)]
+        # nothing decoding: each piece is read before the next is launched;
+        # A's last piece makes it a decoding slot, and B's pieces then go
+        # one in front of each of A's steps
+        assert eng.calls[:6] == ["piece", "read", "piece", "read",
+                                 "prefill", "step"]
+        launched = "".join(c[0] for c in eng.calls if c != "read")
+        assert launched.startswith("pppspsps"), launched
+        assert sched.stats()["prefill_pieces"] == 5
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+@pytest.mark.parametrize("reason", ["cancelled", "deadline", "backpressure",
+                                    "close"])
+def test_exits_in_mid_prefill_return_slot_and_pages(reason):
+    """A generation that leaves while its prompt is still going in — hung
+    up, past its deadline, its first token refused, the scheduler closed —
+    goes through ``_release`` like every other: slot and pages back, the
+    pieces left are never launched, the stream beside it is not disturbed."""
+    eng = _FakePieceEngine(piece=4, slots=2, delay=0.02, num_pages=64,
+                           max_length=128)
+    sched = DecodeScheduler(eng)
+    try:
+        beside = sched.submit([7], max_new_tokens=30)
+        _wait(lambda: sched.stats()["steps"] >= 1, msg="a stream decoding")
+        prompt = list(range(1, 101))        # 25 pieces: a second of them
+        h = sched.submit(prompt, max_new_tokens=5,
+                         deadline_ms=150 if reason == "deadline" else None)
+        if reason == "backpressure":
+            emit = h._emit
+            h._emit = lambda ev: ev[0] != "token" and emit(ev)
+        _wait(lambda: len(eng.pieces) >= 3, msg="the prompt going in")
+        assert eng.pool.used() >= 25
+        if reason == "cancelled":
+            h.cancel()
+        if reason == "close":
+            sched.close()
+            last = h.get(timeout=5)
+            assert last[0] == "error" and isinstance(last[1], ServeError)
+            assert len(eng.pieces) < 26
+            eng.pool.assert_baseline()
+            return
+        last = _events(h)[-1]
+        if reason == "cancelled":
+            assert last == ("end", "cancelled", 0)
+        elif reason == "deadline":
+            assert isinstance(last[1], DeadlineExceeded)
+        else:
+            assert isinstance(last[1], RequestRejected)
+        if reason != "backpressure":     # the rest of the prompt never went
+            assert len(eng.pieces) < 26
+        # the slot serves the next request, beside the stream still running
+        assert list(sched.generate([9, 9], max_new_tokens=3)) == \
+            _fake_seq([9, 9], 3)
+        assert [ev[1] for ev in _events(beside)[:-1]] == _fake_seq([7], 30)
+        assert sched.drain(timeout=5)
+        st = sched.stats()
+        assert st["shed"] == sum(st["shed_by_reason"].values())
+        assert st["shed_by_reason"]["deadline"] == (reason == "deadline")
+        assert st["cancelled"] == (reason == "cancelled")
+        eng.pool.assert_baseline()
+    finally:
+        sched.close()
+
+
+def test_a_model_prefilled_whole_launches_the_calls_it_launched_before():
+    """An engine that offers no ``prefill_piece`` is handed every prompt in
+    one ``launch_prefill`` with the arguments it always had (the stub's takes
+    no ``start``), in the turn it is admitted."""
+    eng = _FakeDecodeEngine(slots=2, delay=0.003)
+    sched = DecodeScheduler(eng)
+    try:
+        a = sched.submit([5], max_new_tokens=12)
+        _wait(lambda: sched.stats()["steps"] >= 2, msg="A decoding")
+        b = sched.submit(list(range(1, 15)), max_new_tokens=4)
+        assert [ev[1] for ev in _events(b)[:-1]] == _fake_seq(range(1, 15), 4)
+        assert [ev[1] for ev in _events(a)[:-1]] == _fake_seq([5], 12)
+        assert sched.drain(timeout=5)
+        assert eng.calls.count("prefill") == 2 and "piece" not in eng.calls
+        st = sched.stats()
+        assert st["prefill_piece"] is None
+        assert st["prefill_pieces"] == st["admitted"] == 2
         eng.pool.assert_baseline()
     finally:
         sched.close()

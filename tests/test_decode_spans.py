@@ -144,7 +144,9 @@ def test_prefill_span_carries_bucket_and_prompt_len(engine):
     _serve_one(engine, prompt=range(1, 12), max_new_tokens=2)
     (prefill,) = [e for e in obs.trace.drain()
                   if e["name"] == "decode.prefill"]
-    assert prefill["args"] == {"bucket": 16, "prompt_len": 11}
+    # one span a program call: a model prefilled whole has one call a prompt
+    assert prefill["args"] == {"bucket": 16, "prompt_len": 11, "start": 0,
+                               "pieces": 1}
 
 
 def test_the_next_step_is_launched_before_the_last_one_is_read(served):
@@ -212,7 +214,7 @@ def test_decode_step_keeps_its_attributes_and_endpoints(served):
 def test_live_span_attributes_are_plain_ints(served):
     want = {"decode.turn": {"joined", "active", "left"},
             "decode.admit": {"admitted"}, "decode.build": {"active"},
-            "decode.prefill": {"bucket", "prompt_len"},
+            "decode.prefill": {"bucket", "prompt_len", "start", "pieces"},
             "decode.step": {"active", "joined", "left", "ahead",
                             "cache.paged_bytes", "cache.state_bytes"},
             "decode.distribute": {"left"}}

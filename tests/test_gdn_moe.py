@@ -298,6 +298,131 @@ def test_grouped_kv_flash_forward_is_causal_attention(blocks):
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
+@pytest.mark.parametrize("start", [0, 32, 96])
+@pytest.mark.parametrize("blocks", [(16, 32), (32, 16), (8, 64)])
+def test_grouped_kv_flash_forward_of_a_piece_is_the_rows_of_the_whole(
+        start, blocks):
+    """Query rows ``start .. start + 32`` against the keys of every position
+    from 0: the rows the whole forward gives at those positions — the same
+    blocks in the same order, so bit for bit — and dense causal attention;
+    what lies behind the piece in the key array (finite, as the model hands
+    it over) is not looked at."""
+    kvh, g, total, c, d = 2, 8, 128, 32, 16
+    rng = np.random.default_rng(7)
+    q = rng.normal(size=(kvh, g, total, d)).astype(np.float32)
+    k = rng.normal(size=(kvh, total, d)).astype(np.float32)
+    v = rng.normal(size=(kvh, total, d)).astype(np.float32)
+    whole = gqa_attention.gqa_flash_attention(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), block_q=blocks[0],
+        block_k=blocks[1])
+    behind = np.arange(total)[None, :, None] >= start + c
+    got = gqa_attention.gqa_flash_attention_from(
+        jnp.asarray(q[:, :, start:start + c]),
+        jnp.asarray(np.where(behind, 1e4, k)),
+        jnp.asarray(np.where(behind, -1e4, v)), jnp.int32(start),
+        block_q=blocks[0], block_k=blocks[1])
+    np.testing.assert_array_equal(got, whole[:, :, start:start + c])
+    sc = np.einsum("hgqd,hkd->hgqk", q[:, :, start:start + c], k) / np.sqrt(d)
+    sc = np.where(start + np.arange(c)[:, None] >= np.arange(total)[None], sc,
+                  -np.inf)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    want = np.einsum("hgqk,hkd->hgqd", p / p.sum(-1, keepdims=True), v)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def test_convolution_of_a_piece_carries_on_from_the_tail_before_it():
+    """Pieces of 8 of a 23-token sequence, each from the tail the one before
+    left: the whole sequence's outputs, and the tail at ``length`` inside the
+    last piece."""
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(24, 6)).astype(np.float32)
+    w = rng.normal(size=(4, 6)).astype(np.float32)
+    want, want_tail = gated_delta.causal_conv(x, w, length=23)
+    tail = None
+    for start in (0, 8, 16):
+        out, tail = gated_delta.causal_conv(x[start:start + 8], w,
+                                            23 - start, tail)
+        np.testing.assert_allclose(out, want[start:start + 8], atol=1e-6)
+    np.testing.assert_array_equal(tail, want_tail)
+    np.testing.assert_array_equal(tail, x[20:23])
+
+
+# -- a prompt continued from a position ----------------------------------------------
+
+def _in_pieces(model, tokens, length, piece, dirty):
+    """``prefill_from`` over tokens (1, n x piece) a piece at a time, the
+    state and the rows carried as the engine carries them (``dirty``: NaN in
+    both before the first piece). (logits, rows, state) as ``prefill``."""
+    total = tokens.shape[1]
+    fill = np.nan if dirty else 0.0
+    pool = jnp.full((model.paged_layers, total) + model.cache_row, fill,
+                    model.cache_dtype)
+    state = {name: jnp.full(shape, fill, dt)
+             for name, (shape, dt) in model.state.items()}
+
+    @jax.jit
+    def one(tokens, start, pool, state):
+        return model.prefill_from(model.params, tokens, start, length,
+                                  lambda layer: pool[layer], state)
+
+    for start in range(0, total, piece):
+        logits, rows, _, state = one(tokens[:, start:start + piece],
+                                     jnp.int32(start), pool, state)
+        pool = pool.at[:, start:start + piece].set(rows)
+    return logits, pool, state
+
+
+@pytest.mark.parametrize("piece,length,dirty", [
+    (64, 150, True),      # an odd last piece: 22 of its 64 positions live
+    (64, 150, False),
+    (128, 200, True),
+    (64, 128, True),      # the last piece full
+])
+def test_a_prompt_in_pieces_is_the_prompt_whole(piece, length, dirty, params):
+    """Logits, every paged row, ``s`` and ``tail``: pieces of 64 and of 128
+    cut the prompt where the delta rule's own scan cuts it, the convolution
+    carries on from the tail and attention reads the rows before the piece —
+    the numbers of ``prefill`` over the whole prompt, whatever (NaN) the
+    slot's state and the pool held before the first piece."""
+    model = gdn_moe.GDNMoEDecodeModel(CFG, params=f32(params))
+    total = -(-length // piece) * piece
+    tokens = np.zeros((1, total), np.int32)
+    tokens[0, :length] = np.random.default_rng(length).integers(
+        0, CFG["vocab_size"], length)
+    want_logits, want_rows, _, want_state = jax.jit(model.prefill)(
+        model.params, tokens, length)
+    logits, rows, state = _in_pieces(model, jnp.asarray(tokens), length,
+                                     piece, dirty)
+    np.testing.assert_allclose(logits, want_logits, atol=2e-5)
+    np.testing.assert_allclose(rows[:, :length], want_rows[:, :length],
+                               atol=2e-5)
+    np.testing.assert_allclose(state["s"], want_state["s"], atol=2e-5)
+    np.testing.assert_array_equal(state["tail"], want_state["tail"])
+    assert np.all(np.isfinite(rows)) and np.all(np.isfinite(state["s"]))
+
+
+@pytest.mark.parametrize("piece,length", [(64, 150), (128, 200)])
+def test_a_prompt_in_pieces_is_the_recurrence_token_by_token(
+        piece, length, params, monkeypatch):
+    """The same pieces against a whole prefill whose delta layers run the
+    plain recurrence, one token after another."""
+    model = gdn_moe.GDNMoEDecodeModel(CFG, params=f32(params))
+    total = -(-length // piece) * piece
+    tokens = np.zeros((1, total), np.int32)
+    tokens[0, :length] = np.random.default_rng(length).integers(
+        0, CFG["vocab_size"], length)
+    logits, rows, state = _in_pieces(model, jnp.asarray(tokens), length,
+                                     piece, True)
+    monkeypatch.setattr(gated_delta, "delta_rule_chunked",
+                        gated_delta.delta_rule_recurrent)
+    want_logits, want_rows, _, want_state = jax.jit(
+        lambda p, t: model.prefill(p, t, length))(model.params, tokens)
+    np.testing.assert_allclose(logits, want_logits, atol=1e-4)
+    np.testing.assert_allclose(rows[:, :length], want_rows[:, :length],
+                               atol=1e-4)
+    np.testing.assert_allclose(state["s"], want_state["s"], atol=1e-4)
+
+
 # -- the whole model through the engine --------------------------------------------
 
 @pytest.fixture
@@ -566,6 +691,55 @@ def test_pages_and_slots_return_to_baseline(ending, scheduler, monkeypatch):
     finally:
         fresh.close()
     assert _baseline(scheduler)
+
+
+def test_a_neighbour_prefilling_in_pieces_does_not_move_a_streams_tokens(
+        scheduler):
+    """The engine feeds this model pieces of its smallest bucket (16): a
+    30-token prompt goes in two, one a turn, in front of the steps of the
+    stream that is decoding beside it — whose tokens, and the prompt's own,
+    are what each gets alone; one ``decode.prefill`` span a piece."""
+    engine = scheduler.engine
+    assert engine.prefill_piece == 16 and engine.buckets == [16]
+    assert engine.stats()["max_prompt"] == 32
+    first = np.arange(7, 12, dtype=np.int32)
+    second = np.arange(40, 70, dtype=np.int32)
+    alone = [list(scheduler.generate(p, max_new_tokens=n))
+             for p, n in ((first, 24), (second, 6))]
+    assert engine.stats()["num_programs"] == 2
+    before = scheduler.stats()
+    obs.enable()
+    try:
+        obs.trace.drain()
+        a = scheduler.submit(first, max_new_tokens=24)
+        got_a = [a.get(timeout=60) for _ in range(3)]    # A is decoding
+        b = scheduler.submit(second, max_new_tokens=6)
+        got_b = []
+        for got, h in ((got_b, b), (got_a, a)):
+            while not got or got[-1][0] == "token":
+                got.append(h.get(timeout=60))
+        assert _baseline(scheduler)
+        spans = obs.trace.drain()
+    finally:
+        obs.disable()
+    assert [ev[1] for ev in got_a[:-1]] == alone[0]
+    assert [ev[1] for ev in got_b[:-1]] == alone[1]
+    st = scheduler.stats()
+    assert st["prefill_piece"] == 16
+    assert st["admitted"] - before["admitted"] == 2
+    assert st["prefill_pieces"] - before["prefill_pieces"] == 3
+    assert engine.stats()["num_programs"] == 2
+    calls = sorted((s for s in spans
+                    if s["name"] in ("decode.prefill", "decode.step")),
+                   key=lambda s: s["ts"])
+    pieces = [s["args"] for s in calls if s["name"] == "decode.prefill"]
+    assert [(p["prompt_len"], p["start"], p["pieces"], p["bucket"])
+            for p in pieces] == [(5, 0, 1, 16), (30, 0, 2, 16), (30, 16, 2, 16)]
+    assert all(p["moe.dropped"] == 0 for p in pieces)
+    # a step between the prompt's two pieces: A did not wait for both
+    names = [s["name"] for s in calls]
+    i = [k for k, n in enumerate(names) if n == "decode.prefill"]
+    assert "decode.step" in names[i[1] + 1:i[2]]
 
 
 def test_a_stream_ended_by_eos_leaves_a_dropped_step_and_a_clean_slot(params):

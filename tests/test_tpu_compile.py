@@ -110,7 +110,10 @@ def _tpu_program(engine, one_chip, kind, monkeypatch):
         lowered = step.lower(params, kv, state, last, packed)
     else:
         bucket = engine.buckets[0]
-        packed = spec((4 + bucket // page + bucket,), i32)
+        if engine.prefill_piece:    # header, the whole page table, a piece
+            packed = spec((5 + engine.max_prompt // page + bucket,), i32)
+        else:
+            packed = spec((4 + bucket // page + bucket,), i32)
         lowered = prefill.lower(params, kv, state, last, packed)
     return lowered, lowered.compile()
 
@@ -506,7 +509,7 @@ def test_tpu_prefill_program_hands_its_state_to_the_slot(gdn_engine, one_chip,
 
 # -- the held experts' rows over a prompt, at the cells' own geometry -----------
 
-def _cell_engine(config, bucket):
+def _cell_engine(config, bucket, slots=2, buckets=None):
     """The engine of a benchmark cell at its published geometry (the cell's
     own ``model`` block), two slots of pool: shapes only, nothing is made."""
     import json
@@ -524,9 +527,9 @@ def _cell_engine(config, bucket):
     shapes = jax.eval_shape(lambda: kind.init_params(cfg, 0))
     model = (gdn_moe.GDNMoEDecodeModel if kind is gdn_moe
              else mla_moe.MLAMoEDecodeModel)(cfg, params=shapes)
-    return cfg, DecodeEngine(model, slots=2, page_size=256,
-                             num_pages=2 * bucket // 256 + 1,
-                             prompt_buckets=[bucket])
+    return cfg, DecodeEngine(model, slots=slots, page_size=256,
+                             num_pages=slots * bucket // 256 + 1,
+                             prompt_buckets=buckets or [bucket])
 
 
 @pytest.mark.parametrize("config,bucket", [("qwen3-next-80b-a3b", 16384),
@@ -585,6 +588,42 @@ def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
     assert cost["bytes_accessed"] < bound, (cost, bound)
     # and its temporaries are the buffer of rows and little else
     assert cost["temp_bytes"] < 1.5 * t * k * d * 4, cost
+
+
+def test_tpu_piece_program_reads_the_prompt_so_far_through_the_page_table(
+        one_chip, monkeypatch):
+    """The ONE prefill program of ``qwen3next-serve-closed-long`` compiled
+    for a v5e at the cell's geometry (32 slots, pages of 256, the cell's six
+    buckets): a piece of 2,048 positions of a prompt of up to 16,384. The
+    page table of the whole prompt rides in the packed array beside the
+    piece's ``start``; the continued flash forward goes through Mosaic; the
+    pool is donated, read through the page table (a gather of whole pages)
+    and written in place — no instruction copies or lays out again a
+    pool-shaped array — and the program's temporaries stay under one
+    layer's rows of the pool."""
+    buckets = [2048, 4096, 6144, 8192, 12288, 16384]
+    cfg, engine = _cell_engine("qwen3-next-80b-a3b", 17408, slots=32,
+                               buckets=buckets)
+    assert engine.prefill_piece == 2048 and engine.buckets == [2048]
+    assert engine.max_prompt == 16384
+    assert engine.kv.shape == (2177, 2, 256, 1024)
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
+    (packed,) = _host_arguments(engine, lowered)
+    assert packed.shape == (5 + 64 + 2048,)
+    text = lowered.as_text()
+    assert "gqa_prefill_from" in text and "moe_rows_back" in text
+    cost = obs.device.analyze_compiled(compiled)
+    held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
+    assert cost["alias_bytes"] >= held
+    assert cost["temp_bytes"] < engine.kv.nbytes // engine.paged_layers, cost
+    lines = _pool_lines(compiled, engine)
+    assert ("{3,2,1,0:T(8,128)(2,1)}" in lines[0]
+            and "parameter(" in lines[0]), lines[0]
+    assert not [line for line in lines if " copy(" in line], lines
+    whole = compiled.as_text()
+    assert not [line for line in whole.splitlines()
+                if "bf16[2177,2,256,1024]" in line and " copy(" in line]
 
 
 # -- state-space layers beside a 2-head pool, relu^2 experts --------------------
