@@ -123,8 +123,10 @@ def export(path: str) -> str:
 
 def telemetry_part(drain: bool = True, role: Optional[str] = None) -> dict:
     """This process's contribution to a fleet-wide telemetry collection:
-    the drained span ring (or a copy with ``drain=False``), the metrics
-    snapshot, and the clock anchor that lets collectors merge many
+    the drained span ring (or a copy with ``drain=False``) with the count
+    of records the full ring has pushed out since the last ``reset()``
+    (``dropped``), the metrics snapshot, and the clock anchor that lets
+    collectors merge many
     processes onto one timeline (obs/export.py ``merge_chrome_parts``).
     This is what a server returns over ``OP_TELEMETRY``."""
     if drain:
@@ -134,7 +136,8 @@ def telemetry_part(drain: bool = True, role: Optional[str] = None) -> dict:
     part = {"pid": os.getpid(), "role": role,
             "wall_epoch": trace.tracer.wall_epoch,
             "sample_rate": context.sample_rate(),
-            "spans": spans, "metrics": metrics.snapshot()}
+            "spans": spans, "dropped": trace.tracer.dropped,
+            "metrics": metrics.snapshot()}
     if tail.enabled():
         # bucket→trace_id exemplars + buffer state ride the part, so one
         # collection carries the exposition's exemplar links and the

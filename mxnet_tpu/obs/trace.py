@@ -21,7 +21,8 @@ Overhead contract (tested in tests/test_obs.py):
   ``MXNET_OBS=1``.
 - **Enabled**: ``__enter__``/``__exit__`` cost two ``time.monotonic()``
   calls, one deque append into a bounded ring buffer (old events drop,
-  newest win — a long run cannot OOM the tracer), and one
+  newest win — a long run cannot OOM the tracer; ``Tracer.dropped`` counts
+  them), and one
   ``jax.profiler.TraceAnnotation`` (under half a microsecond while no
   profiler session is running).
 
@@ -175,6 +176,10 @@ class Tracer:
     def __init__(self, capacity: int = 65536):
         self.capacity = int(capacity)
         self._events: deque = deque(maxlen=self.capacity)
+        # records appended to a full ring, each pushing the oldest out:
+        # whatever is read from the ring has lost that many (reset() zeroes)
+        self.dropped = 0
+        self._dropped_lock = threading.Lock()   # taken by a full ring only
         self._local = threading.local()
         # tid -> the thread's live span stack (the same list object the
         # thread-local holds) — how the sampling profiler (obs/profile.py)
@@ -262,6 +267,9 @@ class Tracer:
             self._record(rec)
 
     def _record(self, rec: tuple) -> None:
+        if len(self._events) >= self.capacity:
+            with self._dropped_lock:    # any thread records: count exactly
+                self.dropped += 1
         self._events.append(rec)  # deque.append is atomic under the GIL
         stream = self._stream
         if stream is not None:
@@ -353,6 +361,7 @@ class Tracer:
 
     def reset(self) -> None:
         self._events.clear()
+        self.dropped = 0
         self._epoch = _trace_epoch()
         self._wall_epoch = time.time()
         # an attached stream's first clock record anchored the OLD epoch;

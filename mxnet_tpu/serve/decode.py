@@ -791,11 +791,17 @@ class StreamHandle:
     ("end", reason, n_tokens), ("error", exc). The queue is sized so the
     scheduler can always emit a full generation without blocking —
     backpressure past that cancels the stream instead of stalling the
-    shared decode batch."""
+    shared decode batch. ``seq`` is the scheduler's number of the request
+    (the identifier its spans share), ``ctx`` its trace context; ``arrived``
+    is when the event ``get`` returned last came back from the device, on
+    the scheduler's clock (a token's; 0.0 for an ending)."""
 
     def __init__(self, capacity: int):
         self._q: "queue.Queue" = queue.Queue(maxsize=capacity)
         self._cancelled = threading.Event()
+        self.seq = 0
+        self.ctx = None
+        self.arrived = 0.0
 
     def cancel(self) -> None:
         """Ask the scheduler to retire this generation at the next step
@@ -805,15 +811,70 @@ class StreamHandle:
     def cancelled(self) -> bool:
         return self._cancelled.is_set()
 
-    def _emit(self, ev) -> bool:
+    def _emit(self, ev, arrived: float = 0.0) -> bool:
         try:
-            self._q.put_nowait(ev)
+            self._q.put_nowait((ev, arrived))
             return True
         except queue.Full:
             return False
 
     def get(self, timeout: float):
-        return self._q.get(timeout=timeout)
+        ev, self.arrived = self._q.get(timeout=timeout)
+        return ev
+
+
+class _StreamTimes:
+    """What the way back cost one stream, kept by its consumer while
+    telemetry is on: per token the *hand-over* (its arrival on the
+    scheduler's clock -> ``get`` returning it on the consumer's thread: the
+    rest of the distribute loop, the queue, the wake-up, the interpreter
+    lock) and the *consume* (the ``yield`` -> the consumer asking for the
+    next: for ``ServeServer`` the frame and the send). One ``decode.stream``
+    span at the stream's end, from the first token seen to the last
+    consumed."""
+
+    __slots__ = ("t_first", "t_last", "tokens", "handover", "handover_max",
+                 "first_handover", "consume", "consume_max")
+
+    def __init__(self, t_first: float):
+        self.t_first = self.t_last = t_first
+        self.tokens = 0
+        self.handover = self.handover_max = 0.0
+        self.consume = self.consume_max = 0.0
+        self.first_handover = None
+
+    def seen(self, index: int, arrived: float, got: float):
+        wait = got - arrived
+        self.tokens += 1
+        self.handover += wait
+        if wait > self.handover_max:
+            self.handover_max = wait
+        if index == 1:
+            self.first_handover = wait
+        self.t_last = got
+
+    def consumed(self, got: float, back: float):
+        took = back - got
+        self.consume += took
+        if took > self.consume_max:
+            self.consume_max = took
+        self.t_last = back
+
+    def record(self, handle: StreamHandle):
+        attrs = {"seq": handle.seq, "tokens": self.tokens,
+                 "handover_us": _us(self.handover),
+                 "handover_max_us": _us(self.handover_max),
+                 "consume_us": _us(self.consume),
+                 "consume_max_us": _us(self.consume_max)}
+        if self.first_handover is not None:
+            attrs["first_handover_us"] = _us(self.first_handover)
+        obs.trace.complete("decode.stream", self.t_first,
+                           self.t_last - self.t_first, ctx=handle.ctx,
+                           **attrs)
+
+
+def _us(seconds: float) -> int:
+    return int(round(seconds * 1e6))
 
 
 class _Gen:
@@ -825,7 +886,7 @@ class _Gen:
     __slots__ = ("seq", "tokens", "prompt_len", "max_new", "deadline",
                  "priority", "temperature", "temp_bits", "ctx", "handle",
                  "slot", "launched", "produced", "retired", "t_submit",
-                 "t_admit", "seed", "fed")
+                 "t_admit", "seed", "fed", "ahead", "t_prefill")
 
     def __init__(self, seq, tokens, max_new, deadline, priority,
                  temperature, handle, seed):
@@ -846,6 +907,8 @@ class _Gen:
         self.retired = False
         self.t_submit = time.monotonic()
         self.t_admit = 0.0
+        self.t_prefill = 0.0    # the launch of its first prefill call
+        self.ahead = 0          # prompts prefilling before it at admission
         self.seed = seed
 
 
@@ -1006,6 +1069,7 @@ class DecodeScheduler:
             self._seq += 1
             g = _Gen(self._seq, arr, max_new, deadline, lane, temperature,
                      handle, seed)
+            handle.seq, handle.ctx = g.seq, g.ctx
             self._lanes[lane].append(g)
             self.submitted += 1
             depth = self._qsize()
@@ -1027,6 +1091,8 @@ class DecodeScheduler:
         budget = (deadline_ms / 1000.0 + 5.0 if deadline_ms is not None
                   else self.default_timeout)
         t_end = time.monotonic() + budget
+        times = None    # a _StreamTimes from the first token seen with
+        #                 telemetry on (a window may open on a running stream)
         try:
             while True:
                 try:
@@ -1035,12 +1101,22 @@ class DecodeScheduler:
                     raise ServeError(
                         "decode stream stalled (scheduler wedged?)")
                 if ev[0] == "token":
+                    if not obs.trace._ENABLED:
+                        yield ev[1]
+                        continue
+                    got = time.monotonic()
+                    if times is None:
+                        times = _StreamTimes(got)
+                    times.seen(ev[2], h.arrived, got)
                     yield ev[1]
+                    times.consumed(got, time.monotonic())
                 elif ev[0] == "end":
                     return
                 else:
                     raise ev[1]
         finally:
+            if times is not None:
+                times.record(h)
             h.cancel()
             with self._cv:
                 self._cv.notify_all()
@@ -1155,6 +1231,8 @@ class DecodeScheduler:
         if flight.kind == "prefill":
             obs.trace.complete("decode.prefill", flight.t_launch,
                                now - flight.t_launch, **attrs)
+            for g in flight.who:    # the call that held its prompt's end
+                self._first_token(g, now, attrs["pieces"])
             return sum(self._token(g, out, now) for g in flight.who)
         left = 0
         with obs.trace.span("decode.distribute") as distribute:
@@ -1191,11 +1269,26 @@ class DecodeScheduler:
         if t0 is not None and g.ctx is not None and g.ctx.sampled:
             obs.trace.complete("decode.token", t0, now - t0, ctx=g.ctx,
                                index=g.produced, slot=g.slot)
-        if not g.handle._emit(("token", tok, g.produced)):
+        if not g.handle._emit(("token", tok, g.produced), now):
             self._retire(g, "backpressure", error=RequestRejected(
                 "stream consumer too slow (token buffer full)"))
             return True
         return self._done(g, tok, now)
+
+    def _first_token(self, g: _Gen, now: float, pieces: int):
+        """``decode.first_token``: submit -> the arrival of ``g``'s first
+        token on the scheduler's clock, with its three parts in µs: the
+        ``decode.queue_wait`` span, the ``decode.prefill_wait`` span (0 for
+        a prompt prefilled whole) and from the first prefill call's launch
+        to the last one's arrival, the steps run between pieces included."""
+        if not obs.trace._ENABLED or g.retired:
+            return
+        obs.trace.complete(
+            "decode.first_token", g.t_submit, now - g.t_submit, ctx=g.ctx,
+            seq=g.seq, prompt_len=g.prompt_len, pieces=pieces,
+            queue_wait_us=_us(g.t_admit - g.t_submit),
+            prefill_wait_us=_us(g.t_prefill - g.t_admit),
+            prefill_us=_us(now - g.t_prefill))
 
     def _count(self, counted: dict) -> dict:
         """What the model counted in the call just read (an expert layer's
@@ -1254,8 +1347,9 @@ class DecodeScheduler:
             g.t_admit = time.monotonic()
             obs.trace.complete("decode.queue_wait", g.t_submit,
                               g.t_admit - g.t_submit, ctx=g.ctx,
-                              priority=g.priority)
+                              seq=g.seq, priority=g.priority)
             if self._piece:
+                g.ahead = len(self._prefilling)
                 self._prefilling.append(g)
             else:
                 self._launch_prefill(g, bucket, g.t_admit)
@@ -1269,6 +1363,12 @@ class DecodeScheduler:
         piece of it (``bucket``: the positions of the call); the call that
         holds the prompt's end makes ``g`` a decoding slot."""
         start = g.fed
+        if not start:
+            g.t_prefill = t_launch
+            if self._piece:     # its stay behind other prompts' pieces
+                obs.trace.complete("decode.prefill_wait", g.t_admit,
+                                   t_launch - g.t_admit, ctx=g.ctx,
+                                   seq=g.seq, ahead=g.ahead)
         where = {"start": start} if self._piece else {}
         launched = self.engine.launch_prefill(
             g.tokens, self.engine.pool.table(g.seq),
@@ -1281,8 +1381,8 @@ class DecodeScheduler:
             g.launched = 1
             self._release_if_last(g)
         self._inflight.append(_InFlight(
-            "prefill", launched, t_launch, [g] if last else [], bucket=bucket,
-            prompt_len=g.prompt_len, start=start,
+            "prefill", launched, t_launch, [g] if last else [], seq=g.seq,
+            bucket=bucket, prompt_len=g.prompt_len, start=start,
             pieces=-(-g.prompt_len // bucket)))
 
     def _feed(self):
@@ -1374,7 +1474,7 @@ class DecodeScheduler:
         obs.trace.complete(
             "decode.generate", g.t_admit or g.t_submit,
             time.monotonic() - (g.t_admit or g.t_submit), ctx=g.ctx,
-            tokens=g.produced, outcome=reason)
+            seq=g.seq, tokens=g.produced, outcome=reason)
         with self._cv:
             self._cv.notify_all()
 

@@ -535,7 +535,8 @@ def test_late_learned_exits_retire_with_a_step_in_flight(reason):
                          deadline_ms=60 if reason == "deadline" else None)
         if reason == "backpressure":
             emit = h._emit
-            h._emit = lambda ev: (ev[0] != "token" or ev[2] <= 3) and emit(ev)
+            h._emit = lambda ev, *arrived: (
+                (ev[0] != "token" or ev[2] <= 3) and emit(ev, *arrived))
         events = []
         while not events or events[-1][0] == "token":
             events.append(h.get(timeout=5))
@@ -721,7 +722,8 @@ def test_exits_in_mid_prefill_return_slot_and_pages(reason):
                          deadline_ms=150 if reason == "deadline" else None)
         if reason == "backpressure":
             emit = h._emit
-            h._emit = lambda ev: ev[0] != "token" and emit(ev)
+            h._emit = lambda ev, *arrived: (ev[0] != "token"
+                                            and emit(ev, *arrived))
         _wait(lambda: len(eng.pieces) >= 3, msg="the prompt going in")
         assert eng.pool.used() >= 25
         if reason == "cancelled":

@@ -19,6 +19,7 @@ Covers the obs layer's contracts (docs/OBSERVABILITY.md):
    monotonic clock + zero-elapsed guard, checkpoint writer error
    surfacing.
 """
+import gc
 import json
 import os
 import sys
@@ -34,6 +35,7 @@ from mxnet_tpu import obs, profiler
 from mxnet_tpu import symbol as sym
 from mxnet_tpu.io import NDArrayIter, PrefetchingIter
 from mxnet_tpu.module import Module
+from mxnet_tpu.serve import decode
 
 pytestmark = pytest.mark.obs
 
@@ -339,6 +341,25 @@ def test_disabled_span_is_shared_noop_and_records_nothing():
     assert obs.metrics.registry.get("never.gauge") is None
 
 
+class _FedStreams:
+    """What ``DecodeScheduler.generate`` needs of its scheduler, with no
+    engine behind it: ``submit`` hands back a stream that already holds
+    ``n`` tokens and its end."""
+
+    default_timeout = 5.0
+    _cv = threading.Condition()
+
+    def __init__(self, n):
+        self.n = n
+
+    def submit(self, tokens, **_kw):
+        h = decode.StreamHandle(capacity=self.n + 1)
+        for i in range(self.n):
+            assert h._emit(("token", i, i + 1), 1.0)
+        assert h._emit(("end", "length", self.n))
+        return h
+
+
 def test_disabled_hot_path_retains_no_allocations():
     assert not obs.enabled()
 
@@ -348,12 +369,26 @@ def test_disabled_hot_path_retains_no_allocations():
                 pass
             obs.inc("c")
             obs.observe("h", 0.5)
+        # a stream's consumer: one test of the flag a token, and nothing kept
+        got = sum(1 for _ in decode.DecodeScheduler.generate(
+            _FedStreams(n), [1]))
+        assert got == n
 
     hot_loop(100)  # warm caches outside the measurement
+    # what the obs layer, the consumer's loop and this file allocate: the
+    # process's other threads (whatever the worker's earlier test files left
+    # running under -n 6) allocate elsewhere, and are no part of the claim
+    ours = [tracemalloc.Filter(True, os.path.join(REPO, "mxnet_tpu", "obs",
+                                                  "*")),
+            tracemalloc.Filter(True, decode.__file__),
+            tracemalloc.Filter(True, __file__)]
     tracemalloc.start()
-    before = tracemalloc.take_snapshot()
+    gc.collect()    # (and empties the interpreter's free lists, which keep
+    #                 up to 2,000 freed tuples of a size: freed, not retained)
+    before = tracemalloc.take_snapshot().filter_traces(ours)
     hot_loop(20000)
-    after = tracemalloc.take_snapshot()
+    gc.collect()
+    after = tracemalloc.take_snapshot().filter_traces(ours)
     tracemalloc.stop()
     retained = sum(s.size_diff for s in after.compare_to(before, "filename")
                    if s.size_diff > 0)
@@ -361,6 +396,7 @@ def test_disabled_hot_path_retains_no_allocations():
     # recording of 20k spans would be megabytes
     assert retained < 64 * 1024, f"disabled mode retained {retained} bytes"
     assert obs.trace.events() == []
+    assert obs.trace.tracer.dropped == 0
 
 
 def test_dispatch_counting_unchanged_when_disabled():
