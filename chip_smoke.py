@@ -28,10 +28,14 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
               tokens prefilled; ``ssm_decode`` against XLA on random states of
               64 x 64 x 128 float32; the step's temporaries under one layer's
               share of pool plus state;
-- *experts*   ``ops.moe.held_experts`` at the two expert cells' prefill-chunk
+- *experts*   ``ops.moe.held_experts`` at the three expert cells' prompt
               and decode-step geometries against a plain masked loop in
-              bfloat16 on the chip: the error, nothing dropped, and the
-              rows handed to the grouped products over the rows held;
+              bfloat16 on the chip: the error, nothing dropped, the row tile
+              and the slot the rows were laid out in, the rows the grouped
+              products were handed over the rows held (what the slots cost
+              in padding) and the time of a call; and the same function on float32 operands at
+              2, 4 and 32 slots (few rows a step: PERF.md section 7), its
+              error printed and not held;
 - *multichip* with >= 4 chips: the same trainer on dp2 x tp2, then on
               dp2 x sp2 at seq 4096 (ring attention, the Pallas kernel
               inside shard_map). Otherwise reported ``not_run``.
@@ -42,8 +46,9 @@ one ends the run with a traceback. A passing run ends with two lines:
 ``summary {...}`` (per-phase status and set-up seconds, the attention path
 each phase ran, the compile cache, ``"claim": null``), then, last, exactly
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`` as
-jax reports the device. It measures set-up time per phase and no rate: what
-the program costs on the chip is the benchmark's to say.
+jax reports the device. It measures set-up time per phase and no rate (the
+experts phase prints what a call took, for the reader): what the program
+costs on the chip is the benchmark's to say.
 """
 from __future__ import annotations
 
@@ -964,12 +969,12 @@ def _step_text(engine):
 # ---------------------------------------------------------------------------
 
 # cell: (k, hidden, expert width, experts held, experts the router scores,
-# tokens of a chunk of its longest prefill, slots of its step, whether an
-# expert has a gate matrix): the gated-delta cell's 16,384 prefill goes in
-# chunks of 4,096, the latent-attention cell's 7,168 in 3,584; the
-# state-space cell's experts are two products a row, 2688 x 1856 stored as
-# 3072 x 2048 (whole tiles of 512, zeros behind), and its step has 128 slots
-EXPERT_CELLS = {"gdn": (10, 2048, 512, 128, 512, 4096, 32, True),
+# tokens of a prompt call, slots of its step, whether an expert has a gate
+# matrix): the gated-delta cell's prompts go in pieces of 2,048, the
+# latent-attention cell's 7,168 in chunks of 3,584; the state-space cell's
+# experts are two products a row, 2688 x 1856 stored as 3072 x 2048 (whole
+# tiles of 512, zeros behind), and its step has 128 slots
+EXPERT_CELLS = {"gdn": (10, 2048, 512, 128, 512, 2048, 32, True),
                 "latent": (8, 4096, 2048, 32, 128, 3584, 32, True),
                 "ssm": (6, 2688, 1856, 32, 128, 2048, 128, False)}
 
@@ -993,8 +998,42 @@ def phase_experts():
                             (jnp.arange(up_w.shape[0]),) + mats)
         return y
 
+    def run(what, h, key, k, held, scored, stored, w):
+        """``held_experts`` over random choices of ``k`` of ``scored``
+        experts against the masked loop, its counters held to the tiles and
+        what a call took printed: the relative error."""
+        t = h.shape[0]
+        picked, chosen = jax.lax.top_k(
+            jax.random.uniform(key, (t, scored)), k)
+        gates = picked / jnp.sum(picked, -1, keepdims=True)
+        fn = jax.jit(lambda *a: moe.held_experts(*a, 0, held, 0, scored))
+        args = (h, chosen, gates, jnp.ones((t,), bool), *stored)
+        y, counted = fn(*args)
+        _require(np.all(np.isfinite(np.asarray(y))), f"{what}: non-finite")
+        err = _rel_err(y, jax.jit(masked_loop)(h, chosen, gates, *w))
+        t0 = time.monotonic()
+        for _ in range(10):
+            out = fn(*args)
+        out[0].block_until_ready()
+        ms = (time.monotonic() - t0) * 100
+        counted = dict(zip(moe.COUNTERS, np.asarray(counted).tolist()))
+        _require(counted["dropped"] == 0, f"{what}: {counted}")
+        slot = moe.row_slot(t * k, scored)
+        print(f"   {what} ({t} x {k} pairs, {counted['held']} held on "
+              f"{counted['touched']} experts): rel err {err:.2e}, row tile "
+              f"{moe.row_tile(t * k, scored, h.dtype)}, slot {slot}, "
+              f"rows_run {counted['rows_run']} / held = "
+              f"{counted['rows_run'] / max(counted['held'], 1):.2f}, "
+              f"{ms:.3f} ms a call", flush=True)
+        # whole slots, and no expert more than a slot over its rows
+        _require(counted["rows_run"] % slot == 0 and counted["held"]
+                 <= counted["rows_run"]
+                 < counted["held"] + counted["touched"] * slot,
+                 f"{what}: the slots run do not follow the rows held")
+        return err
+
     with _Phase("experts"):
-        for cell, (k, d, f, held, scored, chunk, step,
+        for cell, (k, d, f, held, scored, prompt, step,
                    gated) in EXPERT_CELLS.items():
             keys = jax.random.split(jax.random.PRNGKey(k), 5)
             w = [0.03 * jax.random.normal(key, shape, jnp.bfloat16)
@@ -1006,26 +1045,22 @@ def phase_experts():
                 stored = [None, jnp.pad(w[1], ((0, 0), (0, pd), (0, pf))),
                           jnp.pad(w[2], ((0, 0), (0, pf), (0, pd)))]
                 w = [None] + w[1:]
-            for what, t in ((f"{cell} chunk", chunk), (f"{cell} step", step)):
+            for what, t in ((f"{cell} prompt", prompt), (f"{cell} step", step)):
                 h = jax.random.normal(keys[3], (t, d), jnp.bfloat16)
-                picked, chosen = jax.lax.top_k(
-                    jax.random.uniform(keys[4], (t, scored)), k)
-                gates = picked / jnp.sum(picked, -1, keepdims=True)
-                y, counted = jax.jit(moe.held_experts, static_argnums=(7, 8))(
-                    h, chosen, gates, jnp.ones((t,), bool), *stored, 0, held)
-                counted = dict(zip(moe.COUNTERS, np.asarray(counted).tolist()))
-                _check_close(f"held experts, {what} ({t} x {k} pairs, "
-                             f"{counted['held']} held)", y,
-                             jax.jit(masked_loop)(h, chosen, gates, *w), 2e-2)
-                _require(counted["dropped"] == 0, f"{what}: {counted}")
-                c = moe.row_block(t * k)
-                print(f"   {what}: rows_run {counted['rows_run']} / held "
-                      f"{counted['held']} = "
-                      f"{counted['rows_run'] / max(counted['held'], 1):.2f}, "
-                      f"blocks of {c}", flush=True)
-                _require(counted["held"] <= counted["rows_run"]
-                         < counted["held"] + c,
-                         f"{what}: a block behind the last held row ran")
+                err = run(what, h, keys[4], k, held, scored, stored, w)
+                _require(err <= 2e-2, f"{what}: rel err {err:.3e} > 2e-2")
+        # float32 operands with few rows a step (PERF.md section 7: the
+        # grouped product read bfloat16-sized errors at 20 and 40 pairs):
+        # printed, whatever it reads (every cell's operands are bfloat16)
+        k, d, f, held, scored = EXPERT_CELLS["gdn"][:5]
+        keys = jax.random.split(jax.random.PRNGKey(38), 5)
+        w = [0.03 * jax.random.normal(key, shape, jnp.float32)
+             for key, shape in zip(keys, ((held, d, f), (held, d, f),
+                                          (held, f, d)))]
+        for slots in (2, 4, 32):
+            h = jax.random.normal(keys[3], (slots, d), jnp.float32)
+            run(f"gdn step in float32, {slots} slots", h, keys[4], k, held,
+                scored, w, w)
 
 
 # ---------------------------------------------------------------------------

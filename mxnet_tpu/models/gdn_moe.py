@@ -510,3 +510,9 @@ class GDNMoEDecodeModel:
                                     scale=self._scale())
 
     counters = tuple("moe." + name for name in moe.COUNTERS)
+
+    def moe_row_tile(self, tokens):
+        """``DecodeEngine.stats()["moe_row_tile"]``: the row tile the held
+        experts' grouped products run a call of ``tokens`` tokens with."""
+        return moe.layer_row_tile(tokens, self.cfg["experts_per_token"],
+                                  self.cfg["router_experts"], self.cache_dtype)

@@ -15,16 +15,21 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
 - :func:`held_experts` — dropless, and its work follows the rows the held
   experts have, not the static bound ``tokens x k``: the (token, choice)
   pairs are sorted by expert (those on experts held elsewhere, and the
-  tokens that are not live, sort behind the held rows); a block of
-  :func:`row_block` sorted rows at a time is gathered and goes through a
-  grouped product per matrix (``jax.lax.ragged_dot``, which XLA:TPU lowers
-  to a Mosaic grouped matmul driven by the group sizes), as many blocks as
-  hold a held row — a trip count read from the data —, each block's
-  float32 rows written once, a row as one contiguous piece, into a buffer
-  nobody clears; and ONE pass back (``_rows_back``, a Pallas kernel): a held
-  row is fetched once, on its way into its token's weighted sum, and a pair
-  no held expert has is not fetched at all. Nothing is dropped: when every
-  pair falls on a held expert every block runs.
+  tokens that are not live, sort behind the held rows) and laid out so that
+  **every held expert's rows start on a slot of their own**, in slots of
+  :func:`row_slot` rows — a size that fits the rows an expert has, and
+  over a prompt a whole row tile of the kernel (:func:`row_tile`) —; a
+  block of :func:`row_block` such rows at a time is gathered and goes
+  through a grouped product per matrix (``jax.lax.ragged_dot``, which
+  XLA:TPU lowers to a Mosaic grouped matmul that runs once for every
+  (expert, row tile) pair that meet: with a tile a slot, once a tile), as
+  many blocks as hold a slot with a held row — a trip count read from the
+  data —, each block's float32 rows written once, a row as one contiguous
+  piece, into a buffer nobody clears; and ONE pass back (``_rows_back``, a
+  Pallas kernel): a held row is fetched once, on its way into its token's
+  weighted sum, and a pair no held expert has is not fetched at all.
+  Nothing is dropped: when every pair falls on a held expert every block
+  runs.
 - :func:`expert_layer` — routed part + shared expert, and the counters
   (``COUNTERS``) that ``serve/decode.py`` hangs on its spans.
 
@@ -45,11 +50,14 @@ from jax import lax
 from . import flash_attention
 
 __all__ = ["route", "route_softmax", "held_experts", "expert_layer",
-           "gated_mlp", "relu2_mlp", "row_block", "COUNTERS"]
+           "gated_mlp", "relu2_mlp", "row_slot", "row_tile", "row_block",
+           "layer_row_tile", "COUNTERS"]
 
 # per call: live (token, choice) pairs; those on held experts; most tokens on
 # one held expert; held experts with at least one token; held pairs that no
-# row was computed for (must be 0); rows handed to the grouped products
+# row was computed for (must be 0); rows the grouped products were handed:
+# the slots that hold a held row x the slot (``rows_run / held`` is what
+# starting every expert's rows on a slot of their own costs in padding)
 COUNTERS = ("assignments", "held", "load_max", "touched", "dropped",
             "rows_run")
 TOKEN_CHUNK = 4096   # most tokens routed and multiplied at a time: bounds the
@@ -106,16 +114,57 @@ def route_softmax(h, router_w, k: int):
     return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
-def row_block(rows: int) -> int:
-    """Rows of a block of the sorted (token, choice) pairs, from their
-    number. Over a prompt chunk: four of the grouped kernel's largest row
-    tile (512), so the products' tiles are full and the last block run holds
-    at most 2,047 rows behind the last held one. Where the rows are fewer: a
-    quarter of them, in whole sublane tiles — the kernel's row tile follows
-    the rows it is handed, and with a decode step's few rows an expert one
-    256-row tile for every touched expert costs more than reading its
-    weights."""
-    return min(4 * 512, -(-rows // 32) * 8)
+def row_slot(pairs: int, scored: int) -> int:
+    """Rows of a slot of the sorted rows — every held expert's rows start on
+    a slot of their own — from what the shapes say: the smallest power of
+    two, 512 at most, that holds the rows an expert has, taken as their mean
+    (``pairs`` (token, choice) pairs over the ``scored`` experts of the
+    router) + 1.5 of its root (what a Poisson count spreads by: 224 rows an
+    expert fit 256, 40 fit 64, a decode step's 0.6 fit 2). A slot much
+    larger than an expert's rows multiplies, gathers and writes rows nobody
+    asked for; one smaller gives an expert several, and where a slot is a
+    whole tile of the kernel (:func:`row_tile`) its weights are read again
+    for each."""
+    mean = pairs / scored
+    slot = 1
+    while slot < 512 and slot < mean + 1.5 * mean ** 0.5:
+        slot *= 2
+    return slot
+
+
+def row_tile(pairs: int, scored: int, dtype) -> int:
+    """Rows of a tile of the grouped products: the slot, and no less than
+    the operands' sublane tile (16 rows of bfloat16, 8 of float32). The
+    kernel computes a whole tile for every (expert, tile) pair that meet: a
+    512-row tile at 40 rows an expert computed 13.8 times the rows. Over a
+    prompt a slot is a tile, and a tile meets ONE expert; in a decode step
+    several experts' slots share a tile of 16, each expert in one tile."""
+    return max(row_slot(pairs, scored), 32 // jnp.dtype(dtype).itemsize)
+
+
+def row_block(tm: int, tiles: int) -> int:
+    """Rows of a block of the tiled rows: ``tm`` x an ODD number of tiles,
+    2,048 rows at most and ``tiles`` tiles at most. XLA:TPU takes as the
+    grouped kernel's row tile the largest power of two, 512 at most, that
+    divides the rows the product is handed: an odd number of tiles makes
+    that ``tm`` (four tiles of 512, as before PR 43, made it 512 whatever
+    the rows an expert had)."""
+    n = max(1, min(2048 // tm, tiles))
+    return tm * (n - 1 + n % 2)
+
+
+def _chunk_tokens(tokens: int) -> int:
+    """Tokens :func:`expert_layer` routes and multiplies at a time: those of
+    the fewest equal chunks of ``TOKEN_CHUNK`` at most (all of them where
+    such chunks do not divide them)."""
+    n = -(-tokens // TOKEN_CHUNK)
+    return tokens if tokens % n else tokens // n
+
+
+def layer_row_tile(tokens: int, k: int, scored: int, dtype) -> int:
+    """The row tile :func:`expert_layer` runs a call of ``tokens`` tokens
+    with: what ``DecodeEngine.stats()["moe_row_tile"]`` shows."""
+    return row_tile(_chunk_tokens(tokens) * k, scored, dtype)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2))
@@ -214,7 +263,7 @@ def _rows_back(out, where, weight, interpret):
 
 
 def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
-                 held: int, offset=0):
+                 held: int, offset=0, scored: int = None):
     """The held experts' part of the routed sum. h (T, D); chosen, gates
     (T, k); live (T,) bool. The weights are (G, D, F), (G, D, F), (G, F, D)
     (``gate_w`` None: experts of two products, ``relu(h.W_u)^2.W_d``;
@@ -225,8 +274,11 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
     ``offset .. offset + held - 1`` (every expert layer's experts can lie in
     one array, ``offset`` = layer x held, possibly traced: the grouped kernel
     takes the array whole and the other layers' groups are empty, where a
-    slice of it would be copied out for the kernel). Returns (y (T, D)
-    float32, counters (len(COUNTERS),) int32)."""
+    slice of it would be copied out for the kernel). ``scored``: the experts
+    the router chose among (default: the held ones are all there are); with
+    ``T x k`` it says how many rows an expert has, which slot and row tile
+    follow (:func:`row_slot`, :func:`row_tile`). Returns (y (T, D) float32, counters
+    (len(COUNTERS),) int32)."""
     t, k = chosen.shape
     d = h.shape[1]
     wide = up_w.shape[1]    # the hidden size the experts are STORED at
@@ -241,24 +293,47 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
     ends = jnp.searchsorted(in_order, jnp.arange(held, dtype=group.dtype),
                             side="right").astype(jnp.int32)
     sizes = jnp.diff(ends, prepend=0)
-    n_held = ends[-1]
+    starts = ends - sizes
 
-    # The work follows the rows the held experts have. A block of c sorted
-    # rows at a time is gathered and goes through the three grouped
-    # products, as many blocks as hold a held row: the trip count is read
-    # from the data, and a block behind the last held row is not run.
-    c = row_block(t * k)
-    most = -(-t * k // c)
-    run = -(-n_held // c)
-    token = jnp.pad(order // k, (0, most * c - t * k))
+    # Every held expert's rows start on a slot of their own: expert e takes
+    # ceil(sizes_e / slot) slots of slot rows, and what its last slot has
+    # over is computed and never read. Over a prompt a slot is a tile of the
+    # kernel: a tile then meets ONE expert, so the grouped kernel runs once
+    # a tile and an expert's weights are read once a tile it has.
+    scored = scored or held
+    slot, tm = row_slot(t * k, scored), row_tile(t * k, scored, h.dtype)
+    slots = -(-sizes // slot)
+    slot_ends = jnp.cumsum(slots)
+    slot_starts = slot_ends - slots
+    n_slots = slot_ends[-1]
+    # The work follows the rows the held experts have. A block of c slotted
+    # rows at a time is gathered and goes through the grouped products, as
+    # many blocks as hold a slot with a held row: the trip count is read
+    # from the data, and a block behind the last such slot is not run. A
+    # block is about the slots the held experts' share of the pairs fills.
+    share = -(-t * k * held // scored)
+    c = row_block(tm, -(-slot * (min(held, share) + -(-share // slot)) // tm))
+    per = c // slot
+    # every pair on a held expert, and every expert a row into a slot more
+    most = -(-(-(-t * k // slot) + min(held, t * k)) // per)  # blocks at most
+    run = -(-n_slots // per)
+    # slot i is slot sorted rows from its expert's first + slot x (the slots
+    # the expert has before it); a slot behind the last one gathers any rows
+    i = jnp.arange(most * per, dtype=jnp.int32)
+    e = jnp.minimum(jnp.searchsorted(slot_ends, i, side="right",
+                                     method="compare_all"), held - 1)
+    base = starts[e] + (i - slot_starts[e]) * slot
+    token = jnp.take(order // k,
+                     (base[:, None] + jnp.arange(slot)).reshape(-1),
+                     mode="clip")
     lanes = 128 if wide % 128 == 0 else wide
     shape = (wide // lanes, lanes)   # a row as whole (8, 128) tiles' worth
 
     def block(j, out):
         lo = j * c
         rows = h[lax.dynamic_slice(token, (lo,), (c,))]
-        inside = (jnp.clip(ends, lo, lo + c)
-                  - jnp.clip(ends - sizes, lo, lo + c))   # of each group
+        inside = (jnp.clip(slot_ends * slot, lo, lo + c)
+                  - jnp.clip(slot_starts * slot, lo, lo + c))  # of each group
         groups = lax.dynamic_update_slice(
             jnp.zeros((up_w.shape[0],), jnp.int32), inside, (offset,))
         if gate_w is None:
@@ -274,13 +349,19 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
                         _unwritten((most * c,) + shape, ends,
                                    flash_attention._use_interpret()))
     # One pass back: a held row is read once, on its way into its token's
-    # sum; a pair no held expert has is not read at all.
-    where = jnp.where(on_held, jnp.argsort(order).reshape(t, k), -1)
+    # sum; a pair no held expert has is not read at all. A pair's row lies
+    # at its place in the sorted order + what the slots before its expert
+    # have over.
+    over = slot_starts * slot - starts                    # of each expert
+    shift = jnp.sum(jnp.where(group[:, None] == jnp.arange(held), over, 0),
+                    axis=1)                               # of each pair
+    where = jnp.where(on_held, (jnp.argsort(order) + shift).reshape(t, k), -1)
     weight = jnp.where(on_held, gates, 0.0).astype(jnp.float32)
     y = _rows_back(out, where, weight, flash_attention._use_interpret())
+    n_held = ends[-1]
     counters = jnp.stack([
         jnp.sum(live) * k, n_held, jnp.max(sizes), jnp.sum(sizes > 0),
-        jnp.sum(on_held) - jnp.minimum(n_held, t * k), run * c])
+        jnp.sum(on_held) - jnp.minimum(n_held, t * k), n_slots * slot])
     return y.reshape(t, wide)[:, :d], counters.astype(jnp.int32)
 
 
@@ -304,7 +385,7 @@ def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
             chosen, gates = route_softmax(hc, p["router_w"], k)
         y, c = held_experts(hc, chosen, gates, lc, experts.get("gate_w"),
                             experts["up_w"], experts["down_w"], first, held,
-                            offset)
+                            offset, p["router_w"].shape[1])
         if "shared_gate_w" in p:
             shared = gated_mlp(hc, p["shared_gate_w"], p["shared_up_w"],
                                p["shared_down_w"])
@@ -317,10 +398,11 @@ def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
         return y + shared, c
 
     t = h.shape[0]
-    n = -(-t // TOKEN_CHUNK)          # the fewest equal chunks that fit
-    if n == 1 or t % n:
+    each = _chunk_tokens(t)
+    if each == t:
         return chunk((h, live))
-    y, c = lax.map(chunk, (h.reshape(n, t // n, -1), live.reshape(n, t // n)))
+    y, c = lax.map(chunk, (h.reshape(-1, each, h.shape[1]),
+                           live.reshape(-1, each)))
     return y.reshape(t, -1), merge_counters(c)
 
 

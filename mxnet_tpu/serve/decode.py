@@ -773,6 +773,13 @@ class DecodeEngine:
         out["paged_kernel"] = describe(
             self.kv, self.slots, self.max_pages) if (
                 step and describe) else None
+        # the row tile the held experts' grouped products run with, a step
+        # and each prompt bucket (``ops.moe.row_tile``): the model's to say
+        # (None for one with no routed experts)
+        describe = getattr(self.model, "moe_row_tile", None)
+        out["moe_row_tile"] = describe and {
+            "step": describe(self.slots),
+            "prefill": {b: describe(b) for b in self.buckets}}
         out["pool"] = self.pool.stats()
         if self._progcache is not None:
             out["progcache"] = dict(self._progcache.stats,

@@ -1281,6 +1281,7 @@ def test_engine_through_the_grouped_kernel_serves_the_dense_tokens(
         assert got == toks[len(prompt):]
     assert eng.stats()["paged_kernel"] == {
         "page_group": group, "grid_steps": 2 * -(-8 // group) * 2}
+    assert eng.stats()["moe_row_tile"] is None      # no routed experts
     eng.pool.assert_baseline()
 
 

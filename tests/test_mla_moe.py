@@ -322,17 +322,26 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert():
     np.testing.assert_allclose(y[36:], shared[36:], atol=1e-6)
 
 
-def _loaded_pairs(load, t, k, held, first, c):
+def _loaded_pairs(load, t, k, held, first, tm):
     """(chosen (t, k), live (t,)) that put the held experts' rows where the
-    block loop of ``held_experts`` has to decide: ``first .. first + held
-    - 1`` are held, experts ``0 .. first - 1`` are not."""
+    slots (of ``tm`` rows) of ``held_experts`` have to decide: ``first .. first + held - 1``
+    are held, experts ``0 .. first - 1`` are not."""
     chosen = np.zeros((t, k), np.int32)           # expert 0: held elsewhere
+    flat = chosen.reshape(-1)
     live = np.ones((t,), bool)
-    if load == "one_expert":                      # every pair on ONE expert
+    if load in ("one_expert", "two_products"):    # every pair on ONE expert
         chosen[:] = first + 1
-    elif load in ("under_edge", "over_edge"):     # a held row off a block edge
-        n = c - 1 if load == "under_edge" else c + 1
-        chosen.reshape(-1)[:n] = first + np.arange(n) % held
+    elif load == "exactly_tm":        # whole tiles: nothing is padded
+        flat[:tm] = first
+        flat[tm:3 * tm] = first + 2
+    elif load == "tm_plus_1":         # one row into a second tile; a lone row
+        flat[:tm + 1] = first
+        flat[-1] = first + 2
+    elif load == "most_tiles":        # every pair held, every expert one row
+        sizes = [1 + tm * ((t * k - held) // tm // held)] * (held - 1)
+        flat[:] = first + held - 1                # into a tile of its own
+        flat[:sum(sizes)] = first + np.repeat(np.arange(held - 1), sizes)
+        flat[:] = flat[np.random.default_rng(0).permutation(t * k)]
     elif load == "partly_live":
         chosen[:] = (np.arange(t * k).reshape(t, k) * 7) % (first + held)
         live = np.arange(t) % 3 != 1
@@ -343,21 +352,31 @@ def _loaded_pairs(load, t, k, held, first, c):
 
 @pytest.mark.parametrize("case", [
     "TOKEN_CHUNK-16",
-    # tokens, k (10 is not a whole sublane tile, 8 is), load
-    "32-10-one_expert", "32-10-no_held_pair", "32-10-under_edge",
-    "32-10-over_edge", "32-10-partly_live",
-    "32-8-one_expert", "32-8-no_held_pair", "32-8-under_edge",
-    "32-8-over_edge", "32-8-partly_live",
-    "200-10-under_edge", "200-10-over_edge", "200-8-partly_live"])
+    # tokens, k (10 is not a whole sublane tile, 8 is), load[, the experts
+    # the router is said to score: 64 unless given]
+    "32-10-one_expert", "32-10-no_held_pair", "32-10-exactly_tm",
+    "32-10-tm_plus_1", "32-10-most_tiles", "32-10-partly_live",
+    "32-10-two_products",
+    "32-8-one_expert", "32-8-no_held_pair", "32-8-exactly_tm",
+    "32-8-tm_plus_1", "32-8-most_tiles", "32-8-partly_live",
+    "200-10-exactly_tm", "200-10-tm_plus_1", "200-10-most_tiles",
+    "200-8-partly_live", "200-8-two_products",
+    # a decode step's few rows an expert: slots smaller than the kernel's tile
+    "32-10-partly_live-512", "32-10-most_tiles-512", "32-8-tm_plus_1-256"])
 def test_expert_layer_in_chunks_and_row_blocks_is_the_layer(case, monkeypatch):
     """Tokens routed ``TOKEN_CHUNK`` at a time give the same sum and the
-    same counters. And the sorted rows multiplied a block at a time, as many
-    blocks as hold a held row, give the reference's plain masked loop
-    (float32, ``benchmark/reference_mla_moe.py``) with nothing dropped and
-    ``rows_run`` = blocks run x block, for the loads that decide the loop:
-    every pair on one held expert (every block runs), none on a held expert
-    (no block runs, ``y`` exactly 0), the held rows one short of and one past
-    a block's edge, tokens that are not live."""
+    same counters. And the sorted rows laid out in slots of ``row_slot``
+    rows, every held expert's on slots of its own, and multiplied a block
+    at a time, as many blocks as hold such a slot, give the reference's
+    plain masked loop (float32, ``benchmark/reference_mla_moe.py``) with
+    nothing dropped and ``rows_run`` = the slots the held experts have x the
+    slot, for the loads that decide layout and loop: every pair on one held
+    expert (every slot is full), none on a held expert (no block runs, ``y``
+    exactly 0), an expert with exactly a slot of rows and with one more, a
+    lone row, every pair on a held expert and every expert a row into a
+    slot of its own (the most slots the buffer's bound allows), tokens that
+    are not live, experts of two products; and slots of 2 and 4 rows, under
+    the kernel's row tile, as a decode step has them."""
     if case == "TOKEN_CHUNK-16":
         p, experts, offset = _expert_layer_params(CFG, 1)
         h = 0.7 * jax.random.normal(jax.random.PRNGKey(4), (48, 64),
@@ -375,11 +394,13 @@ def test_expert_layer_in_chunks_and_row_blocks_is_the_layer(case, monkeypatch):
         assert c_got["load_max"] <= c_want["load_max"]
         assert c_got["rows_run"] >= c_got["held"]
         return
-    t, k, load = case.split("-")
-    t, k, first, held, d, f = int(t), int(k), 3, 5, 64, 32
-    c = moe.row_block(t * k)
-    assert c < t * k
-    chosen, live = _loaded_pairs(load, t, k, held, first, c)
+    t, k, load, scored = (case.split("-") + ["64"])[:4]
+    t, k, first, held, d, f, scored = int(t), int(k), 3, 5, 64, 32, int(scored)
+    tm = moe.row_slot(t * k, scored)
+    assert tm == {(320, 64): 16, (256, 64): 8, (2000, 64): 64,
+                  (1600, 64): 64, (320, 512): 2, (256, 256): 4}[t * k, scored]
+    assert moe.row_tile(t * k, scored, jnp.float32) == max(tm, 8)
+    chosen, live = _loaded_pairs(load, t, k, held, first, tm)
     keys = jax.random.split(jax.random.PRNGKey(5), 5)
     h = 0.7 * jax.random.normal(keys[0], (t, d), jnp.float32)
     gates = jax.random.uniform(keys[1], (t, k), jnp.float32, 0.1, 1.0)
@@ -389,26 +410,40 @@ def test_expert_layer_in_chunks_and_row_blocks_is_the_layer(case, monkeypatch):
                                   ("experts_down_w", keys[4], (f, d)))}
     # the reference's loop over the same pairs: its router is stood in for
     monkeypatch.setattr(ref, "route", lambda *_: (None, chosen, gates))
+    if load == "two_products":
+        monkeypatch.setattr(
+            ref, "gated_mlp", lambda h, gate, up, down, precision:
+            jnp.square(jax.nn.relu(h @ up)) @ down)
     want = ref.expert_layer({}, w, h, "f32", held=(first, held), shared=False)
     want = jnp.where(live[:, None], want, 0.0)
     # the held experts' groups lie behind another layer's in the one array
     stacked = [jnp.concatenate([jnp.zeros_like(w[name]), w[name]])
                for name in ("experts_gate_w", "experts_up_w",
                             "experts_down_w")]
+    if load == "two_products":
+        stacked[0] = None
     y, counted = moe.held_experts(h, chosen, gates, live, *stacked, first,
-                                  held, held)
+                                  held, held, scored)
     counted = dict(zip(moe.COUNTERS, np.asarray(counted).tolist()))
     on_held = np.asarray((chosen >= first) & live[:, None])
+    sizes = np.bincount(np.asarray(chosen)[on_held] - first, minlength=held)
+    tiles = -(-sizes // tm)
     assert counted["held"] == on_held.sum() and counted["dropped"] == 0
-    assert counted["rows_run"] == -(-counted["held"] // c) * c
-    assert counted["rows_run"] == {
-        "one_expert": t * k, "no_held_pair": 0, "under_edge": c,
-        "over_edge": 2 * c}.get(load, counted["rows_run"])
+    assert counted["load_max"] == sizes.max()
+    assert counted["touched"] == (sizes > 0).sum()
+    assert counted["rows_run"] == tiles.sum() * tm
+    assert tiles.sum() == {
+        "one_expert": -(-t * k // tm), "two_products": -(-t * k // tm),
+        "no_held_pair": 0, "exactly_tm": 3, "tm_plus_1": 3,
+        "most_tiles": (t * k - held) // tm + held}.get(load, tiles.sum())
+    if load == "exactly_tm":
+        assert counted["rows_run"] == counted["held"]
     if load == "no_held_pair":
         assert not np.asarray(y).any()
     else:
         assert float(jnp.abs(want).max()) > 0.1
-    np.testing.assert_allclose(y, want, atol=1e-5)
+    # (relative where relu^2 of eight summed gates reads 6 and more)
+    np.testing.assert_allclose(y, want, atol=1e-5, rtol=1e-5)
 
 
 def test_the_scheduler_hangs_the_models_counters_on_its_spans():
@@ -453,6 +488,12 @@ def test_the_scheduler_hangs_the_models_counters_on_its_spans():
     assert counted["moe.rows_run"] == sum(
         s["args"]["moe.rows_run"] for s in prefill + steps)
     assert counted["moe.rows_run"] >= counted["moe.held"] > 0
+    # the row tile those rows were laid out in, from the shapes alone: 2
+    # slots, and 16 prompt positions, x 3 choices over 8 experts, bfloat16
+    assert engine.stats()["moe_row_tile"] == {"step": 16, "prefill": {16: 16}}
+    # in slots of 16 rows over the prompt, of 4 in a step (under its tile)
+    assert (moe.row_slot(16 * 3, 8), moe.row_slot(2 * 3, 8)) == (16, 4)
+    assert counted["moe.rows_run"] % 4 == 0
     flat = json.dumps(gauges)
     assert "decode.cache_row_bytes" in flat and "moe.assignments" in flat
 

@@ -571,6 +571,7 @@ def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
 
     f, held = cfg["expert_width"], cfg["experts_held"]
     groups = 2 * held                       # this layer's behind another's
+    scored = cfg["router_experts"]
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -578,7 +579,7 @@ def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
     alone = jax.jit(
         lambda h, chosen, gates, live, gate_w, up_w, down_w, offset:
         moe.held_experts(h, chosen, gates, live, gate_w, up_w, down_w,
-                         cfg["experts_first"], held, offset)).lower(
+                         cfg["experts_first"], held, offset, scored)).lower(
         spec((t, d), jnp.bfloat16), spec((t, k), jnp.int32),
         spec((t, k), jnp.float32), spec((t,), jnp.bool_),
         spec((groups, d, f), jnp.bfloat16), spec((groups, d, f), jnp.bfloat16),
@@ -586,8 +587,13 @@ def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
     cost = obs.device.analyze_compiled(alone)
     bound = 3 * groups * d * f * 2 + 2 * t * k * d * 4
     assert cost["bytes_accessed"] < bound, (cost, bound)
-    # and its temporaries are the buffer of rows and little else
-    assert cost["temp_bytes"] < 1.5 * t * k * d * 4, cost
+    # and its temporaries are the buffer of rows and little else: every
+    # held expert's rows start on a tile of their own, so the buffer is
+    # bounded by ceil(tokens x k / tile) + held tiles
+    tile = moe.row_tile(t * k, scored, jnp.bfloat16)
+    assert tile == {"qwen3-next-80b-a3b": 128, "sarvam-105b": 256}[config]
+    most = (-(-t * k // tile) + held) * tile
+    assert cost["temp_bytes"] < 1.5 * most * d * 4, cost
 
 
 def test_tpu_piece_program_reads_the_prompt_so_far_through_the_page_table(
@@ -680,19 +686,36 @@ def test_tpu_state_space_kernel_updates_the_state_in_place(one_chip):
     assert cost["temp_bytes"] < nbytes // ((b + 1) * layers)  # under one slot's
 
 
-@pytest.mark.parametrize("tokens", [128, 2048])
-def test_tpu_two_product_experts_read_their_weights_where_they_lie(
-        tokens, one_chip, monkeypatch):
-    """``held_experts`` with no gate matrix at the cell's geometry — a step of
-    128 slots and a 2,048-token prompt, 6 choices, 8 layers' 32 experts of
-    2688 x 1856 STORED 3072 x 2048 in one array (3.2 GB a matrix: shapes
-    only) — compiled for a v5e: two grouped products, each in tiles of 512 x
-    512 of its weights, and no copy of an array of experts. XLA:TPU tiles
-    each size by the largest of 512, 256, 128 that divides it: at the
-    published 1856 (14.5 lane tiles) it copies the whole up-projection array
-    before the loop, every call, and at 2688 x 1920 it runs tiles of 128 x
-    128 (13 % of the memory's rate on the chip, PR 40):
-    ``models/ssm_moe.py`` stores whole tiles of 512."""
+# cell: (k, hidden, hidden as stored, expert width as stored, experts held,
+# expert layers, experts the router scores, whether an expert has a gate
+# matrix)
+EXPERT_CELLS = {"qwen3next": (10, 2048, 2048, 512, 128, 8, 512, True),
+                "sarvam": (8, 4096, 4096, 2048, 32, 5, 128, True),
+                "nemotron3nano": (6, 2688, 3072, 2048, 32, 8, 128, False)}
+
+
+@pytest.mark.parametrize("cell,tokens,tile", [
+    ("qwen3next", 2048, 64), ("qwen3next", 32, 16), ("sarvam", 3072, 256),
+    ("sarvam", 32, 16), ("nemotron3nano", 2048, 128),
+    ("nemotron3nano", 128, 16)])
+def test_tpu_grouped_products_take_the_row_tile_held_experts_chose(
+        cell, tokens, tile, one_chip, monkeypatch):
+    """``held_experts`` at the three expert cells' geometries — a prompt (a
+    2,048-position piece of qwen3next's, sarvam's median 3,072, nemotron3nano's
+    longest 2,048) and a step of the cell's slots, every expert layer's
+    experts in one array (nemotron3nano's two matrices of 2688 x 1856 STORED
+    3072 x 2048: 3.2 GB a matrix, shapes only) — compiled for a v5e.
+    XLA:TPU takes as the grouped kernel's row tile the largest power of two,
+    512 at most, that divides the rows a product is handed; ``row_block``
+    hands it ``row_tile`` x an odd number, so the compiled products carry
+    the tile ``held_experts`` laid the rows out in (a libtpu that picks
+    otherwise would run tiles that straddle experts: this is the guard).
+    Each product in tiles of 512 x 512 of its weights, and no copy of an
+    array of experts: XLA:TPU tiles each size by the largest of 512, 256,
+    128 that divides it — at the published 1856 (14.5 lane tiles) it copies
+    the whole up-projection array before the loop, every call, and at 2688 x
+    1920 it runs tiles of 128 x 128 (13 % of the memory's rate on the chip,
+    PR 40): ``models/ssm_moe.py`` stores whole tiles of 512."""
     import re
 
     import jax
@@ -701,26 +724,29 @@ def test_tpu_two_product_experts_read_their_weights_where_they_lie(
     from mxnet_tpu.models.ssm_moe import stored_width
 
     _as_on_a_tpu(monkeypatch)
-    k, d, held = 6, 2688, 32
-    wide, f = stored_width(d), stored_width(1856)
-    assert (wide, f) == (3072, 2048)
-    groups = 8 * held
+    k, d, wide, f, held, layers, scored, gated = EXPERT_CELLS[cell]
+    assert (stored_width(2688), stored_width(1856)) == (3072, 2048)
+    assert moe.row_tile(tokens * k, scored, jnp.bfloat16) == tile
+    groups = layers * held
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     compiled = jax.jit(
-        lambda h, chosen, gates, live, up_w, down_w, offset:
-        moe.held_experts(h, chosen, gates, live, None, up_w, down_w, 0, held,
-                         offset)).lower(
+        lambda h, chosen, gates, live, gate_w, up_w, down_w, offset:
+        moe.held_experts(h, chosen, gates, live, gate_w if gated else None,
+                         up_w, down_w, 0, held, offset, scored)).lower(
         spec((tokens, d), jnp.bfloat16), spec((tokens, k), jnp.int32),
         spec((tokens, k), jnp.float32), spec((tokens,), jnp.bool_),
         spec((groups, wide, f), jnp.bfloat16),
+        spec((groups, wide, f), jnp.bfloat16),
         spec((groups, f, wide), jnp.bfloat16), spec((), jnp.int32)).compile()
     text = compiled.as_text()
-    assert text.count("ragged-dot") >= 2
+    products = 3 if gated else 2
+    assert text.count("ragged-dot-none") >= products
     tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
-    assert tilings and all(t[1:] == ("512", "512") for t in tilings), tilings
+    assert len(tilings) >= products and all(
+        t == (str(tile), "512", "512") for t in tilings), tilings
     cost = obs.device.analyze_compiled(compiled)
     one_matrix = groups * wide * f * 2
     # the rows' buffer and a block's products, far from a matrix of experts
