@@ -28,6 +28,15 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
               tokens prefilled; ``ssm_decode`` against XLA on random states of
               64 x 64 x 128 float32; the step's temporaries under one layer's
               share of pool plus state;
+- *scmoe*     the shortcut-connected double-layer latent model
+              (``models.mla_scmoe``: head geometry as published, a query
+              latent, identity experts behind the softmax router) at 128
+              slots through ``DecodeEngine``, in memory filled with NaN
+              beforehand: a 300-token prefill and four steps, the pool a layer
+              a SUB-layer, ``moe.zero`` counted, nothing dropped, and every
+              served token within 0.05 of the first choice of the plain
+              reference's float32 full forward
+              (``benchmark/reference_mla_scmoe.py``, run on the chip);
 - *experts*   ``ops.moe.held_experts`` at the three expert cells' prompt
               and decode-step geometries against a plain masked loop in
               bfloat16 on the chip: the error, nothing dropped, the row tile
@@ -943,6 +952,88 @@ def phase_ssm():
                  "copies the pool or the state")
 
 
+# published head geometry of the double-layer latent model (16 heads of 128 +
+# 64 on a 512-wide latent, a query latent, both latent scales, plain RoPE at
+# theta 1e7), 32 real + 16 identity experts behind the softmax router
+SCMOE = {"vocab_size": 4096, "hidden_size": 1024, "num_layers": 2,
+         "num_heads": 16, "qk_nope": 128, "qk_rope": 64, "v_head": 128,
+         "kv_rank": 512, "q_rank": 384, "latent_scales": True,
+         "dense_width": 2048, "expert_width": 512, "router_experts": 48,
+         "zero_experts": 16, "experts_first": 8, "experts_held": 8,
+         "experts_per_token": 6, "routed_scale": 6.0, "rms_eps": 1e-5,
+         "max_length": 1024, "rope": {"theta": 10000000, "factor": 1}}
+
+
+def phase_scmoe():
+    """Shortcut-connected double layers over the latent pool at 128 slots:
+    a prefill and four steps through the engine's two programs, in memory
+    filled with NaN first, and the served tokens against the plain
+    reference's full forward (``benchmark/reference_mla_scmoe.py``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import reference_mla_scmoe as reference
+    from mxnet_tpu.models import mla_scmoe
+    from mxnet_tpu.serve import DecodeEngine
+    from mxnet_tpu.serve.kvcache import SCRATCH_PAGE
+
+    with _Phase("scmoe"):
+        slots, page, seed = 128, 256, 7
+        _dirty_memory()
+        model = mla_scmoe.MLAScMoEDecodeModel(SCMOE, seed=seed)
+        _require(all(bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+                     for a in jax.tree_util.tree_leaves(model.params)),
+                 "the seeded weights are not all finite")
+        engine = DecodeEngine(model, slots=slots, page_size=page,
+                              num_pages=slots * 2 + 1, prompt_buckets=[512])
+        _require(engine.kv.shape == (slots * 2 + 1, 4, page, 640),
+                 f"the pool {engine.kv.shape} has not a layer a sub-layer")
+        engine.warmup()
+        rng = np.random.RandomState(8)
+        prompt = rng.randint(0, SCMOE["vocab_size"], 300)
+        engine.pool.alloc(0, 2)
+        table = engine.pool.table(0)
+        served = [engine.prefill(prompt, table, slot=77)]
+        c = engine.last_counters
+        _require(c["moe.dropped"] == 0 and c["moe.assignments"] == 2 * 300 * 6
+                 and 0 < c["moe.zero"] < c["moe.assignments"]
+                 and 0 < c["moe.held"] < c["moe.assignments"],
+                 f"prefill counted {c}")
+        print(f"   a 300-token prefill counted {c}", flush=True)
+        tables = np.full((slots, engine.max_pages), SCRATCH_PAGE, np.int32)
+        tables[77, :len(table)] = table
+        z = np.zeros((slots,), np.int32)
+        for i in range(4):
+            pos, lengths, toks = z.copy(), z.copy(), z.copy()
+            pos[77], lengths[77], toks[77] = 300 + i, 301 + i, served[-1]
+            served.append(int(engine.step(
+                toks, pos, tables, lengths,
+                np.zeros((slots,), np.float32))[77]))
+            c = engine.last_counters
+            _require(c["moe.dropped"] == 0 and c["moe.assignments"] == 2 * 6
+                     and c["moe.zero"] + c["moe.held"] <= 12,
+                     f"step counted {c}")
+        engine.pool.free(0)
+        engine.pool.assert_baseline()
+        program = engine.stats()["step_program"]
+        layer = engine.kv.nbytes // model.layers
+        _require(program["temp_bytes"] < layer,
+                 f"the step allocates {program['temp_bytes']} bytes: it "
+                 "slices, copies or lays the pool out again")
+        # the served tokens judged by the reference's float32 logits over
+        # prompt + served: how far each lies below the reference's first
+        seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
+        logits = np.asarray(reference.logits(SCMOE, seed, seq))[299:]
+        gaps = reference.gaps_below_best(logits, served)
+        spread = float(logits.max(axis=1).mean() - logits.mean())
+        print(f"   served {served}; gaps below the reference's best "
+              f"{np.round(gaps, 4).tolist()} (its best lies {spread:.3f} over "
+              "its mean logit)", flush=True)
+        _require(np.isfinite(logits).all() and float(gaps.max()) <= 0.05,
+                 f"a served token lies {gaps.max():.3g} below the "
+                 "reference's first (bfloat16 against float32: 0.05 allowed)")
+
+
 def _dirty_memory():
     """Fill what is free of the device's memory with NaN and free it again.
     A fresh process finds zeros where it never wrote; a long-lived one does
@@ -1077,6 +1168,7 @@ def main():
     phase_latent()
     phase_state()
     phase_ssm()
+    phase_scmoe()
     phase_experts()
     multichip_attn = phase_multichip(losses[0])
     print("summary " + json.dumps({

@@ -23,6 +23,15 @@ leading layers with a SiLU-gated MLP, the rest expert layers
   row, ``u_i = sum_j p_ij c_j``, ``o_i = u_i.W_uv_i``
   (``ops.flash_attention.latent_decode_attention``).
 
+The two attention halves (:func:`prefill_attention`, :func:`decode_attention`)
+are ``models/mla_scmoe.py``'s too, which gives them what this model lacks: a
+**query latent** (a layer with ``q_a_w``: ``cq = RMSNorm(h.W_qa)``, ``q =
+cq.W_qb`` in place of ``h.W_q``), **latent scales** (``cfg["latent_scales"]``:
+the normed query and key-value latents times ``(hidden / rank)^0.5``, the
+cached ``c`` after norm and scale, so both forms still hold the same numbers)
+and plain RoPE (a ``rope`` of ``factor`` 1: the published frequencies, softmax
+scale ``q_head_dim**-0.5``).
+
 Weights and activations are bfloat16 with float32 accumulation; norms,
 RoPE, softmax and the router run in float32.
 
@@ -195,8 +204,10 @@ def yarn_inv_freq(rope: dict, dim: int) -> np.ndarray:
     published frequencies, above the ``beta_slow`` one those divided by
     ``factor``, a linear ramp between."""
     theta, factor = rope["theta"], rope["factor"]
-    orig = rope["original_max_position_embeddings"]
     extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if factor == 1:     # plain RoPE: nothing to divide, no ramp
+        return extra.astype(np.float32)
+    orig = rope["original_max_position_embeddings"]
 
     def correction(rotations):
         return (dim * math.log(orig / (rotations * 2 * math.pi))
@@ -213,6 +224,8 @@ def softmax_scale(cfg: dict) -> float:
     ln(factor) + 1``; cos and sin stay unscaled (``mscale ==
     mscale_all_dim``)."""
     rope = cfg["rope"]
+    if rope["factor"] == 1:
+        return (cfg["qk_nope"] + cfg["qk_rope"]) ** -0.5
     if rope["mscale"] != rope["mscale_all_dim"]:
         raise NotImplementedError("yarn with mscale != mscale_all_dim scales "
                                   "cos and sin: not written")
@@ -236,14 +249,25 @@ def _latent(cfg, lp, x, cos, sin):
     """What both attention forms share, for tokens x (T, D): the normed
     input's queries (q_nope (T, H, nope), q_rope rotated (T, H, rope), both
     bf16) and the cache row ``[c || k_r || 0]`` (T, cache_row_width)
-    bf16."""
+    bf16. A layer with ``q_a_w`` makes its queries from a normed latent;
+    ``cfg["latent_scales"]`` scales the normed latents (module docstring)."""
     heads, nope, rank = cfg["num_heads"], cfg["qk_nope"], cfg["kv_rank"]
+    d, scaled = x.shape[1], cfg.get("latent_scales", False)
     h = rms_norm(x, lp["attn_norm"], cfg["rms_eps"]).astype(x.dtype)
-    q = jnp.einsum("td,ed->te", h, lp["q_w"],     # (out, in): as it lies,
-                   preferred_element_type=jnp.float32)  # no transposed copy
+    if "q_a_w" in lp:
+        cq = rms_norm(_mm(h, lp["q_a_w"]), lp["q_norm"], cfg["rms_eps"])
+        if scaled:
+            cq = cq * (d / cq.shape[1]) ** 0.5
+        q_in, q_w = cq.astype(x.dtype), lp["q_b_w"]
+    else:
+        q_in, q_w = h, lp["q_w"]
+    q = jnp.einsum("td,ed->te", q_in, q_w,     # (out, in): as it lies, no
+                   preferred_element_type=jnp.float32)      # transposed copy
     q = q.reshape(x.shape[0], heads, -1)
     kva = _mm(h, lp["kva_w"])
     c = rms_norm(kva[:, :rank], lp["kv_norm"], cfg["rms_eps"])
+    if scaled:
+        c = c * (d / rank) ** 0.5
     row = jnp.concatenate([c, _rotate(kva[:, rank:], cos, sin)], axis=-1)
     row = jnp.pad(row, ((0, 0), (0, cache_row_width(cfg) - row.shape[1])))
     return (q[..., :nope].astype(x.dtype),
@@ -273,9 +297,9 @@ def _mlp(cfg, lp, x, live, experts):
     return (x.astype(jnp.float32) + y).astype(x.dtype), counters
 
 
-def prefill_layer(cfg, lp, x, cos, sin, live, experts):
-    """One block over a prompt x (S, D), attention expanded through the flash
-    forward. Returns (x', rows (S, R), counters)."""
+def prefill_attention(cfg, lp, x, cos, sin):
+    """``x + Attn(RMSNorm(x))`` over a prompt x (S, D), expanded through the
+    flash forward. Returns (x', rows (S, R))."""
     rank = cfg["kv_rank"]
     q_nope, q_rope, row = _latent(cfg, lp, x, cos, sin)
     c, k_r = row[:, :rank], row[:, rank:rank + cfg["qk_rope"]]
@@ -290,13 +314,11 @@ def prefill_layer(cfg, lp, x, cos, sin, live, experts):
     o = flash_attention(q[None], k[None], v[None], causal=True,
                         scale=softmax_scale(cfg))[0]          # (H, S, v)
     o = jnp.swapaxes(o, 0, 1).reshape(x.shape[0], -1)
-    x = (x.astype(jnp.float32) + _mm(o, lp["o_w"])).astype(x.dtype)
-    x, counters = _mlp(cfg, lp, x, live, experts)
-    return x, row, counters
+    return (x.astype(jnp.float32) + _mm(o, lp["o_w"])).astype(x.dtype), row
 
 
-def decode_layer(cfg, lp, x, cos, sin, live, experts, attend):
-    """One block for one new position per sequence, x (B, D), attention
+def decode_attention(cfg, lp, x, cos, sin, attend):
+    """``x + Attn(RMSNorm(x))`` for one new position per sequence, x (B, D),
     absorbed: ``attend(query (B, H, R), row (B, R)) -> u (B, H, kv_rank)``
     writes the row into the cache and attends over the cached rows."""
     q_nope, q_rope, row = _latent(cfg, lp, x, cos, sin)
@@ -311,9 +333,22 @@ def decode_layer(cfg, lp, x, cos, sin, live, experts, attend):
     o = jnp.swapaxes(jnp.einsum(
         "hbc,hcv->hbv", jnp.swapaxes(u, 0, 1), lp["uv_w"],
         preferred_element_type=jnp.float32), 0, 1).astype(x.dtype)
-    x = (x.astype(jnp.float32)
-         + _mm(o.reshape(x.shape[0], -1), lp["o_w"])).astype(x.dtype)
-    return _mlp(cfg, lp, x, live, experts)
+    return (x.astype(jnp.float32)
+            + _mm(o.reshape(x.shape[0], -1), lp["o_w"])).astype(x.dtype)
+
+
+def prefill_layer(cfg, lp, x, cos, sin, live, experts):
+    """One block over a prompt x (S, D). Returns (x', rows (S, R),
+    counters)."""
+    x, row = prefill_attention(cfg, lp, x, cos, sin)
+    x, counters = _mlp(cfg, lp, x, live, experts)
+    return x, row, counters
+
+
+def decode_layer(cfg, lp, x, cos, sin, live, experts, attend):
+    """One block for one new position per sequence, x (B, D)."""
+    return _mlp(cfg, lp, decode_attention(cfg, lp, x, cos, sin, attend), live,
+                experts)
 
 
 class MLAMoEDecodeModel:

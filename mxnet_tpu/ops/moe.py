@@ -12,6 +12,8 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
   bias chooses, never weighs); ``g = scale * s_chosen / sum(s_chosen)``.
   :func:`route_softmax` is the other family's: ``p = softmax(h.W_r)`` over all
   the experts, the k largest, ``g = p_chosen / sum(p_chosen)``; no bias.
+  :func:`route_softmax_scaled` is a third's: the k largest of ``p + b``,
+  ``g = scale * p_chosen``, NOT renormalised.
 - :func:`held_experts` — dropless, and its work follows the rows the held
   experts have, not the static bound ``tokens x k``: the (token, choice)
   pairs are sorted by expert (those on experts held elsewhere, and the
@@ -30,8 +32,13 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
   weighted sum, and a pair no held expert has is not fetched at all.
   Nothing is dropped: when every pair falls on a held expert every block
   runs.
-- :func:`expert_layer` — routed part + shared expert, and the counters
-  (``COUNTERS``) that ``serve/decode.py`` hangs on its spans.
+- :func:`expert_layer` — routed part + shared expert (where the layer has
+  one), and the counters (``COUNTERS``) that ``serve/decode.py`` hangs on its
+  spans. **Identity experts** (``zero_experts``): the router's last ids are
+  experts that compute nothing, ``E_e(h) = h``; a pair chosen there adds
+  ``g . h`` on every chip alike, as a shared expert is on every chip, runs no
+  grouped row (its id lies outside the held ones, so it sorts behind them)
+  and is counted in one more counter, ``zero`` (``ZERO_COUNTERS``).
 
 **Two forms of expert**, told apart by the leaves a model hands over: with a
 gate matrix three products, ``(silu(h.W_g) * h.W_u).W_d``
@@ -49,9 +56,9 @@ from jax import lax
 
 from . import flash_attention
 
-__all__ = ["route", "route_softmax", "held_experts", "expert_layer",
-           "gated_mlp", "relu2_mlp", "row_slot", "row_tile", "row_block",
-           "layer_row_tile", "COUNTERS"]
+__all__ = ["route", "route_softmax", "route_softmax_scaled", "held_experts",
+           "expert_layer", "gated_mlp", "relu2_mlp", "row_slot", "row_tile",
+           "row_block", "layer_row_tile", "COUNTERS", "ZERO_COUNTERS"]
 
 # per call: live (token, choice) pairs; those on held experts; most tokens on
 # one held expert; held experts with at least one token; held pairs that no
@@ -60,6 +67,8 @@ __all__ = ["route", "route_softmax", "held_experts", "expert_layer",
 # starting every expert's rows on a slot of their own costs in padding)
 COUNTERS = ("assignments", "held", "load_max", "touched", "dropped",
             "rows_run")
+# of a layer with identity experts: live pairs that fell on them, last
+ZERO_COUNTERS = COUNTERS + ("zero",)
 TOKEN_CHUNK = 4096   # most tokens routed and multiplied at a time: bounds the
 #                      sorted rows (tokens x k of them) and their products
 
@@ -93,12 +102,16 @@ def _grouped(rows, w, sizes):
                           precision=flash_attention._dot_prec(rows.dtype))
 
 
+def _router_logits(h, router_w):
+    """``h.W_r`` in float32 at full precision, whatever h is."""
+    return jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
 def route(h, router_w, router_b, k: int, scale: float):
     """h (T, D) -> (chosen (T, k) int32 global expert ids, gates (T, k)
     float32)."""
-    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
-                               router_w.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
+    s = jax.nn.sigmoid(_router_logits(h, router_w))
     _, chosen = lax.top_k(s + router_b.astype(jnp.float32), k)
     picked = jnp.take_along_axis(s, chosen, axis=1)
     return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
@@ -107,11 +120,19 @@ def route(h, router_w, router_b, k: int, scale: float):
 def route_softmax(h, router_w, k: int):
     """h (T, D) -> (chosen (T, k), gates (T, k) float32): the k largest of
     ``softmax(h.W_r)`` over every expert, renormalised to sum 1."""
-    p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32),
-                               router_w.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST), axis=-1)
+    p = jax.nn.softmax(_router_logits(h, router_w), axis=-1)
     picked, chosen = lax.top_k(p, k)
     return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def route_softmax_scaled(h, router_w, router_b, k: int, scale: float):
+    """h (T, D) -> (chosen (T, k), gates (T, k) float32): ``p =
+    softmax(h.W_r)`` over every expert the router scores, identity experts
+    among them; the k largest of ``p + b`` (the bias chooses, never weighs);
+    ``g = scale * p_chosen``, not renormalised."""
+    p = jax.nn.softmax(_router_logits(h, router_w), axis=-1)
+    _, chosen = lax.top_k(p + router_b.astype(jnp.float32), k)
+    return chosen, scale * jnp.take_along_axis(p, chosen, axis=1)
 
 
 def row_slot(pairs: int, scored: int) -> int:
@@ -366,26 +387,41 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
 
 
 def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
-                 scale: float = 1.0, offset=0):
+                 scale: float = 1.0, offset=0, zero_experts: int = 0):
     """One expert layer over h (T, D): the held experts' routed part plus
     the shared expert. ``p``: ``router_w`` (D, E_all), ``router_b`` (E_all,),
     ``shared_{gate,up,down}_w``; ``experts``: ``gate_w``, ``up_w``,
     ``down_w`` as :func:`held_experts` takes them, with ``offset``; a layer
     with no ``gate_w`` / ``shared_gate_w`` has experts of two products
-    (module docstring). A layer
+    (module docstring), one with no ``shared_up_w`` no shared expert. A layer
     with no ``router_b`` routes by :func:`route_softmax` (``scale`` unused);
     one with ``shared_s_w`` (D,) weighs its shared expert by
-    ``sigmoid(h.w_s)``. Tokens go at most ``TOKEN_CHUNK`` at a time. Returns
-    (y (T, D) float32, counters)."""
+    ``sigmoid(h.w_s)``. ``zero_experts`` > 0: the router's last that many ids
+    are identity experts and it routes by :func:`route_softmax_scaled`; their
+    term ``(sum of the gates chosen there) . h`` is added in float32 and the
+    counters are ``ZERO_COUNTERS``. Tokens go at most ``TOKEN_CHUNK`` at a
+    time. Returns (y (T, D) float32, counters)."""
+    scored = p["router_w"].shape[1]
+
     def chunk(args):
         hc, lc = args
-        if "router_b" in p:
+        if zero_experts:
+            chosen, gates = route_softmax_scaled(hc, p["router_w"],
+                                                 p["router_b"], k, scale)
+        elif "router_b" in p:
             chosen, gates = route(hc, p["router_w"], p["router_b"], k, scale)
         else:
             chosen, gates = route_softmax(hc, p["router_w"], k)
         y, c = held_experts(hc, chosen, gates, lc, experts.get("gate_w"),
                             experts["up_w"], experts["down_w"], first, held,
-                            offset, p["router_w"].shape[1])
+                            offset, scored)
+        if zero_experts:
+            on_zero = (chosen >= scored - zero_experts) & lc[:, None]
+            y = y + (jnp.sum(jnp.where(on_zero, gates, 0.0), axis=-1,
+                             keepdims=True) * hc.astype(jnp.float32))
+            c = jnp.concatenate([c, jnp.sum(on_zero, dtype=jnp.int32)[None]])
+        if "shared_up_w" not in p:
+            return y, c
         if "shared_gate_w" in p:
             shared = gated_mlp(hc, p["shared_gate_w"], p["shared_up_w"],
                                p["shared_down_w"])

@@ -798,3 +798,109 @@ def test_tpu_prefill_program_with_state_space_layers(ssm_engine, one_chip,
     cost = obs.device.analyze_compiled(compiled)
     held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
     assert cost["alias_bytes"] >= held
+
+
+# -- double layers over the latent pool, at the cell's own sizes ----------------
+# ``longcat-omni-serve-closed-128`` as the benchmark runs it: published widths,
+# 4 double layers, 16 held of 512 real + 256 identity experts, 128 slots of
+# 2,048 positions. Shapes only: 10.35 GB of weights are never made.
+
+@pytest.fixture(scope="module")
+def scmoe_engine():
+    import json
+    import os
+
+    import jax
+
+    from mxnet_tpu.models import mla_scmoe
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "configs", "longcat-flash-omni.json")
+    with open(path) as f:
+        cfg = json.load(f)["model"]
+    assert cfg.pop("kind") == "mla_scmoe_lm"
+    shapes = jax.eval_shape(lambda: mla_scmoe.init_params(cfg, 0))
+    model = mla_scmoe.MLAScMoEDecodeModel(cfg, params=shapes)
+    return DecodeEngine(model, slots=128, page_size=256,
+                        num_pages=128 * 8 + 1, prompt_buckets=[256])
+
+
+def _weight_copies(compiled, engine):
+    """Instructions of the optimised entry computation that make a new
+    bfloat16 array the size of a sub-layer's query up-projection (38 MB) or
+    larger in the device's main memory (the pool's in-place writes apart,
+    and what XLA prefetches into its fast memory, ``S(1)``, for the product
+    that reads it): a slice, a transpose or a copy of a stack of weights."""
+    import re
+
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY ") + 1:]
+    found = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]", line)
+        if not m or " parameter(" in line or "get-tuple-element(" in line:
+            continue
+        if "S(1)}" in line[:line.index(" = ") + 80]:
+            continue
+        shape = tuple(int(n) for n in m.group(1).split(","))
+        if shape != engine.kv.shape and np.prod(shape) >= 12288 * 1536:
+            if " fusion(" in line or " copy(" in line:
+                found.append(line.strip()[:160])
+    return found
+
+
+def test_tpu_double_layer_step_program_at_the_cells_sizes(
+        scmoe_engine, one_chip, monkeypatch):
+    """The step of the double-layer latent model compiled for a v5e at the
+    cell's sizes: the pool ``(1025, 8, 256, 640)`` bfloat16 has a layer a
+    SUB-layer (8 for 4 double layers) and rests row-major; the step's
+    arguments are the 10.35 GB of weights + 2.69 GB of pool and its
+    temporaries stay under one pool layer's latents; no weight stack is
+    sliced, transposed or copied on its way into a product (``w[j][i]``
+    copied 302 MB a leaf, ``q_b_w`` stored (in, out) 38 MB twice a
+    sub-layer: PR 44); and the held experts' grouped products carry the row
+    tile ``moe.layer_row_tile`` names for 128 tokens x 12 choices over the
+    router's whole width of 768."""
+    import re
+
+    import jax.numpy as jnp
+
+    engine = scmoe_engine
+    assert engine.kv.shape == (1025, 8, 256, 640) and engine.paged_layers == 8
+    assert engine.cache_row_bytes == 1280
+    tile = moe.layer_row_tile(128, 12, 768, jnp.bfloat16)
+    assert tile == 16 and engine.stats()["moe_row_tile"]["step"] == tile
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
+    text = lowered.as_text()
+    assert all(name in text for name in ("mla_decode", "moe_rows",
+                                         "moe_rows_back"))
+    cost = obs.device.analyze_compiled(compiled)
+    assert 13.0e9 < cost["argument_bytes"] < 13.1e9, cost
+    assert cost["temp_bytes"] < engine.kv.nbytes // 8, cost
+    assert cost["alias_bytes"] >= engine.kv.nbytes
+    assert not _weight_copies(compiled, engine)
+    optimised = compiled.as_text()
+    assert optimised.count("ragged-dot-none") >= 3 * 4   # 3 products x 4 layers
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', optimised)
+    assert len(tilings) >= 12 and all(
+        t == (str(tile), "512", "512") for t in tilings), tilings
+
+
+def test_tpu_double_layer_prefill_program_at_the_cells_sizes(
+        scmoe_engine, one_chip, monkeypatch):
+    """A 256-position prefill of the same model for a v5e: the flash forward
+    through Mosaic, the double layers unrolled so that every weight is read
+    where it lies (under a ``lax.scan`` each iteration copied its double
+    layer's 1.28 GB out of the stacks), the pool donated and written in
+    place."""
+    engine = scmoe_engine
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
+    assert lowered.as_text().count("tpu_custom_call") >= 3
+    cost = obs.device.analyze_compiled(compiled)
+    assert cost["temp_bytes"] < 0.5e9, cost
+    assert cost["alias_bytes"] >= engine.kv.nbytes
+    assert not _weight_copies(compiled, engine)
+    lines = _pool_lines(compiled, engine)
+    assert not [line for line in lines if " copy(" in line], lines
