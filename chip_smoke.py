@@ -519,13 +519,17 @@ LATENT = {"vocab_size": 4096, "hidden_size": 1024, "num_layers": 3,
           "v_head": 128, "kv_rank": 512, "dense_width": 2048,
           "expert_width": 512, "router_experts": 32, "experts_first": 8,
           "experts_held": 8, "experts_per_token": 8, "routed_scale": 2.5,
-          "rms_eps": 1e-6, "max_length": 2048,
+          "rms_eps": 1e-6, "max_length": 4096,
           "rope": {"theta": 10000, "factor": 40,
                    "original_max_position_embeddings": 4096, "beta_fast": 32,
                    "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}}
 
 
 def phase_latent():
+    """The latent-attention model through the engine's two programs — a
+    prompt in pieces of 1,024 against the same prompt whole, in memory filled
+    with NaN first —, and its paged kernel against XLA over what the engine
+    wrote."""
     import jax
     import jax.numpy as jnp
 
@@ -536,25 +540,50 @@ def phase_latent():
     from mxnet_tpu.serve.kvcache import SCRATCH_PAGE
 
     with _Phase("latent"):
-        slots, page = 4, 256
+        slots, page, n, padded = 4, 256, 2500, 3072
+        _dirty_memory()
         model = mla_moe.MLAMoEDecodeModel(LATENT, seed=3)
+        # the model offers ``prefill_from``: the prompt goes in pieces of the
+        # smallest bucket, through ONE prefill program
         engine = DecodeEngine(model, slots=slots, page_size=page,
-                              num_pages=slots * 8 + 1, prompt_buckets=[512])
+                              num_pages=slots * 16 + 1,
+                              prompt_buckets=[1024, padded])
         engine.warmup()
+        _require(engine.prefill_piece == 1024 and engine.buckets == [1024]
+                 and engine.max_prompt == padded
+                 and engine.stats()["num_programs"] == 2,
+                 f"pieces of 1024 expected: {engine.stats()}")
         rng = np.random.RandomState(4)
-        prompt = rng.randint(0, LATENT["vocab_size"], 300)
-        engine.pool.alloc(0, 2)
+        prompt = rng.randint(0, LATENT["vocab_size"], n)
+        engine.pool.alloc(0, padded // page)
         table = engine.pool.table(0)
-        tok = engine.prefill(prompt, table)
-        _require(engine.last_counters["moe.dropped"] == 0
-                 and engine.last_counters["moe.assignments"] == 2 * 300 * 8,
-                 f"prefill counted {engine.last_counters}")
+        counted = {}
+        for start in range(0, padded, engine.prefill_piece):
+            tok, c = engine.read(engine.launch_prefill(prompt, table,
+                                                       start=start))
+            counted = {k: counted.get(k, 0) + v for k, v in c.items()}
+        _require(counted["moe.dropped"] == 0
+                 and counted["moe.assignments"] == 2 * n * 8,
+                 f"prefill counted {counted}")
+        # ... against the model's ``prefill``, the whole-prompt kernel
+        whole = np.zeros((1, padded), np.int32)
+        whole[0, :n] = prompt
+        logits, want_rows, _ = jax.jit(model.prefill)(
+            model.params, jnp.asarray(whole), n)
+        rows = np.asarray(engine.kv[np.asarray(table)], np.float32)
+        rows = np.moveaxis(rows, 1, 0).reshape(rows.shape[1], padded, -1)
+        _check_mostly_close(
+            f"{n} tokens in pieces of 1024 against whole: rows", rows[:, :n],
+            np.asarray(want_rows[:, :n], np.float32), 2e-2)
+        _require(tok == int(jnp.argmax(logits)),
+                 f"a prompt in pieces served {tok} first, prefill whole "
+                 f"{int(jnp.argmax(logits))}")
         tables = np.full((slots, engine.max_pages), SCRATCH_PAGE, np.int32)
         tables[0, :len(table)] = table
         z = np.zeros((slots,), np.int32)
         for i in range(4):
             pos, lengths, toks = z.copy(), z.copy(), z.copy()
-            pos[0], lengths[0], toks[0] = 300 + i, 301 + i, tok
+            pos[0], lengths[0], toks[0] = n + i, n + 1 + i, tok
             tok = int(engine.step(toks, pos, tables, lengths,
                                   np.zeros((slots,), np.float32))[0])
             _require(engine.last_counters["moe.dropped"] == 0
@@ -564,7 +593,7 @@ def phase_latent():
         q = jax.random.normal(jax.random.PRNGKey(5), (slots, 16, 640),
                               jnp.bfloat16)
         args = (q, engine.kv, 1, jnp.asarray(tables),
-                jnp.asarray([304, 0, 0, 0], jnp.int32), 512, 0.135)
+                jnp.asarray([n + 4, 0, 0, 0], jnp.int32), 512, 0.135)
         kernel = jax.jit(flash_latent_decode_attention, static_argnums=(2, 5, 6))
         _require_mosaic(kernel, *args)
         got = np.asarray(kernel(*args), np.float32)[0]
@@ -572,7 +601,7 @@ def phase_latent():
                                   static_argnums=(2, 5, 6))(*args),
                           np.float32)[0]
         _require(np.all(np.isfinite(got)), "latent kernel: non-finite")
-        _check_close("paged latent decode (16 x 640) over 304 positions",
+        _check_close(f"paged latent decode (16 x 640) over {n + 4} positions",
                      got, want, 3e-2)
         engine.pool.free(0)
         engine.pool.assert_baseline()

@@ -435,3 +435,79 @@ class MLAMoEDecodeModel:
         experts' grouped products run a call of ``tokens`` tokens with."""
         return moe.layer_row_tile(tokens, self.cfg["experts_per_token"],
                                   self.cfg["router_experts"], self.cache_dtype)
+
+    # -- a prompt continued from a position. Everything below stands at the
+    # end, of the class and of the module, so that what ``models/mla_scmoe.py``
+    # traces its programs through keeps its lines.
+
+    @property
+    def prefill_from(self):
+        """``prefill_from(params, tokens (1, C), start, length, prior,
+        state)`` as ``DecodeEngine`` takes one (:meth:`_prefill_from`). It
+        continues THIS class's :meth:`prefill`: a subclass with a ``prefill``
+        of its own has none (``hasattr`` is false, and its engine prefills
+        whole) until it brings its own."""
+        if type(self).prefill is not MLAMoEDecodeModel.prefill:
+            raise AttributeError("prefill_from")
+        return self._prefill_from
+
+    def _prefill_from(self, params, tokens, start, length, prior, state):
+        """A prompt continued: tokens (1, C) are positions ``start .. start +
+        C - 1`` of a prompt of ``length`` (``start`` () int32, a multiple of
+        C), ``prior(layer) -> (T, R)`` the rows of positions 0 .. T - 1 as
+        the pool has them (T a multiple of C), of which those ``< start`` are
+        read; ``state`` is ``{}``. Returns what :meth:`prefill` returns and
+        ``{}``: the logits at ``length - 1`` (of the last piece alone: zeros
+        before, and the head's weights not read), the piece's rows, its
+        counters."""
+        cfg = self.cfg
+        c, n_dense = tokens.shape[1], cfg["first_dense"]
+        positions = start + jnp.arange(c)
+        cos, sin = self._angles(positions)
+        live = positions < length
+        x = params["embed"][tokens[0]]
+        rows = []
+        for i in range(n_dense):
+            lp = {k: w[i] for k, w in params["dense"].items()}
+            x, row = prefill_attention_from(cfg, lp, x, cos, sin, start,
+                                            prior(i))
+            x, _ = _mlp(cfg, lp, x, live, None)
+            rows.append(row[None])
+
+        def layer(x, xs):
+            lp, j = xs
+            x, row = prefill_attention_from(cfg, lp, x, cos, sin, start,
+                                            prior(n_dense + j))
+            x, counters = _mlp(cfg, lp, x, live, (params["experts"], j))
+            return x, (row, counters)
+
+        x, (moe_rows, counters) = lax.scan(
+            layer, x, (params["moe"], jnp.arange(self.layers - n_dense)))
+        logits = lax.cond(
+            start + c >= length, lambda h: self._head(params, h),
+            lambda h: jnp.zeros((cfg["vocab_size"],), jnp.float32),
+            x[jnp.clip(length - 1 - start, 0, c - 1)])
+        return (logits, jnp.concatenate(rows + [moe_rows]),
+                moe.merge_counters(counters), {})
+
+
+def prefill_attention_from(cfg, lp, x, cos, sin, start, before):
+    """:func:`prefill_attention` for a piece x (C, D) of a prompt, positions
+    ``start .. start + C - 1`` (``start`` traced, a multiple of C), over the
+    rows of every position so far: ``before`` (T, R), the pool's rows of
+    positions 0 .. T - 1, of which those ``< start`` are read — what lies at
+    and behind the piece there is whatever the pool held (memory no program
+    wrote, NaN in the tests), so it is written over or never looked at, not
+    multiplied by zero. Keys and values are expanded from the bfloat16 rows,
+    as the whole prefill expands them, inside the kernel and block by block
+    up to the piece's own: the work follows ``start``, not T. Returns (x',
+    the piece's rows (C, R))."""
+    from ..ops.flash_attention import latent_flash_attention_from
+
+    q_nope, q_rope, row = _latent(cfg, lp, x, cos, sin)
+    q = jnp.swapaxes(jnp.concatenate([q_nope, q_rope], axis=-1), 0, 1)
+    o = latent_flash_attention_from(
+        q, lax.dynamic_update_slice(before, row, (start, 0)), lp["uk_w"],
+        lp["uv_w"], start, softmax_scale(cfg))                  # (H, C, v)
+    o = jnp.swapaxes(o, 0, 1).reshape(x.shape[0], -1)
+    return (x.astype(jnp.float32) + _mm(o, lp["o_w"])).astype(x.dtype), row

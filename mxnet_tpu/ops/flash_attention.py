@@ -926,3 +926,99 @@ def latent_decode_attention(q, pool, layer, page_table, lengths, d_v, scale):
             interpret=_use_interpret())
     return _latent_decode_attention_xla(q, pool, layer, page_table, lengths,
                                         d_v, scale)
+
+
+# ---------------------------------------------------------------------------
+# A latent-attention prompt continued from a position (serve/decode.py,
+# ``prefill_from``; models/mla_moe.py)
+#
+# The flash forward above for the query rows of ONE piece of one sequence
+# against every position so far, the keys and values expanded from the cached
+# latent rows inside the kernel. It stands here, at the end, so that
+# everything above keeps its lines (and the programs traced through them
+# their cache keys).
+# ---------------------------------------------------------------------------
+
+__all__ += ["latent_flash_attention_from"]
+
+
+def latent_flash_attention_from(q, rows, uk_w, uv_w, start, scale,
+                                block_q=None, block_k=None):
+    """Causal expanded latent attention of a piece of one sequence against
+    all of it so far. q (H, C, nope + rope): the query rows of positions
+    ``start .. start + C - 1`` (``start`` () int32, traced, a multiple of
+    C); rows (T, R): the cached rows ``[c || k_r || 0]`` of positions ``0 ..
+    T - 1`` (T a multiple of C, ``c`` as wide as ``uk_w``'s last axis), of
+    which those the piece sees (``< start + C``) are filled in — what lies
+    behind is copied in with the rest and never looked at, whatever it
+    holds; uk_w (H, nope, rank), uv_w (H, rank, Dv). Head i's keys are ``[c
+    . uk_w_i^T || k_r]`` and its values ``c . uv_w_i``, rounded to the rows'
+    dtype as ``models.mla_moe.prefill_attention`` rounds them, a block of
+    ``block_k`` positions at a time as the loop comes to it: the work
+    follows ``start``, nothing expanded rests in HBM. Returns (H, C, Dv) in
+    q's dtype: the rows :func:`flash_attention` gives at those positions
+    over the same keys and values with the same ``block_k`` (the same key
+    blocks in the same order; a block a row does not see leaves its sums as
+    they were). ``block_q`` defaults to 1,024 at most: where that is the
+    whole piece, a head expands a position once."""
+    c = q.shape[1]
+    if rows.shape[0] % c:
+        raise ValueError(
+            f"{rows.shape[0]} positions are not whole pieces of {c}")
+    # blocks that divide the piece: the diagonal ends where the piece ends
+    bq, bk = _resolve_blocks(c, q.shape[2], block_q or 1024, block_k)
+    return _latent_flash_from(jnp.reshape(start, (1,)).astype(jnp.int32), q,
+                              rows, uk_w, uv_w, float(scale), bq, bk,
+                              _use_interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _latent_flash_from(start, q, rows, uk_w, uv_w, scale, bq, bk, interpret):
+    """Grid (heads, query blocks): ``_fwd_core`` with the query rows
+    ``start`` positions down the diagonal (its dynamic offset, as ring
+    attention uses it) and a ``load_kv`` that makes a block's keys and
+    values from the rows, which stay in VMEM whole (one copy for all heads:
+    their block index never changes)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    h, c, d = q.shape
+    t, r = rows.shape
+    nope, rank = uk_w.shape[1:]
+    dv = uv_w.shape[2]
+    prec = _dot_prec(rows.dtype)
+
+    def kernel(start_ref, q_ref, rows_ref, uk_ref, uv_ref, o_ref):
+        def load_kv(j):
+            blk = rows_ref[pl.ds(j * bk, bk), :]
+            lat = blk[:, :rank]
+            k_nope = _dotT(lat, uk_ref[...], prec).astype(blk.dtype)
+            v = jnp.dot(lat, uv_ref[...], preferred_element_type=jnp.float32,
+                        precision=prec).astype(blk.dtype)
+            return (jnp.concatenate([k_nope, blk[:, rank:rank + d - nope]],
+                                    axis=-1), v)
+
+        out, _ = _fwd_core(q_ref[...], load_kv, start_ref[0],
+                           pl.program_id(1) * bq, t, bk, scale, True, dv)
+        o_ref[...] = out.astype(o_ref.dtype)
+
+    def per_head(*block):
+        return pl.BlockSpec((None,) + block, lambda i, j, st: (i, 0, 0))
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h, c // bq),
+            in_specs=[pl.BlockSpec((None, bq, d), lambda i, j, st: (i, j, 0)),
+                      pl.BlockSpec((t, r), lambda i, j, st: (0, 0)),
+                      per_head(nope, rank), per_head(rank, dv)],
+            out_specs=pl.BlockSpec((None, bq, dv),
+                                   lambda i, j, st: (i, j, 0))),
+        out_shape=jax.ShapeDtypeStruct((h, c, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="mla_prefill_from",
+    )(start, q, rows, uk_w, uv_w)

@@ -1270,15 +1270,21 @@ def test_engine_through_the_grouped_kernel_serves_the_dense_tokens(
     _set_page_group(monkeypatch, eng.kv, eng.max_pages, group)
     sched = DecodeScheduler(eng, max_new_tokens=4)
     cfg, params = decode_config(lm), decode_params(lm)
-    for prompt in ([5, 4, 3], list(range(1, 24))):
-        got = list(sched.generate(np.asarray(prompt, np.int32),
-                                  max_new_tokens=4))
-        toks = list(prompt)
-        for _ in range(4):
-            logits, _k, _v = lm_prefill(
-                cfg, params, np.asarray([toks], np.int32))
-            toks.append(int(np.argmax(np.asarray(logits[0, len(toks) - 1]))))
-        assert got == toks[len(prompt):]
+    try:
+        for prompt in ([5, 4, 3], list(range(1, 24))):
+            got = list(sched.generate(np.asarray(prompt, np.int32),
+                                      max_new_tokens=4))
+            toks = list(prompt)
+            for _ in range(4):
+                logits, _k, _v = lm_prefill(
+                    cfg, params, np.asarray([toks], np.int32))
+                toks.append(int(np.argmax(
+                    np.asarray(logits[0, len(toks) - 1]))))
+            assert got == toks[len(prompt):]
+    finally:
+        # an idle scheduler left running records spans into whichever later
+        # test of this worker turns obs on (tests/test_obs.py)
+        sched.close()
     assert eng.stats()["paged_kernel"] == {
         "page_group": group, "grid_steps": 2 * -(-8 // group) * 2}
     assert eng.stats()["moe_row_tile"] is None      # no routed experts

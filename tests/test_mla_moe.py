@@ -11,6 +11,7 @@ too wide. The bfloat16 run is then held to a bfloat16-sized tolerance, and
 the expert choices that flip on near ties are counted and printed.
 """
 import json
+import time
 
 import numpy as np
 import pytest
@@ -584,3 +585,224 @@ def test_expert_choices_that_flip_on_near_ties_are_counted(dtype, monkeypatch):
     print(f"{dtype}: {flips} of 80 (position, layer) choices differ from "
           "the float32 reference's")
     assert flips == 0 if dtype == "float32" else flips <= 16
+
+
+# -- a prompt continued from a position ----------------------------------------------
+
+@pytest.mark.parametrize("start", [0, 32, 96])
+@pytest.mark.parametrize("blocks", [(32, 16), (16, 32), (8, 8)])
+def test_continued_latent_flash_forward_is_the_rows_of_the_whole(start, blocks):
+    """Query rows ``start .. start + 32`` with 24-wide keys (16 expanded + 8
+    rotary) and 16-wide values, expanded inside the kernel from the cached
+    rows: the rows the whole flash forward gives at those positions over the
+    same keys and values — the same blocks in the same order, so bit for bit;
+    latents and expansion weights are small integers, so that the expansion
+    is exact however its sums are ordered — and dense causal attention. What
+    lies behind the piece in the rows (NaN) is never looked at."""
+    h, total, c, nope, rope, dv, rank, width = 3, 128, 32, 16, 8, 16, 32, 128
+    rng = np.random.default_rng(7)
+    q = rng.normal(size=(h, total, nope + rope)).astype(np.float32)
+    rows = np.zeros((total, width), np.float32)
+    rows[:, :rank] = rng.integers(-2, 3, (total, rank))
+    rows[:, rank:rank + rope] = rng.normal(size=(total, rope))
+    uk_w = rng.integers(-1, 2, (h, nope, rank)).astype(np.float32)
+    uv_w = rng.integers(-1, 2, (h, rank, dv)).astype(np.float32)
+    k = np.concatenate([np.einsum("sc,hnc->hsn", rows[:, :rank], uk_w),
+                        np.broadcast_to(rows[None, :, rank:rank + rope],
+                                        (h, total, rope))], axis=-1)
+    v = np.einsum("sc,hcv->hsv", rows[:, :rank], uv_w)
+    whole = flash_attention.flash_attention(
+        jnp.asarray(q)[None], jnp.asarray(k)[None], jnp.asarray(v)[None],
+        causal=True, scale=0.05, block_q=blocks[0], block_k=blocks[1])[0]
+    behind = np.arange(total)[:, None] >= start + c
+    got = flash_attention.latent_flash_attention_from(
+        jnp.asarray(q[:, start:start + c]),
+        jnp.asarray(np.where(behind, np.nan, rows)), jnp.asarray(uk_w),
+        jnp.asarray(uv_w), jnp.int32(start), 0.05, block_q=blocks[0],
+        block_k=blocks[1])
+    np.testing.assert_array_equal(got, whole[:, start:start + c])
+    sc = 0.05 * np.einsum("hqd,hkd->hqk", q[:, start:start + c], k)
+    sc = np.where(start + np.arange(c)[:, None] >= np.arange(total)[None], sc,
+                  -np.inf)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    want = np.einsum("hqk,hkv->hqv", p / p.sum(-1, keepdims=True), v)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def _in_pieces(model, tokens, length, piece):
+    """``prefill_from`` over tokens (1, n x piece) a piece at a time, the rows
+    carried as the engine carries them; the rows' array holds NaN before the
+    first piece and behind every piece. (logits, rows) as ``prefill``."""
+    total = tokens.shape[1]
+    pool = jnp.full((model.layers, total) + model.cache_row, np.nan,
+                    model.cache_dtype)
+
+    @jax.jit
+    def one(tokens, start, pool):
+        return model.prefill_from(model.params, tokens, start, length,
+                                  lambda layer: pool[layer], {})
+
+    for start in range(0, total, piece):
+        logits, rows, _, state = one(tokens[:, start:start + piece],
+                                     jnp.int32(start), pool)
+        assert state == {}
+        if start + piece < length:      # the head runs in the last piece only
+            assert not np.asarray(logits).any()
+        pool = pool.at[:, start:start + piece].set(rows)
+    return logits, pool
+
+
+@pytest.mark.parametrize("piece,length,dtype", [
+    (16, 41, "float32"),      # an odd last piece: 9 of its 16 positions live
+    (16, 48, "float32"),      # the last piece full
+    (32, 50, "float32"),
+    (16, 7, "float32"),       # one piece: start 0 alone
+    (16, 41, "bfloat16"),
+])
+def test_a_prompt_in_pieces_is_the_prompt_whole(piece, length, dtype):
+    """Logits and every cached row of a prompt fed in pieces of 16 and of 32
+    — attention over the rows before the piece, expanded again — against
+    ``prefill`` over the whole prompt (float32: 2e-5, two orders of blocks
+    over the same float32 products; bfloat16: the engine test's 0.03) and
+    against the reference's one forward (2e-4, as the engine test), whatever
+    (NaN) the rows' array held before the first piece and behind each."""
+    params = mla_moe.init_params(CFG, SEED)
+    if dtype == "float32":
+        params = f32(params)
+    model = mla_moe.MLAMoEDecodeModel(CFG, params=params)
+    total = -(-length // piece) * piece
+    tokens = np.zeros((1, total), np.int32)
+    tokens[0, :length] = np.random.default_rng(length).integers(
+        0, CFG["vocab_size"], length)
+    want_logits, want_rows, _ = jax.jit(model.prefill)(model.params, tokens,
+                                                       length)
+    logits, rows = _in_pieces(model, jnp.asarray(tokens), length, piece)
+    tol = 2e-5 if dtype == "float32" else 0.03
+    got, want = (np.asarray(a, np.float32) for a in (rows, want_rows))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[:, :length], want[:, :length], atol=tol)
+    np.testing.assert_allclose(logits, want_logits, atol=tol)
+    reference = np.asarray(ref.logits(CFG, SEED, tokens[0, :length]))[-1]
+    np.testing.assert_allclose(logits, reference,
+                               atol=2e-4 if dtype == "float32" else 0.03)
+
+
+@pytest.fixture
+def scheduler():
+    """Pieces of 16, prompts up to 48, float32 (tokens compared exactly)."""
+    from mxnet_tpu.serve import DecodeScheduler
+
+    model = mla_moe.MLAMoEDecodeModel(
+        CFG, params=f32(mla_moe.init_params(CFG, SEED)))
+    engine = DecodeEngine(model, slots=SLOTS, page_size=PAGE, num_pages=17,
+                          prompt_buckets=[16, 32, 48])
+    sched = DecodeScheduler(engine, max_queue=8, default_timeout=60.0)
+    yield sched
+    sched.close()
+
+
+def _baseline(sched):
+    """No page, no slot and nothing in flight."""
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline:
+        s = sched.stats()
+        if not (s["active"] or s["queued"] or s["engine"]["pool"]["used"]):
+            return all(g is None for g in sched._slots)
+        time.sleep(0.01)
+    return False
+
+
+def _events(handle):
+    events = []
+    while not events or events[-1][0] == "token":
+        events.append(handle.get(timeout=60))
+    return events
+
+
+def test_a_neighbour_prefilling_in_pieces_does_not_move_a_streams_tokens(
+        scheduler):
+    """The engine feeds this model pieces of its smallest bucket (16): a
+    41-token prompt goes in three, one a turn, in front of the steps of the
+    stream that is decoding beside it — whose tokens, and the prompt's own,
+    are what each gets alone; one ``decode.prefill`` span a piece, pieces ÷
+    admissions as reckoned, two programs in all."""
+    from mxnet_tpu import obs
+
+    engine = scheduler.engine
+    assert engine.prefill_piece == 16 and engine.buckets == [16]
+    assert engine.stats()["max_prompt"] == 48
+    first = np.arange(7, 12, dtype=np.int32)
+    second = np.arange(40, 81, dtype=np.int32)
+    alone = [list(scheduler.generate(p, max_new_tokens=n))
+             for p, n in ((first, 24), (second, 6))]
+    assert engine.stats()["num_programs"] == 2
+    before = scheduler.stats()
+    obs.enable()
+    try:
+        obs.trace.drain()
+        a = scheduler.submit(first, max_new_tokens=24)
+        got_a = [a.get(timeout=60) for _ in range(3)]    # A is decoding
+        b = scheduler.submit(second, max_new_tokens=6)
+        got_b = _events(b)
+        got_a += _events(a)
+        assert _baseline(scheduler)
+        spans = obs.trace.drain()
+    finally:
+        obs.disable()
+        obs.reset()
+    assert [ev[1] for ev in got_a[:-1]] == alone[0]
+    assert [ev[1] for ev in got_b[:-1]] == alone[1]
+    st = scheduler.stats()
+    assert st["prefill_piece"] == 16
+    assert st["admitted"] - before["admitted"] == 2
+    assert st["prefill_pieces"] - before["prefill_pieces"] == 1 + 3
+    assert engine.stats()["num_programs"] == 2
+    calls = sorted((s for s in spans
+                    if s["name"] in ("decode.prefill", "decode.step")),
+                   key=lambda s: s["ts"])
+    pieces = [s["args"] for s in calls if s["name"] == "decode.prefill"]
+    assert [(p["prompt_len"], p["start"], p["pieces"], p["bucket"])
+            for p in pieces] == [(5, 0, 1, 16)] + [(41, s, 3, 16)
+                                                   for s in (0, 16, 32)]
+    assert all(p["moe.dropped"] == 0 for p in pieces)
+    # 3 choices x 2 expert layers for every LIVE position of a piece
+    assert [p["moe.assignments"] for p in pieces] == [30, 96, 96, 54]
+    # a step between the prompt's pieces: A did not wait for all three
+    names = [s["name"] for s in calls]
+    i = [k for k, n in enumerate(names) if n == "decode.prefill"]
+    assert "decode.step" in names[i[1] + 1:i[2]]
+    assert "decode.step" in names[i[2] + 1:i[3]]
+
+
+def test_slot_and_pages_come_back_after_a_cancel_in_mid_prefill(scheduler,
+                                                                monkeypatch):
+    """A caller that hangs up after the first of its prompt's three pieces:
+    the other two are never launched, slot and pages come back, the stream
+    beside it gets the tokens it gets alone, and the slot — whose pages now
+    hold a third of a prompt — serves the next request as a fresh engine."""
+    engine = scheduler.engine
+    beside = np.arange(7, 12, dtype=np.int32)
+    prompt = np.arange(30, 71, dtype=np.int32)
+    alone = list(scheduler.generate(beside, max_new_tokens=20))
+    after = list(scheduler.generate(prompt[:20], max_new_tokens=5))
+    launch, starts, handle = engine.launch_prefill, [], []
+
+    def hang_up_after_the_first_piece(tokens, page_ids, **kw):
+        launched = launch(tokens, page_ids, **kw)
+        if len(tokens) == len(prompt):
+            starts.append(kw["start"])
+            handle[0].cancel()
+        return launched
+
+    a = scheduler.submit(beside, max_new_tokens=20)
+    assert a.get(timeout=60)[0] == "token"
+    monkeypatch.setattr(engine, "launch_prefill",
+                        hang_up_after_the_first_piece)
+    handle.append(scheduler.submit(prompt, max_new_tokens=5))
+    assert _events(handle[0])[-1] == ("end", "cancelled", 0)
+    assert starts == [0]
+    assert [ev[1] for ev in [("token", alone[0])] + _events(a)[:-1]] == alone
+    assert _baseline(scheduler)
+    assert scheduler.stats()["cancelled"] == 1
+    assert list(scheduler.generate(prompt[:20], max_new_tokens=5)) == after
+    assert _baseline(scheduler)

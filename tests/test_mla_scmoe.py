@@ -453,3 +453,29 @@ def test_the_scheduler_hangs_the_zero_counter_on_its_spans():
         "step": moe.layer_row_tile(SLOTS, 4, 18, jnp.bfloat16),
         "prefill": {16: moe.layer_row_tile(16, 4, 18, jnp.bfloat16)}}
     assert "moe.zero" in json.dumps(gauges)
+
+
+def test_the_double_layer_model_is_prefilled_whole():
+    """``MLAScMoEDecodeModel`` inherits from ``MLAMoEDecodeModel``, whose
+    ``prefill_from`` continues THAT class's ``prefill``: with a ``prefill`` of
+    its own and no ``prefill_from`` of its own this model offers none, so its
+    engine keeps a program a bucket and the scheduler launches one prefill
+    call an admission, whatever the prompt's length."""
+    from mxnet_tpu.serve import DecodeScheduler
+
+    model = mla_scmoe.MLAScMoEDecodeModel(CFG, seed=SEED)
+    assert not hasattr(model, "prefill_from")
+    engine = DecodeEngine(model, slots=SLOTS, page_size=PAGE, num_pages=17,
+                          prompt_buckets=[16, 32])
+    assert engine.prefill_piece is None and engine.buckets == [16, 32]
+    sched = DecodeScheduler(engine)
+    try:
+        for n in (11, 27):
+            assert len(list(sched.generate(list(range(1, n + 1)),
+                                           max_new_tokens=2))) == 2
+        st = sched.stats()
+    finally:
+        sched.close()
+    assert st["prefill_piece"] is None
+    assert st["prefill_pieces"] == st["admitted"] == 2
+    assert engine.stats()["num_programs"] == 3      # two buckets + the step

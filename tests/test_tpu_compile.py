@@ -336,10 +336,11 @@ def test_tpu_latent_step_program_reads_the_pool_where_it_lies(
 
 def test_tpu_latent_prefill_program_writes_the_pool_in_place(
         latent_engine, one_chip, monkeypatch):
-    """A 1024-position prefill of the latent model for a v5e: the flash
-    forward with 192-wide keys and 128-wide values goes through Mosaic
-    (twice: the dense layer, and the scanned expert layers' one body), the
-    pool is written page by page in place, no pool-shaped copy."""
+    """A 1024-position prefill of the latent model for a v5e (since PR 45
+    the engine's one piece program, here with the piece the whole prompt):
+    the flash forward with 192-wide keys and 128-wide values goes through
+    Mosaic (twice: the dense layer, and the scanned expert layers' one
+    body), the pool is written page by page in place, no pool-shaped copy."""
     engine = latent_engine
     lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") >= 2
@@ -537,7 +538,8 @@ def _cell_engine(config, bucket, slots=2, buckets=None):
 def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
                                                       monkeypatch):
     """The longest prefill of each expert cell compiled for a v5e at the
-    published widths. ``held_experts`` sorts a chunk's ``tokens x k`` pairs
+    published widths (a model fed in pieces given the one bucket: the piece
+    is the whole prompt). ``held_experts`` sorts a chunk's ``tokens x k`` pairs
     by expert and brings the products' float32 rows back to their tokens:
     no ``f32[tokens x k, D]`` value is copied or laid out again (the
     ``reshape`` to ``(tokens, k, D)``, k = 10 on the sublane axis, was 7 % of
@@ -630,6 +632,55 @@ def test_tpu_piece_program_reads_the_prompt_so_far_through_the_page_table(
     whole = compiled.as_text()
     assert not [line for line in whole.splitlines()
                 if "bf16[2177,2,256,1024]" in line and " copy(" in line]
+
+
+def test_tpu_latent_piece_program_expands_the_prompt_so_far(one_chip,
+                                                            monkeypatch):
+    """The ONE prefill program of ``sarvam105b-serve-closed`` compiled for a
+    v5e at the cell's geometry (32 slots, pages of 256, the cell's six
+    buckets): a piece of 1,024 positions of a prompt of up to 7,168. The
+    prefix's latents come through the page table as ONE gather of whole
+    pages a layer and go to the continued flash forward (``mla_prefill_from``,
+    a Mosaic call in the dense layer and one in the scanned expert layers'
+    body) as they are: keys and values are expanded inside it, so nothing
+    shaped like a head's keys rests in HBM, and no batched slice has become
+    a loop over positions — the program's four ``while`` are the layers'
+    scan, the held experts' row blocks with their ``searchsorted``, and the
+    pages written. The pool is donated and written in place, nothing copies
+    a pool-shaped array (one bucket: two programs in all), and the temporaries (0.30 GB:
+    the held experts' buffers) stay far under the whole 7,168-position
+    prefill's 1.51 GB (``PERF.md`` §4)."""
+    buckets = [1024, 2048, 3072, 4096, 5120, 7168]
+    cfg, engine = _cell_engine("sarvam-105b", 8192, slots=32, buckets=buckets)
+    assert engine.prefill_piece == 1024 and engine.buckets == [1024]
+    assert engine.max_prompt == 7168 and not engine.state
+    assert engine.kv.shape == (1025, 6, 256, 640)
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
+    (packed,) = _host_arguments(engine, lowered)
+    assert packed.shape == (5 + 28 + 1024,)
+    text = lowered.as_text()
+    assert "mla_prefill_from" in text and "moe_rows_back" in text
+    cost = obs.device.analyze_compiled(compiled)
+    assert cost["alias_bytes"] >= engine.kv.nbytes
+    assert cost["temp_bytes"] < 0.5e9, cost
+    lines = _pool_lines(compiled, engine)
+    assert ("{3,2,1,0:T(8,128)(2,1)}" in lines[0]
+            and "parameter(" in lines[0]), lines[0]
+    assert not [line for line in lines if " copy(" in line], lines
+    whole = compiled.as_text()
+    heads, wide = cfg["num_heads"], cfg["qk_nope"] + cfg["qk_rope"]
+    assert f"bf16[{heads},7168,{wide}]" not in whole
+    entry = whole[whole.index("\nENTRY ") + 1:]
+    calls = [line for line in entry.splitlines()
+             if "tpu_custom_call" in line and "mla_prefill_from" in line]
+    # the rows (7168, 640) go in whole; the queries are the piece's alone
+    assert calls and all("bf16[7168,640]" in line
+                         and f"bf16[{heads},1024,{wide}]" in line
+                         for line in calls), calls
+    loops = [line for line in whole.splitlines() if " while(" in line]
+    assert len(loops) == 4, [line.strip()[:160] for line in loops]
+    assert sum("searchsorted" in line for line in loops) == 1
 
 
 # -- state-space layers beside a 2-head pool, relu^2 experts --------------------
