@@ -37,6 +37,16 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
               served token within 0.05 of the first choice of the plain
               reference's float32 full forward
               (``benchmark/reference_mla_scmoe.py``, run on the chip);
+- *swa*       the window + global model (``models.swa_moe``: 64 heads of
+              192 over 8 and 4 cached heads, values 128, a window of 128 as a
+              ring in per-slot state beside a 2-layer pool) through
+              ``DecodeEngine``, in memory filled with NaN beforehand: a prompt
+              of 2,500 in pieces of 1,024 against ``prefill`` whole (pool rows,
+              rings, first token), then 72 steps across a ring's wrap with
+              every served token within 0.1 of the first choice of the plain
+              reference's float32 full forward
+              (``benchmark/reference_swa_moe.py``), and ``swa_decode`` and
+              ``gqa_decode_dv`` against their XLA twins over what was written;
 - *experts*   ``ops.moe.held_experts`` at the three expert cells' prompt
               and decode-step geometries against a plain masked loop in
               bfloat16 on the chip: the error, nothing dropped, the row tile
@@ -1063,6 +1073,153 @@ def phase_scmoe():
                  "reference's first (bfloat16 against float32: 0.05 allowed)")
 
 
+# published widths of the window + global model's caches: 64 query heads of
+# 192 over 8 (window) and 4 (global) cached heads, values 128, a window of 128
+# as a ring of 2,560-value rows a slot, the global rows 1,280 in pages of 256
+SWA = {"vocab_size": 4096, "vocab_first": 0, "hidden_size": 1024,
+       "num_layers": 4, "layer_pattern": [0, 1, 1, 0],
+       "moe_pattern": [0, 1, 1, 1], "num_heads": 64, "head_dim": 192,
+       "v_head_dim": 128, "kv_heads": 4, "swa_kv_heads": 8, "window": 128,
+       "swa_sink": True, "rotary_dim": 64, "rope_theta": 5000000,
+       "swa_rope_theta": 10000, "value_scale": 0.707, "dense_width": 2048,
+       "expert_width": 512, "router_experts": 32, "experts_first": 8,
+       "experts_held": 8, "experts_per_token": 8, "routed_scale": 1.0,
+       "rms_eps": 1e-5, "max_length": 4096}
+
+
+def phase_swa():
+    """Window rings beside the pool: a prompt past two pieces of 1,024
+    against ``prefill`` whole — rows, rings, first token —, in memory filled
+    with NaN first; then steps across a ring's wrap, the served tokens
+    against the plain reference's full forward
+    (``benchmark/reference_swa_moe.py``); and the two decode kernels against
+    their XLA twins over what the engine wrote."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import reference_swa_moe as reference
+    from mxnet_tpu.models import swa_moe
+    from mxnet_tpu.ops import swa_attention
+    from mxnet_tpu.serve import DecodeEngine
+    from mxnet_tpu.serve.kvcache import SCRATCH_PAGE
+
+    with _Phase("swa"):
+        slots, page, n, padded, seed, slot = 4, 256, 2500, 3072, 11, 2
+        _dirty_memory()
+        model = swa_moe.SWAMoEDecodeModel(SWA, seed=seed)
+        engine = DecodeEngine(model, slots=slots, page_size=page,
+                              num_pages=slots * 16 + 1,
+                              prompt_buckets=[1024, padded])
+        engine.warmup()
+        _require(engine.prefill_piece == 1024 and engine.paged_layers == 2
+                 and engine.kv.shape == (slots * 16 + 1, 2, page, 1280)
+                 and engine.state["window"].shape == (slots + 1, 2, 128, 2560)
+                 and engine.stats()["num_programs"] == 2,
+                 f"rings beside a 2-layer pool expected: {engine.stats()}")
+        rng = np.random.RandomState(12)
+        prompt = rng.randint(0, SWA["vocab_size"], n)
+        engine.pool.alloc(0, padded // page)
+        table = engine.pool.table(0)
+        counted = {}
+        for start in range(0, padded, engine.prefill_piece):
+            tok, c = engine.read(engine.launch_prefill(prompt, table,
+                                                       slot=slot, start=start))
+            counted = {k: counted.get(k, 0) + v for k, v in c.items()}
+        seen = np.arange(1, n + 1)
+        _require(counted["moe.dropped"] == 0
+                 and counted["moe.assignments"] == 3 * n * 8
+                 and counted["attn.window_rows"] == 2 * np.minimum(
+                     seen, 128).sum()
+                 and counted["attn.global_rows"] == 2 * seen.sum(),
+                 f"the pieces counted {counted}")
+        # ... against the model's ``prefill``, the whole prompt at once
+        whole = np.zeros((1, padded), np.int32)
+        whole[0, :n] = prompt
+        logits, want_rows, _, want_state = jax.jit(model.prefill)(
+            model.params, jnp.asarray(whole), n)
+        rows = np.asarray(engine.kv[np.asarray(table)], np.float32)
+        rows = np.moveaxis(rows, 1, 0).reshape(rows.shape[1], padded, -1)
+        _check_mostly_close(
+            f"{n} tokens in pieces of 1024 against whole: pool rows",
+            rows[:, :n], np.asarray(want_rows[:, :n], np.float32), 2e-2)
+        _check_mostly_close(
+            "the rings after the last piece against whole",
+            np.asarray(engine.state["window"][slot], np.float32),
+            np.asarray(want_state["window"], np.float32), 2e-2)
+        _require(tok == int(jnp.argmax(logits)),
+                 f"a prompt in pieces served {tok} first, prefill whole "
+                 f"{int(jnp.argmax(logits))}")
+        # 72 steps: ring index 2500 mod 128 = 68 runs past 127 into 0
+        served = [tok]
+        tables = np.full((slots, engine.max_pages), SCRATCH_PAGE, np.int32)
+        tables[slot, :len(table)] = table
+        z = np.zeros((slots,), np.int32)
+        for i in range(72):
+            pos, lengths, toks = z.copy(), z.copy(), z.copy()
+            pos[slot], lengths[slot], toks[slot] = n + i, n + 1 + i, served[-1]
+            served.append(int(engine.step(
+                toks, pos, tables, lengths,
+                np.zeros((slots,), np.float32))[slot]))
+        c = engine.last_counters
+        _require(c["moe.dropped"] == 0 and c["moe.assignments"] == 3 * 8
+                 and c["attn.window_rows"] == 2 * 128
+                 and c["attn.global_rows"] == 2 * (n + 72),
+                 f"the last step counted {c}")
+        seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
+        seq = np.concatenate([seq, np.zeros((-len(seq)) % 128, np.int32)])
+        ref_logits = np.asarray(reference.logits(SWA, seed, seq))[
+            n - 1:n + 72]
+        gaps = reference.gaps_below_best(ref_logits, served)
+        print(f"   73 served tokens across the ring's wrap: widest gap below "
+              f"the reference's best {gaps.max():.4f}, "
+              f"{int((gaps > 0).sum())} not its first", flush=True)
+        _require(np.isfinite(ref_logits).all() and float(gaps.max()) <= 0.1,
+                 f"a served token lies {gaps.max():.3g} below the "
+                 "reference's first (bfloat16 against float32: 0.1 allowed)")
+        # the kernels against their twins, over what the engine just wrote
+        q = jax.random.normal(jax.random.PRNGKey(13), (slots, 8, 8, 192),
+                              jnp.bfloat16)
+        positions = jnp.asarray([0, 0, n + 71, 0], jnp.int32)
+        sink = model.params["layers"][1]["sink"]
+        args = (swa_attention._padded_query(q, 8, 192), engine.state["window"],
+                jnp.ones((1,), jnp.int32), positions,
+                swa_attention._sink_lanes(sink, 8, 8), 192 ** -0.5, 128)
+        kernel = jax.jit(swa_attention._swa_decode, static_argnums=(5, 6, 7))
+        _require_mosaic(kernel, *args, False)
+        got = np.asarray(kernel(*args, False), np.float32)[slot]
+        want = np.asarray(jax.jit(
+            swa_attention._swa_decode_xla, static_argnums=(2, 5, 6))(
+            q, engine.state["window"], 1, positions, sink.reshape(8, 8),
+            192 ** -0.5, 128), np.float32)[slot]
+        _check_close("window decode (8 x 8 x 192 over a wrapped ring)", got,
+                     want, 3e-2)
+        q = q.reshape(slots, 4, 16, 192)
+        lengths = jnp.asarray([0, 0, n + 72, 0], jnp.int32)
+        args = (swa_attention._padded_query(q, 4, 192), engine.kv,
+                jnp.ones((1,), jnp.int32), jnp.asarray(tables), lengths,
+                192 ** -0.5, 128)
+        kernel = jax.jit(swa_attention._gqa_decode_dv,
+                         static_argnums=(5, 6, 7))
+        _require_mosaic(kernel, *args, False)
+        got = np.asarray(kernel(*args, False), np.float32)[slot]
+        want = np.asarray(jax.jit(
+            swa_attention._gqa_decode_dv_xla, static_argnums=(2, 5, 6))(
+            q, engine.kv, 1, jnp.asarray(tables), lengths, 192 ** -0.5, 128),
+            np.float32)[slot]
+        _check_close(f"paged decode, keys 192 over values 128, {n + 72} "
+                     "positions", got, want, 3e-2)
+        engine.pool.free(0)
+        engine.pool.assert_baseline()
+        program = engine.stats()["step_program"]
+        ring = engine.state["window"].nbytes // (slots + 1) // 2
+        print(f"   pool {engine.kv.shape}, rings "
+              f"{engine.state['window'].shape}: step temp_bytes "
+              f"{program['temp_bytes']}", flush=True)
+        _require(program["temp_bytes"] < engine.kv.nbytes // 2,
+                 f"the step allocates {program['temp_bytes']} bytes: it "
+                 f"copies the pool or the rings (one ring {ring} bytes)")
+
+
 def _dirty_memory():
     """Fill what is free of the device's memory with NaN and free it again.
     A fresh process finds zeros where it never wrote; a long-lived one does
@@ -1198,6 +1355,7 @@ def main():
     phase_state()
     phase_ssm()
     phase_scmoe()
+    phase_swa()
     phase_experts()
     multichip_attn = phase_multichip(losses[0])
     print("summary " + json.dumps({

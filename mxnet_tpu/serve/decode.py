@@ -468,7 +468,7 @@ class DecodeEngine:
         tokens = packed[_P_TABLE + n:][None]
 
         def prior(layer):   # positions 0 .. max_prompt - 1 of one layer
-            return kv[table, layer].reshape((n * page,) + kv.shape[3:])
+            return _pages(kv, table, layer).reshape((n * page,) + kv.shape[3:])
 
         own = {name: jax.lax.dynamic_index_in_dim(held, slot, keepdims=False)
                for name, held in state.items()}
@@ -1565,3 +1565,27 @@ class DecodeScheduler:
             }
         out["engine"] = self.engine.stats()
         return out
+
+
+# -- the pages of one layer through a page table. At the end of the module, and
+# called from ONE line of ``_piece_fn``, so that every program traced through
+# this file keeps the lines it is cached under.
+
+# The largest window (a page of one layer) that XLA:TPU's gather takes whole:
+# for a larger one it splits the OPERAND, the whole pool, into column halves
+# ("mini-gather-slice": two copies of half the pool each, a piece; seen in the
+# compiled program of a 256 x 2,560 B page, PR 46). 256 x 2,048 B is taken.
+_GATHER_WINDOW_BYTES = 512 * 1024
+
+
+def _pages(kv, table, layer):
+    """``kv[table, layer]``: (n, page_size) + row for a page table of n ids;
+    in half pages where a whole one is over ``_GATHER_WINDOW_BYTES``."""
+    import jax.numpy as jnp
+
+    page = kv.shape[2]
+    window = int(np.prod(kv.shape[2:])) * kv.dtype.itemsize
+    if page % 2 or window <= _GATHER_WINDOW_BYTES:
+        return kv[table, layer]
+    halves = kv.reshape(kv.shape[:2] + (2, page // 2) + kv.shape[3:])
+    return halves[table[:, None], layer, jnp.arange(2)[None, :]]

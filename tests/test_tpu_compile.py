@@ -23,7 +23,7 @@ import pytest
 
 from mxnet_tpu import obs
 from mxnet_tpu.ops import flash_attention, moe
-from mxnet_tpu.serve import DecodeEngine
+from mxnet_tpu.serve import DecodeEngine, decode
 
 pytestmark = pytest.mark.decode
 
@@ -598,6 +598,50 @@ def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
     assert cost["temp_bytes"] < 1.5 * most * d * 4, cost
 
 
+def _reads_whole_pages(optimised, engine):
+    """A piece reads the prompt so far as ``kv[table, layer]``: a gather of
+    WHOLE pages (slices of ``1 x 1 x page x row``) out of the pool, the only
+    pool-sized operand of any gather, and none of half pages — what
+    ``serve/decode.py`` ``_pages`` does for a page of one layer over
+    ``_GATHER_WINDOW_BYTES`` alone."""
+    n, (page, row) = engine.max_prompt // engine.page_size, engine.kv.shape[2:]
+    assert page * row * engine.kv.dtype.itemsize <= decode._GATHER_WINDOW_BYTES
+    pages = [line for line in optimised.splitlines()
+             if f"= bf16[{n},{page},{row}]" in line and " gather(" in line]
+    assert pages and all(f"slice_sizes={{1,1,{page},{row}}}" in line
+                         for line in pages), pages
+    assert f"bf16[{n},2,{page // 2},{row}]" not in optimised
+    assert "mini-gather" not in optimised
+
+
+@pytest.mark.parametrize("shape,whole", [
+    ((9, 2, 256, 1024), True),      # qwen3next's page of one layer: 512 KiB
+    ((9, 6, 256, 640), True),       # sarvam's latent page: 320 KiB
+    ((9, 3, 256, 1280), False),     # 640 KiB: in halves
+    ((9, 3, 255, 1280), True),      # an odd page is never halved
+])
+def test_pages_of_a_layer_are_indexed_as_before_up_to_the_gather_window(
+        shape, whole):
+    """``serve/decode.py`` ``_pages`` traces to the very operations of
+    ``kv[table, layer]`` while a page of one layer is within
+    ``_GATHER_WINDOW_BYTES`` (every model's that had pieces before the
+    window + global one: their piece programs are the ones they had), and
+    gives the same rows in half pages above it."""
+    import jax
+    import jax.numpy as jnp
+
+    kv = jnp.arange(np.prod(shape), dtype=jnp.float32).astype(
+        jnp.bfloat16).reshape(shape)
+    table = jnp.array([4, 0, 7, 7, 2], jnp.int32)
+    got = jax.make_jaxpr(lambda kv, t: decode._pages(kv, t, 1))(kv, table)
+    plain = jax.make_jaxpr(lambda kv, t: kv[t, 1])(kv, table)
+    assert (str(got) == str(plain)) == whole
+    np.testing.assert_array_equal(
+        np.asarray(decode._pages(kv, table, 1).reshape(5, *shape[2:]),
+                   np.float32),
+        np.asarray(kv[table, 1], np.float32))
+
+
 def test_tpu_piece_program_reads_the_prompt_so_far_through_the_page_table(
         one_chip, monkeypatch):
     """The ONE prefill program of ``qwen3next-serve-closed-long`` compiled
@@ -632,6 +676,7 @@ def test_tpu_piece_program_reads_the_prompt_so_far_through_the_page_table(
     whole = compiled.as_text()
     assert not [line for line in whole.splitlines()
                 if "bf16[2177,2,256,1024]" in line and " copy(" in line]
+    _reads_whole_pages(whole, engine)
 
 
 def test_tpu_latent_piece_program_expands_the_prompt_so_far(one_chip,
@@ -680,6 +725,7 @@ def test_tpu_latent_piece_program_expands_the_prompt_so_far(one_chip,
                          for line in calls), calls
     loops = [line for line in whole.splitlines() if " while(" in line]
     assert len(loops) == 4, [line.strip()[:160] for line in loops]
+    _reads_whole_pages(whole, engine)
     assert sum("searchsorted" in line for line in loops) == 1
 
 
@@ -955,3 +1001,141 @@ def test_tpu_double_layer_prefill_program_at_the_cells_sizes(
     assert not _weight_copies(compiled, engine)
     lines = _pool_lines(compiled, engine)
     assert not [line for line in lines if " copy(" in line], lines
+
+
+# -- window rings in per-slot state beside the pool, at the cell's own sizes ----
+# ``mimo-v2-flash-serve-closed-64`` as the benchmark runs it: published widths,
+# 9 window + 3 global layers, 8 held of 256 experts, 64 slots, 2,561 pages of
+# 256, prompts in pieces of 1,024 up to 24,576. Shapes only: 7.4 GB of weights
+# are never made.
+
+@pytest.fixture(scope="module")
+def swa_engine():
+    import json
+    import os
+
+    import jax
+
+    from mxnet_tpu.models import swa_moe
+
+    root = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark")
+    with open(os.path.join(root, "configs", "mimo-v2-flash.json")) as f:
+        cfg = json.load(f)["model"]
+    with open(os.path.join(root, "workloads",
+                           "mimo-v2-flash-serve-closed-64.json")) as f:
+        sv = json.load(f)["serve"]
+    assert cfg.pop("kind") == "swa_moe_lm"
+    shapes = jax.eval_shape(lambda: swa_moe.init_params(cfg, 0))
+    model = swa_moe.SWAMoEDecodeModel(cfg, params=shapes)
+    return DecodeEngine(model, slots=sv["slots"], page_size=sv["page_size"],
+                        num_pages=sv["num_pages"],
+                        prompt_buckets=sv["prompt_buckets"])
+
+
+def _window_kernels_as_on_a_tpu(monkeypatch):
+    from mxnet_tpu.ops import swa_attention
+
+    _as_on_a_tpu(monkeypatch)
+    monkeypatch.setattr(swa_attention, "_use_interpret", lambda: False)
+
+
+def _copies_of(compiled, *shapes):
+    """Instructions of the optimised entry computation that copy an array
+    of one of ``shapes``."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY ") + 1:]
+    names = ["bf16[" + ",".join(str(n) for n in s) + "]" for s in shapes]
+    return [line.strip()[:160] for line in entry.splitlines()
+            if " copy(" in line and any(n in line for n in names)]
+
+
+def test_tpu_window_step_program_at_the_cells_sizes(swa_engine, one_chip,
+                                                    monkeypatch):
+    """The step of the window + global model compiled for a v5e at the cell's
+    sizes: the pool ``(2561, 3, 256, 1280)`` bfloat16 counts the THREE global
+    layers alone, the nine window layers' rings ``(65, 9, 128, 2560)`` are
+    per-slot state donated beside it; one Mosaic call a kind of kernel
+    (``swa_decode`` and ``gqa_decode_dv`` each traced once for all their
+    layers, and the two of ``held_experts``): the 192-wide key slices lower;
+    the step's arguments are the 7.40 GB of weights + 5.03 GB of pool + 0.38
+    GB of rings, its temporaries under 0.3 GB; a row a slot a layer is
+    scattered in place and no instruction copies the rings or the pool; every
+    layer's weights are arrays of their own, so none is sliced out of a stack;
+    and the grouped products carry the row tile ``moe.layer_row_tile`` names
+    for 64 tokens x 8 choices over the router's 256."""
+    import re
+
+    import jax.numpy as jnp
+
+    engine = swa_engine
+    assert engine.kv.shape == (2561, 3, 256, 1280) and engine.paged_layers == 3
+    assert engine.state["window"].shape == (65, 9, 128, 2560)
+    assert engine.cache_row_bytes == 2560 and engine.state_bytes == 5898240
+    stats = engine.stats()
+    assert stats["prefill_piece"] == 1024 and stats["max_prompt"] == 24576
+    tile = moe.layer_row_tile(64, 8, 256, jnp.bfloat16)
+    assert tile == 16 and stats["moe_row_tile"] == {"step": 16,
+                                                    "prefill": {1024: 64}}
+    _window_kernels_as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert all(name in text for name in ("swa_decode", "gqa_decode_dv",
+                                         "moe_rows", "moe_rows_back"))
+    cost = obs.device.analyze_compiled(compiled)
+    held = engine.kv.nbytes + engine.state["window"].nbytes
+    assert 12.8e9 < cost["argument_bytes"] < 12.86e9, cost
+    assert cost["temp_bytes"] < 0.3e9, cost
+    assert cost["alias_bytes"] >= held
+    assert not _copies_of(compiled, engine.kv.shape,
+                          engine.state["window"].shape)
+    # no layer's weights are cut out of a stack: a 100 MB ``q_w`` is an
+    # argument, and the only arrays of its shape the program makes are the
+    # ones XLA prefetches into its fast memory, ``S(1)``
+    optimised = compiled.as_text()
+    entry = optimised[optimised.index("\nENTRY ") + 1:]
+    assert not [line[:160] for line in entry.splitlines()
+                if re.match(r"\s*%?[\w.\-]+ = bf16\[(1,)?12288,4096\]", line)
+                and " parameter(" not in line
+                and "S(1)}" not in line[:line.index(" = ") + 80]]
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', optimised)
+    assert len(tilings) >= 3 * 11 and all(      # 3 products x 11 layers
+        t == (str(tile), "512", "512") for t in tilings), tilings
+
+
+def test_tpu_window_piece_program_at_the_cells_sizes(swa_engine, one_chip,
+                                                     monkeypatch):
+    """The ONE prefill program of the same cell: a piece of 1,024 positions
+    of a prompt of up to 24,576. Both continued forwards go through Mosaic;
+    the pool is read through the page table by the THREE global layers alone
+    (a gather of half pages: a whole page of this row, 640 KB, is more than
+    XLA:TPU's gather takes as one window, and it would split the POOL in
+    column halves — two copies of half of it a piece, 2.9 GB of temporaries;
+    ``serve/decode.py`` ``_GATHER_WINDOW_BYTES``) — a window layer's keys are
+    its slot's ring and the piece's own rows —; pool and rings are donated
+    and written in place; the temporaries stay under 0.6 GB."""
+    import re
+
+    engine = swa_engine
+    _window_kernels_as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
+    (packed,) = _host_arguments(engine, lowered)
+    assert packed.shape == (5 + 96 + 1024,)
+    text = lowered.as_text()
+    assert all(name in text for name in ("swa_prefill_from",
+                                         "gqa_prefill_from_dv",
+                                         "moe_rows_back"))
+    cost = obs.device.analyze_compiled(compiled)
+    held = engine.kv.nbytes + engine.state["window"].nbytes
+    assert cost["alias_bytes"] >= held
+    assert cost["temp_bytes"] < 0.6e9, cost
+    assert not _copies_of(compiled, engine.kv.shape,
+                          engine.state["window"].shape)
+    optimised = compiled.as_text()
+    # what is gathered out of the pool: 96 pages' two halves, three times
+    pages = re.findall(r"= bf16\[96,2,128,1280\]\S* gather\(", optimised)
+    assert len(pages) == 3, len(pages)
+    assert "mini-gather" not in optimised
+    assert not [line[:160] for line in optimised.splitlines()
+                if re.search(r"= bf16\[2561,3,(256|2,128),\d+\]", line)
+                and " slice(" in line]
