@@ -71,6 +71,7 @@ costs on the chip is the benchmark's to say.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -208,36 +209,58 @@ def phase_kernels():
     from mxnet_tpu.ops.flash_attention import (_decode_attention_xla,
                                                decode_page_group,
                                                flash_attention,
-                                               flash_decode_attention)
+                                               flash_decode_attention,
+                                               flash_schedule)
 
     with _Phase("kernels"):
-        # flash forward + gradients, the train phase's exact attention shape
-        b, h, s, d = TRAIN_BATCH, 12, TRAIN_SEQ, 64
+        # flash forward + gradients at gpt2m-train-s1024's own attention
+        # shape and at the train phase's, the schedule the kernels take from
+        # the shapes, and what the kernels alone cost a call
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
-        q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
-                   for kk in keys[:3])
-        w = jax.random.normal(keys[3], (b, h, s, d), jnp.float32)
+        for b, h, s, d in ((4, 16, 1024, 64),
+                           (TRAIN_BATCH, 12, TRAIN_SEQ, 64)):
+            q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
+                       for kk in keys[:3])
+            w = jax.random.normal(keys[3], (b, h, s, d), jnp.float32)
 
-        def loss(attn, q, k, v):
-            out = attn(q, k, v, causal=True)
-            return jnp.sum(out.astype(jnp.float32) * w), out
+            def loss(attn, q, k, v):
+                out = attn(q, k, v, causal=True)
+                return jnp.sum(out.astype(jnp.float32) * w), out
 
-        flash = jax.jit(jax.value_and_grad(
-            lambda *a: loss(flash_attention, *a), argnums=(0, 1, 2),
-            has_aux=True))
-        # the reference sees the same bf16 values, widened: its own
-        # rounding would otherwise be as large as the error under test
-        ref = jax.jit(jax.value_and_grad(
-            lambda *a: loss(plain_attention, *a), argnums=(0, 1, 2),
-            has_aux=True))
-        _require_mosaic(flash, q, k, v)
-        (_, out), grads = flash(q, k, v)
-        (_, out_ref), grads_ref = ref(*(x.astype(jnp.float32)
-                                        for x in (q, k, v)))
-        _check_close("flash fwd  (4,12,2048,64) bf16 causal", out, out_ref,
-                     2e-2)
-        for name, g, gr in zip(("dq", "dk", "dv"), grads, grads_ref):
-            _check_close(f"flash bwd {name}", g, gr, 4e-2)
+            forward = jax.jit(functools.partial(flash_attention, causal=True))
+            flash = jax.jit(jax.value_and_grad(
+                functools.partial(loss, flash_attention), argnums=(0, 1, 2),
+                has_aux=True))
+            # the reference sees the same bf16 values, widened: its own
+            # rounding would otherwise be as large as the error under test
+            ref = jax.jit(jax.value_and_grad(
+                functools.partial(loss, plain_attention), argnums=(0, 1, 2),
+                has_aux=True))
+            _require_mosaic(flash, q, k, v)
+            (_, out), grads = flash(q, k, v)
+            (_, out_ref), grads_ref = ref(*(x.astype(jnp.float32)
+                                            for x in (q, k, v)))
+            shape = f"({b},{h},{s},{d}) bf16 causal"
+            print(f"   flash schedule {shape}: {flash_schedule(s, d, True)}",
+                  flush=True)
+            _check_close(f"flash fwd  {shape}", out, out_ref, 2e-2)
+            for name, g, gr in zip(("dq", "dk", "dv"), grads, grads_ref):
+                _check_close(f"flash bwd {name}", g, gr, 4e-2)
+            took = []
+            for fn in (forward, flash):
+                jax.block_until_ready(fn(q, k, v))
+                t0 = time.monotonic()
+                for _ in range(20):
+                    last = fn(q, k, v)
+                jax.block_until_ready(last)
+                took.append((time.monotonic() - t0) * 50)
+            print(f"   flash alone {shape}: forward {took[0]:.3f} ms, forward "
+                  f"+ backward {took[1]:.3f} ms a call (host clock, 20 calls)",
+                  flush=True)
+        # longcat-omni's longest prefill runs the forward at 192 / 128
+        print("   flash schedule (1,64,1536,192) bf16 causal: "
+              f"{flash_schedule(1536, 192, True)}", flush=True)
+        h, d = 12, 64    # the serve phase's heads
 
         # paged decode, every layer in the one pool, K and V side by side,
         # ragged lengths, one inner layer: the serve phase's geometry in

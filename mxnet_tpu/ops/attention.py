@@ -1,9 +1,8 @@
 """Attention dispatch: plain XLA vs the Pallas flash kernel.
 
-Policy (re-measured round 4 on v5e after the kernel rewrite — bench.py
-bench_lm_long, TransformerLM bf16 train step, end-to-end): flash wins
-1.49x at seq 2048 (76.8 vs 51.7 model TFLOPS) *and* keeps memory O(S·D)
-— so:
+Policy (measured on v5e, TransformerLM bf16 train step, end-to-end): flash
+wins 1.49x at seq 2048 (76.8 vs 51.7 model TFLOPS) *and* keeps memory
+O(S·D) — so:
 - short sequences (< _FLASH_MIN_SEQ): XLA's fused softmax-attention; the
   S×S scores fit easily and kernel launch granularity doesn't pay off.
 - sequences ≥ _FLASH_MIN_SEQ: the Pallas flash kernel (bf16 MXU dots with

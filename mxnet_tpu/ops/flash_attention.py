@@ -1,26 +1,43 @@
 """Hand-written Pallas flash attention for TPU.
 
 The hot op of the transformer family (SURVEY.md §7 step 8). Forward is a
-Pallas kernel: one Q block stays in VMEM while the kernel streams K/V blocks,
+Pallas kernel: a Q block stays in VMEM while the kernel walks K/V tiles,
 keeping online-softmax statistics in f32 — the S×S score matrix is never
 materialized in HBM, so memory is O(S·D) instead of O(S²) and long contexts
 fit on chip. Backward is a second Pallas kernel (one pass over K/V blocks,
-recomputing P from the saved lse; dQ accumulates in a VMEM-resident output
-block across the sequential TPU grid). On non-TPU backends the backward
-falls back to a blocked ``lax.scan`` in plain JAX.
+recomputing P from the saved lse; dQ accumulates in a float32 VMEM scratch
+across the sequential TPU grid and leaves the kernel once, in the operand's
+dtype). On non-TPU backends the backward falls back to a blocked
+``lax.scan`` in plain JAX.
 
-TPU-efficiency notes (measured on v5e, round 4 — tools/profile_lm.py):
-- The K/V loop is phase-split: fully-visible blocks run with NO masking
-  (no iota/compare/select VPU passes), only the O(1) diagonal blocks pay
-  for the causal mask. With head_dim 64 the MXU:VPU work ratio is only
-  ~32:1, so every per-element VPU pass costs as much as a matmul — the
-  round-3 kernel spent most of its 7.2 ms in exactly those passes.
+TPU-efficiency notes (measured on v5e; PERF.md §5, §6):
+- With head_dim 64 every product fills half the MXU and the MXU:VPU work
+  ratio is only ~32:1, so every per-element VPU pass costs as much as a
+  matmul. What a grid step keeps resident (one DMA a block) is therefore
+  walked in (q sub-block, k sub-tile) PAIRS that follow the causal
+  triangle: pairs the diagonal does not reach are never computed, only the
+  pair it crosses pays for the mask (one compare and one select), the rest
+  run the bare body.
+- A loop's end is a wall to the bundle scheduler, and a pair of 256 x 256
+  is over before the MXU has filled (523 bundles for 64 vmatmuls, twice the
+  MXU's pace; 512 x 512: 1.3 x). So the pairs a sub-block sees go as ONE
+  straight-line product whose width is picked by their number
+  (``lax.switch``): up to ``block_k // sub_k`` sub-tiles wide in the
+  forward, ``block_q // sub_q`` sub-blocks tall in the backward. Nothing
+  wide is carried across a loop's or a branch's edge (it would be spilled
+  there and filled again): a body loads what it reads.
+- A forward grid step may advance TWO heads in one body (0.19 → 0.17 ms a
+  call at (4, 16, 1024, 64)); the backward takes one (two spill).
 - Softmax statistics run in the log2 domain (``exp2`` is the native VPU
-  transcendental; ``exp`` lowers to exp2 + a hidden multiply).
-- Fully-masked rows are repaired once per q-block (per-row select) instead
-  of guarding every score element.
-- Block sizes come from a per-(S, D) table measured by tools/tune_flash.py;
-  ``MXNET_FLASH_BLOCK_Q/K`` override.
+  transcendental; ``exp`` lowers to exp2 + a hidden multiply) and stay
+  (rows, 1) columns: a reduction's result is never laid out again.
+- Fully-masked rows are repaired once per q sub-block (per-row select)
+  instead of guarding every score element.
+- The backward computes its scores as ``k qᵀ``: dV and dK are plain
+  products of them, and only dQ contracts their leading dimension.
+- The schedule — blocks, sub-tiles, heads a step — comes from a per-(S, D)
+  table measured by tools/tune_flash.py (:func:`flash_schedule` reports
+  it); ``MXNET_FLASH_BLOCK_Q/K`` override the blocks.
 
 Causal masking takes a **dynamic row offset**: visibility is
 ``row + offset >= col``. offset=0 is standard causal; ring attention
@@ -40,12 +57,13 @@ from __future__ import annotations
 import functools
 import math
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["flash_attention", "flash_attention_with_lse",
+__all__ = ["flash_attention", "flash_attention_with_lse", "flash_schedule",
            "decode_attention", "decode_attention_impl",
            "flash_decode_attention", "latent_decode_attention",
            "flash_latent_decode_attention", "decode_page_group"]
@@ -79,92 +97,194 @@ def _dotA(a, b, prec):
                            preferred_element_type=jnp.float32, precision=prec)
 
 
-def _fwd_core(q, load_kv, offset, q_start, s_total, block_k, scale, causal,
-              d_v):
-    """Shared fwd tile loop: one resident q block vs streamed K/V blocks.
-    ``d_v`` is the width of a value row (it need not be q's and k's).
+def _dot(a, b, prec):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=prec)
 
-    Phase split: blocks [0, nk_full) are fully visible (no mask math);
-    blocks [nk_full, nk_run) get the causal iota mask. Softmax statistics
-    are tracked in the log2 domain on raw (unscaled) scores; the scale
-    folds into the exp2 argument. ``load_kv(j) -> (k_blk, v_blk)`` hides
-    the ref slicing.
-    Returns (normalized out f32, lse).
+
+class _Schedule(NamedTuple):
+    """What a grid step holds and what one loop iteration computes."""
+    block_q: int   # q rows a forward grid step keeps resident, and the most
+    #                one product of the backward is tall
+    block_k: int   # K/V rows a backward grid step keeps resident, and the
+    #                most one product of the forward is wide
+    sub_q: int     # rows of one (q sub-block, k sub-tile) pair
+    sub_k: int     # columns of one pair
+    heads: int     # heads a forward grid step advances side by side
+
+
+def _fwd_core(load_q, load_kv, store, offset, q_start, n_sub, sub_q, s_total,
+              sub_k, wide, scale, causal, d_v, heads=1):
+    """Shared fwd walk: the ``n_sub`` q sub-blocks of ``sub_q`` rows that a
+    grid step holds (the first at row ``q_start``), each against the K/V
+    tiles of ``sub_k`` positions it sees, for every head of the step at
+    once. ``d_v`` is the width of a value row (it need not be q's and k's).
+
+    ``load_q(i)`` gives sub-block i's rows, a tuple with one (sub_q, D)
+    array a head; ``load_kv(j, n)`` tiles [j, j + n) as ONE ``(k_blk,
+    v_blk)`` of n·sub_k positions (n a Python int), a tuple with one pair a
+    head; ``store(i, outs)`` takes the sub-block's ``(normalized out f32,
+    lse (sub_q, 1))`` a head. ``load_kv`` runs once a (sub-block, product):
+    a caller whose tiles are dear to make (the latent kernel expands them)
+    hands over ONE sub-block.
+
+    Per sub-block, tiles [0, nk_full) are fully visible (no mask math);
+    tiles [nk_full, nk_run) are crossed by the diagonal and take the mask;
+    tiles behind it are not walked. With ``wide`` 1 that is two loops of one
+    tile an iteration. A loop's end is a wall to the scheduler, and a tile
+    of few rows and columns is over before the MXU has filled: so with
+    ``wide`` > 1, where the diagonal crosses ONE tile (every offset that is
+    a multiple of ``sub_k``), the visible tiles go ``wide`` at a time as one
+    product — whole ones in a loop, then the 1 … ``wide`` that end on the
+    diagonal in one straight-line body picked by their number, the mask on
+    the last ``sub_k`` columns alone. Softmax statistics are tracked in the
+    log2 domain on raw (unscaled) scores; the scale folds into the exp2
+    argument.
     """
-    bq = q.shape[0]
-    nk = s_total // block_k
-    prec = _dot_prec(q.dtype)
+    nk = s_total // sub_k
     c = scale * _LOG2E  # exp(s*scale - m) == exp2((s - m_raw) * c)
-    if causal:
-        # fully-visible: every col of block j visible to every row ⇔
-        # (j+1)*bk - 1 <= q_start + offset
-        nk_full = jnp.clip((q_start + offset - block_k + 1) // block_k + 1,
-                           0, nk)
-        # any-visible: col_min <= q_end - 1 + offset
-        last = (q_start + bq + offset + block_k - 1) // block_k
-        nk_run = jnp.clip(last, 0, nk)
+
+    def sub_block(i, _):
+        start = q_start + i * sub_q
+        if causal:
+            # fully-visible: every col of tile j visible to every row ⇔
+            # (j+1)*sk - 1 <= start + offset
+            nk_full = jnp.clip((start + offset - sub_k + 1) // sub_k + 1,
+                               0, nk)
+            # any-visible: col_min <= start + sq - 1 + offset
+            nk_run = jnp.clip((start + sub_q + offset + sub_k - 1) // sub_k,
+                              0, nk)
+        else:
+            nk_full = nk_run = nk
+
+        def tiles(j, n, carry, masked):
+            """Tiles [j, j + n) in one product a head, the last under the
+            mask if ``masked``; ``carry`` None: nothing gathered yet."""
+            # everything a product reads is loaded here, inside the body
+            # that uses it: nothing wide lives across a loop's or a
+            # branch's edge, where it would be spilled and filled again
+            if masked:   # row + offset >= col, in the last tile's indices
+                visible = (
+                    lax.broadcasted_iota(jnp.int32, (sub_q, sub_k), 0)
+                    - lax.broadcasted_iota(jnp.int32, (sub_q, sub_k), 1)
+                    >= (j + n - 1) * sub_k - start - offset)
+            new = []
+            for h, (q, (k_blk, v_blk)) in enumerate(zip(load_q(i),
+                                                        load_kv(j, n))):
+                prec = _dot_prec(q.dtype)
+                s = _dotT(q, k_blk, prec)           # raw scores (sq, n·sk)
+                if masked:
+                    last = jnp.where(visible, s[:, (n - 1) * sub_k:],
+                                     _NEG_INF)
+                    s = last if n == 1 else jnp.concatenate(
+                        [s[:, :(n - 1) * sub_k], last], axis=1)
+                new_m = jnp.max(s, axis=-1, keepdims=True)
+                if carry is not None:
+                    acc, m, l = carry[h]
+                    new_m = jnp.maximum(m, new_m)
+                p = jnp.exp2((s - new_m) * c)
+                pv = _dot(p.astype(v_blk.dtype), v_blk, prec)
+                new_l = jnp.sum(p, axis=-1, keepdims=True)
+                if carry is not None:
+                    corr = jnp.exp2((m - new_m) * c)
+                    pv = acc * corr + pv
+                    new_l = l * corr + new_l
+                new.append((pv, new_m, new_l))
+            return tuple(new)
+
+        def finish(carry):
+            if carry is None:    # no column seen
+                carry = zero()
+            outs = []
+            for acc, m, l in carry:
+                # Rows that never saw a visible column (possible only for
+                # offset < 0, ring's partially-masked edge): m stayed
+                # _NEG_INF with p=exp2(0)=1 pollution. One per-row select
+                # repairs them — no per-element guard.
+                row_ok = m > _NEG_INF / 2
+                safe_l = jnp.maximum(l, 1e-30)
+                outs.append((jnp.where(row_ok, acc * (1.0 / safe_l), 0.0),
+                             jnp.where(row_ok & (l > 0),
+                                       m * scale + jnp.log(safe_l),
+                                       _NEG_INF)))
+            store(i, tuple(outs))
+
+        def zero():
+            return ((jnp.zeros((sub_q, d_v), jnp.float32),
+                     jnp.full((sub_q, 1), _NEG_INF, jnp.float32),
+                     jnp.zeros((sub_q, 1), jnp.float32)),) * heads
+
+        def walk_singly():
+            carry = lax.fori_loop(
+                0, nk_full, lambda j, cr: tiles(j, 1, cr, False), zero())
+            if causal:
+                carry = lax.fori_loop(
+                    nk_full, nk_run, lambda j, cr: tiles(j, 1, cr, True),
+                    carry)
+            finish(carry)
+
+        def walk_wide():
+            n_whole, carry = 0, None
+            if nk > wide:    # a row may see whole wide products
+                n_whole = nk_full // wide
+                carry = lax.fori_loop(
+                    0, n_whole,
+                    lambda j, cr: tiles(j * wide, wide, cr, False), zero())
+            rest = nk_run - n_whole * wide       # 0 … wide tiles
+
+            def end(n, cr=None):
+                finish(tiles(n_whole * wide, n, cr, causal) if n else cr)
+
+            if isinstance(rest, int):            # not causal: known here
+                end(rest, carry)
+            else:
+                lax.switch(rest, [functools.partial(end, n)
+                                  for n in range(wide + 1)],
+                           *(() if carry is None else (carry,)))
+
+        if wide == 1:
+            walk_singly()
+        elif not causal:
+            walk_wide()
+        else:
+            lax.cond(nk_run - nk_full <= 1, walk_wide, walk_singly)
+        return 0
+
+    if n_sub == 1:
+        sub_block(0, 0)
     else:
-        nk_full = nk
-        nk_run = nk
-
-    def tile(j, carry, masked):
-        acc, m, l = carry
-        k_blk, v_blk = load_kv(j)
-        s = _dotT(q, k_blk, prec)                      # raw scores (bq,bk)
-        if masked:
-            rows = q_start + lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            cols = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(rows + offset >= cols, s, _NEG_INF)
-        new_m = jnp.maximum(m, jnp.max(s, axis=-1))
-        corr = jnp.exp2((m - new_m) * c)
-        p = jnp.exp2((s - new_m[:, None]) * c)
-        acc = acc * corr[:, None] + jnp.dot(
-            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32,
-            precision=prec)
-        l = l * corr + jnp.sum(p, axis=-1)
-        return acc, new_m, l
-
-    acc0 = jnp.zeros((bq, d_v), jnp.float32)
-    m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    carry = lax.fori_loop(0, nk_full,
-                          functools.partial(tile, masked=False),
-                          (acc0, m0, l0))
-    acc, m, l = lax.fori_loop(nk_full, nk_run,
-                              functools.partial(tile, masked=True), carry)
-    # Rows that never saw a visible column (possible only for offset < 0,
-    # ring's partially-masked edge): m stayed _NEG_INF with p=exp2(0)=1
-    # pollution. One per-row select repairs them — no per-element guard.
-    row_ok = m > _NEG_INF / 2
-    safe_l = jnp.maximum(l, 1e-30)
-    out = jnp.where(row_ok[:, None], acc / safe_l[:, None], 0.0)
-    lse = jnp.where(row_ok & (l > 0), m * scale + jnp.log(safe_l), _NEG_INF)
-    return out, lse
+        lax.fori_loop(0, n_sub, sub_block, 0)
 
 
-def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
-                scale, causal, block_q):
-    """Grid (BH, S // block_q) over split (BH, S, D) tensors."""
+def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sub_q,
+                sub_k, wide, scale, causal):
+    """Grid (BH // heads, S // block_q) over split (BH, S, D) tensors."""
     import jax.experimental.pallas as pl
 
-    q_blk_idx = pl.program_id(1)
+    heads, block_q = q_ref.shape[:2]
+
     # Keep q/k/v in their storage dtype for the MXU dots (bf16×bf16 with f32
     # accumulation runs at full MXU rate; pre-casting to f32 would quarter
     # it) — only the softmax statistics live in f32.
-    q = q_ref[0]                                      # (bq, D)
+    def load_q(i):
+        rows = pl.ds(i * sub_q, sub_q)
+        return tuple(q_ref[h, rows, :] for h in range(heads))
 
-    def load_kv(j):
-        return (k_ref[0, pl.ds(j * block_k, block_k), :],
-                v_ref[0, pl.ds(j * block_k, block_k), :])
+    def load_kv(j, n):
+        cols = pl.ds(j * sub_k, n * sub_k)
+        return tuple((k_ref[h, cols, :], v_ref[h, cols, :])
+                     for h in range(heads))
 
-    out, lse = _fwd_core(q, load_kv, off_ref[0], q_blk_idx * block_q,
-                         k_ref.shape[1], block_k, scale, causal,
-                         v_ref.shape[2])
-    o_ref[0] = out.astype(o_ref.dtype)
-    # lse lives in an (bq, 8)-lane block purely to satisfy TPU tiling
-    lse_ref[0] = jnp.broadcast_to(lse[:, None], (lse.shape[0], 8))
+    def store(i, outs):
+        rows = pl.ds(i * sub_q, sub_q)
+        for h, (out, lse) in enumerate(outs):
+            o_ref[h, rows, :] = out.astype(o_ref.dtype)
+            # lse lives in (rows, 8) lanes purely to satisfy TPU tiling
+            lse_ref[h, rows, :] = jnp.broadcast_to(lse, (sub_q, 8))
+
+    _fwd_core(load_q, load_kv, store, off_ref[0],
+              pl.program_id(1) * block_q, block_q // sub_q, sub_q,
+              k_ref.shape[1], sub_k, wide, scale, causal, v_ref.shape[2],
+              heads)
 
 
 def _sds(shape, dtype, like):
@@ -181,32 +301,39 @@ def _match_vma(x, like):
     return lax.pcast(x, missing, to="varying") if missing else x
 
 
-def _fwd_pallas(q, k, v, offset, scale, causal, block_q, block_k, interpret):
+# jitted: a model's layers call the kernels at the same shapes, and one
+# traced and lowered body serves them all (24 forward and 24 backward
+# bodies of branches were 6 s of every warm start)
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _fwd_pallas(q, k, v, offset, scale, causal, sched, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
     dv = v.shape[-1]          # a value row may be narrower than q's and k's
     bh = b * h
+    hp = sched.heads if bh % sched.heads == 0 else 1   # heads come in pairs
+    block_q = sched.block_q
     q3 = q.reshape(bh, s, d)
     k3 = k.reshape(bh, s, d)
     v3 = v.reshape(bh, s, dv)
     off = _match_vma(jnp.asarray(offset, jnp.int32).reshape(1), q)
-    grid = (bh, s // block_q)
-    kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
-                               causal=causal, block_q=block_q)
+    kernel = functools.partial(_fwd_kernel, sub_q=sched.sub_q,
+                               sub_k=sched.sub_k,
+                               wide=sched.block_k // sched.sub_k, scale=scale,
+                               causal=causal)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh // hp, s // block_q),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, s, dv), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((hp, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((hp, s, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((hp, s, dv), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((hp, block_q, dv), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((hp, block_q, 8), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             _sds((bh, s, dv), q.dtype, q),
@@ -221,101 +348,170 @@ def _fwd_pallas(q, k, v, offset, scale, causal, block_q, block_k, interpret):
 # Backward
 # ---------------------------------------------------------------------------
 
-def _bwd_core(j, k_blk, v_blk, loads, dq_rw, offset, s_total, block_q,
-              block_k, scale, causal):
-    """Shared bwd tile loop: K/V block resident; loops over Q blocks.
+def _bwd_core(k_start, load_kv, loads, add_dq, offset, s_total, sub_q, tall,
+              scale, causal, sub_k, d):
+    """Shared bwd walk: one K/V sub-tile (``load_kv()`` gives ``(k_blk,
+    v_blk)``, each (sub_k, d); its first position ``k_start``) against the
+    q sub-blocks of ``sub_q`` rows that see it.
 
-    dS = P ∘ (dP − δ + dlse) with δ = rowsum(dO ∘ O) precomputed outside.
-    ``loads(i) -> (q_blk, do_blk, lse_blk, dl_blk)``;
-    ``dq_rw = (read_dq(i), write_dq(i, val))`` accumulates dQ into a
-    VMEM-resident output block (legal: the TPU grid runs sequentially per
-    core and dq's index map ignores the kv-block index).
-    Returns (dk_acc, dv_acc) f32.
+    The scores are made as ``k qᵀ`` — (sub_k, rows), a query a COLUMN — so
+    that dV = P do and dK = dS q are plain products and only dQ = dSᵀ k
+    contracts a leading dimension. dS = P ∘ (dP − δ + dlse) with δ =
+    rowsum(dO ∘ O) precomputed outside; the softmax scale waits for the
+    sums (dK here, dQ where it leaves the kernel) instead of passing over
+    every score. ``loads(i, n)`` gives sub-blocks [i, i + n) as ONE ``(q_blk,
+    do_blk, lse_row, dl_row)`` (n a Python int), the two statistics as (1,
+    n·sub_q) rows; ``add_dq(i, n, val)`` adds the unscaled (n·sub_q, D) to
+    those rows of dQ.
+
+    Sub-blocks [i_start, i_full) are crossed by the diagonal and take the
+    mask, [i_full, nq) are fully visible, those before see nothing. With
+    ``tall`` 1 that is two loops of one sub-block an iteration; with
+    ``tall`` > 1, where the diagonal crosses ONE sub-block (every offset
+    that is a multiple of ``sub_q``), the visible sub-blocks go ``tall`` at
+    a time as one product: the 1 … ``tall`` that begin on the diagonal in a
+    straight-line body picked by their number, the mask on the first
+    ``sub_q`` columns alone, then whole ones in a loop (:func:`_fwd_core`
+    says why). Returns ``(dk_acc, dv_acc)`` f32.
     """
-    bk, d = k_blk.shape
-    nq = s_total // block_q
-    prec = _dot_prec(k_blk.dtype)
+    nq = s_total // sub_q
     c = scale * _LOG2E
-    read_dq, write_dq = dq_rw
 
     if causal:
-        # first q block with any visible row: i*bq + bq-1 + offset >= j*bk
-        i_start = jnp.clip((j * block_k - offset) // block_q, 0, nq)
-        # first q block with EVERY row visible: i*bq + offset >= (j+1)*bk - 1
-        i_full = jnp.clip(
-            (j * block_k + block_k - 1 - offset + block_q - 1) // block_q,
-            i_start, nq)
+        # first q sub-block with any visible row: i*sq + sq-1 + offset >=
+        # k_start
+        i_start = jnp.clip((k_start - offset) // sub_q, 0, nq)
+        # first with EVERY row visible: i*sq + offset >= k_start + sk - 1
+        i_full = jnp.clip((k_start + sub_k - 1 - offset + sub_q - 1) // sub_q,
+                          i_start, nq)
     else:
-        i_start = 0
-        i_full = 0
+        i_start = i_full = 0
 
-    def tile(i, carry, masked):
-        dk_acc, dv_acc = carry
-        q_blk, do_blk, lse_blk, dl_blk = loads(i)
-        s = _dotT(q_blk, k_blk, prec)                  # raw scores (bq,bk)
+    def blocks(i, n, carry, masked):
+        """Sub-blocks [i, i + n) in one product, the first under the mask if
+        ``masked``; ``carry`` None: nothing gathered yet. As in the forward,
+        a body loads what it reads itself."""
+        k_blk, v_blk = load_kv()
+        q_blk, do_blk, lse_row, dl_row = loads(i, n)
+        prec = _dot_prec(k_blk.dtype)
+        st = _dotT(k_blk, q_blk, prec)              # raw scores (sk, n·sq)
+        expo = st * c - lse_row * _LOG2E
         if masked:
-            rows = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            cols = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(rows + offset >= cols, s, _NEG_INF)
-            # Rows with lse=_NEG_INF (never visible anywhere — ring's
-            # partially-masked edge, offset<0 unaligned to block_q) reach
-            # masked tiles at block granularity: exp2(s·c − lse·log2e)
-            # would overflow to +inf there (both terms ±1e30). Valid rows
-            # always have exponent ≤ 0 (p ≤ 1), so clamping at 0 plus a
-            # per-row zero repairs them without touching the hot unmasked
-            # path.
-            row_ok = lse_blk > _NEG_INF / 2
-            expo = jnp.minimum(s * c - (lse_blk * _LOG2E)[:, None], 0.0)
-            p = jnp.exp2(expo) * row_ok[:, None]
+            # row + offset >= col, in the first sub-block's indices. Rows
+            # with lse=_NEG_INF (never visible anywhere — ring's
+            # partially-masked edge, offset<0 unaligned to sub_q) reach
+            # masked sub-blocks whole: exp2(s·c − lse·log2e) would overflow
+            # to +inf there. Valid rows always have exponent ≤ 0 (p ≤ 1),
+            # so clamping at 0 plus a per-row zero repairs them without
+            # touching the hot unmasked path.
+            visible = (lax.broadcasted_iota(jnp.int32, (sub_k, sub_q), 1)
+                       - lax.broadcasted_iota(jnp.int32, (sub_k, sub_q), 0)
+                       >= k_start - i * sub_q - offset)
+            first = jnp.where(
+                visible & (lse_row[:, :sub_q] > _NEG_INF / 2),
+                jnp.exp2(jnp.minimum(expo[:, :sub_q], 0.0)), 0.0)
+            p = first if n == 1 else jnp.concatenate(
+                [first, jnp.exp2(expo[:, sub_q:])], axis=1)
         else:
-            # fully-visible pair ⇒ every row visible ⇒ lse finite
-            p = jnp.exp2(s * c - (lse_blk * _LOG2E)[:, None])
-        dp = _dotT(do_blk, v_blk, prec)                # (bq,bk)
-        ds = (p * (dp - dl_blk[:, None]) * scale)
-        pd = p.astype(do_blk.dtype)
-        dsd = ds.astype(q_blk.dtype)
-        dv_acc = dv_acc + _dotA(pd, do_blk, prec)      # (bk,D)
-        dk_acc = dk_acc + _dotA(dsd, q_blk, prec)      # (bk,D)
-        write_dq(i, read_dq(i) + jnp.dot(
-            dsd, k_blk, preferred_element_type=jnp.float32, precision=prec))
-        return dk_acc, dv_acc
+            # fully-visible ⇒ every row visible ⇒ lse finite
+            p = jnp.exp2(expo)
+        dp = _dotT(v_blk, do_blk, prec)                 # (sk, n·sq)
+        dsd = (p * (dp - dl_row)).astype(q_blk.dtype)
+        dk = _dot(dsd, q_blk, prec)                     # (sk, D)
+        dv = _dot(p.astype(do_blk.dtype), do_blk, prec)  # (sk, D)
+        add_dq(i, n, _dotA(dsd, k_blk, prec))           # (n·sq, D)
+        if carry is not None:
+            dk, dv = carry[0] + dk, carry[1] + dv
+        return dk, dv
 
-    z = jnp.zeros((bk, d), jnp.float32)
-    carry = lax.fori_loop(i_start, i_full,
-                          functools.partial(tile, masked=True), (z, z))
-    return lax.fori_loop(i_full, nq,
-                         functools.partial(tile, masked=False), carry)
+    def zero():
+        return (jnp.zeros((sub_k, d), jnp.float32),) * 2
+
+    def walk_singly():
+        carry = zero()
+        if causal:
+            carry = lax.fori_loop(
+                i_start, i_full, lambda i, cr: blocks(i, 1, cr, True), carry)
+        return lax.fori_loop(
+            i_full, nq, lambda i, cr: blocks(i, 1, cr, False), carry)
+
+    def walk_tall():
+        seen = nq - i_start                     # sub-blocks that see the tile
+        # 1 … tall begin on the diagonal, whole products follow
+        if isinstance(seen, int):               # not causal: known here
+            head = (seen - 1) % tall + 1
+            carry = blocks(0, head, None, False)
+        else:
+            head = jnp.where(seen > 0, (seen - 1) % tall + 1, 0)
+            carry = lax.switch(head, [zero] + [
+                functools.partial(blocks, i_start, n, None, True)
+                for n in range(1, tall + 1)])
+        if nq > tall:       # a tile may be seen by whole tall products
+            carry = lax.fori_loop(
+                0, (seen - head) // tall,
+                lambda t, cr: blocks(i_start + head + t * tall, tall, cr,
+                                     False), carry)
+        return carry
+
+    if tall == 1:
+        dk_acc, dv_acc = walk_singly()
+    elif not causal:
+        dk_acc, dv_acc = walk_tall()
+    else:
+        dk_acc, dv_acc = lax.cond(i_full - i_start <= 1, walk_tall,
+                                  walk_singly)
+    return dk_acc * scale, dv_acc
 
 
 def _bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dl_ref, k_ref, v_ref,
-                dq_ref, dk_ref, dv_ref, *, block_q, block_k, scale, causal):
-    """Grid (BH, S // block_k) over split (BH, S, D) tensors."""
+                dq_ref, dk_ref, dv_ref, dq_acc, *, sub_q, sub_k, tall, scale,
+                causal):
+    """Grid (BH, S // block_k) over split (BH, S, D) tensors, one head a
+    step (two in one body spilled: 0.47 ms a call for 0.38, PERF.md §6);
+    the two statistics come as (BH, 1, S) rows. dQ gathers in ``dq_acc``
+    (legal: the TPU grid runs sequentially per core and dQ's index map
+    ignores the kv-block index) and is written once, behind the last K/V
+    block."""
     import jax.experimental.pallas as pl
 
     j = pl.program_id(1)
+    block_k = k_ref.shape[0]
 
     @pl.when(j == 0)
     def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def loads(i):
-        sl = pl.ds(i * block_q, block_q)
-        return (q_ref[0, sl, :], do_ref[0, sl, :],
-                lse_ref[0, sl, :][:, 0], dl_ref[0, sl, :][:, 0])
+    def loads(i, n):
+        rows = pl.ds(i * sub_q, n * sub_q)
+        return q_ref[rows, :], do_ref[rows, :], lse_ref[:, rows], \
+            dl_ref[:, rows]
 
-    dq_rw = (lambda i: dq_ref[0, pl.ds(i * block_q, block_q), :],
-             lambda i, val: dq_ref.__setitem__(
-                 (0, pl.ds(i * block_q, block_q), slice(None)), val))
-    dk_acc, dv_acc = _bwd_core(j, k_ref[0], v_ref[0], loads, dq_rw,
-                               off_ref[0], q_ref.shape[1], block_q, block_k,
-                               scale, causal)
-    dk_ref[0] = dk_acc.astype(dk_ref.dtype)
-    dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+    def add_dq(i, n, val):
+        dq_acc[pl.ds(i * sub_q, n * sub_q), :] += val
+
+    def sub_tile(t, _):
+        cols = pl.ds(t * sub_k, sub_k)
+        dk_acc, dv_acc = _bwd_core(
+            j * block_k + t * sub_k,
+            lambda: (k_ref[cols, :], v_ref[cols, :]), loads, add_dq,
+            off_ref[0], q_ref.shape[0], sub_q, tall, scale, causal, sub_k,
+            k_ref.shape[1])
+        dk_ref[cols, :] = dk_acc.astype(dk_ref.dtype)
+        dv_ref[cols, :] = dv_acc.astype(dv_ref.dtype)
+        return 0
+
+    if block_k == sub_k:
+        sub_tile(0, 0)
+    else:
+        lax.fori_loop(0, block_k // sub_k, sub_tile, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _write():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_pallas(scale, causal, block_q, block_k, interpret, res, g):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _bwd_pallas(scale, causal, sched, interpret, res, g):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -323,48 +519,47 @@ def _bwd_pallas(scale, causal, block_q, block_k, interpret, res, g):
     do, g_lse = g
     b, h, s, d = q.shape
     bh = b * h
+    block_k = sched.block_k
     # δ − dlse folded into ONE per-row vector so the kernel reads it once
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    dl = (delta - g_lse.astype(jnp.float32)).reshape(bh, s)
+    dl3 = (delta - g_lse.astype(jnp.float32)).reshape(bh, 1, s)
+    lse3 = lse.reshape(bh, 1, s)
     q3 = q.reshape(bh, s, d)
     k3 = k.reshape(bh, s, d)
     v3 = v.reshape(bh, s, d)
     do3 = do.astype(q.dtype).reshape(bh, s, d)
-    # (bh, s, 8) lane-padded per-row vectors (same trick as fwd lse output)
-    lse3 = jnp.broadcast_to(lse.reshape(bh, s)[..., None], (bh, s, 8))
-    dl3 = jnp.broadcast_to(dl[..., None], (bh, s, 8))
     off = _match_vma(jnp.asarray(offset, jnp.int32).reshape(1), q)
 
-    grid = (bh, s // block_k)
-    kernel = functools.partial(_bwd_kernel, block_q=block_q, block_k=block_k,
-                               scale=scale, causal=causal)
+    def whole(*minor):
+        return pl.BlockSpec((None,) + minor, lambda i, j: (i, 0, 0))
+
+    def block(*minor):
+        return pl.BlockSpec((None,) + minor, lambda i, j: (i, j, 0))
+
+    kernel = functools.partial(_bwd_kernel, sub_q=sched.sub_q,
+                               sub_k=sched.sub_k,
+                               tall=sched.block_q // sched.sub_q, scale=scale,
+                               causal=causal)
     dq, dk, dv = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, s // block_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),   # q
-            pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),   # do
-            pl.BlockSpec((1, s, 8), lambda i, j: (i, 0, 0)),   # lse
-            pl.BlockSpec((1, s, 8), lambda i, j: (i, 0, 0)),   # δ-dlse
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),  # k
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),  # v
+            whole(s, d), whole(s, d),               # q, do
+            whole(1, s), whole(1, s),               # lse, δ-dlse
+            block(block_k, d), block(block_k, d),   # k, v
         ],
-        out_specs=[
-            pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0)),        # dq
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),  # dk
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),  # dv
-        ],
+        out_specs=[whole(s, d), block(block_k, d), block(block_k, d)],
         out_shape=[
-            _sds((bh, s, d), jnp.float32, q),
+            _sds((bh, s, d), q.dtype, q),
             _sds((bh, s, d), k.dtype, q),
             _sds((bh, s, d), v.dtype, q),
         ],
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
         interpret=interpret,
     )(off, q3, do3, lse3, dl3, k3, v3)
-    return (dq.astype(q.dtype).reshape(b, h, s, d),
-            dk.reshape(b, h, s, d), dv.reshape(b, h, s, d),
-            _int_zero(offset))
+    return dq.reshape(b, h, s, d), dk.reshape(b, h, s, d), \
+        dv.reshape(b, h, s, d)
 
 
 def _bwd_blocked(scale, causal, block_k, res, g):
@@ -423,20 +618,28 @@ def _int_zero(x):
 
 
 # ---------------------------------------------------------------------------
-# Block-size selection
+# The schedule: blocks, sub-tiles and heads a grid step, from the shapes
 # ---------------------------------------------------------------------------
 
-# Measured on TPU v5e by tools/tune_flash.py (round 4): (seq, head_dim) →
-# (block_q, block_k) for fwd; bwd uses the same table. Shapes not listed
-# fall back to the 512/512 heuristic (clipped to S).
+# Shapes not listed walk each 512-row block whole, one head a step: a
+# (512, 512) pair an iteration of a loop, what every shape ran before the
+# table was measured.
+_DEFAULT_SCHEDULE = _Schedule(512, 512, 512, 512, 1)
+
+# (seq, head_dim) → schedule, forward and backward alike, causal or not.
+# Measured on a TPU v5e, the kernels alone, bf16 causal, at the shapes the
+# benchmark's cells run and chip_smoke.py's (PERF.md §6, PR 47, the second
+# sweep: device time of the Mosaic events of 10 calls a schedule;
+# tools/tune_flash.py sweeps the same candidates by slope timing and prints
+# lines like these). 64-wide heads:
+# forward + backward; 192-wide with 128-wide values (latent attention's
+# prefill): forward.
 _BLOCK_TABLE = {
-    (1024, 64): (512, 512),
-    (2048, 64): (512, 512),
-    (4096, 64): (512, 512),
-    (8192, 64): (512, 512),
-    (1024, 128): (512, 512),
-    (2048, 128): (512, 512),
-    (4096, 128): (512, 512),
+    (1024, 64): _Schedule(1024, 1024, 256, 256, 2),   # 0.82 → 0.56 ms
+    (2048, 64): _Schedule(2048, 2048, 256, 256, 1),   # 1.82 → 1.35
+    (512, 192): _Schedule(512, 512, 256, 256, 1),     # 0.167 → 0.091
+    (1024, 192): _Schedule(1024, 1024, 256, 256, 1),  # 0.430 → 0.248
+    (1536, 192): _Schedule(1536, 1536, 256, 256, 1),  # 0.780 → 0.482
 }
 
 
@@ -447,10 +650,10 @@ def _pick_block(s, target):
     return max(blk, 1)
 
 
-def _resolve_blocks(s, d, block_q, block_k):
-    # precedence: explicit argument > env override > tuned table. Env must
-    # not clobber explicit args or tools/tune_flash.py would sweep one
-    # env-pinned size into a bogus uniform table.
+def _resolve_blocks(s, d, block_q, block_k, table=None):
+    # precedence: explicit argument > env override > tuned table (or the
+    # caller's own ``table`` entry). Env must not clobber explicit args or
+    # a sweep would turn one env-pinned size into a bogus uniform table.
     if block_q is None:
         env_q = os.environ.get("MXNET_FLASH_BLOCK_Q")
         block_q = int(env_q) if env_q else None
@@ -458,33 +661,66 @@ def _resolve_blocks(s, d, block_q, block_k):
         env_k = os.environ.get("MXNET_FLASH_BLOCK_K")
         block_k = int(env_k) if env_k else None
     if block_q is None or block_k is None:
-        tq, tk = _BLOCK_TABLE.get((s, d), (512, 512))
-        block_q = block_q if block_q is not None else tq
-        block_k = block_k if block_k is not None else tk
+        table = table or _BLOCK_TABLE.get((s, d), _DEFAULT_SCHEDULE)
+        block_q = block_q if block_q is not None else table.block_q
+        block_k = block_k if block_k is not None else table.block_k
     return _pick_block(s, block_q), _pick_block(s, block_k)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, offset, scale, causal, block_q, block_k, interpret):
-    return _fwd_pallas(q, k, v, offset, scale, causal, block_q, block_k,
-                       interpret)
+def _resolve_schedule(s, d, block_q=None, block_k=None):
+    """The table's schedule for (s, d) with the blocks :func:`_resolve_blocks`
+    settles on; a sub-tile divides its block."""
+    table = _BLOCK_TABLE.get((s, d), _DEFAULT_SCHEDULE)
+    block_q, block_k = _resolve_blocks(s, d, block_q, block_k, table)
+    return _Schedule(block_q, block_k, _pick_block(block_q, table.sub_q),
+                     _pick_block(block_k, table.sub_k), table.heads)
 
 
-def _flash_fwd(q, k, v, offset, scale, causal, block_q, block_k, interpret):
-    out, lse = _fwd_pallas(q, k, v, offset, scale, causal, block_q, block_k,
-                           interpret)
+def flash_schedule(s, d, causal):
+    """What the flash kernels do at sequence length ``s`` and head width
+    ``d``, from the shapes alone: the blocks a grid step keeps resident, the
+    (q rows, k columns) of the pairs it walks them in, the heads it advances
+    side by side in the forward (one where a call's batch × heads is odd;
+    the backward always one), and per head at offset 0 the pairs run, the
+    pairs that take the mask, and both as shares of the S × S square."""
+    sched = _resolve_schedule(s, d)
+    sq, sk = sched.sub_q, sched.sub_k
+    if causal:   # a pair runs if its first column is within its last row
+        run = sum(min(s // sk, (i * sq + sq - 1) // sk + 1)
+                  for i in range(s // sq))
+        full = sum(min(s // sk, max(0, (i * sq - sk + 1) // sk + 1))
+                   for i in range(s // sq))
+    else:
+        run = full = (s // sq) * (s // sk)
+    return {"block_q": sched.block_q, "block_k": sched.block_k,
+            "sub_tile": (sq, sk), "heads_per_step": sched.heads,
+            "tiles_run": run, "tiles_masked": run - full,
+            "computed_share": run * sq * sk / (s * s),
+            "masked_share": (run - full) * sq * sk / (s * s)}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, offset, scale, causal, sched, interpret):
+    return _fwd_pallas(q, k, v, offset, scale, causal, sched, interpret)
+
+
+def _flash_fwd(q, k, v, offset, scale, causal, sched, interpret):
+    out, lse = _fwd_pallas(q, k, v, offset, scale, causal, sched, interpret)
     return (out, lse), (q, k, v, offset, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, sched, interpret, res, g):
     if res[2].shape[-1] != res[0].shape[-1]:
         raise NotImplementedError(
             "flash attention with d_v != d_qk is forward-only (prefill)")
     impl = os.environ.get("MXNET_FLASH_BWD", "auto")
-    use_pallas = impl == "pallas" or (impl == "auto" and not interpret)
+    # on the chip the kernel slices its statistics' LANES by q sub-block
+    use_pallas = impl == "pallas" or (
+        impl == "auto" and not interpret and sched.sub_q % 128 == 0)
     if use_pallas:
-        return _bwd_pallas(scale, causal, block_q, block_k, interpret, res, g)
-    return _bwd_blocked(scale, causal, block_k, res, g)
+        return _bwd_pallas(scale, causal, sched, interpret, res, g) + (
+            _int_zero(res[3]),)
+    return _bwd_blocked(scale, causal, sched.sub_k, res, g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -504,9 +740,9 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None, offset=0,
     d = q.shape[-1]
     s = q.shape[-2]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(d)
-    bq, bk = _resolve_blocks(s, d, block_q, block_k)
+    sched = _resolve_schedule(s, d, block_q, block_k)
     offset = jnp.asarray(offset, jnp.int32)
-    return _flash(q, k, v, offset, scale, causal, bq, bk, _use_interpret())
+    return _flash(q, k, v, offset, scale, causal, sched, _use_interpret())
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
@@ -966,7 +1202,10 @@ def latent_flash_attention_from(q, rows, uk_w, uv_w, start, scale,
         raise ValueError(
             f"{rows.shape[0]} positions are not whole pieces of {c}")
     # blocks that divide the piece: the diagonal ends where the piece ends
-    bq, bk = _resolve_blocks(c, q.shape[2], block_q or 1024, block_k)
+    # (the table's schedules are the whole forward's: a piece keeps key
+    # blocks of 512, each expanded once and walked as one tile)
+    bq, bk = _resolve_blocks(c, q.shape[2], block_q or 1024, block_k,
+                             _DEFAULT_SCHEDULE)
     return _latent_flash_from(jnp.reshape(start, (1,)).astype(jnp.int32), q,
                               rows, uk_w, uv_w, float(scale), bq, bk,
                               _use_interpret())
@@ -998,9 +1237,14 @@ def _latent_flash_from(start, q, rows, uk_w, uv_w, scale, bq, bk, interpret):
             return (jnp.concatenate([k_nope, blk[:, rank:rank + d - nope]],
                                     axis=-1), v)
 
-        out, _ = _fwd_core(q_ref[...], load_kv, start_ref[0],
-                           pl.program_id(1) * bq, t, bk, scale, True, dv)
-        o_ref[...] = out.astype(o_ref.dtype)
+        def store(_, outs):
+            o_ref[...] = outs[0][0].astype(o_ref.dtype)
+
+        # ONE sub-block, the whole query block, a tile a product: a tile
+        # is expanded once
+        _fwd_core(lambda _: (q_ref[...],), lambda j, _: (load_kv(j),), store,
+                  start_ref[0], pl.program_id(1) * bq, 1, bq, t, bk, 1, scale,
+                  True, dv)
 
     def per_head(*block):
         return pl.BlockSpec((None,) + block, lambda i, j, st: (i, 0, 0))

@@ -273,6 +273,67 @@ def test_tpu_paged_kernel_reads_a_group_of_pages_at_the_cell_geometry(
     assert obs.device.analyze_compiled(compiled)["temp_bytes"] == 0
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("shape,d_v,backward", [
+    ((4, 16, 1024, 64), 64, True),       # gpt2m-train-s1024, every layer
+    ((1, 64, 1536, 192), 128, False),    # longcat-omni's longest prefill
+])
+def test_tpu_flash_kernels_lower_with_the_schedule_they_report(
+        shape, d_v, backward, one_chip, monkeypatch):
+    """The flash forward (and at the train cell's shape the backward) for a
+    v5e: through Mosaic, on the grid and in the blocks that
+    ``flash_schedule`` reports from the shapes; the backward's three results
+    in the operands' dtype (dQ gathers in a float32 VMEM scratch: none in
+    HBM, no convert behind the kernel), the scratch a small part of VMEM;
+    no temporary beside the forward's (rows, 8) float32 lse."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    b, h, s, d = shape
+    sched = flash_attention.flash_schedule(s, d, True)
+    heads = sched["heads_per_step"]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, h, s, d_v), jnp.bfloat16, sharding=one_chip)
+
+    def forward(q, k, v):
+        return flash_attention.flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return forward(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else forward
+    calls = list(_pallas_calls(jax.make_jaxpr(fn)(q, q, v).jaxpr))
+    assert len(calls) == (2 if backward else 1)
+    grids = [call.params["grid_mapping"].grid for call in calls]
+    assert grids[0] == (b * h // heads, s // sched["block_q"])
+    assert (heads, sched["block_q"], d) in [
+        tuple(n.block_size for n in m.block_shape)
+        for m in calls[0].params["grid_mapping"].block_mappings]
+    if backward:
+        assert grids[1] == (b * h, s // sched["block_k"])   # a head a step
+        assert [str(out.aval.dtype) for out in calls[1].outvars] == \
+            ["bfloat16"] * 3
+        (scratch,) = calls[1].params["grid_mapping"].scratch_avals
+        assert (scratch.shape, str(scratch.dtype)) == ((s, d), "float32")
+        assert s * d * 4 <= 2 ** 20    # of the 16 MiB a call may use
+    lowered = jax.jit(fn).lower(q, q, v)
+    assert lowered.as_text().count("tpu_custom_call") == len(calls)
+    compiled = lowered.compile()
+    temp = obs.device.analyze_compiled(compiled)["temp_bytes"]
+    assert temp <= b * h * s * 128 * 4 + 2 ** 20   # the lse, lanes padded
+
+
 # -- the latent pool: one bfloat16 row a position, no head axis ----------------
 # The row's 576 values (kv_rank 512 + qk_rope 64, the published widths) are
 # 4.5 lane tiles: at that width the TPU client picks a layout of its own for

@@ -1,16 +1,23 @@
-"""On-chip flash attention block-size sweep → _BLOCK_TABLE defaults.
+"""On-chip flash attention schedule sweep → _BLOCK_TABLE.
 
-Times the Pallas fwd and fwd+bwd at (B,H,S,D) over a block-size grid with
-slope timing (tools/_chiptime.py: difference of two scan-chain depths, so
-the fixed per-dispatch host cost cancels). Prints a JSON table;
-the winners get hardcoded into ops/flash_attention._BLOCK_TABLE.
+Times the Pallas forward (and, where a cell trains through it, forward +
+backward) over the schedules the kernels can take — the blocks a grid step
+keeps resident, the (q rows, k columns) of the pairs it walks them in, 128
+and unequal sizes included, one or two heads a step — at the shapes the
+benchmark's cells run: gpt2m-train-s1024's (4, 16, 1024, 64) and the
+latent models' prefill, (1, 64, {512, 1024, 1536}, 192) with 128-wide
+values. Slope timing (tools/_chiptime.py: difference of two scan-chain
+depths, so the fixed per-dispatch host cost cancels). Prints a JSON table
+and, last, the lines ``ops/flash_attention._BLOCK_TABLE`` is filled from: a
+shape whose best schedule is not faster than whole (512, 512) pairs by 2 %
+keeps those and gets no line.
 
-Usage: python tools/tune_flash.py [S ...]   (default 1024 2048 4096)
+Usage: python tools/tune_flash.py
 """
 from __future__ import annotations
 
-import functools
 import json
+import math
 import os
 import sys
 
@@ -21,39 +28,59 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tools._chiptime import slope_time  # noqa: E402
 
+# (batch, heads, seq, head_dim), value width, timed through the backward too
+SHAPES = [((4, 16, 1024, 64), 64, True),
+          ((1, 64, 512, 192), 128, False),
+          ((1, 64, 1024, 192), 128, False),
+          ((1, 64, 1536, 192), 128, False)]
+SUBS = (128, 256, 512)
+MAX_PRODUCT = 8     # sub-tiles one straight-line product may span
 
-def sweep(S, B=4, H=12, D=64, causal=True, dtype=jnp.bfloat16):
-    from mxnet_tpu.ops.flash_attention import flash_attention
 
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, H, S, D), dtype)
-    k = jax.random.normal(key, (B, H, S, D), dtype)
-    v = jax.random.normal(key, (B, H, S, D), dtype)
-    flops_fwd = 2 * 2 * S * S * D * B * H // (2 if causal else 1)
+def candidates(s):
+    from mxnet_tpu.ops.flash_attention import _Schedule
 
+    for block in sorted({512, s}):
+        for sub_q in SUBS:
+            for sub_k in SUBS:
+                if (block % sub_q or block % sub_k
+                        or block // min(sub_q, sub_k) > MAX_PRODUCT):
+                    continue
+                for heads in (1, 2):
+                    yield _Schedule(block, block, sub_q, sub_k, heads)
+
+
+def sweep(shape, d_v, backward, dtype=jnp.bfloat16):
+    from mxnet_tpu.ops import flash_attention as fa
+
+    b, h, s, d = shape
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(kq, shape, dtype)
+    k = jax.random.normal(kk, shape, dtype)
+    v = jax.random.normal(kv, (b, h, s, d_v), dtype)
     results = {}
-    cands = [(bq, bk) for bq in (256, 512, 1024) for bk in (256, 512, 1024)
-             if bq <= S and bk <= S]
-    for bq, bk in cands:
-        fa = functools.partial(flash_attention, causal=causal,
-                               block_q=bq, block_k=bk)
+    for sched in candidates(s):
+        def attn(c, sched=sched):
+            return fa._flash(c, k, v, jnp.int32(0), 1.0 / math.sqrt(d), True,
+                             sched, False)[0]
+
+        def fwd(c):   # the chain's carry keeps q's shape
+            return jnp.concatenate([attn(c), c[..., d_v:]], axis=-1)
+
+        def fwd_bwd(c):
+            f = lambda qq: (attn(qq).astype(jnp.float32) ** 2).sum()
+            return jax.grad(f)(c).astype(dtype)
+
+        name = "x".join(map(str, sched))
         try:
-            t_f = slope_time(lambda c: fa(c, k, v), q, 10, 50)
-
-            def fb(c):
-                f = lambda qq: (fa(qq, k, v).astype(jnp.float32) ** 2).sum()
-                return jax.grad(f)(c).astype(dtype)
-
-            t_b = slope_time(fb, q, 10, 50)
+            row = {"fwd_ms": round(slope_time(fwd, q, 10, 50) * 1e3, 4)}
+            if backward:
+                row["fwdbwd_ms"] = round(
+                    slope_time(fwd_bwd, q, 10, 50) * 1e3, 4)
         except Exception as e:
-            results[f"{bq}x{bk}"] = f"FAIL {type(e).__name__}"
-            continue
-        results[f"{bq}x{bk}"] = {
-            "fwd_ms": round(t_f * 1e3, 3),
-            "fwd_tflops": round(flops_fwd / t_f / 1e12, 1),
-            "fwdbwd_ms": round(t_b * 1e3, 3),
-        }
-        print(f"  S={S} {bq}x{bk}: {results[f'{bq}x{bk}']}", file=sys.stderr)
+            row = f"FAIL {type(e).__name__}"
+        results[name] = row
+        print(f"  {shape} {name}: {row}", file=sys.stderr)
     return results
 
 
@@ -61,11 +88,19 @@ def main():
     from mxnet_tpu import platform as mxplatform
 
     mxplatform.devices_or_exit(what="tools/tune_flash.py")
-    seqs = [int(a) for a in sys.argv[1:]] or [1024, 2048, 4096]
-    out = {}
-    for S in seqs:
-        out[str(S)] = sweep(S)
+    out, table = {}, []
+    for shape, d_v, backward in SHAPES:
+        rows = sweep(shape, d_v, backward)
+        out[str(shape)] = rows
+        key = "fwdbwd_ms" if backward else "fwd_ms"
+        timed = {n: r[key] for n, r in rows.items() if isinstance(r, dict)}
+        best = min(timed, key=timed.get)
+        if timed[best] < 0.98 * timed.get("512x512x512x512x1", 0.0):
+            table.append(f"    ({shape[2]}, {shape[3]}): _Schedule("
+                         f"{best.replace('x', ', ')}),   # {timed[best]} ms "
+                         f"({key}) for {timed['512x512x512x512x1']}")
     print(json.dumps(out, indent=1))
+    print("_BLOCK_TABLE = {\n" + "\n".join(table) + "\n}")
 
 
 if __name__ == "__main__":
