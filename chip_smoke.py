@@ -201,14 +201,52 @@ def _require_mosaic(jitted, *args):
              "kernel lowered without the Mosaic custom call")
 
 
+def _attention_paths(config, batch, seq):
+    """(flash_packed, flash, plain): the ``attention.impl.*`` counts of one
+    trace of the forward of a benchmark train cell's model (built as
+    ``benchmark/configs/<config>.json`` says) at (batch, seq): one count an
+    attention layer, by the path ``MultiHeadAttention`` took."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import models, obs
+    from mxnet_tpu.parallel.functional import functionalize
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs", config + ".json")) as f:
+        model = json.load(f)["model"]
+    net = getattr(models, model["constructor"])(
+        **{k: model[k] for k in model["constructor_args"]})
+    net.initialize()
+    _, apply = functionalize(net)
+    params = {p.name: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16)
+              for p in net._iter_params()}
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    was_on = obs.enabled()
+    obs.enable()
+    try:
+        before = dict(obs.metrics.snapshot()["counters"])
+        jax.eval_shape(lambda p, *ins: apply(p, *ins)[0], params,
+                       *((tokens,) * (2 if model["kind"] == "mlm" else 1)))
+        after = obs.metrics.snapshot()["counters"]
+    finally:
+        if not was_on:
+            obs.disable()
+    return tuple(after.get("attention.impl." + impl, 0)
+                 - before.get("attention.impl." + impl, 0)
+                 for impl in ("flash_packed", "flash", "plain"))
+
+
 def phase_kernels():
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops.attention import plain_attention
     from mxnet_tpu.ops.flash_attention import (_decode_attention_xla,
+                                               _join_heads,
                                                decode_page_group,
                                                flash_attention,
+                                               flash_attention_packed,
                                                flash_decode_attention,
                                                flash_schedule)
 
@@ -217,8 +255,13 @@ def phase_kernels():
         # shape and at the train phase's, the schedule the kernels take from
         # the shapes, and what the kernels alone cost a call
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
-        for b, h, s, d in ((4, 16, 1024, 64),
-                           (TRAIN_BATCH, 12, TRAIN_SEQ, 64)):
+        # the packed entry's errors at the cell's shape are held to the
+        # band the (B, H, S, D) entry measured there (PERF.md §6, PR 47),
+        # at the train phase's to PR 27's
+        for (b, h, s, d), packed_tols in (
+                ((4, 16, 1024, 64), (3e-3, 4e-3, 5.2e-3, 3e-3)),
+                ((TRAIN_BATCH, 12, TRAIN_SEQ, 64),
+                 (3e-3, 5.2e-3, 4e-3, 3.3e-3))):
             q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
                        for kk in keys[:3])
             w = jax.random.normal(keys[3], (b, h, s, d), jnp.float32)
@@ -241,22 +284,54 @@ def phase_kernels():
             (_, out_ref), grads_ref = ref(*(x.astype(jnp.float32)
                                             for x in (q, k, v)))
             shape = f"({b},{h},{s},{d}) bf16 causal"
-            print(f"   flash schedule {shape}: {flash_schedule(s, d, True)}",
-                  flush=True)
+            print(f"   flash schedule {shape}: "
+                  f"{flash_schedule(s, d, True, b, h)}", flush=True)
             _check_close(f"flash fwd  {shape}", out, out_ref, 2e-2)
             for name, g, gr in zip(("dq", "dk", "dv"), grads, grads_ref):
                 _check_close(f"flash bwd {name}", g, gr, 4e-2)
+            # the packed entry over the SAME values laid out as the fused
+            # projection writes them, (B, S, 3·H·D): no transpose around it
+            qkv = jnp.concatenate([_join_heads(x) for x in (q, k, v)], -1)
+            w_packed = _join_heads(w)
+
+            def packed_loss(qkv):
+                out = flash_attention_packed(qkv, h, causal=True)
+                return jnp.sum(out.astype(jnp.float32) * w_packed), out
+
+            packed = jax.jit(jax.value_and_grad(packed_loss, has_aux=True))
+            packed_forward = jax.jit(functools.partial(
+                flash_attention_packed, heads=h, causal=True))
+            _require_mosaic(packed, qkv)
+            (_, out), grad = packed(qkv)
+            for name, g, gr, tol in zip(
+                    ("fwd", "dq", "dk", "dv"),
+                    (out,) + tuple(jnp.split(grad, 3, axis=-1)),
+                    [_join_heads(x) for x in (out_ref,) + grads_ref],
+                    packed_tols):
+                _check_close(f"flash packed {name} ({b},{s},3x{h * d})", g,
+                             gr, tol)
             took = []
-            for fn in (forward, flash):
-                jax.block_until_ready(fn(q, k, v))
+            for fn, args in ((forward, (q, k, v)), (flash, (q, k, v)),
+                             (packed_forward, (qkv,)), (packed, (qkv,))):
+                jax.block_until_ready(fn(*args))
                 t0 = time.monotonic()
                 for _ in range(20):
-                    last = fn(q, k, v)
+                    last = fn(*args)
                 jax.block_until_ready(last)
                 took.append((time.monotonic() - t0) * 50)
             print(f"   flash alone {shape}: forward {took[0]:.3f} ms, forward "
-                  f"+ backward {took[1]:.3f} ms a call (host clock, 20 calls)",
-                  flush=True)
+                  f"+ backward {took[1]:.3f} ms a call; packed {took[2]:.3f}, "
+                  f"{took[3]:.3f} (host clock, 20 calls)", flush=True)
+        # which entry the benchmark's two train programs take, a layer
+        for config, batch, seq, want in (
+                ("gpt2-medium", 4, 1024, (24, 0, 0)),
+                ("bert-large", 32, 128, (0, 0, 24))):
+            got = _attention_paths(config, batch, seq)
+            print(f"   attention layers of {config}'s train program at "
+                  f"({batch}, {seq}): {got[0]} flash_packed, {got[1]} flash, "
+                  f"{got[2]} plain", flush=True)
+            _require(got == want, f"{config}: attention paths {got}, "
+                     f"expected {want}")
         # longcat-omni's longest prefill runs the forward at 192 / 128
         print("   flash schedule (1,64,1536,192) bf16 causal: "
               f"{flash_schedule(1536, 192, True)}", flush=True)

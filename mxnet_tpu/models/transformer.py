@@ -46,12 +46,14 @@ class MultiHeadAttention(HybridBlock):
         self._dropout = dropout
 
     def hybrid_forward(self, F, x, mask=None):
-        # x: (B, S, U)
-        b, s, u = x.shape
+        b, s, u = x.shape  # x: (B, S, U)
         h, d = self._heads, self._units // self._heads
         qkv = self.qkv(x)  # (B, S, 3U)
-        # split (not tensor indexing) keeps this F-generic: the same code
-        # traces eagerly and symbolically (Symbol has no tensor indexing)
+        # the flash kernels may read the projection where it lies
+        out = _packed_attention(self, F, qkv, mask)
+        if out is not None:
+            return out
+        # split, not tensor indexing: Symbol has none, and F stays generic
         qkv = qkv.reshape((b, s, 3, h, d))
         q, k, v = F.split(qkv, num_outputs=3, axis=2, squeeze_axis=True)
         q = q.transpose((0, 2, 1, 3))  # (B, H, S, D)
@@ -81,9 +83,7 @@ class MultiHeadAttention(HybridBlock):
                 lambda qq, kk, vv: par.sequence_sharded_attention(
                     qq, kk, vv, mesh, causal=self._causal),
                 [q, k, v])
-        else:
-            # flash (Pallas) for long sequences, fused XLA
-            # softmax-attention otherwise — see ops/attention.py policy
+        else:  # flash or fused XLA softmax-attention: ops/attention.py
             def attn(qq, kk, vv, mm=None):
                 return fused_attention(qq, kk, vv, mask=mm,
                                        causal=self._causal)
@@ -556,3 +556,34 @@ def sample_token(logits, rng, temperature):
     drawn = jax.random.categorical(
         rng, logits.astype(jnp.float32) / safe_t).astype(jnp.int32)
     return jnp.where(t > 0, drawn, greedy)
+
+
+def _packed_attention(layer, F, qkv, mask):
+    """``MultiHeadAttention`` past its fused projection where the flash
+    kernels take ``qkv`` (B, S, 3U) as it lies and hand back (B, S, U), what
+    ``proj`` multiplies — no mask, one device, and shapes for which
+    ``ops.attention.attention_impl`` answers ``flash_packed`` —: the
+    layer's output, with no transpose between its matmuls and the Mosaic
+    calls. None otherwise: the layer then splits the heads itself. Counts
+    the path a traced layer takes in ``attention.impl.<path>`` (``obs``).
+    It stands here, at the end, so that the functions above keep their
+    lines (the decode kernels traced through them are keyed on those)."""
+    from .. import obs, parallel as par
+    from ..ndarray.ndarray import invoke_fn
+    from ..ops.attention import attention_impl
+    from ..ops.flash_attention import flash_attention_packed
+
+    b, s, _ = qkv.shape
+    h = layer._heads
+    shape = (b, h, s, layer._units // h)
+    mesh = par.current_mesh()
+    impl = attention_impl(shape, shape, mask is not None,
+                          fused_qkv=mesh is None or mesh.size == 1)
+    obs.inc("attention.impl." + impl)
+    if impl != "flash_packed":
+        return None
+    out = layer.proj(invoke_fn(
+        lambda t: flash_attention_packed(t, h, causal=layer._causal), [qkv]))
+    if layer._dropout:
+        out = F.Dropout(out, p=layer._dropout)
+    return out

@@ -20,7 +20,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import flash_attention, flash_attention_with_lse
+from .flash_attention import (flash_attention, flash_attention_with_lse,
+                              packed_layout)
 
 __all__ = ["fused_attention", "plain_attention", "attention_impl"]
 
@@ -42,29 +43,36 @@ def plain_attention(q, k, v, mask=None, causal=False, scale=None):
     return jnp.einsum("bhqk,bhkd->bhqd", w, v)
 
 
-def attention_impl(q_shape, k_shape, has_mask=False, impl=None) -> str:
-    """``"flash"`` or ``"plain"`` — the dispatch rule, from shapes alone so
-    callers that must know before they trace (the mesh wrapper in
-    parallel/ring_attention.py) ask the same question ``fused_attention``
-    does. ``impl``/``MXNET_ATTENTION_IMPL`` = ``flash`` where the kernel
-    cannot run raises."""
+def attention_impl(q_shape, k_shape, has_mask=False, impl=None,
+                   fused_qkv=False) -> str:
+    """``"flash"``, ``"flash_packed"`` or ``"plain"`` — the dispatch rule,
+    from shapes alone so callers that must know before they trace (the mesh
+    wrapper in parallel/ring_attention.py) ask the same question
+    ``fused_attention`` does. ``flash_packed`` only to a caller that holds
+    the ``fused_qkv`` projection (B, S, 3·H·D) the shapes were split from,
+    on one device, where the flash kernels read it as it lies
+    (``flash_attention.packed_layout``: 128 lanes hold whole heads or a
+    head whole 128-lane tiles); ``flash`` is the (B, H, S, D) entry.
+    ``impl``/``MXNET_ATTENTION_IMPL`` = ``flash`` (either entry) where the
+    kernel cannot run raises."""
     impl = impl or os.environ.get("MXNET_ATTENTION_IMPL", "auto")
     # block specs cover the full head dim, so only S needs tiling-friendly
     # factors (block sizes are shrunk to divide S; 8 is the sublane minimum)
     s_q, s_k = q_shape[-2], k_shape[-2]
     can_flash = (not has_mask and len(q_shape) == 4 and s_q == s_k
                  and s_q % 8 == 0)
-    if impl == "flash":
-        if not can_flash:
-            raise ValueError(
-                "impl='flash' cannot run here: the kernel takes no explicit "
-                "mask and needs 4-D q/k with equal sequence lengths that "
-                f"are a multiple of 8 (q {tuple(q_shape)}, k "
-                f"{tuple(k_shape)}, mask={'given' if has_mask else 'none'})")
-        return "flash"
-    if impl == "plain":
+    if impl == "flash" and not can_flash:
+        raise ValueError(
+            "impl='flash' cannot run here: the kernel takes no explicit "
+            "mask and needs 4-D q/k with equal sequence lengths that "
+            f"are a multiple of 8 (q {tuple(q_shape)}, k "
+            f"{tuple(k_shape)}, mask={'given' if has_mask else 'none'})")
+    if impl != "flash" and (impl == "plain" or not can_flash
+                            or s_q < _FLASH_MIN_SEQ):
         return "plain"
-    return "flash" if can_flash and s_q >= _FLASH_MIN_SEQ else "plain"
+    h, d = q_shape[1], q_shape[3]
+    return ("flash_packed" if fused_qkv and q_shape == k_shape
+            and packed_layout(h * d, h) else "flash")
 
 
 def fused_attention(q, k, v, mask=None, causal=False, scale=None, impl=None):

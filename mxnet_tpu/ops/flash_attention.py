@@ -676,27 +676,27 @@ def _resolve_schedule(s, d, block_q=None, block_k=None):
                      _pick_block(block_k, table.sub_k), table.heads)
 
 
-def flash_schedule(s, d, causal):
-    """What the flash kernels do at sequence length ``s`` and head width
-    ``d``, from the shapes alone: the blocks a grid step keeps resident, the
-    (q rows, k columns) of the pairs it walks them in, the heads it advances
-    side by side in the forward (one where a call's batch × heads is odd;
-    the backward always one), and per head at offset 0 the pairs run, the
-    pairs that take the mask, and both as shares of the S × S square."""
+def flash_schedule(s, d, causal, batch=1, heads=None):
+    """What the flash kernels do at (``s``, ``d``), from the shapes alone:
+    the blocks a grid step keeps resident, the (q rows, k columns) of the
+    pairs it walks them in, the heads a forward step of the (B, H, S, D) entry
+    advances (one where batch × heads is odd; its backward always one), per
+    head at offset 0 the pairs run, masked, and their shares of the square;
+    ``packed``: the packed entry's blocks and grids (None: not taken)."""
     sched = _resolve_schedule(s, d)
     sq, sk = sched.sub_q, sched.sub_k
+    run = full = (s // sq) * (s // sk)
     if causal:   # a pair runs if its first column is within its last row
         run = sum(min(s // sk, (i * sq + sq - 1) // sk + 1)
                   for i in range(s // sq))
         full = sum(min(s // sk, max(0, (i * sq - sk + 1) // sk + 1))
                    for i in range(s // sq))
-    else:
-        run = full = (s // sq) * (s // sk)
     return {"block_q": sched.block_q, "block_k": sched.block_k,
             "sub_tile": (sq, sk), "heads_per_step": sched.heads,
             "tiles_run": run, "tiles_masked": run - full,
             "computed_share": run * sq * sk / (s * s),
-            "masked_share": (run - full) * sq * sk / (s * s)}
+            "masked_share": (run - full) * sq * sk / (s * s),
+            "packed": _packed_schedule(s, d, sched, batch, heads)}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -1266,3 +1266,358 @@ def _latent_flash_from(start, q, rows, uk_w, uv_w, scale, bq, bk, interpret):
         interpret=interpret,
         name="mla_prefill_from",
     )(start, q, rows, uk_w, uv_w)
+
+
+# ---------------------------------------------------------------------------
+# The packed entry (models/transformer.py ``MultiHeadAttention``)
+#
+# The same two cores over the layouts a transformer layer's own matmuls
+# write and read: q, k and v are column blocks of the fused projection's
+# (B, S, 3·U) output — ONE operand, handed over three times —, o and the
+# three gradients are (B, S, U), what ``proj`` and the projection's
+# gradient matmuls contract, and the lse is written as the rows the
+# backward reads. A Mosaic call is opaque to XLA: every other layout at its
+# edge is a transpose or a copy that XLA has to make and wait for (PERF.md
+# §6, PR 48). A column block is 128 lanes or a whole head, whichever is
+# wider; narrower heads lie side by side in it, and a product takes ONE of
+# them by a mask on its smaller operand (the other heads' lanes add exact
+# zeros; a contraction or a result of 64 lanes costs the MXU what one of
+# 128 does), so nothing is shifted across lanes and the block's heads share
+# its accumulators' registers. It stands here, at the end, as the latent
+# entry above does and for the same reason.
+# ---------------------------------------------------------------------------
+
+__all__ += ["flash_attention_packed", "packed_layout"]
+
+
+def packed_layout(units, heads):
+    """``(lanes, heads)`` of one column block of :func:`flash_attention_
+    packed` for ``heads`` heads in ``units`` columns, or None where the
+    entry cannot take them: a block is whole 128-lane tiles and whole
+    heads."""
+    d = units // heads
+    if units % heads or units % 128 or (128 % d and d % 128):
+        return None
+    return max(d, 128), max(128 // d, 1)
+
+
+def _head_lanes(shape, h, d):
+    """Where the lanes of a block's head ``h`` (``d`` wide) are."""
+    lane = lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane >= h * d) & (lane < (h + 1) * d)
+
+
+def _only_head(x, h, hb):
+    """``x`` (rows, lanes) with the lanes of the block's other heads zero."""
+    if hb == 1:
+        return x
+    return jnp.where(_head_lanes(x.shape, h, x.shape[1] // hb), x,
+                     jnp.zeros_like(x))
+
+
+def _as_rows(columns):
+    """(rows, 1) float32 columns, one a head of a block → (heads, rows): the
+    columns side by side in the first lanes of ONE (rows, 128) array, which
+    is transposed once."""
+    wide = jnp.broadcast_to(columns[0], (columns[0].shape[0], 128))
+    for n, col in enumerate(columns[1:], 1):
+        wide = jnp.where(_head_lanes(wide.shape, n, 1), col, wide)
+    return wide.T[:len(columns)]
+
+
+def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, hb, sub_q,
+                       sub_k, wide, scale, causal):
+    """Grid (B · U // lanes, S // block_q): the ``hb`` heads of one column
+    block side by side in one body (one after the other measured slower,
+    0.179 → 0.195 ms a call at (4, 1024, 16 x 64)), a head's scores from
+    its own lanes of q, its result picked out of the product's 128."""
+    import jax.experimental.pallas as pl
+
+    block_q, lanes = q_ref.shape
+
+    def load_q(i):
+        q = q_ref[pl.ds(i * sub_q, sub_q), :]
+        return tuple(_only_head(q, h, hb) for h in range(hb))
+
+    def load_kv(j, n):
+        cols = pl.ds(j * sub_k, n * sub_k)
+        return ((k_ref[cols, :], v_ref[cols, :]),) * hb
+
+    def store(i, outs):
+        rows = pl.ds(i * sub_q, sub_q)
+        out = outs[0][0]
+        for h in range(1, hb):   # a head's lanes of its own result
+            out = jnp.where(_head_lanes(out.shape, h, lanes // hb),
+                            outs[h][0], out)
+        o_ref[rows, :] = out.astype(o_ref.dtype)
+        # the heads' lse columns as the rows the backward reads
+        lse_ref[:, rows] = _as_rows([lse for _, lse in outs])
+
+    _fwd_core(load_q, load_kv, store, 0, pl.program_id(1) * block_q,
+              block_q // sub_q, sub_q, k_ref.shape[0], sub_k, wide, scale,
+              causal, lanes, hb)
+
+
+def _packed_specs(nblk, lanes, s):
+    """``spec(rows, part)``: the BlockSpec over a (B, S, n · lanes) array
+    that gives grid step (i, j) — i a (batch row, column block c) pair —
+    ``rows`` rows of column block ``part · nblk + c`` (part 0, 1, 2: q, k,
+    v in the fused projection): block j of them, or all ``s``."""
+    import jax.experimental.pallas as pl
+
+    def spec(rows, part=0):
+        return pl.BlockSpec((None, rows, lanes), lambda i, j: (
+            i // nblk, j if rows < s else 0, part * nblk + i % nblk))
+
+    return spec
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _packed_fwd_pallas(qkv, heads, scale, causal, sched, interpret):
+    import jax.experimental.pallas as pl
+
+    b, s, u3 = qkv.shape
+    u = u3 // 3
+    lanes, hb = packed_layout(u, heads)
+    nblk = u // lanes
+    block_q = sched.block_q
+    spec = _packed_specs(nblk, lanes, s)
+    kernel = functools.partial(_packed_fwd_kernel, hb=hb, sub_q=sched.sub_q,
+                               sub_k=sched.sub_k,
+                               wide=sched.block_k // sched.sub_k, scale=scale,
+                               causal=causal)
+    out, lse = pl.pallas_call(
+        kernel,
+        grid=(b * nblk, s // block_q),
+        in_specs=[spec(block_q), spec(s, 1), spec(s, 2)],
+        out_specs=[
+            spec(block_q),
+            pl.BlockSpec((None, hb, block_q), lambda i, j: (i, 0, j)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, u), qkv.dtype),
+            jax.ShapeDtypeStruct((b * nblk, hb, s), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qkv, qkv, qkv)
+    return out, lse.reshape(b, heads, s)
+
+
+def _packed_bwd_kernel(q_ref, do_ref, o_ref, lse_ref, glse_ref, k_ref, v_ref,
+                       out_ref, dq_acc, dl, dq_out, dkv, sem, *, hb, nblk,
+                       sub_q, sub_k, tall, scale, causal):
+    """Grid (B · U // lanes, S // block_k): the block's heads one after the
+    other through :func:`_bwd_core` (two chains in one body spill), each
+    with k and v masked to its lanes — so its dQ lands in its own lanes of
+    the block's ONE accumulator, the others' take exact zeros — and its dK
+    and dV picked out of the products' 128 lanes as they are written.
+
+    δ − dlse is made here, as rows, from the ``do`` and ``o`` blocks a step
+    holds anyway (outside, XLA wrote the float32 product out and laid it
+    out again to sum it position-minor: 32 MB a layer). The three gradients
+    leave as column blocks of ONE (B, S, 3·U) array left in HBM — the
+    projection's gradient as its matmuls read it; joined outside they were
+    three passes over it a layer —: a step's dK and dV blocks, and behind a
+    block's last step its dQ, are copied out of VMEM while the next step
+    computes, and waited for where that step first writes the buffer."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i, j = pl.program_id(0), pl.program_id(1)
+    nj = pl.num_programs(1)
+    s, lanes = q_ref.shape
+    block_k = k_ref.shape[0]
+    d = lanes // hb
+    row, col = i // nblk, i % nblk
+
+    def copy_out(src, part, first, rows):
+        """``src`` → rows [first, first + rows) of column block ``col`` of
+        q's (part 0), k's (1) or v's (2) gradient."""
+        return pltpu.make_async_copy(
+            src, out_ref.at[row, pl.ds(first, rows),
+                            pl.ds(pl.multiple_of((part * nblk + col) * lanes,
+                                                 128), lanes)],
+            sem.at[part])
+
+    def dkv_out(part):
+        return copy_out(dkv.at[part - 1], part, j * block_k, block_k)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        def stats(r, _):
+            rows = pl.ds(r * sub_q, sub_q)
+            prod = do_ref[rows, :].astype(jnp.float32) \
+                * o_ref[rows, :].astype(jnp.float32)
+            dl[:, rows] = _as_rows(
+                [jnp.sum(_only_head(prod, h, hb), axis=-1, keepdims=True)
+                 for h in range(hb)]) - glse_ref[:, rows]
+            return 0
+
+        lax.fori_loop(0, s // sub_q, stats, 0)
+
+    def add_dq(r, n, val):
+        dq_acc[pl.ds(r * sub_q, n * sub_q), :] += val
+
+    def sub_tile(t, _):
+        cols = pl.ds(t * sub_k, sub_k)
+        for h in range(hb):
+            def loads(r, n, h=h):
+                rows = pl.ds(r * sub_q, n * sub_q)
+                return q_ref[rows, :], do_ref[rows, :], \
+                    lse_ref[h:h + 1, rows], dl[h:h + 1, rows]
+
+            grads = _bwd_core(
+                j * block_k + t * sub_k,
+                lambda h=h: (_only_head(k_ref[cols, :], h, hb),
+                             _only_head(v_ref[cols, :], h, hb)),
+                loads, add_dq, 0, s, sub_q, tall, scale, causal, sub_k, lanes)
+            if h == 0:   # the step before may still be copying its blocks out
+                @pl.when((t == 0) & ((i > 0) | (j > 0)))
+                def _wait():
+                    dkv_out(1).wait()
+                    dkv_out(2).wait()
+            for part, acc in enumerate(grads):
+                if h:    # beside the heads already written
+                    acc = jnp.where(_head_lanes(acc.shape, h, d), acc,
+                                    dkv[part, cols, :].astype(jnp.float32))
+                dkv[part, cols, :] = acc.astype(dkv.dtype)
+        return 0
+
+    if block_k == sub_k:
+        sub_tile(0, 0)
+    else:
+        lax.fori_loop(0, block_k // sub_k, sub_tile, 0)
+    dkv_out(1).start()
+    dkv_out(2).start()
+
+    @pl.when(j == nj - 1)
+    def _write():
+        @pl.when(i > 0)
+        def _wait():
+            copy_out(dq_out, 0, 0, s).wait()
+
+        dq_out[...] = (dq_acc[...] * scale).astype(dq_out.dtype)
+        copy_out(dq_out, 0, 0, s).start()
+
+    @pl.when((i == pl.num_programs(0) - 1) & (j == nj - 1))
+    def _last():
+        dkv_out(1).wait()
+        dkv_out(2).wait()
+        copy_out(dq_out, 0, 0, s).wait()
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _packed_bwd_pallas(heads, scale, causal, sched, interpret, res, g):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    qkv, o, lse = res
+    do, g_lse = g
+    b, s, u = o.shape
+    lanes, hb = packed_layout(u, heads)
+    nblk = u // lanes
+    block_k = sched.block_k
+    spec = _packed_specs(nblk, lanes, s)
+    stats = pl.BlockSpec((None, hb, s), lambda i, j: (i, 0, 0))
+    kernel = functools.partial(_packed_bwd_kernel, hb=hb, nblk=nblk,
+                               sub_q=sched.sub_q, sub_k=sched.sub_k,
+                               tall=sched.block_q // sched.sub_q, scale=scale,
+                               causal=causal)
+    return pl.pallas_call(
+        kernel,
+        grid=(b * nblk, s // block_k),
+        in_specs=[spec(s), spec(s), spec(s), stats, stats,  # q do o lse dlse
+                  spec(block_k, 1), spec(block_k, 2)],          # k, v
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        scratch_shapes=[pltpu.VMEM((s, lanes), jnp.float32),    # dQ's sums
+                        pltpu.VMEM((hb, s), jnp.float32),       # δ − dlse
+                        pltpu.VMEM((s, lanes), qkv.dtype),      # dQ, on its way
+                        pltpu.VMEM((2, block_k, lanes), qkv.dtype),  # dK, dV
+                        pltpu.SemaphoreType.DMA((3,))],
+        # a step hands its copies on to the next: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(qkv, do.astype(qkv.dtype), o,
+      lse.reshape(b * nblk, hb, s),
+      g_lse.astype(jnp.float32).reshape(b * nblk, hb, s), qkv, qkv)
+
+
+def _split_heads(x, heads, parts=1):
+    """(B, S, parts · U) → ``parts`` arrays (B, H, S, D), the layout of the
+    entries above."""
+    b, s, _ = x.shape
+    x = x.reshape(b, s, parts, heads, -1)
+    return tuple(x[:, :, i].transpose(0, 2, 1, 3) for i in range(parts))
+
+
+def _join_heads(x):
+    """(B, H, S, D) → (B, S, U)."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _flash_packed(qkv, heads, scale, causal, sched, interpret):
+    return _packed_fwd_pallas(qkv, heads, scale, causal, sched, interpret)
+
+
+def _flash_packed_fwd(qkv, heads, scale, causal, sched, interpret):
+    out, lse = _packed_fwd_pallas(qkv, heads, scale, causal, sched, interpret)
+    return (out, lse), (qkv, out, lse)    # no second copy of q, k, v
+
+
+def _flash_packed_bwd(heads, scale, causal, sched, interpret, res, g):
+    impl = os.environ.get("MXNET_FLASH_BWD", "auto")
+    if impl == "pallas" or (impl == "auto" and not interpret
+                            and sched.sub_q % 128 == 0):   # as _flash_bwd
+        return (_packed_bwd_pallas(heads, scale, causal, sched, interpret,
+                                   res, g),)
+    qkv, o, lse = res
+    grads = _bwd_blocked(
+        scale, causal, sched.sub_k,
+        _split_heads(qkv, heads, 3) + (jnp.int32(0),)
+        + _split_heads(o, heads) + (lse,),
+        _split_heads(g[0], heads) + (g[1],))
+    return (jnp.concatenate([_join_heads(x) for x in grads[:3]], axis=-1),)
+
+
+_flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
+def flash_attention_packed(qkv, heads, causal=False, scale=None,
+                           with_lse=False):
+    """Flash attention over a fused projection's output as it lies. qkv
+    (B, S, 3·U): a position's U columns of q, of k and of v, head h's in
+    columns ``h·D … (h + 1)·D − 1`` of each → (B, S, U), head h's result in
+    the same columns: what ``flash_attention`` gives for the three
+    (B, H, S, D) transposes, with no transpose made (and ``(out, lse (B, H,
+    S))`` ``with_lse``). :func:`packed_layout` says which (U, heads) it
+    takes; S as ``flash_attention``. The schedule is the table's by (S, D);
+    the gradient comes back as ONE (B, S, 3·U) array."""
+    s, u3 = qkv.shape[1:]
+    if u3 % 3 or packed_layout(u3 // 3, heads) is None:
+        raise ValueError(
+            f"flash_attention_packed cannot take {heads} heads in "
+            f"{u3} / 3 columns: see packed_layout")
+    d = u3 // 3 // heads
+    scale = float(scale) if scale is not None else 1.0 / math.sqrt(d)
+    out, lse = _flash_packed(qkv, heads, scale, causal,
+                             _resolve_schedule(s, d), _use_interpret())
+    return (out, lse) if with_lse else out
+
+
+def _packed_schedule(s, d, sched, batch, heads):
+    """:func:`flash_schedule`'s account of the packed entry for ``heads``
+    heads of ``d`` lanes over ``batch`` rows; None where it takes none."""
+    layout = heads and packed_layout(heads * d, heads)
+    if not layout:
+        return None
+    steps = batch * heads // layout[1]
+    return {"layout": "packed", "block_lanes": layout[0],
+            "heads_per_block": layout[1],
+            "grid_fwd": (steps, s // sched.block_q),
+            "grid_bwd": (steps, s // sched.block_k)}

@@ -1,16 +1,21 @@
 """Pallas flash attention kernel (ops/flash_attention.py): numerics vs plain
 attention, gradients, lse, dispatcher policy, and ring-attention integration
 (flash per-block math on the sp mesh)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mxnet_tpu import obs
 from mxnet_tpu.ops import flash_attention as fa
-from mxnet_tpu.ops.attention import fused_attention, plain_attention
+from mxnet_tpu.ops.attention import (attention_impl, fused_attention,
+                                     plain_attention)
 from mxnet_tpu.ops.flash_attention import (_Schedule, flash_attention,
+                                           flash_attention_packed,
                                            flash_attention_with_lse,
-                                           flash_schedule)
+                                           flash_schedule, packed_layout)
 
 
 def _rand(shape, key, dtype=jnp.float32):
@@ -278,3 +283,170 @@ def test_ring_attention_flash_grad():
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_ring, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3)
+
+
+# -- the packed entry: q, k, v as column blocks of the fused projection -------
+
+@pytest.mark.parametrize("s", [1024, 2048])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads,d", [(2, 64), (2, 128), (3, 64)])
+def test_packed_entry_matches_plain_and_the_heads_entry(heads, d, causal, s,
+                                                        monkeypatch):
+    """``flash_attention_packed`` over (B, S, 3U) — two 64-wide heads in one
+    128-lane block, or a 128-wide head a block — against ``plain_attention``
+    AND the (B, H, S, D) entry on the transposed heads, both kernels
+    interpreted: the forward, the lse, and dq, dk, dv through a loss that
+    weighs the lse too (its gradient path), at the table's schedules for
+    the sequence lengths the table holds. Three 64-wide heads are 192
+    columns, no whole 128-lane tiles: the entry refuses them and
+    ``attention_impl`` answers ``flash``, the (B, H, S, D) entry."""
+    shape = (1, heads, s, d)
+    if (heads * d) % 128:
+        assert packed_layout(heads * d, heads) is None
+        assert attention_impl(shape, shape, fused_qkv=True) == "flash"
+        with pytest.raises(ValueError, match="packed_layout"):
+            flash_attention_packed(_rand((1, s, 3 * heads * d), 0), heads)
+        return
+    assert packed_layout(heads * d, heads) == (128, 128 // d)
+    assert attention_impl(shape, shape, fused_qkv=True) == "flash_packed"
+    assert attention_impl(shape, shape) == "flash"
+    monkeypatch.setenv("MXNET_FLASH_BWD", "pallas")
+    qkv = _rand((1, s, 3 * heads * d), 0)
+    w_out, w_lse = _rand((1, s, heads * d), 1), _rand((1, heads, s), 2)
+
+    def loss(attn):
+        def f(qkv):
+            out, lse = attn(qkv)
+            return (out * w_out).sum() + (lse * w_lse).sum(), (out, lse)
+        return jax.value_and_grad(f, has_aux=True)
+
+    def by_heads(attn):   # a (B, H, S, D) attention over the projection
+        def f(qkv):
+            out, lse = attn(*fa._split_heads(qkv, heads, 3))
+            return fa._join_heads(out), lse
+        return f
+
+    def plain(q, k, v):
+        sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+        if causal:
+            sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
+        return (plain_attention(q, k, v, causal=causal),
+                jax.scipy.special.logsumexp(sc, axis=-1))
+
+    (_, got), g_got = loss(lambda x: flash_attention_packed(
+        x, heads, causal=causal, with_lse=True))(qkv)
+    (_, ref), g_ref = loss(by_heads(plain))(qkv)
+    (_, old), g_old = loss(by_heads(functools.partial(
+        flash_attention_with_lse, causal=causal)))(qkv)
+    for a, b, c in zip(got + (g_got,), ref + (g_ref,), old + (g_old,)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+        # the same cores walk the same tiles: the entries differ by the
+        # order of a few float32 sums at most
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=2e-5)
+
+
+def test_packed_schedule_is_reported_from_the_shapes():
+    """``flash_schedule`` with the call's batch and heads: the packed
+    entry's blocks and grids at the train cell's shape — two heads a
+    128-lane block, half the (B, H, S, D) backward's grid steps — and None
+    where the entry takes no such heads or is not asked."""
+    got = flash_schedule(1024, 64, True, batch=4, heads=16)
+    assert got["packed"] == {
+        "layout": "packed", "block_lanes": 128, "heads_per_block": 2,
+        "grid_fwd": (32, 1024 // got["block_q"]),
+        "grid_bwd": (32, 1024 // got["block_k"])}
+    assert flash_schedule(1024, 128, True, 2, 8)["packed"]["grid_fwd"][0] == 16
+    assert flash_schedule(1024, 256, True, 2, 4)["packed"]["block_lanes"] == 256
+    assert flash_schedule(1024, 64, True, batch=4, heads=3)["packed"] is None
+    assert flash_schedule(1024, 64, True)["packed"] is None
+
+
+def _attention_counts():
+    counters = obs.metrics.snapshot()["counters"]
+    return tuple(counters.get("attention.impl." + impl, 0)
+                 for impl in ("flash_packed", "flash", "plain"))
+
+
+@pytest.mark.parametrize("seq,masked", [(128, False), (1024, True)])
+def test_attention_layer_off_the_packed_path_is_the_layer_it_was(seq, masked):
+    """``MultiHeadAttention`` at seq 128 (plain attention by the policy) and
+    at seq 1,024 under a mask (the kernels take none): the jaxpr of the
+    lines it ran before the packed entry — reshape, split, three
+    transposes, ``fused_attention``, transpose back, ``proj`` —, no
+    ``pallas_call`` in it, the same output bit for bit, and ONE count on
+    ``attention.impl.plain`` for the traced layer."""
+    from mxnet_tpu import nd
+    from mxnet_tpu.models.transformer import MultiHeadAttention
+    from mxnet_tpu.ndarray.ndarray import invoke_fn
+    from mxnet_tpu.parallel.functional import functionalize
+
+    units, heads = 128, 2
+    layer = MultiHeadAttention(units, heads, causal=not masked,
+                               prefix="attn_")
+    layer.initialize()
+    names, apply = functionalize(layer)
+    params = {p.name: p.data()._data for p in layer._iter_params()}
+    x = _rand((1, seq, units), 0)
+    ins = (x,) + ((jnp.tril(jnp.ones((1, 1, seq, seq), bool)),) * masked)
+
+    def before(params, x, mask=None):   # the parent commit's hybrid_forward
+        def forward(x, mask=None):
+            qkv = layer.qkv(x).reshape((1, seq, 3, heads, units // heads))
+            q, k, v = (t.transpose((0, 2, 1, 3)) for t in nd.split(
+                qkv, num_outputs=3, axis=2, squeeze_axis=True))
+            out = invoke_fn(
+                lambda q, k, v, m=None: fused_attention(
+                    q, k, v, mask=m, causal=not masked),
+                [q, k, v] + ([mask] if mask is not None else []))
+            return layer.proj(out.transpose((0, 2, 1, 3)).reshape(
+                (1, seq, units)))
+        return functionalize_call(forward, params, x, mask)
+
+    def functionalize_call(forward, params, *ins):
+        layer_forward, layer.hybrid_forward = layer.hybrid_forward, (
+            lambda F, *a: forward(*a))
+        try:
+            return apply(params, *(i for i in ins if i is not None))[0]
+        finally:
+            layer.hybrid_forward = layer_forward
+
+    obs.enable()
+    try:
+        obs.reset()
+        jaxpr = jax.make_jaxpr(lambda p, *i: apply(p, *i)[0])(params, *ins)
+        assert _attention_counts() == (0, 0, 1)
+    finally:
+        obs.disable()
+    assert "pallas_call" not in str(jaxpr)
+    assert str(jaxpr) == str(jax.make_jaxpr(before)(params, *ins))
+    np.testing.assert_array_equal(np.asarray(apply(params, *ins)[0]),
+                                  np.asarray(before(params, *ins)))
+
+
+def test_attention_layer_takes_the_packed_entry_at_1024():
+    """No mask, no mesh, seq 1,024, two 64-wide heads: ONE count on
+    ``attention.impl.flash_packed``, the two kernels over the projection as
+    it lies — no transpose of heads in the layer's jaxpr — and the output
+    of the same layer forced onto plain attention."""
+    from mxnet_tpu.models.transformer import MultiHeadAttention
+    from mxnet_tpu.parallel.functional import functionalize
+
+    layer = MultiHeadAttention(128, 2, causal=True, prefix="attn_")
+    layer.initialize()
+    _, apply = functionalize(layer)
+    params = {p.name: p.data()._data for p in layer._iter_params()}
+    x = _rand((1, 1024, 128), 0)
+    obs.enable()
+    try:
+        obs.reset()
+        jaxpr = jax.make_jaxpr(lambda p, x: apply(p, x)[0])(params, x)
+        assert _attention_counts() == (1, 0, 0)
+    finally:
+        obs.disable()
+    assert "pallas_call" in str(jaxpr)
+    assert "permutation=(0, 2, 1, 3)" not in str(jaxpr)   # no head is moved
+    got = apply(params, x)[0]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("MXNET_ATTENTION_IMPL", "plain")
+        ref = apply(params, x)[0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)

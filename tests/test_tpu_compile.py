@@ -284,54 +284,153 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
-@pytest.mark.parametrize("shape,d_v,backward", [
-    ((4, 16, 1024, 64), 64, True),       # gpt2m-train-s1024, every layer
-    ((1, 64, 1536, 192), 128, False),    # longcat-omni's longest prefill
+@pytest.mark.parametrize("shape,d_v,backward,packed", [
+    ((4, 16, 1024, 64), 64, True, False),     # (B, H, S, D): the mesh path
+    ((4, 16, 1024, 64), 64, True, True),      # gpt2m-train-s1024, every layer
+    ((1, 64, 1536, 192), 128, False, False),  # longcat-omni's longest prefill
 ])
 def test_tpu_flash_kernels_lower_with_the_schedule_they_report(
-        shape, d_v, backward, one_chip, monkeypatch):
+        shape, d_v, backward, packed, one_chip, monkeypatch):
     """The flash forward (and at the train cell's shape the backward) for a
     v5e: through Mosaic, on the grid and in the blocks that
     ``flash_schedule`` reports from the shapes; the backward's three results
     in the operands' dtype (dQ gathers in a float32 VMEM scratch: none in
     HBM, no convert behind the kernel), the scratch a small part of VMEM;
-    no temporary beside the forward's (rows, 8) float32 lse."""
+    no temporary beside the forward's (rows, 8) float32 lse. ``packed``:
+    the entry that reads the fused projection (4, 1024, 3072) in 128-lane
+    column blocks of two heads — half the grid steps, every block 128 lanes
+    wide, the lse as (2, S) rows, δ made inside the backward, which writes
+    the projection's whole gradient itself: no temporary beside the rows."""
     import jax
     import jax.numpy as jnp
 
     monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
     b, h, s, d = shape
-    sched = flash_attention.flash_schedule(s, d, True)
+    sched = flash_attention.flash_schedule(s, d, True, b, h)
     heads = sched["heads_per_step"]
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((b, h, s, d_v), jnp.bfloat16, sharding=one_chip)
+    args = (q, q, v)
+    if packed:
+        args = (jax.ShapeDtypeStruct((b, s, 3 * h * d), jnp.bfloat16,
+                                     sharding=one_chip),)
+        lanes, heads = sched["packed"]["block_lanes"], \
+            sched["packed"]["heads_per_block"]
 
-    def forward(q, k, v):
-        return flash_attention.flash_attention(q, k, v, causal=True)
+    def forward(*args):
+        if packed:
+            return flash_attention.flash_attention_packed(*args, h,
+                                                          causal=True)
+        return flash_attention.flash_attention(*args, causal=True)
 
-    def loss(q, k, v):
-        return forward(q, k, v).astype(jnp.float32).sum()
+    def loss(*args):
+        return forward(*args).astype(jnp.float32).sum()
 
-    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else forward
-    calls = list(_pallas_calls(jax.make_jaxpr(fn)(q, q, v).jaxpr))
+    fn = jax.grad(loss, argnums=tuple(range(len(args)))) if backward \
+        else forward
+    calls = list(_pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr))
     assert len(calls) == (2 if backward else 1)
     grids = [call.params["grid_mapping"].grid for call in calls]
+    blocks = [[tuple(getattr(n, "block_size", 1) for n in m.block_shape)
+               for m in call.params["grid_mapping"].block_mappings]
+              for call in calls]
     assert grids[0] == (b * h // heads, s // sched["block_q"])
-    assert (heads, sched["block_q"], d) in [
-        tuple(n.block_size for n in m.block_shape)
-        for m in calls[0].params["grid_mapping"].block_mappings]
+    if packed:
+        assert grids == [sched["packed"]["grid_fwd"],
+                         sched["packed"]["grid_bwd"]]
+        assert blocks[0] == [(1, sched["block_q"], lanes), (1, s, lanes),
+                             (1, s, lanes), (1, sched["block_q"], lanes),
+                             (1, heads, sched["block_q"])]
+        assert {(1, s, lanes), (1, heads, s),
+                (1, sched["block_k"], lanes)} <= set(blocks[1])
+    else:
+        assert (heads, sched["block_q"], d) in blocks[0]
     if backward:
-        assert grids[1] == (b * h, s // sched["block_k"])   # a head a step
-        assert [str(out.aval.dtype) for out in calls[1].outvars] == \
-            ["bfloat16"] * 3
-        (scratch,) = calls[1].params["grid_mapping"].scratch_avals
-        assert (scratch.shape, str(scratch.dtype)) == ((s, d), "float32")
-        assert s * d * 4 <= 2 ** 20    # of the 16 MiB a call may use
-    lowered = jax.jit(fn).lower(q, q, v)
+        outs = [(v.aval.shape, str(v.aval.dtype)) for v in calls[1].outvars]
+        scratch = [(a.shape, str(a.dtype)) for a in
+                   calls[1].params["grid_mapping"].scratch_avals
+                   if hasattr(a, "dtype") and "sem" not in str(a.dtype)]
+        if packed:   # ONE gradient, the projection's, written by the kernel
+            assert outs == [((b, s, 3 * h * d), "bfloat16")]
+            assert scratch == [((s, lanes), "float32"),
+                               ((heads, s), "float32"),
+                               ((s, lanes), "bfloat16"),
+                               ((2, sched["block_k"], lanes), "bfloat16")]
+        else:
+            assert grids[1] == (b * h, s // sched["block_k"])  # a head a step
+            assert outs == [((b * h, s, d), "bfloat16")] * 3
+            assert scratch == [((s, d), "float32")]
+        assert s * 128 * 4 <= 2 ** 20    # of the 16 MiB a call may use
+    lowered = jax.jit(fn).lower(*args)
     assert lowered.as_text().count("tpu_custom_call") == len(calls)
     compiled = lowered.compile()
     temp = obs.device.analyze_compiled(compiled)["temp_bytes"]
-    assert temp <= b * h * s * 128 * 4 + 2 ** 20   # the lse, lanes padded
+    if packed:   # the lse's rows and its (zero) cotangent: δ is the kernel's
+        assert temp <= 2 * b * h * s * 4 + 2 ** 20
+    else:
+        assert temp <= b * h * s * 128 * 4 + 2 ** 20   # the lse, lanes padded
+
+
+def test_tpu_attention_layer_hands_the_kernels_its_own_layouts(one_chip,
+                                                               monkeypatch):
+    """One ``MultiHeadAttention`` layer's ``value_and_grad`` at the train
+    cell's (4, 1024, 1024), 16 heads, bfloat16, for a v5e: exactly two Mosaic
+    calls, their operands and results the step program's own arrays — the
+    fused projection (4, 1024, 3072), (4, 1024, 1024) for o, do, dq, dk, dv,
+    the lse as rows — and no ``copy`` or ``transpose`` of a head-major
+    (4, 16, 1024, 64), of a position-major (4, 1024, 16, 64), of the whole
+    projection or of the old (64, 1024, 8) lse anywhere in the optimised
+    module: a Mosaic call is opaque to XLA, and each such layout at its edge
+    was a copy made and waited for (8.4 ms of gpt2m-train-s1024's 90.4 ms
+    step, PERF.md §6, PR 48)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models.transformer import MultiHeadAttention
+    from mxnet_tpu.parallel.functional import functionalize
+
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    b, s, u, h = 4, 1024, 1024, 16
+    layer = MultiHeadAttention(u, h, causal=True, prefix="attn_")
+    layer.initialize()
+    _, apply = functionalize(layer)
+
+    def loss(params, x):
+        out, _ = apply({n: w.astype(jnp.bfloat16)
+                        for n, w in params.items()}, x)
+        return (x + out).astype(jnp.float32).sum()
+
+    params = {p.name: jax.ShapeDtypeStruct(p.shape, jnp.float32,
+                                           sharding=one_chip)
+              for p in layer._iter_params()}
+    x = jax.ShapeDtypeStruct((b, s, u), jnp.bfloat16, sharding=one_chip)
+    obs.enable()
+    try:
+        obs.reset()
+        lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            params, x)
+        counters = obs.metrics.snapshot()["counters"]
+    finally:
+        obs.disable()
+    assert counters.get("attention.impl.flash_packed") == 1
+    assert "attention.impl.flash" not in counters
+    text = lowered.compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2
+    # results, then the operands' shapes (operand_layout_constraints)
+    big = [set(re.findall(r"(?:bf16|f32)\[[\d,]+\]", call.split(
+        "backend_config")[0])) for call in calls]
+    assert big[0] == {"bf16[4,1024,3072]", "bf16[4,1024,1024]",
+                      "f32[32,2,1024]"}
+    assert big[1] == big[0]
+    moved = [line.strip()[:140] for line in text.splitlines()
+             if re.search(r" (copy|transpose)\(", line) and re.search(
+                 r"\[(4,16,1024,64|4,1024,16,64|4,1024,3,16,64|64,1024,64"
+                 r"|4,1024,3072|64,1024,8|64,1,1024)\]", line)]
+    assert not moved, moved
 
 
 # -- the latent pool: one bfloat16 row a position, no head axis ----------------
