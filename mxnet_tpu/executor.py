@@ -10,14 +10,13 @@ function. BatchNorm moving stats thread through as explicit aux outputs
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import obs
+from . import obs, progcache
 from .base import MXNetError
 from .context import Context, current_context
 from .ndarray import NDArray
@@ -202,15 +201,13 @@ class Executor:
         self._jit_cache: Dict = {}
         self._vjp = None
         self._last_inputs = None
-        # device-plane program accounting (obs/device.py), populated only
-        # while capture is active (zero-cost-when-off): one entry per
-        # distinct (site, input signature) compile, carrying XLA
-        # flops/bytes/HBM; the signature's AOT executable replaces the
-        # jit wrapper for execution
+        # program accounting, populated only while obs is on (zero-cost-
+        # when-off): one entry per distinct (site, input signature) built
+        # through progcache.build, carrying XLA flops/bytes/HBM; the
+        # signature's executable replaces the jit wrapper for execution
         self.compile_log: List[dict] = []
         self._seen_sigs: set = set()
         self._aot: Dict = {}
-        self._sig_cost: Dict = {}
 
     # ------------------------------------------------------------------
     @property
@@ -227,23 +224,19 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _device_account(self, site: str, jitted, call_args, sig):
-        """Device-plane bookkeeping shared by forward and backward: on a
-        signature's first sighting (and capture active) AOT-compile once —
-        cost/memory analysis into ``compile_log``, the executable into the
-        AOT cache. Returns ``(fn_to_call, is_compile)``."""
+        """Program accounting shared by forward and backward: on a
+        signature's first sighting (and obs on) build once — cost/memory
+        analysis into ``compile_log``, the executable kept for later
+        calls. Returns ``(fn_to_call, is_compile)``."""
         is_compile = sig not in self._seen_sigs
         if is_compile:
             self._seen_sigs.add(sig)
-            if obs.device.active():
-                entry = {"site": site, "train": sig[1], "avals": sig[2]}
-                compiled, cost = obs.device.capture(
-                    jitted, call_args, site="executor", label=site)
-                if compiled is not None:
-                    self._aot[sig] = compiled
-                if cost:
-                    entry.update(cost)
-                    self._sig_cost[sig] = cost
-                self.compile_log.append(entry)
+            if obs.enabled():
+                self._aot[sig], built = progcache.build(
+                    jitted, call_args,
+                    key=progcache.program_key("executor", site, sig))
+                self.compile_log.append(
+                    {"site": site, "train": sig[1], "avals": sig[2], **built})
         return self._aot.get(sig, jitted), is_compile
 
     def _get_fn(self, train: bool):
@@ -291,32 +284,18 @@ class Executor:
 
         if _profiler.counting_dispatches():
             _profiler.count_dispatch("compiled")
-        rec = obs.enabled()
-        t0 = time.monotonic() if rec else 0.0
-        # device-plane accounting only when capture is active (or produced
-        # an AOT executable earlier): the disabled hot path must not pay
-        # the per-call aval-signature build (zero-cost-when-off contract)
-        fn, sig, is_compile = jitted, None, False
-        if obs.device.active() or self._aot:
+        # program accounting only when obs is on (or produced an
+        # executable earlier): the disabled hot path must not pay the
+        # per-call aval-signature build (zero-cost-when-off contract)
+        fn, is_compile = jitted, False
+        if obs.enabled() or self._aot:
             sig = ("forward", bool(is_train), _avals_sig(arg_vals),
                    _avals_sig(aux_vals))
             fn, is_compile = self._device_account(
                 "forward", jitted, (key_data, arg_vals, aux_vals), sig)
         with obs.trace.span("device.forward", train=bool(is_train),
-                            compile=is_compile) as sp:
+                            compile=is_compile):
             outs, new_aux = fn(key_data, arg_vals, aux_vals)
-            cost = self._sig_cost.get(sig) if rec and not is_compile \
-                else None
-            if cost:
-                # block before timing: on async backends the call above
-                # returns futures, and attributing MFU to dispatch latency
-                # would be meaningless — accurate device timing costs the
-                # overlap, the same NaiveEngine-style trade the profiler's
-                # aggregate_stats makes (docs/OBSERVABILITY.md). Only paid
-                # when there IS a cost record to attribute.
-                jax.block_until_ready((outs, new_aux))
-                obs.device.annotate_span(sp, "forward",
-                                         time.monotonic() - t0, cost)
         if is_train and self._grad_req != "null":
             # backward replays the same RNG key → identical dropout masks
             self._last_inputs = (key_data, arg_vals, aux_vals, bool(is_train))
@@ -365,23 +344,15 @@ class Executor:
         if _profiler.counting_dispatches():
             _profiler.count_dispatch("compiled")
         grad_fn = self._get_grad_fn(train)
-        rec = obs.enabled()
-        t0 = time.monotonic() if rec else 0.0
-        fn, sig, is_compile = grad_fn, None, False
-        if obs.device.active() or self._aot:
+        fn, is_compile = grad_fn, False
+        if obs.enabled() or self._aot:
             sig = ("backward", bool(train), _avals_sig(arg_vals),
                    _avals_sig(cot))
             fn, is_compile = self._device_account(
                 "backward", grad_fn, (key_data, arg_vals, aux_vals, cot),
                 sig)
-        with obs.trace.span("device.backward", compile=is_compile) as sp:
+        with obs.trace.span("device.backward", compile=is_compile):
             grads = fn(key_data, arg_vals, aux_vals, cot)
-            cost = self._sig_cost.get(sig) if rec and not is_compile \
-                else None
-            if cost:
-                jax.block_until_ready(grads)  # see forward: honest MFU
-                obs.device.annotate_span(sp, "backward",
-                                         time.monotonic() - t0, cost)
         for n, g in zip(self._arg_names, grads):
             if n in self.grad_dict and g is not None:
                 if self._grad_req == "add":

@@ -499,8 +499,8 @@ class CachedOp:
     def __init__(self, block: HybridBlock):
         self.block = block
         self._cache = {}
-        # device-plane accounting (obs/device.py): one entry per compiled
-        # cache entry, carrying XLA flops/bytes/HBM when capture is active
+        # program accounting (progcache.build): one entry per compiled
+        # cache entry, carrying XLA flops/bytes/HBM, while obs is on
         self.compile_log = []
 
     def __call__(self, *inputs):
@@ -594,8 +594,9 @@ class CachedOp:
                  "out_fmt": out_fmt_holder, "n_out": None}
 
         # Wrap fn so first execution finalizes n_out/num_outputs metadata
-        # (and, when device capture is active, AOT-compiles once for cost
-        # accounting and keeps that executable for later calls).
+        # (and, with obs on, builds the program once through
+        # progcache.build for its cost and keeps that executable for later
+        # calls).
         aot = {"compiled": None, "logged": False}
 
         def finalizing_fn(*vals, **kw):
@@ -604,33 +605,32 @@ class CachedOp:
 
             if _profiler.counting_dispatches():
                 _profiler.count_dispatch("compiled")
-            # Device-plane accounting only when capture is active (or
-            # already produced an executable) — the disabled hot path must
-            # not pay the per-call scans. A nested hybridized block's
-            # CachedOp runs INSIDE its parent's trace: tracer args can't
-            # feed an AOT executable (and there is no standalone program
-            # to account), so only concrete calls capture/log and tracer
-            # calls inline through the jit wrapper.
+            # Program accounting only when obs is on (or already produced
+            # an executable) — the disabled hot path must not pay the
+            # per-call scans. A nested hybridized block's CachedOp runs
+            # INSIDE its parent's trace: tracer args can't feed a built
+            # executable (and there is no standalone program to account),
+            # so only concrete calls build/log and tracer calls inline
+            # through the jit wrapper.
             fn = jitted
             if aot["compiled"] is not None or \
-                    (not aot["logged"] and _obs.device.active()):
+                    (not aot["logged"] and _obs.enabled()):
                 concrete = not any(isinstance(v, jax.core.Tracer)
                                    for v in vals)
                 if concrete and not aot["logged"]:
+                    from .. import progcache as _progcache
+
                     aot["logged"] = True
-                    log_entry = {"block": block.name, "train": train,
-                                 "avals": tuple(
-                                     (tuple(v.shape),
-                                      str(getattr(v, "dtype", "?")))
-                                     for v in vals)}
-                    compiled, cost = _obs.device.capture(
-                        jitted, vals, site="cachedop", label=block.name,
-                        kwargs=kw)
-                    if compiled is not None:
-                        aot["compiled"] = compiled
-                    if cost:
-                        log_entry.update(cost)
-                    self.compile_log.append(log_entry)
+                    avals = tuple((tuple(v.shape),
+                                   str(getattr(v, "dtype", "?")))
+                                  for v in vals)
+                    aot["compiled"], built = _progcache.build(
+                        jitted, vals, kwargs=kw,
+                        key=_progcache.program_key(
+                            "cachedop", block.name, (train, avals)))
+                    self.compile_log.append(
+                        {"block": block.name, "train": train,
+                         "avals": avals, **built})
                 if concrete and aot["compiled"] is not None:
                     fn = aot["compiled"]
             res = fn(*vals, **kw)

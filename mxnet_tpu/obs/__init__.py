@@ -43,7 +43,7 @@ from . import tail  # tail-based trace retention (verdict at root close)
 from . import profile  # continuous sampling profiler
 from . import blackbox  # crash flight recorder
 from . import slo  # SLO monitor over merged telemetry
-from . import device  # device plane: XLA cost/memory accounting, MFU
+from . import device  # device plane: program cost registry, live memory
 from . import health  # training-health plane: numerics sentinel + rollback
 from . import fleetstats  # training-fleet plane: step attribution, stragglers
 

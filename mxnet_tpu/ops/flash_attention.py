@@ -37,7 +37,7 @@ TPU-efficiency notes (measured on v5e; PERF.md §5, §6):
   products of them, and only dQ contracts their leading dimension.
 - The schedule — blocks, sub-tiles, heads a step — comes from a per-(S, D)
   table measured by tools/tune_flash.py (:func:`flash_schedule` reports
-  it); ``MXNET_FLASH_BLOCK_Q/K`` override the blocks.
+  it).
 
 Causal masking takes a **dynamic row offset**: visibility is
 ``row + offset >= col``. offset=0 is standard causal; ring attention
@@ -651,15 +651,8 @@ def _pick_block(s, target):
 
 
 def _resolve_blocks(s, d, block_q, block_k, table=None):
-    # precedence: explicit argument > env override > tuned table (or the
-    # caller's own ``table`` entry). Env must not clobber explicit args or
-    # a sweep would turn one env-pinned size into a bogus uniform table.
-    if block_q is None:
-        env_q = os.environ.get("MXNET_FLASH_BLOCK_Q")
-        block_q = int(env_q) if env_q else None
-    if block_k is None:
-        env_k = os.environ.get("MXNET_FLASH_BLOCK_K")
-        block_k = int(env_k) if env_k else None
+    # explicit argument, else the tuned table (or the caller's own
+    # ``table`` entry)
     if block_q is None or block_k is None:
         table = table or _BLOCK_TABLE.get((s, d), _DEFAULT_SCHEDULE)
         block_q = block_q if block_q is not None else table.block_q

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import obs
+from .. import obs, progcache
 from ..ndarray import NDArray
 from ..ops import get_op
 
@@ -387,7 +387,6 @@ class FusedUpdateEngine:
         self._donate = _donate_default() if donate is None else bool(donate)
         self.exec_count = 0
         self.compile_log: List[dict] = []
-        self._costs: Dict = {}  # cache key -> device cost record
         # training-health plane (obs/health.py): when active, the step
         # program also emits device-resident numerics stats; both stay
         # device-side (zero syncs) until a sampled step batch-fetches them
@@ -472,7 +471,6 @@ class FusedUpdateEngine:
                tuple(self._aval(x) for x in gs),
                tuple(tuple(self._aval(x) for x in lp) for lp in state_leaves),
                scaler_on, factor, window, cgn_on, health_on, self._donate)
-        _device = obs.device
 
         rec = obs.enabled()
         t0 = time.monotonic() if rec else 0.0
@@ -498,25 +496,13 @@ class FusedUpdateEngine:
             profiler.count_dispatch("compiled")
             profiler.count_dispatch("h2d")  # the packed lr/wd/t hyper vectors
         with obs.trace.span("update.fused", optimizer=type(opt).__name__,
-                            n_params=n, compile=is_compile) as sp:
+                            n_params=n, compile=is_compile):
             new_ws, new_flat, new_ex, scaler_out, health_out = jitted(
                 ws, gs, state_leaves, lrs, wds, ts, rescale, scale, unskipped,
                 streak_in, cgn_val, extras)
-            cost = self._costs.get(key) if rec and not is_compile else None
-            if cost:
-                # analytic MFU + roofline on the executed program's span
-                # (compile calls excluded: their wall time is the
-                # compiler). Block first: on async backends the dispatch
-                # returns futures and MFU over dispatch latency would be
-                # meaningless — accurate attribution costs the overlap,
-                # the profiler aggregate_stats trade
-                jax.block_until_ready(new_ws)
-                _device.annotate_span(sp, "update", time.monotonic() - t0,
-                                      cost)
         if rec:
             # first call traces+compiles (blocking); later calls dispatch —
-            # wall time only, UNLESS a cost record made the attribution
-            # block above (then this is honest device time)
+            # host wall time only
             obs.observe("update.compile_seconds" if is_compile
                         else "update.execute_seconds",
                         time.monotonic() - t0)
@@ -559,19 +545,16 @@ class FusedUpdateEngine:
         derivation (``progcache.program_key``), so the device-plane cost
         registry, this engine's ``compile_log``, and the persistent cache
         agree on the program's identity byte for byte."""
-        from .. import progcache as _progcache
-
-        return _progcache.program_key("update", type(self.optimizer).__name__,
-                                      key)
+        return progcache.program_key("update", type(self.optimizer).__name__,
+                                     key)
 
     def _compile(self, key, example):
         """Resolve one cache-key miss to an executable + its compile_log
-        entry: persistent-cache hit (deserialize the stored executable —
-        zero fresh XLA work) > AOT compile with device-cost capture >
-        plain ``jax.jit``. A corrupt/stale/foreign entry was already
-        counted as a reject by the cache and lands here as a miss."""
-        from .. import progcache as _progcache
-
+        entry. With the persistent cache armed or ``obs`` on the step goes
+        through ``progcache.build`` (a hit deserializes the stored
+        executable — zero fresh XLA work; a corrupt/stale/foreign entry was
+        already counted as a reject by the cache and lands as a miss);
+        otherwise it stays the plain ``jax.jit`` wrapper."""
         opt = self.optimizer
         (_, _, specs, mp, _, _, _, scaler_on, factor, window, cgn_on,
          health_on, _) = key
@@ -581,42 +564,18 @@ class FusedUpdateEngine:
             "avals": key[4],
             "state_structure": specs,
             "flags": (scaler_on, cgn_on, health_on),
+            "cache_hit": False,
         }
-        _device = obs.device
-        pc = _progcache.cache()
-        pk = None
-        if pc is not None:
-            pk = self._program_key(key)
-            entry["program_key"] = pk.digest
-            cached = pc.get(pk)
-            if cached is not None:
-                entry["cache_hit"] = True
-                cost = _device.adopt_cached_cost(pk, cached.meta)
-                if cost:
-                    entry.update(cost)
-                    self._costs[key] = cost
-                return cached.executable, entry
-        entry["cache_hit"] = False
         jitted = self._build(specs, mp, scaler_on, factor, window, cgn_on,
                              health_on)
-        compiled = cost = None
-        if _device.active():
-            # ONE compile serves accounting and execution: the AOT
-            # executable replaces the jit wrapper in the cache, and its
-            # XLA cost/memory analyses land in this compile_log entry
-            compiled, cost = _device.capture(jitted, example, site="update",
-                                             label=type(opt).__name__,
-                                             key=pk)
-        elif pc is not None:  # cache armed, cost capture vetoed: plain AOT
-            compiled = _progcache.aot_compile(jitted, example)
-            cost = _device.analyze_compiled(compiled)
-        if compiled is not None:
-            if pc is not None:
-                pc.put(pk, compiled, meta=dict(cost or {}))
-            jitted = compiled
-        if cost:
-            entry.update(cost)
-            self._costs[key] = cost
+        pc = progcache.cache()
+        if pc is not None or obs.enabled():
+            # ONE compile serves accounting and execution: the executable
+            # replaces the jit wrapper in the cache, and its XLA cost and
+            # memory analyses land in this compile_log entry
+            jitted, built = progcache.build(
+                jitted, example, key=self._program_key(key), cache=pc)
+            entry.update(built)
         return jitted, entry
 
     def prewarm(self, indices, weights, grads, states, loss_scaler=None,

@@ -97,12 +97,6 @@ class ShardedTrainer:
             raise ValueError(f"grad_accum must be >= 1, got {grad_accum}")
 
         self._t = 0
-        # XLA cost/memory record of the compiled step (obs/device.py),
-        # filled at first compile when device capture is active — the
-        # analytic-MFU numerator bench.py reports beside measured MFU;
-        # _aot_step holds (batch avals, AOT executable) for that signature
-        self.step_cost: Optional[Dict] = None
-        self._aot_step = None
         self._in_sh = batch_sharding(mesh, input_specs if isinstance(input_specs, P)
                                      else P(*input_specs))
         self._label_sh = batch_sharding(mesh, label_specs if isinstance(label_specs, P)
@@ -274,10 +268,6 @@ class ShardedTrainer:
         out_shardings = (NamedSharding(self.mesh, P()), self._param_shardings,
                          in_shardings[1])
         donate = (0, 1) if self._donate else ()
-        # kept for profiling harnesses (tools/profile_lm_step.py): the
-        # un-jitted step can be lax.scan-chained so many steps cost one
-        # dispatch
-        self._raw_step_fn = step_fn
         return jax.jit(step_fn, in_shardings=in_shardings,
                        out_shardings=out_shardings, donate_argnums=donate)
 
@@ -304,33 +294,9 @@ class ShardedTrainer:
 
         if self._step_fn is None:
             self._step_fn = self._build(len(vals) - 1)
-            from ..obs import device as _device
-
-            if _device.active():
-                # device-plane accounting (obs/device.py): AOT-compile the
-                # step ONCE inside the mesh scope — XLA flops/bytes/HBM into
-                # step_cost (bench.py's analytic-MFU source), the same
-                # executable kept for matching batches. Keyed by the batch
-                # avals: an AOT Compiled cannot retrace, so a later ragged
-                # batch must fall back to the jit wrapper, not crash
-                sig = tuple((tuple(v.shape), str(v.dtype)) for v in vals)
-                with mesh_scope(self.mesh):
-                    compiled, cost = _device.capture(
-                        self._step_fn,
-                        (self.param_vals, self.opt_state,
-                         jnp.float32(self._lr), jnp.float32(self._t + 1),
-                         *vals),
-                        site="train_step", label=type(self.net).__name__)
-                if compiled is not None:
-                    self._aot_step = (sig, compiled)
-                self.step_cost = cost
         self._t += 1
-        step = self._step_fn
-        if self._aot_step is not None and self._aot_step[0] == tuple(
-                (tuple(v.shape), str(v.dtype)) for v in vals):
-            step = self._aot_step[1]
         with mesh_scope(self.mesh):  # attention layers pick sp/ring impls
-            loss, self.param_vals, self.opt_state = step(
+            loss, self.param_vals, self.opt_state = self._step_fn(
                 self.param_vals, self.opt_state, jnp.float32(self._lr),
                 jnp.float32(self._t), *vals)
         return NDArray(loss)

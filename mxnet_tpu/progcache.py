@@ -6,16 +6,21 @@ not: every new serve replica pays a full XLA compilation per shape bucket
 at ``warmup()``, and the fused update engine recompiles its one-program
 step at every train start — the single biggest obstacle to spawning
 replicas on demand (serve/autoscale.py) and to fast elastic rejoin
-(kvstore/elastic.py). PR 8 already AOT-compiles every choke-point program
-once (``jit.lower().compile()``) and keys it in the device-plane
-(site,label) cost registry; this module turns that *identity* store into a
-*persistent cross-process* cache:
+(kvstore/elastic.py). This module is where a compiled program comes to
+exist, and where it is kept across processes:
 
-- **One key derivation** (:func:`program_key`): ``serve/engine.py``,
-  ``optimizer/fused.py``, and the ``obs/device.py`` registry all derive
-  their program identity through this one function — a
-  :class:`ProgramKey` carries the (site, label) the device registry files
-  under plus a canonical SHA-256 ``digest`` over the program's statics
+- **One build path** (:func:`build`): look in the cache, else lower and
+  compile ahead of time, read the compiler's cost and memory analysis
+  (:func:`analyze_compiled`, :data:`COST_FIELDS`), put the executable in
+  the cache, and hand back the executable with the part of a
+  ``compile_log`` entry that is the same at every site. ``serve/decode.py``,
+  ``serve/engine.py``, ``optimizer/fused.py``, ``executor.py`` and
+  ``gluon/block.py`` all build through it; nothing else in the package
+  compiles a lowering.
+- **One key derivation** (:func:`program_key`): every site derives its
+  program identity through this one function — a :class:`ProgramKey`
+  carries the (site, label) the ``obs/device.py`` registry files under
+  plus a canonical SHA-256 ``digest`` over the program's statics
   (graph/optimizer fingerprint, avals, toggles). The same digest lands in
   ``compile_log`` entries, device cost records, and cache filenames, so
   the three surfaces can never key the same program differently.
@@ -62,8 +67,8 @@ from .checkpoint.atomic import atomic_write_bytes, crc32_bytes
 
 __all__ = ["ProgramKey", "ProgramCache", "CacheEntry", "program_key",
            "env_fingerprint", "code_fingerprint", "active", "cache",
-           "configure", "aot_compile", "serialize_compiled", "default_dir",
-           "reset"]
+           "configure", "build", "analyze_compiled", "COST_FIELDS",
+           "serialize_compiled", "default_dir", "reset"]
 
 # entry format version — bump on any layout/semantic change so old caches
 # read as structured rejects, not parse errors
@@ -211,14 +216,6 @@ def env_fingerprint() -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 # serialization helpers
 # ---------------------------------------------------------------------------
-
-def aot_compile(jitted, args: tuple, kwargs: Optional[dict] = None):
-    """``jitted.lower(*args).compile()`` — the capture-free AOT path for
-    when the persistent cache is on but device-cost capture is vetoed (the
-    two switches stay independent). A compile error is the caller's to
-    see: the jit path it would fall back to runs the same compiler."""
-    return jitted.lower(*args, **(kwargs or {})).compile()
-
 
 def _program_devices(compiled) -> list:
     """The devices a ``jax.stages.Compiled`` was compiled for, in the
@@ -463,6 +460,104 @@ class ProgramCache:
                        if e.endswith(".mxprog"))
         except OSError:
             return 0
+
+
+# ---------------------------------------------------------------------------
+# the build path — THE one place a compiled program comes to exist
+# ---------------------------------------------------------------------------
+
+# what a compiled program says of itself; the field order is the
+# compile_log/report schema, keep stable
+COST_FIELDS = ("flops", "bytes_accessed", "argument_bytes", "output_bytes",
+               "temp_bytes", "generated_code_bytes", "alias_bytes",
+               "peak_hbm_bytes")
+
+
+def analyze_compiled(compiled) -> dict:
+    """Extract the cost/memory record from a ``jax.stages.Compiled``.
+    Missing analyses (backend-dependent) just leave fields at 0 — the
+    record is always structurally complete."""
+    cost: dict = {k: 0 for k in COST_FIELDS}
+    try:
+        ca = compiled.cost_analysis()
+        if ca:
+            cost["flops"] = int(ca.get("flops", 0) or 0)
+            cost["bytes_accessed"] = int(ca.get("bytes accessed", 0) or 0)
+    except Exception:  # lint-ok: cost analysis is best-effort by contract
+        pass
+    try:
+        ma = compiled.memory_analysis()
+        if ma is not None:
+            arg = int(getattr(ma, "argument_size_in_bytes", 0))
+            out = int(getattr(ma, "output_size_in_bytes", 0))
+            tmp = int(getattr(ma, "temp_size_in_bytes", 0))
+            code = int(getattr(ma, "generated_code_size_in_bytes", 0))
+            alias = int(getattr(ma, "alias_size_in_bytes", 0))
+            cost.update(argument_bytes=arg, output_bytes=out, temp_bytes=tmp,
+                        generated_code_bytes=code, alias_bytes=alias,
+                        # donated buffers alias an argument into an output;
+                        # counting both would double the footprint
+                        peak_hbm_bytes=max(arg + out + tmp + code - alias, 0))
+    except Exception:  # lint-ok: memory analysis is best-effort by contract
+        pass
+    return cost
+
+
+def build(jitted, args: tuple, *, key: Optional[ProgramKey] = None,
+          cache: Optional["ProgramCache"] = None,
+          meta: Optional[dict] = None, kwargs: Optional[dict] = None):
+    """Build ``jitted`` (a ``jax.jit`` wrapper) for the example ``args``
+    ahead of time: ``(executable, entry)``.
+
+    With a ``cache`` and a ``key``, ``cache.get(key)`` first: on a hit the
+    executable is the deserialized one (the same machine code an earlier
+    process compiled) and its cost the :data:`COST_FIELDS` the writer left
+    in the entry's metadata. Otherwise ``jitted`` is lowered and compiled,
+    the cost read from the compiled program (:func:`analyze_compiled`),
+    and the executable put in the cache with ``{**cost, **meta}``. The
+    caller keeps ``executable`` for this signature: ONE compile is measured
+    and run.
+
+    ``entry`` is the part of a ``compile_log`` line that is the same at
+    every site — ``cache_hit``, ``program_key`` (the digest, when keyed)
+    and the cost fields; a site adds its own keys and appends. With ``obs``
+    on, the cost is also filed under the key's (site, label) through
+    ``obs.device.record`` (the ``device.compile`` event and gauges).
+
+    Errors have two meanings, both kept here. A compile error is the
+    caller's to see: where the program must exist ahead of time
+    (``DecodeEngine``, any site with a cache armed) there is nothing to
+    fall back to, and where the build is only for the record the jit
+    wrapper would run the same compiler. A failure to *lower* (an exotic
+    backend, a lowering restriction) leaves the caller on its jit wrapper:
+    ``jitted`` itself comes back, with an entry that has no cost.
+    """
+    entry: Dict[str, Any] = {"cache_hit": False}
+    keyed = cache is not None and key is not None
+    if key is not None:
+        entry["program_key"] = key.digest
+    cached = cache.get(key) if keyed else None
+    if cached is not None:
+        entry["cache_hit"] = True
+        executable = cached.executable
+        cost = {k: cached.meta[k] for k in COST_FIELDS if k in cached.meta}
+    else:
+        try:
+            lowered = jitted.lower(*args, **(kwargs or {}))
+        except Exception:  # lint-ok: the jit wrapper decides, never raise
+            return jitted, entry
+        executable = lowered.compile()
+        cost = analyze_compiled(executable)
+        if keyed:
+            cache.put(key, executable, meta={**cost, **(meta or {})})
+    entry.update(cost)
+    if cost and key is not None:
+        from . import obs
+
+        if obs.enabled():
+            obs.device.record(key.site, key.label,
+                              dict(cost, program_key=key.digest))
+    return executable, entry
 
 
 # ---------------------------------------------------------------------------

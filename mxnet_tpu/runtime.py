@@ -109,10 +109,6 @@ _ENV_REGISTRY = {
     "MXNET_NP_SILENT_FALLBACK": (None, "1 = silence the once-per-name "
                                  "warning when mxnet_tpu.numpy delegates "
                                  "an op to real numpy (host round-trip)."),
-    "MXNET_FLASH_BLOCK_Q": (None, "Flash-attention Q block-length "
-                            "override (default: tuned per backend)."),
-    "MXNET_FLASH_BLOCK_K": (None, "Flash-attention K block-length "
-                            "override."),
     "MXNET_FLASH_BWD": ("auto", "auto|flash|plain — flash-attention "
                         "backward-pass implementation."),
     "MXNET_FUSED_UPDATE": ("1", "0 = bypass the fused optimizer-update "
@@ -165,14 +161,6 @@ _ENV_REGISTRY = {
                                   "backend does ('*' = all; "
                                   "chaos/platform.py)."),
     # device-plane observability (obs/device.py, docs/OBSERVABILITY.md)
-    "MXNET_DEVICE_COST": (None, "1 = force XLA cost/memory capture at every "
-                          "compile choke point (0 = veto); default follows "
-                          "the obs telemetry flag."),
-    "MXNET_DEVICE_PEAK_TFLOPS": (None, "Peak compute rate used by analytic "
-                                 "MFU/roofline math (overrides the "
-                                 "per-backend nominal default)."),
-    "MXNET_DEVICE_PEAK_GBPS": (None, "Peak memory bandwidth for the "
-                               "roofline balance point."),
     "MXNET_OBS_MEMORY": ("1", "0 = skip the per-batch device.live_bytes "
                          "sampling even with telemetry on."),
     "MXNET_DEVICE_LEAK_WINDOW": ("10", "Leak-detector sliding window "

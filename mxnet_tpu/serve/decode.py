@@ -61,9 +61,10 @@ lays out again any part of it, and on a TPU it rests in the layout the
 Mosaic kernel reads (``_row_major``). ``stats()["step_program"]`` is the
 compiler's account of that (``temp_bytes``, ``bytes_accessed``).
 
-Program accounting mirrors InferenceEngine exactly: ``compile_log``
-entries, progcache get/put so a scaled-out replica deserializes instead
-of compiling (``decode.cache_hit`` vs ``decode.compile``), and
+Program accounting mirrors InferenceEngine: every program is built by
+``progcache.build`` (its ``compile_log`` entry, cache get/put so a
+scaled-out replica deserializes instead of compiling —
+``decode.cache_hit`` vs ``decode.compile``), and
 analysis/trace.py::check_decode_engine proves the
 ``len(prompt_buckets) + 1`` program bound.
 
@@ -91,7 +92,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from .. import copytrack, obs, tsan
+from .. import copytrack, obs, progcache, tsan
 from ..obs import context as obs_context
 from ..obs._env import env_float, env_int
 from .engine import DeadlineExceeded, Draining, RequestRejected, ServeError
@@ -325,16 +326,13 @@ class DecodeEngine:
         # and the coldstart idiom read both the same way
         self._programs: Dict[tuple, int] = {}
         self._aot: Dict[tuple, object] = {}
-        self._sig_key: Dict[tuple, object] = {}
         self.compile_log: List[dict] = []
         self.cache_hits = 0
         self.exec_count = 0
         self._stat_lock = tsan.lock("serve.decode.stats")
 
-        from .. import progcache as _progcache
-
-        self._progcache = (_progcache.ProgramCache(progcache_dir)
-                           if progcache_dir else _progcache.cache())
+        self._progcache = (progcache.ProgramCache(progcache_dir)
+                           if progcache_dir else progcache.cache())
         self._key_statics = (
             type(self.model).__name__, tuple(sorted(self.cfg.items())),
             self.slots, self.page_size, self.num_pages, self.max_pages,
@@ -522,19 +520,9 @@ class DecodeEngine:
 
     # -- program accounting (the engine.py compile path, decode-keyed) --
 
-    def _program_key(self, sig, label: str):
-        pk = self._sig_key.get(sig)
-        if pk is None:
-            from .. import progcache as _progcache
-
-            pk = _progcache.program_key("decode", label,
-                                        (self._key_statics, sig))
-            self._sig_key[sig] = pk
-        return pk
-
     def _launch(self, kind: str, label: str, jitted, packed) -> tuple:
-        """Queue one program call with full accounting: compile_log entry +
-        progcache get/put on a fresh signature, ``decode.*`` metrics, and
+        """Queue one program call with full accounting: a fresh signature
+        built through ``progcache.build``, ``decode.*`` metrics, and
         the swap of the pool and the last-token vector for the call's
         (not yet computed) results. Returns what :meth:`read` takes."""
         sig = (kind, (tuple(packed.shape), str(packed.dtype)))
@@ -542,45 +530,21 @@ class DecodeEngine:
         cache_hit = False
         call_args = (self._params, self.kv, self.state, self.last, packed)
         if is_compile:
-            entry = {"sig": sig, "kind": kind, "label": label,
-                     "param_avals": self._param_avals}
-            pc = self._progcache
-            pk = None
-            if pc is not None:
-                pk = self._program_key(sig, label)
-                entry["program_key"] = pk.digest
-                cached = pc.get(pk)
-                if cached is not None:
-                    cache_hit = True
-                    self._aot[sig] = cached.executable
-                    cost = obs.device.adopt_cached_cost(pk, cached.meta)
-                    if cost:
-                        entry.update(cost)
-            entry["cache_hit"] = cache_hit
-            if not cache_hit:
-                # always through the AOT path: the one compile is measured
-                # (stats()["step_program"]) and run, observed or not
-                if obs.device.active():
-                    compiled, cost = obs.device.capture(
-                        jitted, call_args, site="decode", label=label,
-                        key=pk)
-                else:
-                    from .. import progcache as _progcache
-
-                    compiled = _progcache.aot_compile(jitted, call_args)
-                    cost = obs.device.analyze_compiled(compiled)
-                if compiled is not None:
-                    self._aot[sig] = compiled
-                    if pc is not None:
-                        pc.put(pk, compiled,
-                               meta=dict(cost or {}, kind=kind))
-                if cost:
-                    entry.update(cost)
-            self.compile_log.append(entry)
+            # always ahead of time: the one compile is measured
+            # (stats()["step_program"]) and run, observed or not
+            self._aot[sig], built = progcache.build(
+                jitted, call_args, cache=self._progcache,
+                key=progcache.program_key("decode", label,
+                                          (self._key_statics, sig)),
+                meta={"kind": kind})
+            cache_hit = built["cache_hit"]
+            self.compile_log.append({
+                "sig": sig, "kind": kind, "label": label,
+                "param_avals": self._param_avals, **built})
             if cache_hit:
                 with self._stat_lock:
                     self.cache_hits += 1
-        fn = self._aot.get(sig, jitted)
+        fn = self._aot[sig]
         with obs.trace.span("decode.execute", kind=kind, label=label,
                             compile=is_compile, cache_hit=cache_hit):
             # the one upload and the launch: returns before the device is

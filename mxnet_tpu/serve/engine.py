@@ -39,7 +39,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .. import copytrack, obs
+from .. import copytrack, obs, progcache
 from ..base import MXNetError
 
 __all__ = ["InferenceEngine", "ServeError", "RequestRejected",
@@ -336,11 +336,9 @@ class InferenceEngine:
         self.compile_log: List[dict] = []
         self._free_cache: Dict[tuple, tuple] = {}
         self.exec_count = 0
-        # device-plane accounting (obs/device.py): when capture is active a
-        # signature's program is AOT-compiled ONCE — the same executable is
+        # what progcache.build made of a signature: ONE executable,
         # analyzed (flops/bytes/HBM into compile_log) and then executed
         self._aot: Dict[tuple, object] = {}      # sig -> compiled executable
-        self._sig_cost: Dict[tuple, dict] = {}   # sig -> cost record
 
         # persistent AOT program cache (mxnet_tpu/progcache.py): explicit
         # dir (an artifact's programs/ payload) beats the process-global
@@ -348,15 +346,10 @@ class InferenceEngine:
         # traced program short of the batch signature — the graph itself,
         # argument layout, pad value, and mesh placement; progcache adds
         # the platform/topology/version fingerprint per entry.
-        from .. import progcache as _progcache
-
-        self._progcache = (_progcache.ProgramCache(progcache_dir)
-                           if progcache_dir else _progcache.cache())
-        self._sig_key: Dict[tuple, object] = {}   # sig -> ProgramKey
-        self._key_statics = None
+        self._progcache = (progcache.ProgramCache(progcache_dir)
+                           if progcache_dir else progcache.cache())
+        self._key_statics = self._compute_key_statics()
         self.cache_hits = 0
-        if self._progcache is not None:
-            self._key_statics = self._compute_key_statics()
 
     # ------------------------------------------------------------------
     # properties / stats
@@ -406,14 +399,8 @@ class InferenceEngine:
         """One :class:`~mxnet_tpu.progcache.ProgramKey` per signature —
         the SAME derivation the device-plane cost registry and the
         persistent cache file names use (progcache.program_key)."""
-        pk = self._sig_key.get(sig)
-        if pk is None:
-            from .. import progcache as _progcache
-
-            pk = _progcache.program_key("serve", f"bucket{bucket}",
-                                        (self._key_statics, sig))
-            self._sig_key[sig] = pk
-        return pk
+        return progcache.program_key("serve", f"bucket{bucket}",
+                                     (self._key_statics, sig))
 
     def stats(self) -> dict:
         staged = self._staged
@@ -586,52 +573,24 @@ class InferenceEngine:
                 "sig": sig, "bucket": bucket,
                 "param_avals": self._param_avals,
                 "version_at_compile": snapshot.version,
+                "cache_hit": False,
             }
             pc = self._progcache
-            pk = None
-            if pc is not None:
-                # persistent cache first: a hit deserializes the SAME
-                # machine code an earlier process compiled — zero fresh
-                # XLA work, bitwise-identical outputs
-                pk = self._program_key(sig, bucket)
-                entry["program_key"] = pk.digest
-                cached = pc.get(pk)
-                if cached is not None:
-                    cache_hit = True
-                    self._aot[sig] = cached.executable
-                    cost = obs.device.adopt_cached_cost(pk, cached.meta)
-                    if cost:
-                        entry.update(cost)
-                        self._sig_cost[sig] = cost
-            entry["cache_hit"] = cache_hit
-            if not cache_hit and (obs.device.active() or pc is not None):
-                # one AOT compile per signature: cost/memory analysis into
-                # the compile_log entry, the executable into the sig cache
-                # (params stay traced arguments — reload still swaps arrays
-                # without touching the program)
+            if pc is not None or rec:
+                # one build per signature (a cache hit deserializes the
+                # SAME machine code an earlier process compiled): cost and
+                # memory analysis into the compile_log entry, the
+                # executable into the sig cache (params stay traced
+                # arguments — reload still swaps arrays without touching
+                # the program)
                 with self._mesh_ctx():
-                    if obs.device.active():
-                        compiled, cost = obs.device.capture(
-                            self._jitted,
-                            (self._rng_data, arg_vals,
-                             list(snapshot.aux_vals)),
-                            site="serve", label=f"bucket{bucket}", key=pk)
-                    else:  # cache armed, cost capture vetoed: plain AOT
-                        from .. import progcache as _progcache
-
-                        compiled = _progcache.aot_compile(
-                            self._jitted,
-                            (self._rng_data, arg_vals,
-                             list(snapshot.aux_vals)))
-                        cost = obs.device.analyze_compiled(compiled)
-                if compiled is not None:
-                    self._aot[sig] = compiled
-                    if pc is not None:
-                        pc.put(pk, compiled,
-                               meta=dict(cost or {}, bucket=bucket))
-                if cost:
-                    entry.update(cost)
-                    self._sig_cost[sig] = cost
+                    self._aot[sig], built = progcache.build(
+                        self._jitted,
+                        (self._rng_data, arg_vals, list(snapshot.aux_vals)),
+                        key=self._program_key(sig, bucket), cache=pc,
+                        meta={"bucket": bucket})
+                entry.update(built)
+                cache_hit = built["cache_hit"]
             self.compile_log.append(entry)
             if cache_hit:
                 with self._stat_lock:
@@ -639,21 +598,10 @@ class InferenceEngine:
         fn = self._aot.get(sig, self._jitted)
         with obs.trace.span("serve.execute", bucket=bucket, rows=n_valid,
                             compile=is_compile, cache_hit=cache_hit,
-                            version=snapshot.version) as sp:
+                            version=snapshot.version):
             with self._mesh_ctx():
                 outs, _new_aux = fn(self._rng_data, arg_vals,
                                     list(snapshot.aux_vals))
-            cost = self._sig_cost.get(sig) if rec and not is_compile \
-                else None
-            if cost:
-                # MFU over device work only (block, no D2H yet) so the
-                # serve phase is comparable with forward/backward/update;
-                # the span itself still covers the host materialization
-                # (intentional sync: sampled timing boundary, not a stall)
-                copytrack.TRACKER.host_sync("serve.engine.block_until_ready")
-                jax.block_until_ready(outs)  # lint: disable=host-sync-on-hot-path
-                obs.device.annotate_span(sp, "serve.execute",
-                                         time.monotonic() - t0, cost)
             # materialize on host: the wire sends numpy, and an unwaited
             # future would let the execute span under-report real latency
             # (intentional sync: THE accounted d2h hop — copytrack counts
@@ -745,35 +693,32 @@ class InferenceEngine:
         """Export this engine's compiled executables into ``directory`` as
         a persistent program-cache payload (the artifact ``programs/``
         convention ``serve.load`` auto-discovers — ``serve.ship_programs``
-        wraps this with descriptor bookkeeping). Signatures compiled
-        through the plain jit path (no cache/capture active) are
-        AOT-recompiled from their recorded signature so every warmed
-        bucket ships. Returns the number of entries written."""
-        from .. import progcache as _progcache
-
-        if self._key_statics is None:
-            self._key_statics = self._compute_key_statics()
-        pc = _progcache.ProgramCache(directory, keep=keep or 0,
-                                     durable=durable)
+        wraps this with descriptor bookkeeping). Signatures that ran on
+        the plain jit path (no cache, ``obs`` off) are built from their
+        recorded signature so every warmed bucket ships. Returns the
+        number of entries written."""
+        pc = progcache.ProgramCache(directory, keep=keep or 0,
+                                    durable=durable)
         snapshot = self._params
-        written = 0
+        logged = {e["sig"]: e for e in self.compile_log}
         for sig in list(self._programs):
             bucket = int(sig[0][0][0])
+            pk = self._program_key(sig, bucket)
             compiled = self._aot.get(sig)
             if compiled is None:
-                # same trace scope as infer's compile sites: model code
-                # (ring attention etc.) discovers the mesh slice at trace
-                # time — an unscoped retrace would ship (and install) the
+                # same trace scope as infer's build: model code (ring
+                # attention etc.) discovers the mesh slice at trace time —
+                # an unscoped retrace would ship (and install) the
                 # non-mesh variant of the program
                 with self._mesh_ctx():
-                    compiled = _progcache.aot_compile(
-                        self._jitted, self._args_for_sig(sig, snapshot))
-                self._aot[sig] = compiled
-            pk = self._program_key(sig, bucket)
-            meta = dict(self._sig_cost.get(sig) or {}, bucket=bucket)
-            if pc.put(pk, compiled, meta=meta):
-                written += 1
-        return written
+                    self._aot[sig], _ = progcache.build(
+                        self._jitted, self._args_for_sig(sig, snapshot),
+                        key=pk, cache=pc, meta={"bucket": bucket})
+            else:
+                cost = {k: logged[sig][k] for k in progcache.COST_FIELDS
+                        if k in logged[sig]}
+                pc.put(pk, compiled, meta=dict(cost, bucket=bucket))
+        return pc.stats["write"]
 
     def _args_for_sig(self, sig, snapshot) -> tuple:
         """Rebuild example program arguments from a recorded signature

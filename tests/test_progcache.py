@@ -15,9 +15,14 @@ docs/PERFORMANCE.md "Program cache and cold start").
 - elastic-rejoin prewarm: a checkpoint-derived ``prewarm_batch`` derives
   the SAME key a real fit's engine uses (hit, not a wasted compile);
 - keep-last-N GC;
+- the one build path: ``progcache.build`` with no cache, on a miss, on a
+  hit and on a hit whose writer left no cost; every site's ``compile_log``
+  entry carries its common keys; and no other module of the package
+  compiles a lowering or names the deleted second MFU;
 - the chaos leg (slow): a ProcReplica SIGKILLed and respawned against the
   same cache dir becomes ready with zero fresh XLA compiles.
 """
+import ast
 import os
 import time
 
@@ -207,6 +212,194 @@ def test_gc_keep_last_n(tmp_path):
     # the most recently used survives, the oldest is gone
     assert cache.get(keys[-1]) is not None
     assert cache.get(keys[0]) is None
+
+
+# ---------------------------------------------------------------------------
+# build — the one place a compiled program comes to exist
+# ---------------------------------------------------------------------------
+
+_COMMON = {"cache_hit", "program_key"} | set(progcache.COST_FIELDS)
+
+
+_BUILD_CASES = ["no_cache", "miss", "hit", "hit_without_cost"]
+
+
+@pytest.mark.parametrize("case", _BUILD_CASES)
+def test_build(tmp_path, case):
+    import jax
+    import jax.numpy as jnp
+
+    # a program of its own a case: XLA:CPU dedupes identical kernels
+    # process-wide, and a twin's export is refused (ProgramCache.put)
+    scale = 2.0 + _BUILD_CASES.index(case)
+    fn = jax.jit(lambda x: (x * scale + 1.0).sum())
+    x = jnp.arange(6.0).reshape(2, 3)
+    key = progcache.program_key("test", case, (case, x.shape))
+    cache = None if case == "no_cache" \
+        else progcache.ProgramCache(str(tmp_path))
+    hit = case.startswith("hit")
+    if hit:  # what an earlier process left: with its cost, or without
+        compiled = fn.lower(x).compile()
+        cost = {} if case == "hit_without_cost" \
+            else progcache.analyze_compiled(compiled)
+        assert cache.put(key, compiled, meta=dict(cost, kind="t"))
+    writes = cache.stats["write"] if cache else 0
+
+    exe, entry = progcache.build(fn, (x,), key=key, cache=cache,
+                                 meta={"kind": "t"})
+    assert float(exe(x)) == float(fn(x)) == 15.0 * scale + 6.0
+    assert entry["cache_hit"] is hit
+    assert entry["program_key"] == key.digest
+    if case == "hit_without_cost":
+        assert set(entry) == {"cache_hit", "program_key"}
+    else:
+        assert set(entry) == _COMMON
+        assert entry["flops"] > 0 and entry["bytes_accessed"] > 0
+    if cache is not None:
+        # one put on a miss, none on a hit
+        assert cache.stats["write"] - writes == (case == "miss")
+        assert cache.stats["hit"] == hit
+        if case == "miss":
+            meta = cache.get(key).meta
+            assert meta["kind"] == "t" and meta["flops"] == entry["flops"]
+    # an unkeyed build is the same program with nothing to name it by
+    _, bare = progcache.build(fn, (x,))
+    assert set(bare) == _COMMON - {"program_key"}
+
+
+def test_build_leaves_the_caller_on_its_jit_wrapper_if_lowering_fails():
+    """The one guard: a failure to lower hands ``jitted`` itself back with
+    an entry that has no cost; a compile error is the caller's to see."""
+    import jax
+
+    class Unlowerable:
+        def lower(self, *a, **k):
+            raise NotImplementedError("no AOT lowering here")
+
+    jitted = Unlowerable()
+    exe, entry = progcache.build(jitted, (1,))
+    assert exe is jitted and entry == {"cache_hit": False}
+
+    class Uncompilable:
+        def lower(self, *a, **k):
+            return self
+
+        def compile(self):
+            raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED")
+
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        progcache.build(Uncompilable(), (1,))
+
+
+def _log_decode():
+    from mxnet_tpu.models.transformer import transformer_lm
+
+    lm = transformer_lm(vocab_size=31, units=16, hidden_size=32,
+                        num_layers=1, num_heads=2, max_length=32,
+                        dropout=0.0)
+    lm.initialize()
+    lm(nd.zeros((1, 8)))
+    eng = serve.DecodeEngine(lm, slots=2, page_size=8, num_pages=8,
+                             prompt_buckets=[8])
+    assert eng.warmup() == 2
+    assert eng.stats()["step_program"]["bytes_accessed"] > 0
+    return eng.compile_log
+
+
+def _log_serve():
+    eng = _engine(*_mlp())
+    eng.warmup((4,))
+    return eng.compile_log
+
+
+def _log_update():
+    up = opt_mod.Updater(opt_mod.create("sgd", learning_rate=0.1))
+    w = nd.array(np.ones((5, 4), np.float32))
+    up.update_batch([0], [w.ones_like()], [w])
+    return up._engine.compile_log
+
+
+def _log_executor():
+    net = sym.SoftmaxOutput(
+        sym.FullyConnected(sym.Variable("data"), num_hidden=3, name="fc"),
+        name="softmax")
+    ex = net.simple_bind(mx.cpu(), data=(2, 4), softmax_label=(2,))
+    ex.forward(is_train=True, data=np.ones((2, 4), np.float32))
+    ex.backward()
+    return ex.compile_log
+
+
+def _log_cachedop():
+    from mxnet_tpu import gluon
+
+    net = gluon.nn.Dense(3)
+    net.initialize()
+    net.hybridize()
+    net(nd.ones((2, 4)))
+    return net._cached_op.compile_log
+
+
+@pytest.mark.parametrize("site, observed, programs", [
+    (_log_decode, True, 2), (_log_decode, False, 2), (_log_serve, True, 3),
+    (_log_update, True, 1), (_log_executor, True, 2),
+    (_log_cachedop, True, 1)],
+    ids=["decode", "decode-unobserved", "serve", "update", "executor",
+         "cachedop"])
+def test_every_sites_compile_log_entry_has_builds_keys(site, observed,
+                                                       programs):
+    """Each site adds its own keys to what ``build`` hands back; the
+    decode engine builds ahead of time whether or not anyone watches, the
+    others (no cache armed) when ``obs`` is on."""
+    from mxnet_tpu import obs
+
+    obs.reset()
+    if observed:
+        obs.enable()
+    try:
+        log = site()
+        recorded = obs.device.costs()
+    finally:
+        obs.disable()
+        obs.reset()
+    assert len(log) == programs
+    for entry in log:
+        assert _COMMON < set(entry), sorted(entry)
+        assert entry["cache_hit"] is False
+        assert len(entry["program_key"]) == 64 and entry["flops"] > 0
+    # the registry mirrors the records only while someone watches
+    digests = {c["program_key"] for c in recorded.values()}
+    assert digests <= {e["program_key"] for e in log}
+    assert bool(digests) is observed
+
+
+def test_no_module_but_progcache_compiles_a_lowering():
+    """The five hand-written build paths and the second MFU stay gone: in
+    ``mxnet_tpu/`` only ``progcache.build`` calls ``.compile()`` on a
+    lowering, and nothing names ``get_peak`` / ``annotate_span``."""
+    root = os.path.dirname(os.path.abspath(progcache.__file__))
+    compiles, named = [], []
+    for dirpath, _dirs, files in os.walk(root):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            rel = os.path.relpath(path, root)
+            with open(path, encoding="utf-8") as f:
+                tree = ast.parse(f.read(), filename=path)
+            for node in ast.walk(tree):
+                # re.compile(pattern) and friends take arguments; a
+                # lowering's .compile() takes none
+                if isinstance(node, ast.Call) \
+                        and isinstance(node.func, ast.Attribute) \
+                        and node.func.attr == "compile" \
+                        and not node.args and not node.keywords:
+                    compiles.append(f"{rel}:{node.lineno}")
+                ident = getattr(node, "attr", None) or getattr(node, "id",
+                                                               None)
+                if ident in ("get_peak", "annotate_span"):
+                    named.append(f"{rel}:{node.lineno}")
+    assert [c.split(":")[0] for c in compiles] == ["progcache.py"], compiles
+    assert named == []
 
 
 # ---------------------------------------------------------------------------

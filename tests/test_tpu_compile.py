@@ -21,7 +21,7 @@ collects (on-chip-measurement guide, section 2).
 import numpy as np
 import pytest
 
-from mxnet_tpu import obs
+from mxnet_tpu import obs, progcache
 from mxnet_tpu.ops import flash_attention, moe
 from mxnet_tpu.serve import DecodeEngine, decode
 
@@ -169,7 +169,7 @@ def test_tpu_step_program_reads_the_pool_where_it_lies(
     (packed,) = _host_arguments(engine, lowered)
     assert (packed.shape, str(packed.dtype)) == (
         (SLOTS + 1, 3 + engine.max_pages), "int32")
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     assert cost["bytes_accessed"] > 0
     assert cost["temp_bytes"] < _k_slice_bytes(engine), cost
     lines = _pool_lines(compiled, engine)
@@ -197,7 +197,7 @@ def test_tpu_prefill_program_writes_the_pool_in_place(
     lowered, compiled = _tpu_program(engine, one_chip, "prefill",
                                      monkeypatch)
     assert len(_host_arguments(engine, lowered)) == 1
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     assert cost["temp_bytes"] < _k_slice_bytes(engine), cost
     lines = _pool_lines(compiled, engine)
     assert "{4,3,2,1,0:T(8,128)}" in lines[0] and "parameter(" in lines[0]
@@ -270,7 +270,7 @@ def test_tpu_paged_kernel_reads_a_group_of_pages_at_the_cell_geometry(
         spec((slots, max_pages), jnp.int32), spec((slots,), jnp.int32))
     assert lowered.as_text().count("tpu_custom_call") == 1
     compiled = lowered.compile()
-    assert obs.device.analyze_compiled(compiled)["temp_bytes"] == 0
+    assert progcache.analyze_compiled(compiled)["temp_bytes"] == 0
 
 
 def _pallas_calls(jaxpr):
@@ -364,7 +364,7 @@ def test_tpu_flash_kernels_lower_with_the_schedule_they_report(
     lowered = jax.jit(fn).lower(*args)
     assert lowered.as_text().count("tpu_custom_call") == len(calls)
     compiled = lowered.compile()
-    temp = obs.device.analyze_compiled(compiled)["temp_bytes"]
+    temp = progcache.analyze_compiled(compiled)["temp_bytes"]
     if packed:   # the lse's rows and its (zero) cotangent: δ is the kernel's
         assert temp <= 2 * b * h * s * 4 + 2 ** 20
     else:
@@ -478,7 +478,7 @@ def test_tpu_latent_step_program_reads_the_pool_where_it_lies(
     assert engine.kv.shape == (257, 3, 64, 640)
     assert engine.cache_row_bytes == 1280
     lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     assert cost["temp_bytes"] < engine.kv.nbytes // MLA["num_layers"], cost
     lines = _pool_lines(compiled, engine)
     assert ("{3,2,1,0:T(8,128)(2,1)}" in lines[0]
@@ -504,7 +504,7 @@ def test_tpu_latent_prefill_program_writes_the_pool_in_place(
     engine = latent_engine
     lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") >= 2
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     lines = _pool_lines(compiled, engine)
     assert not [line for line in lines
                 if " copy(" in line or " fusion(" in line], lines
@@ -574,13 +574,13 @@ def test_tpu_grouped_kv_kernel_at_the_published_geometry(dtype, one_chip,
         spec((32, 68), jnp.int32), spec((32,), jnp.int32))
     assert lowered.as_text().count("tpu_custom_call") == 1
     assert "gqa_decode" in lowered.as_text()
-    assert obs.device.analyze_compiled(lowered.compile())["temp_bytes"] == 0
+    assert progcache.analyze_compiled(lowered.compile())["temp_bytes"] == 0
     if dtype == "bfloat16":
         lowered = jax.jit(gqa_attention.gqa_flash_attention).lower(
             spec((2, 8, 16384, 256)), spec((2, 16384, 256)),
             spec((2, 16384, 256)))
         assert lowered.as_text().count("tpu_custom_call") == 1
-        assert obs.device.analyze_compiled(
+        assert progcache.analyze_compiled(
             lowered.compile())["temp_bytes"] == 0
 
 
@@ -607,7 +607,7 @@ def test_tpu_delta_rule_kernel_updates_the_state_in_place(one_chip,
         spec((b, h)), spec((b, h)), spec((b,), jnp.bool_))
     assert lowered.as_text().count("tpu_custom_call") == 1
     assert "gdn_decode" in lowered.as_text()
-    cost = obs.device.analyze_compiled(lowered.compile())
+    cost = progcache.analyze_compiled(lowered.compile())
     nbytes = (b + 1) * 6 * h * d * d * 4
     assert cost["alias_bytes"] >= nbytes
     assert cost["temp_bytes"] < nbytes // ((b + 1) * 6)   # under one slot's
@@ -636,7 +636,7 @@ def test_tpu_step_program_with_state_beside_pages(gdn_engine, one_chip,
     assert text.count("tpu_custom_call") == 4
     assert all(name in text for name in ("gdn_decode", "gqa_decode",
                                          "moe_rows", "moe_rows_back"))
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
     assert cost["temp_bytes"] < held // GDN["num_layers"], cost
     assert cost["alias_bytes"] >= held
@@ -661,7 +661,7 @@ def test_tpu_prefill_program_hands_its_state_to_the_slot(gdn_engine, one_chip,
     _as_on_a_tpu(monkeypatch)
     lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
     assert "gqa_prefill" in lowered.as_text()
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
     assert cost["alias_bytes"] >= held
     lines = _pool_lines(compiled, engine)
@@ -746,7 +746,7 @@ def test_tpu_prefill_moves_the_held_experts_rows_once(config, bucket, one_chip,
         spec((t, k), jnp.float32), spec((t,), jnp.bool_),
         spec((groups, d, f), jnp.bfloat16), spec((groups, d, f), jnp.bfloat16),
         spec((groups, f, d), jnp.bfloat16), spec((), jnp.int32)).compile()
-    cost = obs.device.analyze_compiled(alone)
+    cost = progcache.analyze_compiled(alone)
     bound = 3 * groups * d * f * 2 + 2 * t * k * d * 4
     assert cost["bytes_accessed"] < bound, (cost, bound)
     # and its temporaries are the buffer of rows and little else: every
@@ -825,7 +825,7 @@ def test_tpu_piece_program_reads_the_prompt_so_far_through_the_page_table(
     assert packed.shape == (5 + 64 + 2048,)
     text = lowered.as_text()
     assert "gqa_prefill_from" in text and "moe_rows_back" in text
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
     assert cost["alias_bytes"] >= held
     assert cost["temp_bytes"] < engine.kv.nbytes // engine.paged_layers, cost
@@ -866,7 +866,7 @@ def test_tpu_latent_piece_program_expands_the_prompt_so_far(one_chip,
     assert packed.shape == (5 + 28 + 1024,)
     text = lowered.as_text()
     assert "mla_prefill_from" in text and "moe_rows_back" in text
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     assert cost["alias_bytes"] >= engine.kv.nbytes
     assert cost["temp_bytes"] < 0.5e9, cost
     lines = _pool_lines(compiled, engine)
@@ -937,7 +937,7 @@ def test_tpu_state_space_kernel_updates_the_state_in_place(one_chip):
         spec((b,), jnp.bool_))
     assert lowered.as_text().count("tpu_custom_call") == 1
     assert "ssm_decode" in lowered.as_text()
-    cost = obs.device.analyze_compiled(lowered.compile())
+    cost = progcache.analyze_compiled(lowered.compile())
     nbytes = (b + 1) * layers * 64 * 64 * 128 * 4
     assert cost["alias_bytes"] >= nbytes
     assert cost["temp_bytes"] < nbytes // ((b + 1) * layers)  # under one slot's
@@ -1004,7 +1004,7 @@ def test_tpu_grouped_products_take_the_row_tile_held_experts_chose(
     tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
     assert len(tilings) >= products and all(
         t == (str(tile), "512", "512") for t in tilings), tilings
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     one_matrix = groups * wide * f * 2
     # the rows' buffer and a block's products, far from a matrix of experts
     assert cost["temp_bytes"] < one_matrix // 4, cost
@@ -1033,7 +1033,7 @@ def test_tpu_step_program_with_state_space_layers_at_128_slots(
     assert text.count("tpu_custom_call") == 4
     assert all(name in text for name in ("ssm_decode", "gqa_decode",
                                          "moe_rows", "moe_rows_back"))
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
     assert cost["temp_bytes"] < held // len(SSM["pattern"]), cost
     assert cost["alias_bytes"] >= held
@@ -1052,7 +1052,7 @@ def test_tpu_prefill_program_with_state_space_layers(ssm_engine, one_chip,
     _as_on_a_tpu(monkeypatch)
     lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
     assert lowered.as_text().count("gqa_prefill") >= 1
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
     assert cost["alias_bytes"] >= held
 
@@ -1132,7 +1132,7 @@ def test_tpu_double_layer_step_program_at_the_cells_sizes(
     text = lowered.as_text()
     assert all(name in text for name in ("mla_decode", "moe_rows",
                                          "moe_rows_back"))
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     assert 13.0e9 < cost["argument_bytes"] < 13.1e9, cost
     assert cost["temp_bytes"] < engine.kv.nbytes // 8, cost
     assert cost["alias_bytes"] >= engine.kv.nbytes
@@ -1155,7 +1155,7 @@ def test_tpu_double_layer_prefill_program_at_the_cells_sizes(
     _as_on_a_tpu(monkeypatch)
     lowered, compiled = _tpu_program(engine, one_chip, "prefill", monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") >= 3
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     assert cost["temp_bytes"] < 0.5e9, cost
     assert cost["alias_bytes"] >= engine.kv.nbytes
     assert not _weight_copies(compiled, engine)
@@ -1242,7 +1242,7 @@ def test_tpu_window_step_program_at_the_cells_sizes(swa_engine, one_chip,
     assert text.count("tpu_custom_call") == 4
     assert all(name in text for name in ("swa_decode", "gqa_decode_dv",
                                          "moe_rows", "moe_rows_back"))
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     held = engine.kv.nbytes + engine.state["window"].nbytes
     assert 12.8e9 < cost["argument_bytes"] < 12.86e9, cost
     assert cost["temp_bytes"] < 0.3e9, cost
@@ -1285,7 +1285,7 @@ def test_tpu_window_piece_program_at_the_cells_sizes(swa_engine, one_chip,
     assert all(name in text for name in ("swa_prefill_from",
                                          "gqa_prefill_from_dv",
                                          "moe_rows_back"))
-    cost = obs.device.analyze_compiled(compiled)
+    cost = progcache.analyze_compiled(compiled)
     held = engine.kv.nbytes + engine.state["window"].nbytes
     assert cost["alias_bytes"] >= held
     assert cost["temp_bytes"] < 0.6e9, cost
