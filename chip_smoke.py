@@ -47,6 +47,13 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
               reference's float32 full forward
               (``benchmark/reference_swa_moe.py``), and ``swa_decode`` and
               ``gqa_decode_dv`` against their XLA twins over what was written;
+- *kda*       the delta rule with a decay per key channel (``ops/kda.py``) at
+              the published 32 heads of 128 x 128: ``kda_decode`` through
+              Mosaic and its XLA twin against the token-by-token recurrence
+              at 2, 32 and 128 slots (dead slots and the other layer
+              untouched, NaN-filled memory first), the kernel's time a layer
+              at 128 slots, and 200 tokens through the chunked form with a
+              whole chunk of log decays at the -5 bound;
 - *experts*   ``ops.moe.held_experts`` at the three expert cells' prompt
               and decode-step geometries against a plain masked loop in
               bfloat16 on the chip: the error, nothing dropped, the row tile
@@ -1318,6 +1325,91 @@ def phase_swa():
                  f"copies the pool or the rings (one ring {ring} bytes)")
 
 
+def phase_kda():
+    """The one-token delta rule with a decay per key channel at the published
+    head geometry (32 heads of 128 x 128, float32 state): the ``kda_decode``
+    kernel through Mosaic and its XLA twin against the token-by-token
+    recurrence at 2, 32 and 128 slots, a dead slot's state untouched, the
+    other layer of the array untouched, in memory filled with NaN first;
+    and a 200-token prompt through the chunked form (log decays at the -5
+    bound among them) against the recurrence. Printed and held."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import kda
+
+    h, dk, dv, layers = 32, 128, 128, 2
+
+    def unit(key, rows):
+        x = jax.random.normal(key, (rows, h, dk))
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    with _Phase("kda"):
+        _dirty_memory()
+        for slots in (2, 32, 128):
+            keys = jax.random.split(jax.random.PRNGKey(slots), 7)
+            states = jax.random.normal(keys[0], (slots + 1, layers, h, dk, dv))
+
+            q, k = unit(keys[1], slots) * dk ** -0.5, unit(keys[2], slots)
+            v = jax.random.normal(keys[3], (slots, h, dv))
+            g = -5.0 * jax.random.uniform(keys[4], (slots, h, dk)) ** 3
+            beta = jax.random.uniform(keys[5], (slots, h))
+            live = jnp.arange(slots) % 5 != 1          # slot 1, 6, ... dead
+            want_o, want_s = jax.vmap(
+                lambda *a: kda.kda_recurrent(*(x[None] for x in a[:5]), a[5])
+            )(q, k, v, g, beta, states[:slots, 1])
+            want_s = jnp.where(live[:, None, None, None], want_s,
+                               states[:slots, 1])
+            before = np.asarray(states)
+            for impl in ("pallas", "xla"):
+                fn = jax.jit(lambda s, *a, impl=impl: kda.kda_step(
+                    s, 1, *a, impl=impl), donate_argnums=0)
+                if impl == "pallas":
+                    _require_mosaic(fn, jnp.array(before), q, k, v, g, beta,
+                                    live)
+                o, after = fn(jnp.array(before), q, k, v, g, beta, live)
+                alive = np.asarray(live)
+                _check_close(f"kda_step [{impl}] at {slots} slots: outputs",
+                             np.asarray(o)[alive],
+                             np.asarray(want_o[:, 0])[alive], 1e-4)
+                _check_close(f"kda_step [{impl}] at {slots} slots: states",
+                             np.asarray(after[:slots, 1]), np.asarray(want_s),
+                             1e-4)
+                _require(np.array_equal(np.asarray(after[:slots, 0]),
+                                        before[:slots, 0]),
+                         f"kda_step [{impl}] wrote the other layer's state")
+            if slots == 128:
+                fn = jax.jit(lambda s, *a: kda.kda_step(s, 1, *a),
+                             donate_argnums=0)
+                s = jnp.array(before)
+                _, s = fn(s, q, k, v, g, beta, live)
+                s.block_until_ready()
+                t0 = time.monotonic()
+                for _ in range(30):
+                    _, s = fn(s, q, k, v, g, beta, live)
+                s.block_until_ready()
+                ms = (time.monotonic() - t0) / 30 * 1e3
+                moved = 2 * slots * h * dk * dv * 4
+                print(f"   kda_decode at 128 slots: {ms:.3f} ms a layer for "
+                      f"{moved / 1e6:.0f} MB of state both ways = "
+                      f"{moved / ms / 1e6:.0f} GB/s", flush=True)
+        # a prompt in chunks: a whole chunk at the bound, and a ragged end
+        keys = jax.random.split(jax.random.PRNGKey(200), 6)
+        n = 200
+        q, k = unit(keys[0], n) * dk ** -0.5, unit(keys[1], n)
+        v = jax.random.normal(keys[2], (n, h, dv))
+        g = -5.0 * jax.random.uniform(keys[3], (n, h, dk)) ** 3
+        g = g.at[64:128].set(-5.0)
+        beta = jax.random.uniform(keys[4], (n, h))
+        s0 = jax.random.normal(keys[5], (h, dk, dv))
+        want_o, want_s = jax.jit(kda.kda_recurrent)(q, k, v, g, beta, s0)
+        o, s1 = jax.jit(kda.kda_chunked)(q, k, v, g, beta, s0)
+        _require(bool(jnp.all(jnp.isfinite(o)) and jnp.all(jnp.isfinite(s1))),
+                 "the chunked rule is not finite at the -5 bound")
+        _check_close("kda_chunked over 200 tokens: outputs", o, want_o, 1e-4)
+        _check_close("kda_chunked over 200 tokens: state", s1, want_s, 1e-4)
+
+
 def _dirty_memory():
     """Fill what is free of the device's memory with NaN and free it again.
     A fresh process finds zeros where it never wrote; a long-lived one does
@@ -1454,6 +1546,7 @@ def main():
     phase_ssm()
     phase_scmoe()
     phase_swa()
+    phase_kda()
     phase_experts()
     multichip_attn = phase_multichip(losses[0])
     print("summary " + json.dumps({

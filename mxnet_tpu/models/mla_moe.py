@@ -297,6 +297,19 @@ def _mlp(cfg, lp, x, live, experts):
     return (x.astype(jnp.float32) + y).astype(x.dtype), counters
 
 
+def _head_gate(cfg, lp, x, o):
+    """The attention halves' **head-wise output gate**
+    (``models/kda_mla_moe.py``'s layers have it). o (T, H, v), every head's
+    attention output for tokens x (T, D), times ``sigmoid(RMSNorm(x) . W_og)``
+    (T, H) where the layer has the leaf (``og_w`` (hidden, H)); else o
+    itself: a layer without it traces not one operation more."""
+    if "og_w" not in lp:
+        return o
+    h = rms_norm(x, lp["attn_norm"], cfg["rms_eps"]).astype(x.dtype)
+    gate = jax.nn.sigmoid(_mm(h, lp["og_w"]))
+    return (o.astype(jnp.float32) * gate[:, :, None]).astype(o.dtype)
+
+
 def prefill_attention(cfg, lp, x, cos, sin):
     """``x + Attn(RMSNorm(x))`` over a prompt x (S, D), expanded through the
     flash forward. Returns (x', rows (S, R))."""
@@ -313,7 +326,7 @@ def prefill_attention(cfg, lp, x, cos, sin):
     q = jnp.swapaxes(jnp.concatenate([q_nope, q_rope], axis=-1), 0, 1)
     o = flash_attention(q[None], k[None], v[None], causal=True,
                         scale=softmax_scale(cfg))[0]          # (H, S, v)
-    o = jnp.swapaxes(o, 0, 1).reshape(x.shape[0], -1)
+    o = _head_gate(cfg, lp, x, jnp.swapaxes(o, 0, 1)).reshape(x.shape[0], -1)
     return (x.astype(jnp.float32) + _mm(o, lp["o_w"])).astype(x.dtype), row
 
 
@@ -333,8 +346,8 @@ def decode_attention(cfg, lp, x, cos, sin, attend):
     o = jnp.swapaxes(jnp.einsum(
         "hbc,hcv->hbv", jnp.swapaxes(u, 0, 1), lp["uv_w"],
         preferred_element_type=jnp.float32), 0, 1).astype(x.dtype)
-    return (x.astype(jnp.float32)
-            + _mm(o.reshape(x.shape[0], -1), lp["o_w"])).astype(x.dtype)
+    return (x.astype(jnp.float32) + _mm(_head_gate(cfg, lp, x, o).reshape(
+        x.shape[0], -1), lp["o_w"])).astype(x.dtype)
 
 
 def prefill_layer(cfg, lp, x, cos, sin, live, experts):
@@ -509,5 +522,6 @@ def prefill_attention_from(cfg, lp, x, cos, sin, start, before):
     o = latent_flash_attention_from(
         q, lax.dynamic_update_slice(before, row, (start, 0)), lp["uk_w"],
         lp["uv_w"], start, softmax_scale(cfg))                  # (H, C, v)
-    o = jnp.swapaxes(o, 0, 1).reshape(x.shape[0], -1)
+    o = _head_gate(cfg, lp, x, jnp.swapaxes(o, 0, 1)).reshape(x.shape[0], -1)
     return (x.astype(jnp.float32) + _mm(o, lp["o_w"])).astype(x.dtype), row
+

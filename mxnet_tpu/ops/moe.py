@@ -14,6 +14,8 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
   the experts, the k largest, ``g = p_chosen / sum(p_chosen)``; no bias.
   :func:`route_softmax_scaled` is a third's: the k largest of ``p + b``,
   ``g = scale * p_chosen``, NOT renormalised.
+  :func:`route_grouped` is :func:`route` with the choice limited to a
+  token's best groups of experts (a group's score: its two largest ``s + b``).
 - :func:`held_experts` — dropless, and its work follows the rows the held
   experts have, not the static bound ``tokens x k``: the (token, choice)
   pairs are sorted by expert (those on experts held elsewhere, and the
@@ -38,7 +40,9 @@ stood in for (on one chip the layer runs without its ``all_to_all``).
   experts that compute nothing, ``E_e(h) = h``; a pair chosen there adds
   ``g . h`` on every chip alike, as a shared expert is on every chip, runs no
   grouped row (its id lies outside the held ones, so it sorts behind them)
-  and is counted in one more counter, ``zero`` (``ZERO_COUNTERS``).
+  and is counted in one more counter, ``zero`` (``ZERO_COUNTERS``); a layer
+  routed by groups counts the tokens that kept a group with a held expert,
+  ``group_hit`` (``GROUP_COUNTERS``).
 
 **Two forms of expert**, told apart by the leaves a model hands over: with a
 gate matrix three products, ``(silu(h.W_g) * h.W_u).W_d``
@@ -56,9 +60,10 @@ from jax import lax
 
 from . import flash_attention
 
-__all__ = ["route", "route_softmax", "route_softmax_scaled", "held_experts",
-           "expert_layer", "gated_mlp", "relu2_mlp", "row_slot", "row_tile",
-           "row_block", "layer_row_tile", "COUNTERS", "ZERO_COUNTERS"]
+__all__ = ["route", "route_softmax", "route_softmax_scaled", "route_grouped",
+           "held_experts", "expert_layer", "gated_mlp", "relu2_mlp",
+           "row_slot", "row_tile", "row_block", "layer_row_tile", "COUNTERS",
+           "ZERO_COUNTERS", "GROUP_COUNTERS"]
 
 # per call: live (token, choice) pairs; those on held experts; most tokens on
 # one held expert; held experts with at least one token; held pairs that no
@@ -69,6 +74,9 @@ COUNTERS = ("assignments", "held", "load_max", "touched", "dropped",
             "rows_run")
 # of a layer with identity experts: live pairs that fell on them, last
 ZERO_COUNTERS = COUNTERS + ("zero",)
+# of a layer whose router keeps some groups a token: live tokens that kept a
+# group with a held expert in it (the others hold nothing here), last
+GROUP_COUNTERS = COUNTERS + ("group_hit",)
 TOKEN_CHUNK = 4096   # most tokens routed and multiplied at a time: bounds the
 #                      sorted rows (tokens x k of them) and their products
 
@@ -133,6 +141,28 @@ def route_softmax_scaled(h, router_w, router_b, k: int, scale: float):
     p = jax.nn.softmax(_router_logits(h, router_w), axis=-1)
     _, chosen = lax.top_k(p + router_b.astype(jnp.float32), k)
     return chosen, scale * jnp.take_along_axis(p, chosen, axis=1)
+
+
+def route_grouped(h, router_w, router_b, k: int, scale: float, groups: int,
+                  groups_kept: int):
+    """:func:`route` with the choice limited to groups: the experts lie in
+    ``groups`` equal groups by id, a group's score is the sum of its two
+    largest choosing scores ``s + b``, a token keeps its ``groups_kept`` best
+    groups and chooses the ``k`` largest ``s + b`` among their experts (ties
+    to the lower id, of groups and of experts); gates as :func:`route`'s.
+    h (T, D) -> (chosen (T, k) int32, gates (T, k) float32, kept (T, groups)
+    bool)."""
+    s = jax.nn.sigmoid(_router_logits(h, router_w))
+    choose = s + router_b.astype(jnp.float32)
+    t, e = choose.shape
+    two, _ = lax.top_k(choose.reshape(t, groups, e // groups), 2)
+    _, best = lax.top_k(jnp.sum(two, axis=-1), groups_kept)
+    kept = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+    _, chosen = lax.top_k(
+        jnp.where(jnp.repeat(kept, e // groups, axis=1), choose, -jnp.inf), k)
+    picked = jnp.take_along_axis(s, chosen, axis=1)
+    return (chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True),
+            kept)
 
 
 def row_slot(pairs: int, scored: int) -> int:
@@ -387,7 +417,8 @@ def held_experts(h, chosen, gates, live, gate_w, up_w, down_w, first: int,
 
 
 def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
-                 scale: float = 1.0, offset=0, zero_experts: int = 0):
+                 scale: float = 1.0, offset=0, zero_experts: int = 0,
+                 groups: int = 0, groups_kept: int = 0):
     """One expert layer over h (T, D): the held experts' routed part plus
     the shared expert. ``p``: ``router_w`` (D, E_all), ``router_b`` (E_all,),
     ``shared_{gate,up,down}_w``; ``experts``: ``gate_w``, ``up_w``,
@@ -399,8 +430,12 @@ def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
     ``sigmoid(h.w_s)``. ``zero_experts`` > 0: the router's last that many ids
     are identity experts and it routes by :func:`route_softmax_scaled`; their
     term ``(sum of the gates chosen there) . h`` is added in float32 and the
-    counters are ``ZERO_COUNTERS``. Tokens go at most ``TOKEN_CHUNK`` at a
-    time. Returns (y (T, D) float32, counters)."""
+    counters are ``ZERO_COUNTERS``. ``groups`` > 0: it routes by
+    :func:`route_grouped` (``groups_kept`` of ``groups`` groups a token) and
+    the counters are ``GROUP_COUNTERS``: the last counts the live tokens whose
+    kept groups hold one of the experts ``first .. first + held - 1``. Tokens
+    go at most ``TOKEN_CHUNK`` at a time. Returns (y (T, D) float32,
+    counters)."""
     scored = p["router_w"].shape[1]
 
     def chunk(args):
@@ -408,6 +443,10 @@ def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
         if zero_experts:
             chosen, gates = route_softmax_scaled(hc, p["router_w"],
                                                  p["router_b"], k, scale)
+        elif groups:
+            chosen, gates, kept = route_grouped(
+                hc, p["router_w"], p["router_b"], k, scale, groups,
+                groups_kept)
         elif "router_b" in p:
             chosen, gates = route(hc, p["router_w"], p["router_b"], k, scale)
         else:
@@ -420,6 +459,11 @@ def expert_layer(h, p, experts, live, *, first: int, held: int, k: int,
             y = y + (jnp.sum(jnp.where(on_zero, gates, 0.0), axis=-1,
                              keepdims=True) * hc.astype(jnp.float32))
             c = jnp.concatenate([c, jnp.sum(on_zero, dtype=jnp.int32)[None]])
+        if groups:
+            per = scored // groups
+            here = kept[:, first // per:(first + held - 1) // per + 1]
+            c = jnp.concatenate([c, jnp.sum(jnp.any(here, axis=1) & lc,
+                                            dtype=jnp.int32)[None]])
         if "shared_up_w" not in p:
             return y, c
         if "shared_gate_w" in p:
@@ -447,3 +491,4 @@ def merge_counters(c):
     largest ``load_max``."""
     i = COUNTERS.index("load_max")
     return jnp.sum(c, axis=0).at[i].set(jnp.max(c[:, i]))
+

@@ -81,9 +81,9 @@ def engine():
     return _engine()
 
 
-def _tpu_program(engine, one_chip, kind, monkeypatch):
+def _traced(engine, one_chip, kind, monkeypatch):
     """The engine's own program, jitted as its constructor does on a TPU
-    (pool row-major and donated) and compiled for the described chip."""
+    (pool row-major and donated) and traced for the described chip."""
     import jax
     import jax.numpy as jnp
 
@@ -107,14 +107,18 @@ def _tpu_program(engine, one_chip, kind, monkeypatch):
     # the one array a call uploads (``DecodeEngine``'s docstring)
     if kind == "step":
         packed = spec(engine.blank_step().shape, i32)
-        lowered = step.lower(params, kv, state, last, packed)
+        return step.trace(params, kv, state, last, packed)
+    bucket = engine.buckets[0]
+    if engine.prefill_piece:    # header, the whole page table, a piece
+        packed = spec((5 + engine.max_prompt // page + bucket,), i32)
     else:
-        bucket = engine.buckets[0]
-        if engine.prefill_piece:    # header, the whole page table, a piece
-            packed = spec((5 + engine.max_prompt // page + bucket,), i32)
-        else:
-            packed = spec((4 + bucket // page + bucket,), i32)
-        lowered = prefill.lower(params, kv, state, last, packed)
+        packed = spec((4 + bucket // page + bucket,), i32)
+    return prefill.trace(params, kv, state, last, packed)
+
+
+def _tpu_program(engine, one_chip, kind, monkeypatch):
+    """:func:`_traced`, lowered and compiled for the described chip."""
+    lowered = _traced(engine, one_chip, kind, monkeypatch).lower()
     return lowered, lowered.compile()
 
 
@@ -1299,3 +1303,219 @@ def test_tpu_window_piece_program_at_the_cells_sizes(swa_engine, one_chip,
     assert not [line[:160] for line in optimised.splitlines()
                 if re.search(r"= bf16\[2561,3,(256|2,128),\d+\]", line)
                 and " slice(" in line]
+
+
+# -- Kimi delta attention's state beside a latent pool, at the cell's sizes ------
+# ``ling3-flash-serve-closed-128`` as the benchmark runs it: published widths,
+# 11 KDA + 2 latent-attention layers, 32 held of 512 experts, 128 slots, the
+# cell's pool of pages of 256, prompts in pieces of 2,048 up to 4,096. Shapes
+# only: 6.5 GB of weights, 3.1 GB of state and the pool are never made.
+
+@pytest.fixture(scope="module")
+def kda_engine():
+    import json
+    import os
+
+    import jax
+
+    from mxnet_tpu.models import kda_mla_moe
+
+    root = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark")
+    with open(os.path.join(root, "configs", "ling-3.0-flash-vl.json")) as f:
+        cfg = json.load(f)["model"]
+    with open(os.path.join(root, "workloads",
+                           "ling3-flash-serve-closed-128.json")) as f:
+        sv = json.load(f)["serve"]
+    assert cfg.pop("kind") == "kda_mla_moe_lm"
+    shapes = jax.eval_shape(lambda: kda_mla_moe.init_params(cfg, 0))
+    model = kda_mla_moe.KDAMLAMoEDecodeModel(cfg, params=shapes)
+    return DecodeEngine(model, slots=sv["slots"], page_size=sv["page_size"],
+                        num_pages=sv["num_pages"],
+                        prompt_buckets=sv["prompt_buckets"])
+
+
+def test_tpu_channel_decay_kernel_updates_the_state_in_place(one_chip):
+    """``kda_decode`` at the published geometry — 129 slots x 11 layers x 32
+    heads x 128 x 128 float32 (2.98 GB: shapes only here) — goes through
+    Mosaic under its own name, the decay a column a head beside the key's,
+    and the states array is the kernel's operand AND result (aliased)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import kda
+
+    b, h, dk, dv = 128, 32, 128, 128
+
+    def spec(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lowered = jax.jit(
+        lambda s, q, k, v, g, beta, live: kda.kda_step(
+            s, 7, q, k, v, g, beta, live),
+        donate_argnums=0).lower(
+            spec((b + 1, 11, h, dk, dv)), spec((b, h, dk)), spec((b, h, dk)),
+            spec((b, h, dv)), spec((b, h, dk)), spec((b, h)),
+            spec((b,), jnp.bool_))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert "kda_decode" in lowered.as_text()
+    compiled = lowered.compile()
+    cost = progcache.analyze_compiled(compiled)
+    state = (b + 1) * 11 * h * dk * dv * 4
+    assert cost["alias_bytes"] >= state and cost["temp_bytes"] < state // 100
+
+
+def test_tpu_kda_step_program_at_the_cells_sizes(kda_engine, one_chip,
+                                                 monkeypatch):
+    """The step of the KDA + latent model compiled for a v5e at the cell's
+    sizes: the pool counts the TWO latent layers alone (rows of 640), the
+    eleven KDA layers' states ``(129, 11, 32, 128, 128)`` float32 and tails
+    ``(129, 11, 288, 128)`` are per-slot state donated beside it; one Mosaic
+    call a kind of kernel (``kda_decode`` and ``mla_decode`` each traced once
+    for all their layers, and the two of ``held_experts``); the step's
+    arguments are the 6.51 GB of weights + the pool + 3.08 GB of state; its
+    temporaries stay under ONE KDA layer's state of the batch (268 MB): no
+    instruction copies the states, the tails (the folded convolution step:
+    unfolded, XLA:TPU laid the tails out slot-minor and copied all 105 MB
+    there and back a layer) or the pool; no layer's weights are cut out of
+    a stack; and the grouped products carry the row tile
+    ``moe.layer_row_tile`` names for 128 tokens x 8 choices over 512."""
+    import re
+
+    import jax.numpy as jnp
+
+    engine = kda_engine
+    assert engine.kv.shape[1:] == (2, 256, 640) and engine.paged_layers == 2
+    assert engine.state["s"].shape == (129, 11, 32, 128, 128)
+    assert engine.state["tail"].shape == (129, 11, 288, 128)   # 3 x 12288
+    assert engine.cache_row_bytes == 1280
+    assert engine.state_bytes == 11 * (2097152 + 73728)
+    stats = engine.stats()
+    assert stats["prefill_piece"] == 2048 and stats["max_prompt"] == 4096
+    tile = moe.layer_row_tile(128, 8, 512, jnp.bfloat16)
+    assert tile == 16 and stats["moe_row_tile"] == {"step": 16,
+                                                    "prefill": {2048: 64}}
+    _as_on_a_tpu(monkeypatch)
+    lowered, compiled = _tpu_program(engine, one_chip, "step", monkeypatch)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 5
+    assert all(name in text for name in ("kda_decode", "mla_decode",
+                                         "moe_rows", "moe_rows_back"))
+    cost = progcache.analyze_compiled(compiled)
+    held = engine.kv.nbytes + sum(a.nbytes for a in engine.state.values())
+    weights = 2 * 3256770784 - 4 * 11 * 32 + 4 * 11 * 32 * 2  # A_log float32
+    assert abs(cost["argument_bytes"] - (weights + held)) < 1e6, cost
+    layer_state = 128 * 32 * 128 * 128 * 4
+    assert cost["temp_bytes"] < layer_state, cost
+    assert cost["alias_bytes"] >= held
+    optimised = compiled.as_text()
+    entry = optimised[optimised.index("\nENTRY ") + 1:]
+    shapes = ("f32[129,11,32,128,128]", "bf16[129,11,288,128]",
+              "bf16[" + ",".join(str(n) for n in engine.kv.shape) + "]")
+    assert not [line.strip()[:160] for line in entry.splitlines()
+                if " copy(" in line and any(
+                    s in line[:line.index(" copy(")] for s in shapes)]
+    # a KDA layer's 105 MB ``in_w`` is an argument: the program makes no
+    # array of its shape
+    assert not [line[:160] for line in entry.splitlines()
+                if re.match(r"\s*%?[\w.\-]+ = bf16\[(1,)?2560,20512\]", line)
+                and " parameter(" not in line
+                and "S(1)}" not in line[:line.index(" = ") + 80]]
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', optimised)
+    assert len(tilings) >= 3 * 12 and all(      # 3 products x 12 layers
+        t[0] == str(tile) for t in tilings), tilings
+
+
+def test_tpu_kda_piece_program_at_the_cells_sizes(kda_engine, one_chip,
+                                                  monkeypatch):
+    """The ONE prefill program of the same cell, lowered for a v5e (not
+    compiled here: 40 s; compile-only its temporaries are 1.37 GB under
+    10.94 of arguments, and every run on the chip peaks at 11.0 GB: PERF.md
+    section 4): a piece of 2,048 positions of a prompt of up to 4,096, the
+    whole page table of 16 pages in the packed array. The latent layers'
+    continued forward goes through Mosaic (``mla_prefill_from``, once a
+    latent layer) and reads the pool through the page table; the KDA layers
+    read nothing of it — the chunked rule is XLA: state in, state out —;
+    pool and state are donated."""
+    import jax
+
+    engine = kda_engine
+    _as_on_a_tpu(monkeypatch)
+    lowered = _traced(engine, one_chip, "prefill", monkeypatch).lower()
+    (packed,) = _host_arguments(engine, lowered)
+    assert packed.shape == (5 + 16 + 2048,)
+    text = lowered.as_text()
+    assert all(name in text for name in ("mla_prefill_from", "moe_rows",
+                                         "moe_rows_back"))
+    assert "kda_decode" not in text
+    donated = [info.donated for info in
+               jax.tree_util.tree_leaves(lowered.args_info)]
+    resident = len(jax.tree_util.tree_leaves(engine._params))
+    assert donated[resident:resident + 3] == [True, True, True]
+    assert not any(donated[:resident])
+
+
+# What PR 50 gave the code these cells share — a head-wise gate as an optional
+# leaf of ``models/mla_moe.py``'s attention halves (``_head_gate``), a grouped
+# router as a fourth branch of ``ops/moe.py``'s ``expert_layer`` — is not on
+# the path of the models that were there: each one's programs are traced twice
+# in this process, as the code stands and with both additions cut off (the gate
+# the identity it is without its leaf, the grouped router refusing every call),
+# and the two jaxprs are the same text, kernel bodies included. Nothing is
+# pasted from another commit, so a later change to these programs moves both
+# sides alike.
+
+def _anew(engine):
+    """A second engine around the same model: jax hands a jit of a bound
+    method it has traced before that trace, whatever a module global has been
+    patched to since, so a program is traced anew through an engine of its
+    own."""
+    return DecodeEngine(engine.model, slots=engine.slots,
+                        page_size=engine.page_size,
+                        num_pages=engine.num_pages,
+                        prompt_buckets=list(engine.buckets))
+
+
+def _without_pr50(monkeypatch):
+    from mxnet_tpu.models import mla_moe
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("the grouped router was called")
+
+    monkeypatch.setattr(mla_moe, "_head_gate", lambda cfg, lp, x, o: o)
+    monkeypatch.setattr(moe, "route_grouped", refuse)
+
+
+@pytest.mark.parametrize("kind", ["step", "prefill"])
+@pytest.mark.parametrize("family", ["gdn_engine", "latent_engine",
+                                    "scmoe_engine"])
+def test_programs_of_the_models_that_share_code_take_nothing_new(
+        family, kind, one_chip, monkeypatch, request):
+    """``qwen3next-serve-closed-long``'s model (``gdn_moe``),
+    ``sarvam105b-serve-closed``'s (``mla_moe``) and
+    ``longcat-omni-serve-closed-128``'s (``mla_scmoe``)."""
+    import re
+
+    import jax
+
+    engine = request.getfixturevalue(family)
+    assert not [path for path, _ in jax.tree_util.tree_leaves_with_path(
+        engine._params) if "og_w" in jax.tree_util.keystr(path)]
+    _as_on_a_tpu(monkeypatch)
+
+    def text(of):
+        return re.sub(r" at 0x[0-9a-f]+", "", str(
+            _traced(of, one_chip, kind, monkeypatch).jaxpr))
+
+    as_it_stands = text(_anew(engine))
+    _without_pr50(monkeypatch)
+    assert text(_anew(engine)) == as_it_stands
+
+
+def test_the_cut_that_comparison_makes_bites(kda_engine, one_chip,
+                                              monkeypatch):
+    """The same cut refuses the model that does route by groups: the
+    comparison above would see a program that took the new branch."""
+    _as_on_a_tpu(monkeypatch)
+    _without_pr50(monkeypatch)
+    with pytest.raises(AssertionError, match="grouped router"):
+        _traced(_anew(kda_engine), one_chip, "step", monkeypatch)
