@@ -18,6 +18,13 @@ hidden 3072, 12 layers, 12 heads), with random weights made from a seed:
               bfloat16 values in 640 columns) through ``DecodeEngine``: the paged latent
               kernel against the XLA gather on the chip, the step program's
               temporaries under one layer's latents, nothing dropped;
+- *state*     the gated-delta model (``models.gdn_moe``) through
+              ``DecodeEngine``, state beside pages: a prompt in pieces against
+              the prompt whole, and its kernels against XLA — ``gqa_decode``,
+              the flash forward, ``gdn_decode``, and ``gdn_prefill`` at a
+              2,048-position piece of 32 heads of 128 x 128 against the
+              token-by-token recurrence and the XLA chunked form (error and
+              time a layer of both printed);
 - *ssm*       the Mamba-2 + relu^2-experts model (``models.ssm_moe``) at its
               published widths, four layers, 128 slots, through
               ``DecodeEngine``, in memory filled with NaN beforehand (the
@@ -741,10 +748,69 @@ GDN = {"vocab_size": 4096, "hidden_size": 1024, "num_layers": 4,
        "experts_per_token": 8, "rms_eps": 1e-6, "max_length": 2048}
 
 
+def _delta_rule_over_a_piece():
+    """``gdn_prefill`` at the published geometry — a 2,048-position piece of
+    32 value heads on 16 key heads of 128 x 128, from a random state, the
+    last 100 positions masked — through Mosaic, against the token-by-token
+    recurrence and against the XLA chunked form: the kernel's error against
+    the recurrence at most twice the XLA form's own, and what each form
+    takes a layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gated_delta
+
+    s, hk, hv, d, dead = 2048, 16, 32, 128, 100
+    keys = jax.random.split(jax.random.PRNGKey(21), 6)
+    q, k = (jax.random.normal(key, (s, hk, d), jnp.float32)
+            for key in keys[:2])
+    q, k = (a / jnp.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
+    v = jax.random.normal(keys[2], (s, hv, d), jnp.float32)
+    live = (jnp.arange(s) < s - dead)[:, None]
+    g = jnp.where(live, -jnp.exp(jax.random.uniform(
+        keys[3], (s, hv), minval=-4.0, maxval=1.0)), 0.0)
+    beta = jnp.where(live, jax.random.uniform(keys[4], (s, hv)), 0.0)
+    state = jax.random.normal(keys[5], (hv, d, d), jnp.float32)
+    rep = hv // hk
+    want_o, want_s = jax.jit(gated_delta.delta_rule_recurrent)(
+        jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1), v, g, beta,
+        state)
+    forms, errors = {}, {}
+    for impl in ("xla", "pallas"):
+        forms[impl] = jax.jit(functools.partial(
+            gated_delta.delta_rule_chunked, impl=impl))
+        got_o, got_s = forms[impl](q, k, v, g, beta, state)
+        _require(bool(jnp.all(jnp.isfinite(got_o)))
+                 and bool(jnp.all(jnp.isfinite(got_s))),
+                 f"the chunked delta rule ({impl}): non-finite values")
+        errors[impl] = (_rel_err(got_o, want_o), _rel_err(got_s, want_s))
+    text = forms["pallas"].lower(q, k, v, g, beta, state).as_text()
+    _require(_MOSAIC in text and "gdn_prefill" in text,
+             "the chunked rule lowered without the gdn_prefill Mosaic call")
+    took = {}
+    for impl, form in forms.items():
+        t0 = time.monotonic()
+        for _ in range(20):
+            out = form(q, k, v, g, beta, state)
+        jax.block_until_ready(out)
+        took[impl] = (time.monotonic() - t0) * 50
+    print(f"   the delta rule over a 2,048-position piece (32 x 128 x 128, "
+          f"100 masked) against the recurrence: gdn_prefill rel err "
+          f"{errors['pallas'][0]:.2e} outputs, {errors['pallas'][1]:.2e} "
+          f"state, {took['pallas']:.3f} ms a layer; XLA chunked "
+          f"{errors['xla'][0]:.2e}, {errors['xla'][1]:.2e}, "
+          f"{took['xla']:.3f} ms a layer", flush=True)
+    for what, got, own in zip(("outputs", "state"), errors["pallas"],
+                              errors["xla"]):
+        _require(got <= 2 * max(own, 1e-6),
+                 f"gdn_prefill's {what} are {got:.2e} from the recurrence, "
+                 f"the XLA form's {own:.2e}")
+
+
 def phase_state():
     """State beside pages: the gated-delta model through the engine's two
     programs — a prompt in pieces against the same prompt whole, in memory
-    filled with NaN first —, and its three kernels against XLA over what the
+    filled with NaN first —, and its four kernels against XLA over what the
     engine wrote."""
     import jax
     import jax.numpy as jnp
@@ -902,6 +968,7 @@ def phase_state():
         _check_close("one-token delta rule, states (32 x 128 x 128)",
                      np.asarray(got_s)[:slots], np.asarray(want_s)[:slots],
                      1e-4)
+        _delta_rule_over_a_piece()
         engine.pool.free(0)
         engine.pool.assert_baseline()
         program = engine.stats()["step_program"]

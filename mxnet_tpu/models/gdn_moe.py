@@ -23,9 +23,10 @@ of them with ``live`` and returns them updated in place.
 - **prefill**: the periods (``full_interval - 1`` delta layers and a full
   one) under one ``lax.scan`` over their stacked weights, the delta layers
   of a period under another; the delta rule in chunks
-  (``ops.gated_delta.delta_rule_chunked``), positions past the prompt's
-  length masked out of the state; attention through
-  ``gqa_flash_attention``.
+  (``ops.gated_delta.delta_rule_chunked``: the ``gdn_prefill`` kernel on a
+  TPU at lane-wide heads, XLA einsums elsewhere; ``delta_rule()`` says
+  which), positions past the prompt's length masked out of the state;
+  attention through ``gqa_flash_attention``.
 - **step**: one token a slot; ``delta_rule_step`` (the ``gdn_decode``
   kernel: one read and one write of each live slot's state in place) and
   ``gqa_decode_attention`` (the ``gqa_decode`` kernel over the pool).
@@ -276,9 +277,9 @@ def _delta_inputs(cfg, lp, x):
 
 
 def _delta_heads(cfg, lp, conv, b, a):
-    """From the convolution's output (T, C) float32: q, k (T, HV, dk) — l2
-    normed, q scaled, a key head repeated for its value heads —, v (T, HV,
-    dv), g, beta (T, HV); float32."""
+    """From the convolution's output (T, C) float32: q, k (T, HK, dk) — l2
+    normed, q scaled; key head j is value heads ``j HV / HK ..``'s —, v (T,
+    HV, dv), g, beta (T, HV); float32."""
     hk, hv = cfg["linear_key_heads"], cfg["linear_value_heads"]
     dk, t = cfg["linear_key_dim"], conv.shape[0]
     x = jax.nn.silu(conv)
@@ -290,8 +291,7 @@ def _delta_heads(cfg, lp, conv, b, a):
     k = l2(x[:, hk * dk:2 * hk * dk].reshape(t, hk, dk))
     g = (-jnp.exp(lp["A_log"].astype(jnp.float32))
          * jax.nn.softplus(a + lp["dt_bias"].astype(jnp.float32)))
-    return (jnp.repeat(q, hv // hk, axis=1), jnp.repeat(k, hv // hk, axis=1),
-            x[:, 2 * hk * dk:].reshape(t, hv, -1), g, jax.nn.sigmoid(b))
+    return q, k, x[:, 2 * hk * dk:].reshape(t, hv, -1), g, jax.nn.sigmoid(b)
 
 
 def _delta_output(cfg, lp, x, o, z):
@@ -412,7 +412,9 @@ class GDNMoEDecodeModel:
             # a position past the prompt writes nothing into the state
             g = jnp.where(live[:, None], g, 0.0)
             beta = jnp.where(live[:, None], beta, 0.0)
-            o, state = gated_delta.delta_rule_chunked(q, k, v, g, beta, s0)
+            o, state = gated_delta.delta_rule_chunked(
+                q, k, v, g, beta, s0, impl=decode_attention_impl(),
+                interpret=_use_interpret())
             x = _delta_output(cfg, lp, x, o, z)
             x, counters = _mlp(cfg, mp, x, live, experts, i)
             return x, (state, tail, counters)
@@ -496,6 +498,8 @@ class GDNMoEDecodeModel:
                     jnp.where(live[:, None, None], new, old).reshape(
                         (b,) + tails.shape[2:]))
                 q, k, v, g, beta = _delta_heads(cfg, lp, conv, bb, a)
+                q, k = (jnp.repeat(u, v.shape[1] // u.shape[1], axis=1)
+                        for u in (q, k))
                 o, s_all = gated_delta.delta_rule_step(
                     s_all, j, q, k, v, g, beta, live, impl=impl,
                     interpret=_use_interpret())
@@ -510,6 +514,13 @@ class GDNMoEDecodeModel:
                                     scale=self._scale())
 
     counters = tuple("moe." + name for name in moe.COUNTERS)
+
+    def delta_rule(self):
+        """``DecodeEngine.stats()["delta_rule"]``: the form the delta rule
+        takes over a prompt in this process (``gated_delta.chunked_form``)."""
+        return gated_delta.chunked_form(
+            self.cfg["linear_key_dim"], self.cfg["linear_value_dim"],
+            impl=decode_attention_impl(), interpret=_use_interpret())
 
     def moe_row_tile(self, tokens):
         """``DecodeEngine.stats()["moe_row_tile"]``: the row tile the held

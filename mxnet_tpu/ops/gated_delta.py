@@ -14,7 +14,10 @@ query ``q`` and key ``k`` (key wide), a value ``v`` (value wide), a log decay
   ``CHUNK`` tokens: inside a chunk the tokens' writes are solved together (a
   unit lower-triangular system, by forward substitution — rows inside 16-wide
   blocks, then the blocks —: stable whatever the keys), and only the chunks
-  follow one another. XLA einsums in float32.
+  follow one another. Float32 at ``HIGHEST`` in both of its forms: XLA
+  einsums, and where backend and shapes allow (:func:`chunked_form`) ONE
+  Pallas kernel (``gdn_prefill``) that keeps a chunk's system and the state
+  in VMEM and writes nothing chunk-sized to HBM but the outputs.
   Returns the outputs and the final state, which a prefill hands to the step.
 - :func:`delta_rule_step` — one token for every slot of a decode batch, as a
   Pallas kernel (``gdn_decode``): the state array of every slot and layer is
@@ -34,8 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["CHUNK", "causal_conv", "causal_conv_step", "delta_rule_recurrent",
-           "delta_rule_chunked", "delta_rule_step"]
+__all__ = ["CHUNK", "causal_conv", "causal_conv_step", "chunked_form",
+           "delta_rule_recurrent", "delta_rule_chunked", "delta_rule_step"]
 
 CHUNK = 64          # tokens solved together (the family's habit)
 _HI = lax.Precision.HIGHEST
@@ -131,17 +134,32 @@ def _unit_lower_inverse(m, block=16):
         for a in range(nb)], axis=-2)
 
 
-def delta_rule_chunked(q, k, v, g, beta, state=None, chunk=CHUNK):
+def delta_rule_chunked(q, k, v, g, beta, state=None, chunk=CHUNK, impl="xla",
+                       interpret=False):
     """:func:`delta_rule_recurrent`'s numbers, ``chunk`` tokens at a time.
-    Shapes as there; S need not be a multiple of ``chunk`` (the pad writes
-    nothing: beta 0, g 0). A token with ``beta`` 0 and ``g`` 0 leaves the
-    state as it was, which is how a caller masks the positions past a
-    prompt's length."""
+    Shapes as there, but q and k may come with fewer heads than v (a key head
+    serves ``HV / HK`` value heads in a row); S need not be a multiple of
+    ``chunk`` (the pad writes nothing: beta 0, g 0). A token with ``beta`` 0
+    and ``g`` 0 leaves the state as it was, which is how a caller masks the
+    positions past a prompt's length. ``impl`` ``"pallas"`` is the
+    ``gdn_prefill`` kernel where :func:`chunked_form` says it fits, else —
+    and by default — the XLA einsums below: one algorithm, one precision."""
     f32 = jnp.float32
-    s, h, dk = q.shape
-    dv = v.shape[2]
+    s, h, dv = v.shape
+    dk = q.shape[2]
     n = -(-s // chunk)
     pad = n * chunk - s
+    if state is None:
+        state = jnp.zeros((h, dk, dv), f32)
+    if chunked_form(dk, dv, chunk, impl, interpret) == "gdn_prefill":
+        def flat(a):       # (S, H, d) -> (S + pad, H d): the rows as they lie
+            return jnp.pad(a.astype(f32).reshape(s, -1), ((0, pad), (0, 0)))
+
+        o, state = _gdn_prefill(flat(q), flat(k), flat(v), flat(g),
+                                flat(beta), state.astype(f32), interpret)
+        return o[:s].reshape(s, h, dv), state
+    if q.shape[1] != h:
+        q, k = (jnp.repeat(a, h // a.shape[1], axis=1) for a in (q, k))
 
     def chunks(a):     # (S, H, ...) -> (H, n, chunk, ...)
         a = jnp.pad(a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
@@ -166,8 +184,6 @@ def delta_rule_chunked(q, k, v, g, beta, state=None, chunk=CHUNK):
     last = gc[..., -1:]                                       # (H, n, 1)
     q_in = q * jnp.exp(gc)[..., None]        # against the state coming in
     k_out = k * jnp.exp(last - gc)[..., None]  # into the state going out
-    if state is None:
-        state = jnp.zeros((h, dk, dv), f32)
 
     def one(st, xs):
         u_n, w_n, qk_n, q_n, k_n, last_n = xs
@@ -275,3 +291,175 @@ def _gdn_decode(states, layer, slot, q_t, k_t, v, decay, beta, interpret):
         name="gdn_decode",
     )(layer, slot, states, q_t, k_t, v, decay, beta)
     return o, states
+
+
+_SUB = 16           # rows of a diagonal block solved row by row
+
+
+def chunked_form(dk, dv, chunk=CHUNK, impl="xla", interpret=False):
+    """The form :func:`delta_rule_chunked` takes for heads ``dk`` x ``dv``:
+    ``"gdn_prefill"`` (the kernel) or ``"xla"``. The kernel wants ``impl``
+    ``"pallas"``, chunks of ``CHUNK`` and, compiled, a head that is whole
+    128-lane blocks of a ``(S, H d)`` row."""
+    lanes = interpret or (dk % 128 == 0 and dv % 128 == 0)
+    return ("gdn_prefill" if impl == "pallas" and chunk == CHUNK and lanes
+            else "xla")
+
+
+def _heads_a_step(hv, rep, most=4):
+    """Value heads a grid step: whole key heads' groups of ``rep``, at most
+    ``most`` where that divides ``hv``."""
+    fits = [n for n in range(rep, min(most, hv) + 1, rep) if hv % n == 0]
+    return fits[-1] if fits else rep
+
+
+def _gdn_prefill(q, k, v, g, beta, state, interpret):
+    """:func:`delta_rule_chunked` as ONE kernel. q, k ``(S, HK dk)``, v ``(S,
+    HV dv)`` — rows of heads side by side: a head is a column block, and
+    value head h reads key head ``h // (HV / HK)`` —, g, beta ``(S, HV)``,
+    state ``(HV, dk, dv)``; S a multiple of ``CHUNK``. Returns ``(o (S, HV
+    dv), state)``.
+
+    Grid (head groups, chunks), the chunks in order: a grid step holds one
+    chunk of ``_heads_a_step`` value heads; their state lives in the result's
+    VMEM block from the first chunk (copied in) to the last (written back),
+    and nothing chunk-sized but ``o`` goes to HBM. Per head and chunk, all
+    float32, every product at ``HIGHEST``: the running log decay (a
+    triangular matmul for all heads at once), ``M = strict(kb k^T . decay)``,
+    ``T = (I + M)^-1`` by substitution — the ``_SUB``-wide diagonal blocks
+    row by row on the VPU, the four of a chunk side by side in the lanes,
+    then block substitution ``P <- P - P M_under P`` for the blocks of 32
+    and of 64 (exact: ``P M_under`` squares to zero; no power of M is
+    formed) —, then ``v_new = T (vb - (kb e^gc) S)``, ``o = (q e^gc) S + (q
+    k^T . decay) v_new`` and ``S <- e^last S + (k e^(last - gc))^T v_new``:
+    the XLA form's ``u - w S`` with T taken out of the bracket, which is why
+    neither u nor w is made. The value heads of one key head share ``[k; q]
+    k^T``. The body runs stage by stage over the step's heads, not head by
+    head: Mosaic's schedule follows the source, and one head's chain of
+    dependent products leaves the MXU idle most of the time (1.67 -> 1.00 ms
+    a layer of 2,048 x 32 heads on a v5e; 0.73 with the lane gather below
+    and the products of the block substitution cut to the rows that are not
+    zero)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    s, hv = g.shape
+    dk, dv = state.shape[1:]
+    rep = hv // (q.shape[1] // dk)
+    hb = _heads_a_step(hv, rep)
+    c, nb = CHUNK, CHUNK // _SUB
+    heads = range(hb)
+
+    def dot(a, b, dims=((1,), (0,))):
+        return lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                               preferred_element_type=f32)
+
+    def odd(a, size):     # the rows of a's odd blocks of ``size`` rows
+        return jnp.concatenate([a[n * size:(n + 1) * size]
+                                for n in range(1, c // size, 2)], axis=0)
+
+    def spread(a, size):  # :func:`odd`'s rows back in place, zeros between
+        zero = jnp.zeros((size, a.shape[1]), f32)
+        return jnp.concatenate(
+            [part for n in range(0, a.shape[0], size)
+             for part in (zero, a[n:n + size])], axis=0)
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, o_ref, out_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            out_ref[...] = s_ref[...]
+
+        row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        lower, strict = row >= col, row > col
+        diagonal = row // _SUB == col // _SUB
+        head = (lax.broadcasted_iota(jnp.int32, (c, hv), 1)
+                - pl.program_id(0) * hb)
+
+        def column(a, i):      # head i's column of a (C, HV) array: (C, 1)
+            return jnp.sum(jnp.where(head == i, a, 0.0), axis=1,
+                           keepdims=True)
+
+        def keys(i, ref):      # head i's key head's block of q or k
+            j = i // rep
+            return ref[:, j * dk:(j + 1) * dk]
+
+        gc_all = dot(lower.astype(f32), g_ref[...])            # (C, HV)
+        gc = [column(gc_all, i) for i in heads]
+        b = [column(b_ref[...], i) for i in heads]
+        # [k; q] k^T of a key head, for its value heads: (2 C, C)
+        both = [dot(jnp.concatenate([keys(i, k_ref), keys(i, q_ref)], axis=0),
+                    keys(i, k_ref), ((1,), (1,))) for i in heads[::rep]]
+        decay = []
+        for i in heads:
+            # gc along the lanes: the same numbers, so that the diagonal of
+            # the decay is exp(0)
+            across = jnp.sum(jnp.where(row == col, gc[i], 0.0), axis=0,
+                             keepdims=True)
+            decay.append(jnp.where(
+                lower, jnp.exp(jnp.minimum(gc[i] - across, 0.0)), 0.0))
+        m = [jnp.where(strict, both[i // rep][:c] * b[i] * decay[i], 0.0)
+             for i in heads]
+        # what the state coming in gives the keys and the queries: (2 C, dv)
+        into = [jnp.exp(gc[i]) for i in heads]
+        read = [dot(jnp.concatenate(
+            [keys(i, k_ref) * (b[i] * into[i]), keys(i, q_ref) * into[i]],
+            axis=0), out_ref[i]) for i in heads]
+        # (I + M)^-1, the diagonal blocks first, side by side: (row in its
+        # block, block x column). x_r -= m_rj x_j for the rows r > j of
+        # every block (m_rj is 0 for the others): column j of each block
+        # across the block's lanes is ONE lane gather
+        sub_row = lax.broadcasted_iota(jnp.int32, (_SUB, c), 0)
+        sub_col = lax.broadcasted_iota(jnp.int32, (_SUB, c), 1)
+        inside = [jnp.where(diagonal, m[i], 0.0) for i in heads]
+        inside = [sum(a[n:n + _SUB] for n in range(0, c, _SUB))
+                  for a in inside]
+        x = [(sub_row == sub_col % _SUB).astype(f32) for _ in heads]
+        for j in range(_SUB - 1):
+            for i in heads:
+                x[i] = x[i] - x[i][j:j + 1] * jnp.take_along_axis(
+                    inside[i], sub_col // _SUB * _SUB + j, axis=1)
+        t = [jnp.where(diagonal, jnp.concatenate([a] * nb, axis=0), 0.0)
+             for a in x]
+        size = _SUB
+        while size < c:
+            # the blocks under the diagonal of every (2 size)-wide block
+            # fill the odd blocks of ``size`` rows: the products over those
+            under = ((row // (2 * size) == col // (2 * size))
+                     & (row // size == col // size + 1))
+            inner = [spread(dot(odd(jnp.where(under, m[i], 0.0), size), t[i]),
+                            size) for i in heads]
+            t = [t[i] - spread(dot(odd(t[i], size), inner[i]), size)
+                 for i in heads]
+            size *= 2
+        v_new = [dot(t[i], v_ref[:, i * dv:(i + 1) * dv] * b[i] - read[i][:c])
+                 for i in heads]
+        for i in heads:
+            o_ref[:, i * dv:(i + 1) * dv] = read[i][c:] + dot(
+                both[i // rep][c:] * decay[i], v_new[i])
+        for i in heads:
+            last = gc[i][c - 1:c]                                  # (1, 1)
+            out_ref[i] = out_ref[i] * jnp.exp(last) + dot(
+                keys(i, k_ref) * jnp.exp(last - gc[i]), v_new[i],
+                ((0,), (0,)))
+
+    def rows(width):
+        return pl.BlockSpec((c, width), lambda h, n: (n, h))
+
+    every = pl.BlockSpec((c, hv), lambda h, n: (n, 0))
+    held = pl.BlockSpec((hb, dk, dv), lambda h, n: (h, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(hv // hb, s // c),
+        in_specs=[rows(hb // rep * dk), rows(hb // rep * dk), rows(hb * dv),
+                  every, every, held],
+        out_specs=[rows(hb * dv), held],
+        out_shape=[jax.ShapeDtypeStruct((s, hv * dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=interpret,
+        name="gdn_prefill",
+    )(q, k, v, g, beta, state)

@@ -744,6 +744,11 @@ class DecodeEngine:
         out["moe_row_tile"] = describe and {
             "step": describe(self.slots),
             "prefill": {b: describe(b) for b in self.buckets}}
+        # the form the delta rule takes over a prompt or a piece of one
+        # (``gdn_prefill``, the kernel, or ``xla``): the model's to say
+        # (None for one with no such layers)
+        describe = getattr(self.model, "delta_rule", None)
+        out["delta_rule"] = describe and describe()
         out["pool"] = self.pool.stats()
         if self._progcache is not None:
             out["progcache"] = dict(self._progcache.stats,

@@ -1288,6 +1288,7 @@ def test_engine_through_the_grouped_kernel_serves_the_dense_tokens(
     assert eng.stats()["paged_kernel"] == {
         "page_group": group, "grid_steps": 2 * -(-8 // group) * 2}
     assert eng.stats()["moe_row_tile"] is None      # no routed experts
+    assert eng.stats()["delta_rule"] is None        # no delta layers
     eng.pool.assert_baseline()
 
 

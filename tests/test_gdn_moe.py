@@ -152,21 +152,47 @@ def _by_the_equations(q, k, v, g, beta, state):
     return np.stack(out), state
 
 
+def _chunked(impl, *args, **kwargs):
+    """The chunked rule in one of its two forms: XLA's einsums, or the
+    ``gdn_prefill`` kernel interpreted."""
+    return gated_delta.delta_rule_chunked(*args, impl=impl, interpret=True,
+                                          **kwargs)
+
+
+def test_the_form_follows_backend_and_shape():
+    """The kernel where the caller's backend runs Pallas kernels, chunks
+    are ``CHUNK`` and — compiled — a head is whole 128-lane blocks;
+    everything else is XLA's."""
+    form = gated_delta.chunked_form
+    assert form(128, 128, impl="pallas") == "gdn_prefill"
+    assert form(16, 8, impl="pallas", interpret=True) == "gdn_prefill"
+    assert form(128, 128) == form(128, 128, impl="xla") == "xla"
+    assert form(16, 8, impl="pallas") == form(128, 64, impl="pallas") == "xla"
+    assert form(128, 128, chunk=32, impl="pallas") == "xla"
+    assert gated_delta._heads_a_step(32, 2) == 4
+    assert gated_delta._heads_a_step(3, 1) == 3
+    assert gated_delta._heads_a_step(4, 8) == 8
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("length", [1, 63, 64, 150])
-def test_chunked_delta_rule_is_the_recurrence(length):
+def test_chunked_delta_rule_is_the_recurrence(length, impl):
     """Outputs and final state, at lengths off the chunk, from a state that
-    is not zero: the chunked form, the scanned recurrence and the equations
-    written out in float64 agree to float32 rounding."""
+    is not zero: the chunked form (XLA's and the kernel), the scanned
+    recurrence and the equations written out in float64 agree to float32
+    rounding."""
     q, k, v, g, beta = _delta_inputs(length)
     state = np.random.default_rng(9).normal(size=(3, 16, 8)).astype(np.float32)
     want_o, want_s = _by_the_equations(q, k, v, g, beta, state)
     o_r, s_r = gated_delta.delta_rule_recurrent(q, k, v, g, beta, state)
-    o_c, s_c = gated_delta.delta_rule_chunked(q, k, v, g, beta, state)
+    o_c, s_c = _chunked(impl, q, k, v, g, beta, state)
     for got_o, got_s in ((o_r, s_r), (o_c, s_c)):
         np.testing.assert_allclose(got_o, want_o, atol=2e-5)
         np.testing.assert_allclose(got_s, want_s, atol=2e-5)
-    # from no state, as a prefill starts; and a masked tail writes nothing
-    o0, s0 = gated_delta.delta_rule_chunked(q, k, v, g, beta, chunk=32)
+    # from no state, as a prefill starts (XLA's form in chunks of 32 too);
+    # and a masked tail writes nothing
+    chunk = 32 if impl == "xla" else gated_delta.CHUNK
+    o0, s0 = _chunked(impl, q, k, v, g, beta, chunk=chunk)
     want_o, want_s = _by_the_equations(q, k, v, g, beta,
                                        np.zeros_like(state))
     np.testing.assert_allclose(o0, want_o, atol=2e-5)
@@ -176,11 +202,12 @@ def test_chunked_delta_rule_is_the_recurrence(length):
               for a in (q, k, v)]
     dead = [np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)])
             for a in (g, beta)]
-    _, s_masked = gated_delta.delta_rule_chunked(*padded, *dead)
+    _, s_masked = _chunked(impl, *padded, *dead)
     np.testing.assert_allclose(s_masked, s0, atol=2e-5)
 
 
-def test_chunked_delta_rule_with_keys_that_repeat():
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_chunked_delta_rule_with_keys_that_repeat(impl):
     """Every key the same, beta 1, no decay: the chunk's triangular system
     is as far from the identity as it gets (a power series of it would
     cancel catastrophically). Forward substitution holds."""
@@ -192,9 +219,31 @@ def test_chunked_delta_rule_with_keys_that_repeat():
     g, beta = np.zeros((s, h), np.float32), np.ones((s, h), np.float32)
     want_o, want_s = _by_the_equations(k, k, v, g, beta,
                                        np.zeros((h, dk, dv)))
-    o, st = gated_delta.delta_rule_chunked(k, k, v, g, beta)
+    o, st = _chunked(impl, k, k, v, g, beta)
     np.testing.assert_allclose(o, want_o, atol=1e-4)
     np.testing.assert_allclose(st, want_s, atol=1e-4)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_chunked_delta_rule_at_lane_wide_heads(impl):
+    """The shape the chip runs: heads of 128 x 128, two value heads on ONE
+    key head — both in a grid step of the kernel, sharing ``[k; q] k^T`` —,
+    192 tokens (three chunks carry the state), the last 20 masked, from a
+    state that is not zero."""
+    s, hv, d = 192, 2, 128
+    q, k, _, _, _ = _delta_inputs(s, h=1, dk=d, seed=5)
+    _, _, v, g, beta = _delta_inputs(s, h=hv, dv=d, seed=6)
+    g[-20:], beta[-20:] = 0.0, 0.0
+    state = np.random.default_rng(7).normal(size=(hv, d, d)).astype(np.float32)
+    assert gated_delta._heads_a_step(hv, hv) == 2
+    want_o, want_s = _by_the_equations(
+        np.repeat(q, hv, axis=1), np.repeat(k, hv, axis=1), v, g, beta, state)
+    o, st = _chunked(impl, q, k, v, g, beta, state)
+    np.testing.assert_allclose(o, want_o, atol=5e-5)
+    np.testing.assert_allclose(st, want_s, atol=5e-5)
+    np.testing.assert_array_equal(st, _chunked(impl, q[:-20], k[:-20],
+                                               v[:-20], g[:-20], beta[:-20],
+                                               state)[1])
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -401,20 +450,30 @@ def test_a_prompt_in_pieces_is_the_prompt_whole(piece, length, dirty, params):
     assert np.all(np.isfinite(rows)) and np.all(np.isfinite(state["s"]))
 
 
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("piece,length", [(64, 150), (128, 200)])
 def test_a_prompt_in_pieces_is_the_recurrence_token_by_token(
-        piece, length, params, monkeypatch):
-    """The same pieces against a whole prefill whose delta layers run the
-    plain recurrence, one token after another."""
+        piece, length, impl, params, monkeypatch):
+    """The same pieces — their delta layers in XLA's chunked form, and in the
+    ``gdn_prefill`` kernel (interpreted; the model picks it as on a TPU) —
+    against a whole prefill whose delta layers run the plain recurrence, one
+    token after another."""
+    monkeypatch.setenv("MXNET_DECODE_ATTN", impl)
     model = gdn_moe.GDNMoEDecodeModel(CFG, params=f32(params))
+    assert model.delta_rule() == {"xla": "xla", "pallas": "gdn_prefill"}[impl]
     total = -(-length // piece) * piece
     tokens = np.zeros((1, total), np.int32)
     tokens[0, :length] = np.random.default_rng(length).integers(
         0, CFG["vocab_size"], length)
     logits, rows, state = _in_pieces(model, jnp.asarray(tokens), length,
                                      piece, True)
-    monkeypatch.setattr(gated_delta, "delta_rule_chunked",
-                        gated_delta.delta_rule_recurrent)
+
+    def recurrent(q, k, v, g, beta, state, **_):
+        q, k = (jnp.repeat(a, v.shape[1] // a.shape[1], axis=1)
+                for a in (q, k))
+        return gated_delta.delta_rule_recurrent(q, k, v, g, beta, state)
+
+    monkeypatch.setattr(gated_delta, "delta_rule_chunked", recurrent)
     want_logits, want_rows, _, want_state = jax.jit(
         lambda p, t: model.prefill(p, t, length))(model.params, tokens)
     np.testing.assert_allclose(logits, want_logits, atol=1e-4)
@@ -494,6 +553,7 @@ def test_engine_prefill_then_decode_through_pages_and_state(
     bfloat16: 0.03 absolute, as the latent model's test has it."""
     monkeypatch.setenv("MXNET_DECODE_ATTN", "pallas")   # interpreted kernels
     engine = _engine(params, dtype)
+    assert engine.stats()["delta_rule"] == "gdn_prefill"
     # 2 of 8 layers are paged; a row is k and v of the one cached head
     assert engine.kv.shape == (17, 2, PAGE, 32) and engine.kv.dtype == dtype
     assert engine.paged_layers == 2

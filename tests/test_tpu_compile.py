@@ -672,6 +672,41 @@ def test_tpu_prefill_program_hands_its_state_to_the_slot(gdn_engine, one_chip,
     assert not [line for line in lines if " copy(" in line], lines
 
 
+def test_tpu_piece_program_runs_the_delta_rule_as_one_kernel(
+        gdn_engine, one_chip, monkeypatch):
+    """The same program with the delta rule in its two forms. As on a TPU the
+    two delta layers run ``gdn_prefill`` — ONE Mosaic call, traced once under
+    the layers' scan — beside the flash forward and the held experts' two
+    (the step keeps its four kernels:
+    ``test_tpu_step_program_with_state_beside_pages``); no XLA chunk array
+    (``(heads, chunks, 64, ..)``) is left, and the program's temporaries stay
+    under a ceiling between the two forms' readings: 27,972,608 B with the
+    kernel, 74,715,136 B with XLA's einsums at this size (1,024 positions,
+    narrow experts; at the cell's own widths the held experts' rows set
+    ``temp_bytes``, ``PERF.md`` section 6)."""
+    from mxnet_tpu.ops import gated_delta
+
+    _as_on_a_tpu(monkeypatch)
+    assert gdn_engine.stats()["delta_rule"] == "gdn_prefill"
+    lowered, compiled = _tpu_program(_anew(gdn_engine), one_chip, "prefill",
+                                     monkeypatch)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert text.count("gdn_prefill") == 1
+    chunks = "f32[32,16,64,"          # (heads, chunks of a 1,024 piece, 64, ..)
+    assert chunks not in compiled.as_text()
+    kernel = progcache.analyze_compiled(compiled)["temp_bytes"]
+    monkeypatch.setattr(gated_delta, "chunked_form", lambda *a, **k: "xla")
+    assert gdn_engine.stats()["delta_rule"] == "xla"
+    lowered, compiled = _tpu_program(_anew(gdn_engine), one_chip, "prefill",
+                                     monkeypatch)
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    assert chunks in compiled.as_text()
+    einsums = progcache.analyze_compiled(compiled)["temp_bytes"]
+    print(f"piece temp_bytes: gdn_prefill {kernel}, XLA {einsums}")
+    assert kernel < 48 * 2 ** 20 < einsums, (kernel, einsums)
+
+
 # -- the held experts' rows over a prompt, at the cells' own geometry -----------
 
 def _cell_engine(config, bucket, slots=2, buckets=None):
