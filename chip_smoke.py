@@ -772,39 +772,52 @@ def _delta_rule_over_a_piece():
     beta = jnp.where(live, jax.random.uniform(keys[4], (s, hv)), 0.0)
     state = jax.random.normal(keys[5], (hv, d, d), jnp.float32)
     rep = hv // hk
-    want_o, want_s = jax.jit(gated_delta.delta_rule_recurrent)(
+    want = jax.jit(gated_delta.delta_rule_recurrent)(
         jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1), v, g, beta,
         state)
+    _chunked_forms_against_the_recurrence(
+        "the delta rule over a 2,048-position piece (32 x 128 x 128, 100 "
+        "masked)", gated_delta.delta_rule_chunked, "gdn_prefill",
+        (q, k, v, g, beta, state), want)
+
+
+def _chunked_forms_against_the_recurrence(what, chunked, kernel, args, want):
+    """A chunked rule ``chunked(*args, impl=)`` in its two forms — ``impl``
+    ``"pallas"`` through the Mosaic kernel named ``kernel``, and XLA —
+    against the recurrence's ``want`` (outputs, state): both finite, the
+    kernel's error at most twice the XLA form's own, and what each form
+    takes a layer from ``(S, H, d)`` arrays (the relayouts around the call
+    included), printed."""
+    import jax
+    import jax.numpy as jnp
+
     forms, errors = {}, {}
     for impl in ("xla", "pallas"):
-        forms[impl] = jax.jit(functools.partial(
-            gated_delta.delta_rule_chunked, impl=impl))
-        got_o, got_s = forms[impl](q, k, v, g, beta, state)
-        _require(bool(jnp.all(jnp.isfinite(got_o)))
-                 and bool(jnp.all(jnp.isfinite(got_s))),
-                 f"the chunked delta rule ({impl}): non-finite values")
-        errors[impl] = (_rel_err(got_o, want_o), _rel_err(got_s, want_s))
-    text = forms["pallas"].lower(q, k, v, g, beta, state).as_text()
-    _require(_MOSAIC in text and "gdn_prefill" in text,
-             "the chunked rule lowered without the gdn_prefill Mosaic call")
+        forms[impl] = jax.jit(functools.partial(chunked, impl=impl))
+        got = forms[impl](*args)
+        _require(all(bool(jnp.all(jnp.isfinite(a))) for a in got),
+                 f"{what} ({impl}): non-finite values")
+        errors[impl] = tuple(_rel_err(a, b) for a, b in zip(got, want))
+    text = forms["pallas"].lower(*args).as_text()
+    _require(_MOSAIC in text and kernel in text,
+             f"{what} lowered without the {kernel} Mosaic call")
     took = {}
     for impl, form in forms.items():
         t0 = time.monotonic()
         for _ in range(20):
-            out = form(q, k, v, g, beta, state)
+            out = form(*args)
         jax.block_until_ready(out)
         took[impl] = (time.monotonic() - t0) * 50
-    print(f"   the delta rule over a 2,048-position piece (32 x 128 x 128, "
-          f"100 masked) against the recurrence: gdn_prefill rel err "
+    print(f"   {what} against the recurrence: {kernel} rel err "
           f"{errors['pallas'][0]:.2e} outputs, {errors['pallas'][1]:.2e} "
           f"state, {took['pallas']:.3f} ms a layer; XLA chunked "
           f"{errors['xla'][0]:.2e}, {errors['xla'][1]:.2e}, "
           f"{took['xla']:.3f} ms a layer", flush=True)
-    for what, got, own in zip(("outputs", "state"), errors["pallas"],
+    for part, got, own in zip(("outputs", "state"), errors["pallas"],
                               errors["xla"]):
         _require(got <= 2 * max(own, 1e-6),
-                 f"gdn_prefill's {what} are {got:.2e} from the recurrence, "
-                 f"the XLA form's {own:.2e}")
+                 f"{kernel}'s {part} are {got:.2e} from the recurrence, the "
+                 f"XLA form's {own:.2e}")
 
 
 def phase_state():
@@ -1392,14 +1405,48 @@ def phase_swa():
                  f"copies the pool or the rings (one ring {ring} bytes)")
 
 
+def _channel_decay_rule_over_a_piece():
+    """``kda_prefill`` at the published geometry — a 2,048-position piece of
+    32 heads of 128 x 128, from a random state, a whole chunk at the -5
+    bound among the others, the last 100 positions masked — through Mosaic,
+    against the token-by-token recurrence and against the XLA chunked form:
+    the kernel's error against the recurrence at most twice the XLA form's
+    own, and what each form takes a layer from ``(S, H, d)`` arrays, the
+    relayouts around the call included."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import kda
+
+    s, h, d, dead = 2048, 32, 128, 100
+    keys = jax.random.split(jax.random.PRNGKey(23), 6)
+    q, k = (jax.random.normal(key, (s, h, d), jnp.float32)
+            for key in keys[:2])
+    q, k = (a / jnp.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
+    q = q * d ** -0.5
+    v = jax.random.normal(keys[2], (s, h, d), jnp.float32)
+    live = (jnp.arange(s) < s - dead)[:, None]
+    g = -5.0 * jax.random.uniform(keys[3], (s, h, d)) ** 3
+    g = jnp.where(live[..., None], g.at[640:704].set(-5.0), 0.0)
+    beta = jnp.where(live, jax.random.uniform(keys[4], (s, h)), 0.0)
+    state = jax.random.normal(keys[5], (h, d, d), jnp.float32)
+    _chunked_forms_against_the_recurrence(
+        "the channel-decay rule over a 2,048-position piece (32 x 128 x 128, "
+        "a chunk at -5, 100 masked)", kda.kda_chunked, "kda_prefill",
+        (q, k, v, g, beta, state),
+        jax.jit(kda.kda_recurrent)(q, k, v, g, beta, state))
+
+
 def phase_kda():
     """The one-token delta rule with a decay per key channel at the published
     head geometry (32 heads of 128 x 128, float32 state): the ``kda_decode``
     kernel through Mosaic and its XLA twin against the token-by-token
     recurrence at 2, 32 and 128 slots, a dead slot's state untouched, the
     other layer of the array untouched, in memory filled with NaN first;
-    and a 200-token prompt through the chunked form (log decays at the -5
-    bound among them) against the recurrence. Printed and held."""
+    a 200-token prompt through the chunked form (log decays at the -5
+    bound among them) against the recurrence; and a 2,048-position piece
+    through the ``kda_prefill`` kernel
+    (:func:`_channel_decay_rule_over_a_piece`). Printed and held."""
     import jax
     import jax.numpy as jnp
 
@@ -1475,6 +1522,7 @@ def phase_kda():
                  "the chunked rule is not finite at the -5 bound")
         _check_close("kda_chunked over 200 tokens: outputs", o, want_o, 1e-4)
         _check_close("kda_chunked over 200 tokens: state", s1, want_s, 1e-4)
+        _channel_decay_rule_over_a_piece()
 
 
 def _dirty_memory():

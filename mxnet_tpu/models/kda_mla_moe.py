@@ -38,8 +38,10 @@ returns them updated in place.
   arrays of their own (no program slices a layer out of a stack). A piece is
   positions ``start .. start + C - 1``, C a multiple of the rule's chunk: a
   KDA layer goes on from the state and tail the piece before left
-  (``ops.kda.kda_chunked``, positions past the prompt masked out of the
-  state), an MLA layer reads the pool's rows before the piece
+  (``ops.kda.kda_chunked``: the ``kda_prefill`` kernel on a TPU at
+  lane-wide heads, XLA einsums elsewhere; ``delta_rule()`` says which;
+  positions past the prompt masked out of the state), an MLA layer reads
+  the pool's rows before the piece
   (``mla_moe.prefill_attention_from``).
 - **step**: one token a slot; ``ops.kda.kda_step`` (the ``kda_decode``
   kernel: one read and one write of each live slot's state in place) and
@@ -493,7 +495,9 @@ class KDAMLAMoEDecodeModel:
                 # a position past the prompt writes nothing into the state
                 g = jnp.where(live[:, None, None], g, 0.0)
                 beta = jnp.where(live[:, None], beta, 0.0)
-                o, s1 = kda.kda_chunked(q, k, v, g, beta, s0)
+                o, s1 = kda.kda_chunked(
+                    q, k, v, g, beta, s0, impl=decode_attention_impl(),
+                    interpret=flash_attention._use_interpret())
                 x = _kda_output(cfg, lp, x, o, gate)
                 states.append(s1)
                 tails.append(tail.reshape(self.state["tail"][0][1:]))
@@ -549,6 +553,14 @@ class KDAMLAMoEDecodeModel:
         return latent_decode_attention(
             query, pool, layer, page_table, lengths, self.cfg["kv_rank"],
             mla_moe.softmax_scale(self.cfg))
+
+    def delta_rule(self):
+        """``DecodeEngine.stats()["delta_rule"]``: the form the delta rule
+        takes over a prompt in this process (``kda.chunked_form``)."""
+        return kda.chunked_form(
+            self.cfg["kda_key_dim"], self.cfg["kda_value_dim"],
+            impl=decode_attention_impl(),
+            interpret=flash_attention._use_interpret())
 
     def moe_row_tile(self, tokens):
         """``DecodeEngine.stats()["moe_row_tile"]``: the row tile the held

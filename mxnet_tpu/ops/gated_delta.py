@@ -313,6 +313,66 @@ def _heads_a_step(hv, rep, most=4):
     return fits[-1] if fits else rep
 
 
+def _dot(a, b, dims=((1,), (0,))):
+    """A kernel body's float32 product at ``HIGHEST``, ``dims`` the
+    contracted axes of a and of b."""
+    return lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                           preferred_element_type=jnp.float32)
+
+
+def _chunk_inverses(m, row, col, diagonal):
+    """Inside a kernel body (``gdn_prefill``, ``ops/kda.py``'s
+    ``kda_prefill``): ``(I + M)^-1`` for every M of the list ``m`` — one a
+    head of the grid step, (CHUNK, CHUNK), strictly lower triangular —, stage
+    by stage over the list. ``row``, ``col``: the (CHUNK, CHUNK) iotas,
+    ``diagonal``: ``row // _SUB == col // _SUB``. The ``_SUB``-wide diagonal
+    blocks row by row on the VPU, the four of a chunk side by side in the
+    lanes, then block substitution ``P <- P - P M_under P`` for the blocks of
+    32 and of 64 (exact: ``P M_under`` squares to zero; no power of M is
+    formed)."""
+    f32 = jnp.float32
+    c, nb = CHUNK, CHUNK // _SUB
+    heads = range(len(m))
+
+    def odd(a, size):     # the rows of a's odd blocks of ``size`` rows
+        return jnp.concatenate([a[n * size:(n + 1) * size]
+                                for n in range(1, c // size, 2)], axis=0)
+
+    def spread(a, size):  # :func:`odd`'s rows back in place, zeros between
+        zero = jnp.zeros((size, a.shape[1]), f32)
+        return jnp.concatenate(
+            [part for n in range(0, a.shape[0], size)
+             for part in (zero, a[n:n + size])], axis=0)
+
+    # the diagonal blocks first, side by side: (row in its block, block x
+    # column). x_r -= m_rj x_j for the rows r > j of every block (m_rj is 0
+    # for the others): column j of each block across the block's lanes is
+    # ONE lane gather
+    sub_row = lax.broadcasted_iota(jnp.int32, (_SUB, c), 0)
+    sub_col = lax.broadcasted_iota(jnp.int32, (_SUB, c), 1)
+    inside = [jnp.where(diagonal, m[i], 0.0) for i in heads]
+    inside = [sum(a[n:n + _SUB] for n in range(0, c, _SUB)) for a in inside]
+    x = [(sub_row == sub_col % _SUB).astype(f32) for _ in heads]
+    for j in range(_SUB - 1):
+        for i in heads:
+            x[i] = x[i] - x[i][j:j + 1] * jnp.take_along_axis(
+                inside[i], sub_col // _SUB * _SUB + j, axis=1)
+    t = [jnp.where(diagonal, jnp.concatenate([a] * nb, axis=0), 0.0)
+         for a in x]
+    size = _SUB
+    while size < c:
+        # the blocks under the diagonal of every (2 size)-wide block fill
+        # the odd blocks of ``size`` rows: the products over those
+        under = ((row // (2 * size) == col // (2 * size))
+                 & (row // size == col // size + 1))
+        inner = [spread(_dot(odd(jnp.where(under, m[i], 0.0), size), t[i]),
+                        size) for i in heads]
+        t = [t[i] - spread(_dot(odd(t[i], size), inner[i]), size)
+             for i in heads]
+        size *= 2
+    return t
+
+
 def _gdn_prefill(q, k, v, g, beta, state, interpret):
     """:func:`delta_rule_chunked` as ONE kernel. q, k ``(S, HK dk)``, v ``(S,
     HV dv)`` — rows of heads side by side: a head is a column block, and
@@ -348,22 +408,8 @@ def _gdn_prefill(q, k, v, g, beta, state, interpret):
     dk, dv = state.shape[1:]
     rep = hv // (q.shape[1] // dk)
     hb = _heads_a_step(hv, rep)
-    c, nb = CHUNK, CHUNK // _SUB
+    c = CHUNK
     heads = range(hb)
-
-    def dot(a, b, dims=((1,), (0,))):
-        return lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
-                               preferred_element_type=f32)
-
-    def odd(a, size):     # the rows of a's odd blocks of ``size`` rows
-        return jnp.concatenate([a[n * size:(n + 1) * size]
-                                for n in range(1, c // size, 2)], axis=0)
-
-    def spread(a, size):  # :func:`odd`'s rows back in place, zeros between
-        zero = jnp.zeros((size, a.shape[1]), f32)
-        return jnp.concatenate(
-            [part for n in range(0, a.shape[0], size)
-             for part in (zero, a[n:n + size])], axis=0)
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, o_ref, out_ref):
         @pl.when(pl.program_id(1) == 0)
@@ -385,11 +431,11 @@ def _gdn_prefill(q, k, v, g, beta, state, interpret):
             j = i // rep
             return ref[:, j * dk:(j + 1) * dk]
 
-        gc_all = dot(lower.astype(f32), g_ref[...])            # (C, HV)
+        gc_all = _dot(lower.astype(f32), g_ref[...])            # (C, HV)
         gc = [column(gc_all, i) for i in heads]
         b = [column(b_ref[...], i) for i in heads]
         # [k; q] k^T of a key head, for its value heads: (2 C, C)
-        both = [dot(jnp.concatenate([keys(i, k_ref), keys(i, q_ref)], axis=0),
+        both = [_dot(jnp.concatenate([keys(i, k_ref), keys(i, q_ref)], axis=0),
                     keys(i, k_ref), ((1,), (1,))) for i in heads[::rep]]
         decay = []
         for i in heads:
@@ -403,44 +449,18 @@ def _gdn_prefill(q, k, v, g, beta, state, interpret):
              for i in heads]
         # what the state coming in gives the keys and the queries: (2 C, dv)
         into = [jnp.exp(gc[i]) for i in heads]
-        read = [dot(jnp.concatenate(
+        read = [_dot(jnp.concatenate(
             [keys(i, k_ref) * (b[i] * into[i]), keys(i, q_ref) * into[i]],
             axis=0), out_ref[i]) for i in heads]
-        # (I + M)^-1, the diagonal blocks first, side by side: (row in its
-        # block, block x column). x_r -= m_rj x_j for the rows r > j of
-        # every block (m_rj is 0 for the others): column j of each block
-        # across the block's lanes is ONE lane gather
-        sub_row = lax.broadcasted_iota(jnp.int32, (_SUB, c), 0)
-        sub_col = lax.broadcasted_iota(jnp.int32, (_SUB, c), 1)
-        inside = [jnp.where(diagonal, m[i], 0.0) for i in heads]
-        inside = [sum(a[n:n + _SUB] for n in range(0, c, _SUB))
-                  for a in inside]
-        x = [(sub_row == sub_col % _SUB).astype(f32) for _ in heads]
-        for j in range(_SUB - 1):
-            for i in heads:
-                x[i] = x[i] - x[i][j:j + 1] * jnp.take_along_axis(
-                    inside[i], sub_col // _SUB * _SUB + j, axis=1)
-        t = [jnp.where(diagonal, jnp.concatenate([a] * nb, axis=0), 0.0)
-             for a in x]
-        size = _SUB
-        while size < c:
-            # the blocks under the diagonal of every (2 size)-wide block
-            # fill the odd blocks of ``size`` rows: the products over those
-            under = ((row // (2 * size) == col // (2 * size))
-                     & (row // size == col // size + 1))
-            inner = [spread(dot(odd(jnp.where(under, m[i], 0.0), size), t[i]),
-                            size) for i in heads]
-            t = [t[i] - spread(dot(odd(t[i], size), inner[i]), size)
-                 for i in heads]
-            size *= 2
-        v_new = [dot(t[i], v_ref[:, i * dv:(i + 1) * dv] * b[i] - read[i][:c])
+        t = _chunk_inverses(m, row, col, diagonal)
+        v_new = [_dot(t[i], v_ref[:, i * dv:(i + 1) * dv] * b[i] - read[i][:c])
                  for i in heads]
         for i in heads:
-            o_ref[:, i * dv:(i + 1) * dv] = read[i][c:] + dot(
+            o_ref[:, i * dv:(i + 1) * dv] = read[i][c:] + _dot(
                 both[i // rep][c:] * decay[i], v_new[i])
         for i in heads:
             last = gc[i][c - 1:c]                                  # (1, 1)
-            out_ref[i] = out_ref[i] * jnp.exp(last) + dot(
+            out_ref[i] = out_ref[i] * jnp.exp(last) + _dot(
                 keys(i, k_ref) * jnp.exp(last - gc[i]), v_new[i],
                 ((0,), (0,)))
 

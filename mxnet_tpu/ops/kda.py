@@ -27,8 +27,12 @@ Per head, with a state ``S`` (key x value, float32) and per token a query
   block (i, j in one sub-chunk) R is its middle: exponents within +-8 |g|,
   +-40 at the family's bound ``g > -5``, far inside float32 on both sides
   (which needs ``g >= -5.5``: the model's gate sees to that).
-  The triangular solve is ``gated_delta``'s; the chunks follow one another
-  under a scan, state in, state out. XLA, float32.
+  The triangular solve is ``gated_delta``'s; the chunks follow one another,
+  state in, state out. Float32 at ``HIGHEST`` in both of its forms: XLA
+  einsums under a scan, and where backend and shapes allow
+  (:func:`chunked_form`) ONE Pallas kernel (``kda_prefill``) that keeps a
+  chunk's blocks, its triangular system and the heads' state in VMEM and
+  writes nothing chunk-sized to HBM but the outputs.
 - :func:`kda_step` — one token for every slot of a decode batch, as a Pallas
   kernel (``kda_decode``): the state array of every slot and layer is operand
   and result **in place**, a grid step reads and writes one slot's state of
@@ -41,14 +45,17 @@ caller masks the positions past a prompt's length.
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import gated_delta
 from .gated_delta import CHUNK, _unit_lower_inverse
 
-__all__ = ["CHUNK", "SUB", "kda_recurrent", "kda_chunked", "kda_step"]
+__all__ = ["CHUNK", "SUB", "chunked_form", "kda_recurrent", "kda_chunked",
+           "kda_step"]
 
 SUB = 16            # tokens of a sub-chunk: 16 x 5 = 80 < ln(float32 max) = 88
 _HI = lax.Precision.HIGHEST
@@ -122,10 +129,22 @@ def _decayed_products(rows, k, g):
     return jnp.concatenate(out, axis=-2), gc
 
 
-def kda_chunked(q, k, v, g, beta, state=None, chunk=CHUNK):
+def chunked_form(dk, dv, chunk=CHUNK, impl="xla", interpret=False):
+    """The form :func:`kda_chunked` takes for heads ``dk`` x ``dv``:
+    ``"kda_prefill"`` (the kernel) or ``"xla"`` — the kernel wherever
+    ``gated_delta.chunked_form`` picks that rule's."""
+    fits = gated_delta.chunked_form(dk, dv, chunk, impl, interpret) != "xla"
+    return "kda_prefill" if fits else "xla"
+
+
+def kda_chunked(q, k, v, g, beta, state=None, chunk=CHUNK, impl="xla",
+                interpret=False):
     """:func:`kda_recurrent`'s numbers, ``chunk`` tokens at a time (a
     multiple of ``SUB``). Shapes as there; S need not be a multiple of
-    ``chunk`` (the pad writes nothing: beta 0, g 0)."""
+    ``chunk`` (the pad writes nothing: beta 0, g 0). ``impl`` ``"pallas"``
+    is the ``kda_prefill`` kernel where :func:`chunked_form` says it fits,
+    else — and by default — the XLA einsums below: one algorithm, one
+    precision."""
     f32 = jnp.float32
     s, h, dk = q.shape
     dv = v.shape[2]
@@ -133,6 +152,15 @@ def kda_chunked(q, k, v, g, beta, state=None, chunk=CHUNK):
         raise ValueError(f"a chunk of {chunk} is not whole sub-chunks of {SUB}")
     n = -(-s // chunk)
     pad = n * chunk - s
+    if state is None:
+        state = jnp.zeros((h, dk, dv), f32)
+    if chunked_form(dk, dv, chunk, impl, interpret) == "kda_prefill":
+        def flat(a):       # (S, H, d) -> (S + pad, H d): the rows as they lie
+            return jnp.pad(a.astype(f32).reshape(s, -1), ((0, pad), (0, 0)))
+
+        o, state = _kda_prefill(flat(q), flat(k), flat(v), flat(g),
+                                flat(beta), state.astype(f32), interpret)
+        return o[:s].reshape(s, h, dv), state
 
     def chunks(a):     # (S, H, ...) -> (H, n, chunk, ...)
         a = jnp.pad(a.astype(f32), ((0, pad),) + ((0, 0),) * (a.ndim - 1))
@@ -150,8 +178,6 @@ def kda_chunked(q, k, v, g, beta, state=None, chunk=CHUNK):
     last = gc[..., -1, :]                                     # (H, n, dk)
     q_in = q * jnp.exp(gc)                   # against the state coming in
     k_out = k * jnp.exp(last[..., None, :] - gc)   # into the state going out
-    if state is None:
-        state = jnp.zeros((h, dk, dv), f32)
 
     def one(st, xs):
         u_n, w_n, qk_n, q_n, k_n, last_n = xs
@@ -258,3 +284,134 @@ def _kda_decode(states, layer, slot, q_t, k_t, a_t, v, beta, interpret):
         name="kda_decode",
     )(layer, slot, states, q_t, k_t, a_t, v, beta)
     return o, states
+
+
+@functools.partial(jax.jit, static_argnums=(6,))
+def _kda_prefill(q, k, v, g, beta, state, interpret):
+    """:func:`kda_chunked` as ONE kernel (a program of its own: a model
+    that unrolls its layers lowers the body once, not once a layer). q, k, g
+    ``(S, H dk)``, v ``(S, H dv)`` — rows of heads side by side: a head is a
+    column block —, beta ``(S, H)``, state ``(H, dk, dv)``; S a multiple of
+    ``CHUNK``. Returns ``(o (S, H dv), state)``.
+
+    ``gated_delta._gdn_prefill``'s plan — grid (head groups, chunks), the
+    chunks in order, the step's heads' state in the result's VMEM block from
+    the first chunk to the last, ``T = (I + M)^-1`` by the same substitution
+    (``gated_delta._chunk_inverses``), ``v_new = T (vb - (kb e^G) S)``, ``o =
+    (q e^G) S + QK v_new``, ``S <- Diag(e^last) S + (k e^(last - G))^T
+    v_new``, the body stage by stage over the step's heads — with the decay
+    a (C, dk) array INSIDE the products. ``M`` and ``QK`` are
+    :func:`_decayed_products`' blocks, k's and q's rows of a head stacked
+    ((2 C, dk)) so that a pushed weight tile serves both: the four diagonal
+    blocks out of ONE product about the sub-chunks' middles, and for each
+    sub-chunk a > 0 its 2 x ``SUB`` rows ``e^(G - R_a)`` against the keys
+    ``e^(min(R_a - G, 0))`` about R_a where it starts (the keys from a on
+    are columns nobody reads: the blocks above the diagonal). The running
+    sum is made inside the sub-chunks (a block-triangular matmul for all
+    heads at once) and the totals added on. Float32, every product at
+    ``HIGHEST``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    s, h = beta.shape
+    dk, dv = state.shape[1:]
+    hb = gated_delta._heads_a_step(h, 1)
+    c, nb = CHUNK, CHUNK // SUB
+    heads = range(hb)
+    dot = gated_delta._dot
+
+    def per_sub(rows):    # nb rows (1, dk), each over its sub-chunk: (C, dk)
+        return jnp.concatenate(
+            [jnp.broadcast_to(r, (SUB, r.shape[1])) for r in rows], axis=0)
+
+    def twice(a):         # a factor for [k; q]
+        return jnp.concatenate([a, a], axis=0)
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, o_ref, out_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            out_ref[...] = s_ref[...]
+
+        row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        lower, strict = row >= col, row > col
+        diagonal = row // SUB == col // SUB
+        head = (lax.broadcasted_iota(jnp.int32, (c, h), 1)
+                - pl.program_id(0) * hb)
+        channel = (lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+                   == lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+
+        b = [jnp.sum(jnp.where(head == i, b_ref[...], 0.0), axis=1,
+                     keepdims=True) for i in heads]            # (C, 1)
+        ks = [k_ref[:, i * dk:(i + 1) * dk] for i in heads]
+        kq = [jnp.concatenate([ks[i], q_ref[:, i * dk:(i + 1) * dk]], axis=0)
+              for i in heads]                                  # (2 C, dk)
+        # the log decays summed inside the sub-chunks: in [-80, 0]
+        # (a product, not a log-step scan of shifted adds: rows whose later
+        # decays are 0 — a prompt's masked end — must sum to the SAME number,
+        # and a scan groups their terms differently, an ulp of G apart)
+        local_all = dot((lower & diagonal).astype(f32), g_ref[...])
+        local = [local_all[:, i * dk:(i + 1) * dk] for i in heads]
+        total = [[a[n + SUB - 1:n + SUB] for n in range(0, c, SUB)]
+                 for a in local]
+        # where each sub-chunk starts and, last, where the chunk ends
+        ref = [list(itertools.accumulate(t, initial=jnp.zeros((1, dk), f32)))
+               for t in total]
+        gc = [local[i] + per_sub(ref[i][:nb]) for i in heads]
+        # diagonal blocks about the sub-chunk's middle: exponents within
+        # +-8 |g|
+        mid = [a - per_sub([a[n + SUB // 2 - 1:n + SUB // 2]
+                            for n in range(0, c, SUB)]) for a in local]
+        diag = [dot(kq[i] * twice(jnp.exp(mid[i])), ks[i] * jnp.exp(-mid[i]),
+                    ((1,), (1,))) for i in heads]              # (2 C, C)
+        # under the diagonal, about where the rows' sub-chunk starts
+        rows_in = [kq[i] * twice(jnp.exp(local[i])) for i in heads]
+        under = [[jnp.zeros((2 * SUB, c), f32)] for _ in heads]
+        for a in range(1, nb):
+            for i in heads:
+                before = ks[i] * jnp.exp(jnp.minimum(ref[i][a] - gc[i], 0.0))
+                mine = jnp.concatenate(
+                    [rows_in[i][a * SUB:(a + 1) * SUB],
+                     rows_in[i][c + a * SUB:c + (a + 1) * SUB]], axis=0)
+                under[i].append(dot(mine, before, ((1,), (1,))))
+        kk = [jnp.where(diagonal, diag[i][:c], jnp.concatenate(
+            [u[:SUB] for u in under[i]], axis=0)) for i in heads]
+        qk = [jnp.where(lower, jnp.where(diagonal, diag[i][c:], jnp.concatenate(
+            [u[SUB:] for u in under[i]], axis=0)), 0.0) for i in heads]
+        m = [jnp.where(strict, kk[i] * b[i], 0.0) for i in heads]
+        # what the state coming in gives the keys and the queries: (2 C, dv)
+        into = [jnp.exp(a) for a in gc]
+        read = [dot(kq[i] * jnp.concatenate([b[i] * into[i], into[i]], axis=0),
+                    out_ref[i]) for i in heads]
+        t = gated_delta._chunk_inverses(m, row, col, diagonal)
+        v_new = [dot(t[i], v_ref[:, i * dv:(i + 1) * dv] * b[i] - read[i][:c])
+                 for i in heads]
+        for i in heads:
+            o_ref[:, i * dv:(i + 1) * dv] = read[i][c:] + dot(qk[i], v_new[i])
+        for i in heads:
+            last = ref[i][nb]                                      # (1, dk)
+            # the chunk's decay down the state's rows: last as a column
+            fade = jnp.exp(jnp.sum(jnp.where(channel, last, 0.0), axis=1,
+                                   keepdims=True))                 # (dk, 1)
+            out_ref[i] = out_ref[i] * fade + dot(
+                ks[i] * jnp.exp(last - gc[i]), v_new[i], ((0,), (0,)))
+
+    def rows(width):
+        return pl.BlockSpec((c, width), lambda j, n: (n, j))
+
+    held = pl.BlockSpec((hb, dk, dv), lambda j, n: (j, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(h // hb, s // c),
+        in_specs=[rows(hb * dk), rows(hb * dk), rows(hb * dv), rows(hb * dk),
+                  pl.BlockSpec((c, h), lambda j, n: (n, 0)), held],
+        out_specs=[rows(hb * dv), held],
+        out_shape=[jax.ShapeDtypeStruct((s, h * dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=interpret,
+        name="kda_prefill",
+    )(q, k, v, g, beta, state)

@@ -10,6 +10,7 @@ The mathematics is checked in float32 (the same bodies run on a float32
 tree), where the program must agree with the reference to rounding; the
 bfloat16 run is then held to a bfloat16-sized tolerance.
 """
+import functools
 import json
 import os
 import time
@@ -175,9 +176,18 @@ def _rule_inputs(seed, n, h=2, dk=16, dv=8, decays="mixed"):
             rng.standard_normal((h, dk, dv)).astype(np.float32))
 
 
+# the chunked rule's two forms (``kda.chunked_form``), the kernel interpreted
+# at 2 heads of 16 x 8; jitted once each, so that the cases of a length share
+# a program
+FORMS = {"xla": jax.jit(kda.kda_chunked),
+         "kda_prefill": jax.jit(functools.partial(
+             kda.kda_chunked, impl="pallas", interpret=True))}
+
+
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("decays", ["mixed", "uniform", "bound", "none"])
 @pytest.mark.parametrize("length", [1, 15, 37, 64, 100, 200])
-def test_chunked_rule_is_the_recurrence(length, decays):
+def test_chunked_rule_is_the_recurrence(length, decays, form):
     """``kda_chunked`` against ``kda_recurrent`` from a state that is not
     zero: lengths that are no multiple of the sub-chunk (16) or the chunk
     (64), decays spread over (-5, 0) by channel, no decay at all, and EVERY
@@ -186,13 +196,14 @@ def test_chunked_rule_is_the_recurrence(length, decays):
     of size ~1: float32 rounding through the solve."""
     q, k, v, g, beta, s0 = _rule_inputs(length, length, decays=decays)
     want_o, want_s = kda.kda_recurrent(q, k, v, g, beta, s0)
-    o, s1 = jax.jit(kda.kda_chunked)(q, k, v, g, beta, s0)
+    o, s1 = FORMS[form](q, k, v, g, beta, s0)
     assert np.all(np.isfinite(o)) and np.all(np.isfinite(s1))
     np.testing.assert_allclose(o, want_o, atol=5e-6)
     np.testing.assert_allclose(s1, want_s, atol=5e-6)
 
 
-def test_chunked_rule_with_one_chunk_at_the_bound_among_others():
+@pytest.mark.parametrize("form", list(FORMS))
+def test_chunked_rule_with_one_chunk_at_the_bound_among_others(form):
     """Tokens 64-127 — one whole chunk — decay every channel by e^-5 a
     token, the chunks around it hardly: the state that reaches token 128 is
     what the chunk wrote itself, and all of it finite."""
@@ -200,7 +211,7 @@ def test_chunked_rule_with_one_chunk_at_the_bound_among_others():
     g = np.array(g)
     g[64:128] = -5.0
     want_o, want_s = kda.kda_recurrent(q, k, v, g, beta, s0)
-    o, s1 = jax.jit(kda.kda_chunked)(q, k, v, g, beta, s0)
+    o, s1 = FORMS[form](q, k, v, g, beta, s0)
     assert np.all(np.isfinite(o))
     np.testing.assert_allclose(o, want_o, atol=5e-6)
     np.testing.assert_allclose(s1, want_s, atol=5e-6)
@@ -208,15 +219,16 @@ def test_chunked_rule_with_one_chunk_at_the_bound_among_others():
         kda.kda_chunked(q, k, v, g, beta, s0, chunk=24)
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("piece,length", [(16, 43), (64, 150), (128, 200)])
-def test_pieces_carrying_the_state_are_the_whole_prompt(piece, length):
+def test_pieces_carrying_the_state_are_the_whole_prompt(piece, length, form):
     """The state a piece hands the next is all the next needs: pieces of 16
     (under a chunk), 64 and 128, the last one ragged, against the whole
     prompt in one call and against the recurrence; a position masked (beta
     0, g 0) behind the prompt's end writes nothing."""
     q, k, v, g, beta, s0 = _rule_inputs(length + piece, length)
     want_o, want_s = kda.kda_recurrent(q, k, v, g, beta, s0)
-    chunked = jax.jit(kda.kda_chunked)
+    chunked = FORMS[form]
     whole_o, whole_s = chunked(q, k, v, g, beta, s0)
     state, outs = jnp.asarray(s0), []
     padded = -(-length // piece) * piece
@@ -239,6 +251,42 @@ def test_pieces_carrying_the_state_are_the_whole_prompt(piece, length):
     np.testing.assert_allclose(state, whole_s, atol=5e-6)
     np.testing.assert_allclose(got, want_o, atol=5e-6)
     np.testing.assert_allclose(state, want_s, atol=5e-6)
+
+
+def test_the_chunked_rules_form_follows_chunked_form(monkeypatch):
+    """``kda_chunked`` takes the kernel exactly where
+    ``gated_delta.chunked_form`` takes ``gdn_prefill``: ``impl`` pallas,
+    chunks of ``CHUNK``, and heads of whole 128-lane blocks or interpreted;
+    and it is ``kda.chunked_form`` the function asks, once a call."""
+    from mxnet_tpu.ops import gated_delta
+
+    assert kda.chunked_form(128, 128, impl="pallas") == "kda_prefill"
+    assert kda.chunked_form(16, 8, impl="pallas") == "xla"
+    assert kda.chunked_form(16, 8, impl="pallas",
+                            interpret=True) == "kda_prefill"
+    assert kda.chunked_form(128, 128, 32, "pallas") == "xla"
+    assert kda.chunked_form(128, 128) == "xla"
+    for args in ((128, 128, 64, "pallas", False), (128, 64, 64, "pallas", False),
+                 (16, 8, 64, "pallas", True), (128, 128, 32, "pallas", True),
+                 (128, 128, 64, "xla", True)):
+        assert (kda.chunked_form(*args) == "kda_prefill") == (
+            gated_delta.chunked_form(*args) == "gdn_prefill"), args
+    q, k, v, g, beta, s0 = _rule_inputs(3, 64)
+
+    def kernels(chunk, impl):
+        def fn(*args):
+            return kda.kda_chunked(*args, chunk=chunk, impl=impl,
+                                   interpret=True)
+        return str(jax.make_jaxpr(fn)(q, k, v, g, beta, s0)).count(
+            "pallas_call")
+
+    assert kernels(64, "pallas") == 1
+    assert kernels(32, "pallas") == 0 and kernels(64, "xla") == 0
+    asked, real = [], kda.chunked_form
+    monkeypatch.setattr(kda, "chunked_form",
+                        lambda *a, **kw: asked.append(a) or real(*a, **kw))
+    assert kernels(64, "pallas") == 1
+    assert asked == [(16, 8, 64, "pallas", True)]
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -538,8 +586,13 @@ def test_engine_prefill_then_decode_through_state_and_pages(
     other models' tests have 0.03; this one's logits are 2-3 times theirs
     (an embedding row is N(0,1); every KDA layer added a normed 0.6 when the
     tolerance was set, 0.08 since its output projection is drawn at an eighth)."""
+    assert _engine(params, dtype).stats()["delta_rule"] == "xla"
     monkeypatch.setenv("MXNET_DECODE_ATTN", "pallas")   # interpreted kernels
     engine = _engine(params, dtype)
+    # the pieces go through the kda_prefill kernel, the steps through
+    # kda_decode
+    assert engine.model.delta_rule() == "kda_prefill"
+    assert engine.stats()["delta_rule"] == "kda_prefill"
     assert engine.kv.shape == (25, N_MLA, PAGE, ROW) and engine.kv.dtype == dtype
     assert engine.paged_layers == N_MLA and engine.prefill_piece == PIECE
     stats = engine.stats()

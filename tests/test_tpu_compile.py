@@ -1469,8 +1469,9 @@ def test_tpu_kda_piece_program_at_the_cells_sizes(kda_engine, one_chip,
     whole page table of 16 pages in the packed array. The latent layers'
     continued forward goes through Mosaic (``mla_prefill_from``, once a
     latent layer) and reads the pool through the page table; the KDA layers
-    read nothing of it — the chunked rule is XLA: state in, state out —;
-    pool and state are donated."""
+    read nothing of it — the chunked rule is the ``kda_prefill`` kernel,
+    state in, state out: ONE Mosaic body (a jitted function, lowered once)
+    called once a KDA layer —; pool and state are donated."""
     import jax
 
     engine = kda_engine
@@ -1482,11 +1483,94 @@ def test_tpu_kda_piece_program_at_the_cells_sizes(kda_engine, one_chip,
     assert all(name in text for name in ("mla_prefill_from", "moe_rows",
                                          "moe_rows_back"))
     assert "kda_decode" not in text
+    assert engine.stats()["delta_rule"] == "kda_prefill"
+    assert text.count("call @_kda_prefill(") == len(engine.model.kda_layers)
+    assert text.count('kernel_name = "kda_prefill"') == 1
     donated = [info.donated for info in
                jax.tree_util.tree_leaves(lowered.args_info)]
     resident = len(jax.tree_util.tree_leaves(engine._params))
     assert donated[resident:resident + 3] == [True, True, True]
     assert not any(donated[:resident])
+
+
+def test_tpu_kda_piece_program_runs_the_rule_as_one_kernel(one_chip,
+                                                           monkeypatch):
+    """The piece program with the channel-decay rule in its two forms,
+    COMPILED for a v5e — at the published 32 heads of 128 x 128 but one KDA
+    layer (dense MLP) and one latent layer (experts) of narrow widths, 1,024
+    positions, because the XLA form of two layers alone compiles for 15 s.
+    As on a TPU the KDA layer is one ``kda_prefill`` Mosaic call and no
+    chunk array of the XLA form (``(heads, chunks, 64, ..)``) is left; the
+    kernel's program needs no more temporaries than the einsums' (at the
+    cell's own size 1,119,729,152 B against 1,367,903,232: the engine's
+    compile log on the chip, PERF.md section 6 PR 52)."""
+    import json
+    import os
+
+    import jax
+
+    from mxnet_tpu.models import kda_mla_moe
+    from mxnet_tpu.ops import kda
+
+    root = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark")
+    with open(os.path.join(root, "configs", "ling-3.0-flash-vl.json")) as f:
+        cfg = json.load(f)["model"]
+    cfg.pop("kind")
+    cfg.update(layers=[1, 5], num_layers=2, hidden_size=512, dense_width=512,
+               expert_width=128, vocab_size=1024, experts_held=8,
+               kv_rank=128, max_length=2048)
+    shapes = jax.eval_shape(lambda: kda_mla_moe.init_params(cfg, 0))
+    engine = DecodeEngine(kda_mla_moe.KDAMLAMoEDecodeModel(cfg, params=shapes),
+                          slots=2, page_size=256, num_pages=17,
+                          prompt_buckets=[1024])
+    _as_on_a_tpu(monkeypatch)
+
+    def compiled_piece():
+        _, compiled = _tpu_program(_anew(engine), one_chip, "prefill",
+                                   monkeypatch)
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and "kda_prefill" in line]
+        return (len(calls), "f32[32,16,64," in text,
+                progcache.analyze_compiled(compiled)["temp_bytes"])
+
+    assert engine.stats()["delta_rule"] == "kda_prefill"
+    calls, chunks, kernel = compiled_piece()
+    assert calls == len(engine.model.kda_layers) == 1 and not chunks
+    monkeypatch.setattr(kda, "chunked_form", lambda *a, **k: "xla")
+    assert engine.stats()["delta_rule"] == "xla"
+    calls, chunks, einsums = compiled_piece()
+    assert calls == 0 and chunks
+    print(f"piece temp_bytes: kda_prefill {kernel}, XLA {einsums}")
+    assert kernel <= einsums, (kernel, einsums)
+
+
+def test_gdn_prefill_traces_what_it_traced_before_the_solve_was_shared():
+    """``gdn_prefill``'s body calls ``gated_delta._chunk_inverses`` — the
+    substitution it shares with ``kda_prefill`` since PR 52 — where it had
+    the same lines inline: the traced program (every equation of the kernel
+    body, no path, no line) is the one the commit before made, by its hash —
+    taken there (b31d503) and here with the lines below, 4 value heads on 2
+    key heads of 128 x 128 over 128 tokens. A deliberate change to the
+    kernel moves it; so does another jax."""
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gated_delta
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    jaxpr = jax.jit(lambda *a: gated_delta._gdn_prefill(*a, False)).trace(
+        spec(128, 256), spec(128, 256), spec(128, 512), spec(128, 4),
+        spec(128, 4), spec(4, 128, 128)).jaxpr
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jaxpr))
+    assert "gdn_prefill" in text and len(text) > 50000
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "071917ce7b4d917089b536e271ef5527e12ee94ee939d8399aabcd897e1a0c7e")
 
 
 # What PR 50 gave the code these cells share — a head-wise gate as an optional
